@@ -193,4 +193,7 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from distributed_sgd_tpu import compile_cache
+
+    compile_cache.place()
     main()

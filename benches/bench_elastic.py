@@ -302,4 +302,7 @@ def main(smoke: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from distributed_sgd_tpu import compile_cache
+
+    compile_cache.place()
     main(smoke="--smoke" in sys.argv)

@@ -69,11 +69,10 @@ def log(msg: str) -> None:
 
 
 def _ensure_devices(n: int) -> None:
-    """An n-device virtual CPU mesh BEFORE the backend initializes: set
-    the env knobs first (they are read at backend creation), then — if an
-    ambient platform plugin already claimed the process — rebuild the
-    backend via the config API (`jax_num_cpu_devices` where this jax has
-    it; XLA_FLAGS re-parse otherwise), the dryrun_multichip approach."""
+    """An n-device virtual CPU mesh (this bench never runs on the chip):
+    the env knobs are read at backend creation, so set them first; if the
+    process already initialized a smaller backend, rebuild it through the
+    config API."""
     import os
 
     flags = os.environ.get("XLA_FLAGS", "")
@@ -89,10 +88,7 @@ def _ensure_devices(n: int) -> None:
         from jax.extend import backend as _jex_backend
 
         _jex_backend.clear_backends()
-        try:
-            jax.config.update("jax_num_cpu_devices", n)
-        except AttributeError:  # older jax: XLA_FLAGS re-parse path
-            pass
+        jax.config.update("jax_num_cpu_devices", n)
     assert len(jax.devices()) >= n, (
         f"need {n} devices, found {len(jax.devices())} — run under "
         f"XLA_FLAGS=--xla_force_host_platform_device_count={n}")
@@ -293,4 +289,7 @@ def main(smoke: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from distributed_sgd_tpu import compile_cache
+
+    compile_cache.place()
     main(smoke="--smoke" in sys.argv)

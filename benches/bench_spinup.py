@@ -10,12 +10,17 @@ Three claims, each hard-asserted every run (smoke and full):
    FRESH subprocess per configuration (in-process A/B would share jax's
    jit cache and measure nothing):
 
-   - ``knobsoff``: DSGD_COMPILE_CACHE unset — today's join (lazy JIT
-     under the first request, no warmup, no cache files);
+   - ``knobsoff``: the passive library, no ``compile_cache.place()`` —
+     lazy JIT under the first request, no warmup, no cache files;
    - ``cold``: cache dir EMPTY — the first-ever join, which pays every
      XLA compile and populates the shared cache;
    - ``warm``: same cache dir, now populated — every later join; the
      warmup's compiles are disk hits.
+
+   The children's cache is placed the way any process's is: through
+   ``JAX_COMPILATION_CACHE_DIR`` in their environment, pointing at the
+   fixed ``<checkout>/.jax_cache/spinup-ab`` (emptied before each cold
+   child).
 
    The clock starts after interpreter + jax import (identical in every
    configuration; including it would only dilute the ratio) and stops
@@ -47,6 +52,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -91,7 +97,8 @@ def _child(spec: dict) -> None:
 
     cache_dir = spec["cache_dir"]
     if cache_dir:
-        compile_cache.configure(cache_dir)
+        # the directory itself arrived as JAX_COMPILATION_CACHE_DIR
+        compile_cache.place(warmup=True)
     lo, hi = spec["slice"]
     t0 = time.perf_counter()
     # -- the joining worker's spin-up sequence (the measured region) -------
@@ -128,9 +135,14 @@ def _child(spec: dict) -> None:
 
 def _run_child(store: str, lo: int, hi: int, cache_dir) -> dict:
     spec = {"store": store, "slice": [lo, hi], "cache_dir": cache_dir}
+    # CPU children BY DESIGN: this parent has imported the package (hence
+    # jax), so on a TPU machine it may hold the chip a child would need —
+    # one process per chip (chip_smoke.py is the jax-free-parent pattern)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    # a would-be cache path the knobs-off child must NOT create
     env.pop("DSGD_COMPILE_CACHE", None)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if cache_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     out = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--child",
          json.dumps(spec)],
@@ -231,7 +243,10 @@ def main(smoke: bool = False) -> None:
         from distributed_sgd_tpu.data.host_shard import host_slice
 
         lo, hi = host_slice(rows, 1, 4)
-        cache = os.path.join(tmp, "compile-cache")
+        from distributed_sgd_tpu import compile_cache
+
+        cache = os.path.join(compile_cache.DEFAULT_DIR, "spinup-ab")
+        shutil.rmtree(cache, ignore_errors=True)
 
         # knobs-off FIRST: proves the path writes nothing even before any
         # cache dir exists anywhere

@@ -12,7 +12,7 @@ and at every epoch boundary evaluate the SAME weights twice:
 - ``f32``: the engine's own jitted evaluate (the number every BENCH
   round reports);
 - ``f64``: the reference objective re-computed under
-  ``jax.experimental.enable_x64`` — float64 margins, float64 loss
+  ``jax.enable_x64`` — float64 margins, float64 loss
   accumulation, float64 regularizer — on the identical weights/data.
 
 The per-epoch |f32 - f64| divergence table is committed to BASELINE.md
@@ -101,7 +101,7 @@ def objective_x64(w, idx, val, y, lam: float) -> float:
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         w64 = jnp.asarray(np.asarray(w, dtype=np.float64))
         v64 = jnp.asarray(np.asarray(val, dtype=np.float64))
         margins = jnp.einsum("np,np->n", v64,
@@ -192,4 +192,7 @@ def main(smoke: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from distributed_sgd_tpu import compile_cache
+
+    compile_cache.place()
     main(smoke="--smoke" in sys.argv)

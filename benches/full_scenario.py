@@ -159,4 +159,7 @@ def main(argv) -> int:
 
 
 if __name__ == "__main__":
+    from distributed_sgd_tpu import compile_cache
+
+    compile_cache.place()
     raise SystemExit(main(sys.argv[1:]))

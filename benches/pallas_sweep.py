@@ -98,4 +98,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from distributed_sgd_tpu import compile_cache
+
+    compile_cache.place()
     main()

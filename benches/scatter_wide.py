@@ -367,6 +367,9 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from distributed_sgd_tpu import compile_cache
+
+    compile_cache.place()
     if "--crossover" in sys.argv:
         crossover()
     elif "--fused-ab" in sys.argv:

@@ -28,23 +28,27 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    import orbax.checkpoint as ocp
-
-    _HAVE_ORBAX = True
-except Exception:  # pragma: no cover - baked into the image, but stay safe
-    _HAVE_ORBAX = False
-
 log = logging.getLogger("dsgd.checkpoint")
+
+
+def _orbax():
+    """`orbax.checkpoint`, imported when the first Checkpointer is built:
+    the import drags in google-cloud logging, whose start-up scan of every
+    installed distribution cost ~4 s here and ~35 s per process on the
+    chip machine (chip run, PR 21) — paid by every role, checkpointing or
+    not, while it sat at module level."""
+    try:
+        import orbax.checkpoint as ocp
+    except ImportError as e:
+        raise RuntimeError("orbax is not available") from e
+    return ocp
 
 
 class Checkpointer:
     """Epoch-cadence training-state checkpointing."""
 
     def __init__(self, directory: str, keep: int = 3):
-        if not _HAVE_ORBAX:
-            raise RuntimeError("orbax is not available")
-        import os
+        ocp = self._ocp = _orbax()
         self.directory = os.path.abspath(directory)
         self._mgr = ocp.CheckpointManager(
             self.directory,
@@ -58,7 +62,8 @@ class Checkpointer:
             state = {"weights": np.asarray(weights)}
             if extra:
                 state.update({k: np.asarray(v) for k, v in extra.items()})
-            saved = self._mgr.save(step, args=ocp.args.StandardSave(state))
+            saved = self._mgr.save(
+                step, args=self._ocp.args.StandardSave(state))
             self._mgr.wait_until_finished()
         if saved:
             log.info("checkpoint saved at step %d -> %s", step, self.directory)
@@ -105,7 +110,8 @@ class Checkpointer:
         # handler as a side effect) — a restore-only process (resume at
         # startup, the serving hot-reload poll) needs the args spelled out
         with span("ckpt.restore", step=step):
-            state = self._mgr.restore(step, args=ocp.args.StandardRestore())
+            state = self._mgr.restore(
+                step, args=self._ocp.args.StandardRestore())
         state["weights"] = jnp.asarray(state["weights"])
         return step, state
 

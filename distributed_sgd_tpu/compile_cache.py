@@ -8,33 +8,37 @@ latency-bound on SPIN-UP rather than on steady-state math: the kernels a
 fresh worker compiles are byte-identical to the ones every previous
 worker already compiled.
 
-``DSGD_COMPILE_CACHE=<dir>`` turns that waste into a hit:
-
-- **persistent cache** — ``configure(dir)`` points jax's persistent
-  compilation cache at a shared directory (min-compile-time/min-size
-  floors dropped so every training/serving kernel is eligible).  XLA
-  backend compiles are keyed by the lowered HLO, so a joining worker, a
-  restarted master, or a fresh serve replica re-compiling a known
-  flagship shape reads the executable from disk instead of re-running
-  XLA.  jax's own monitoring events feed the
-  ``compile.cache.hits``/``compile.cache.misses`` counters
+- **persistent cache** — every entry point (main.py, bench.py,
+  chip_smoke.py, the benches' ``__main__``s) calls ``place()`` before its
+  first jit.  The directory is placed from OUTSIDE: when
+  ``JAX_COMPILATION_CACHE_DIR`` is set, jax's own reading of it stands
+  and nothing here touches the setting; otherwise the cache lives at the
+  fixed ``<checkout>/.jax_cache`` (the path is part of what makes two
+  runs hit the same entries, so it never contains a temp dir, pid or
+  time).  The min-compile-time/min-size floors are dropped so every
+  training/serving kernel is eligible.  XLA backend compiles are keyed by
+  the lowered HLO, so a joining worker, a restarted master, or a fresh
+  serve replica re-compiling a known flagship shape reads the executable
+  from disk instead of re-running XLA.  jax's own monitoring events feed
+  the ``compile.cache.hits``/``compile.cache.misses`` counters
   (utils/metrics.py), so the instruments cover every compile in the
   process — not just the warmed ones.
-- **AOT warmup** — ``warmup_async(name, thunks)`` runs a role's flagship
-  compile thunks on ONE background daemon thread at bind/build time
-  (worker ``_grad_fn``/``_window_fn`` per capacity bucket and the hier
-  psum kernels via ``WorkerNode.warmup_thunks``, the mesh BoundSync epoch
-  program via ``BoundSync.warmup_thunks``, the serving per-bucket Predict
-  via ``PredictEngine.warmup_thunks``) so a joining node compiles while
-  it registers/loads instead of under its first request.  Worker/serving
+- **AOT warmup** (``DSGD_COMPILE_CACHE=1``) — ``warmup_async(name,
+  thunks)`` runs a role's flagship compile thunks on ONE background
+  daemon thread at bind/build time (worker ``_grad_fn``/``_window_fn``
+  per capacity bucket and the hier psum kernels via
+  ``WorkerNode.warmup_thunks``, the mesh BoundSync epoch program via
+  ``BoundSync.warmup_thunks``, the serving per-bucket Predict via
+  ``PredictEngine.warmup_thunks``) so a joining node compiles while it
+  registers/loads instead of under its first request.  Worker/serving
   thunks execute the real jitted callable once on inert zero inputs, so
   they populate the IN-PROCESS dispatch cache too: the first real
   dispatch after warmup performs no tracing at all
   (tests/test_compile_cache.py proves it with a poisoned-trace spy).
 
-Knobs-off contract: with ``DSGD_COMPILE_CACHE`` unset nothing here runs —
-``configure`` is never called, jax's cache config keeps its defaults, no
-warmup thread starts, and no file is ever written (asserted by
+The library stays passive: nothing here runs until an entry point calls
+``place()`` — a ``WorkerNode`` built in a test configures nothing, starts
+no warmup thread and writes no file (asserted by
 tests/test_compile_cache.py and ``bench.py --spinup``).
 
 Concurrency: a real dispatch arriving while its shape is still warming is
@@ -48,61 +52,79 @@ faster time-to-first-contribution for a warm-cache join vs a cold one.
 from __future__ import annotations
 
 import logging
+import os
 import threading
 import time
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 log = logging.getLogger("dsgd.compile_cache")
 
 # one warmup thunk: (label, zero-arg callable that triggers the compile)
 WarmupThunk = Tuple[str, Callable[[], object]]
 
-_configured_dir: Optional[str] = None
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+
+_placed_dir: Optional[str] = None
+_warmup = False
 _listener_installed = False
 
 
-def configured_dir() -> Optional[str]:
-    """The active cache directory, or None when the knob is off."""
-    return _configured_dir
+def cache_dir() -> Optional[str]:
+    """The active cache directory; None until an entry point placed it."""
+    return _placed_dir
 
 
-def enabled() -> bool:
-    return _configured_dir is not None
+def warmup_enabled() -> bool:
+    """True when the entry point asked for the AOT warmup pass."""
+    return _warmup
 
 
-def configure(cache_dir: str, metrics=None) -> None:
-    """Enable jax's persistent compilation cache at `cache_dir` and start
-    counting its hits/misses.  Must run BEFORE the first jit dispatch of
-    the process (main.py calls it right after config load); idempotent.
+def place(warmup: bool = False, metrics=None) -> str:
+    """Turn on jax's persistent compilation cache and start counting its
+    hits/misses; returns the directory in use.  Must run BEFORE the first
+    jit dispatch of the process; idempotent.  `warmup` additionally arms
+    the bind/build-time AOT warmup pass (DSGD_COMPILE_CACHE).
     """
-    global _configured_dir
+    global _placed_dir, _warmup
     import jax
 
     from distributed_sgd_tpu.utils import metrics as metrics_mod
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    from_env = bool(os.environ.get(ENV_DIR))
+    if not from_env:
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
     # every kernel is spin-up-relevant: drop the "only cache slow/large
     # compiles" floors so the per-capacity worker kernels (fast compiles
     # individually, the whole set is what a join waits on) are eligible
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    _configured_dir = cache_dir
+    _placed_dir = jax.config.jax_compilation_cache_dir
+    _warmup = _warmup or bool(warmup)
     _install_listener(metrics or metrics_mod.global_metrics())
-    log.info("persistent compile cache on: %s", cache_dir)
+    log.info("persistent compile cache: %s (%s)%s", _placed_dir,
+             f"from {ENV_DIR}" if from_env else "default",
+             ", AOT warmup on" if _warmup else "")
+    return _placed_dir
+
+
+def counts(metrics=None) -> Tuple[int, int]:
+    """(hits, misses) of the persistent cache in this process so far."""
+    from distributed_sgd_tpu.utils import metrics as metrics_mod
+
+    m = metrics or metrics_mod.global_metrics()
+    return (m.counter(metrics_mod.COMPILE_CACHE_HITS).value,
+            m.counter(metrics_mod.COMPILE_CACHE_MISSES).value)
 
 
 def _install_listener(metrics) -> None:
     """Feed jax's compilation-cache monitoring events into our counters.
-    Registered once per process; a jax without the private monitoring
-    surface just leaves the counters at zero (the cache still works)."""
+    Registered once per process."""
     global _listener_installed
     if _listener_installed:
         return
-    try:
-        from jax._src import monitoring
-    except Exception as e:  # noqa: BLE001 - instruments are best-effort
-        log.warning("compile-cache hit/miss counters unavailable (%s)", e)
-        return
+    from jax._src import monitoring
 
     from distributed_sgd_tpu.utils import metrics as metrics_mod
 
@@ -168,10 +190,8 @@ def warmup_async(name: str, thunks: Sequence[WarmupThunk],
 
 
 def cache_file_count() -> int:
-    """Number of entries in the configured cache dir (0 when off/empty);
+    """Number of entries in the placed cache dir (0 when unplaced/empty);
     the cross-process reuse tests assert this stops growing on a rerun."""
-    import os
-
-    if _configured_dir is None or not os.path.isdir(_configured_dir):
+    if _placed_dir is None or not os.path.isdir(_placed_dir):
         return 0
-    return len(os.listdir(_configured_dir))
+    return len(os.listdir(_placed_dir))

@@ -32,6 +32,22 @@ def _env(name: str, default, cast):
     return cast(raw)
 
 
+def _env_gate(name: str) -> bool:
+    """A boolean env gate that refuses anything but a boolean word: the
+    variable used to name a directory, and silently reading a path as
+    "off" would drop the warmup an operator asked for."""
+    raw = (os.environ.get(name) or "").strip().lower()
+    if raw in ("", "0", "false", "no", "off"):
+        return False
+    if raw in ("1", "true", "yes", "on"):
+        return True
+    raise ValueError(
+        f"{name}={os.environ[name]!r}: the variable is an on/off gate for "
+        f"the AOT warmup pass and no longer names a directory — set "
+        f"JAX_COMPILATION_CACHE_DIR=<dir> to place the cache and "
+        f"{name}=1 to warm it")
+
+
 @dataclass
 class Config:
     # -- reference-parity fields (utils/Config.scala:3-21) ------------------
@@ -235,13 +251,13 @@ class Config:
     feature_shards: int = 1
     # -- elastic spin-up fast path (compile_cache.py, data/row_store.py;
     # docs/HIERARCHY.md "Elastic composition") --------------------------
-    # persistent compile cache + AOT warmup: point jax's persistent
-    # compilation cache at a (shareable) directory and pre-compile each
-    # role's flagship shapes on a background thread at bind/build time,
-    # so a joining worker / restarted master / fresh serve replica never
-    # JITs under traffic.  None (default): jax's cache config untouched,
-    # no warmup thread, zero files written (asserted by test + bench).
-    compile_cache: Optional[str] = None
+    # AOT warmup: pre-compile each role's flagship shapes on a background
+    # thread at bind/build time, so a joining worker / restarted master /
+    # fresh serve replica never JITs under traffic.  A gate only — the
+    # persistent cache itself is always on under main.py and its
+    # directory is placed from outside (JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache; compile_cache.place).
+    compile_cache: bool = False
     # neighbor-range over-provisioning for host-local slices: each
     # worker loads ceil(f * slice) extra rows on both sides, so an
     # elastic resplit within the margin costs ZERO reload and a bigger
@@ -765,7 +781,7 @@ class Config:
             master_shards=_env("DSGD_MASTER_SHARDS", cls.master_shards, int),
             feature_shards=_env("DSGD_FEATURE_SHARDS", cls.feature_shards, int),
             host_devices=_env("DSGD_HOST_DEVICES", cls.host_devices, int),
-            compile_cache=_env("DSGD_COMPILE_CACHE", None, str),
+            compile_cache=_env_gate("DSGD_COMPILE_CACHE"),
             host_overprovision=_env("DSGD_HOST_OVERPROVISION",
                                     cls.host_overprovision, float),
             row_store=_env("DSGD_ROW_STORE", None, str),

@@ -113,6 +113,9 @@ class SyncTrainer:
     ) -> FitResult:
         bound_train = self.engine.bind(train)
         bound_test = self.engine.bind(test)
+        log.info("train split: %d rows, per device %s", len(train), " ".join(
+            f"[id={d} rows={r} bytes_in_use={b}]"
+            for d, r, b in bound_train.placement()))
         w = (
             jnp.zeros((self.model.n_features,), dtype=jnp.float32)
             if initial_weights is None

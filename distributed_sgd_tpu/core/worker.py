@@ -259,6 +259,10 @@ class WorkerNode:
         self.metrics.gauge(metrics_mod.SCATTER_FORMULATION).set(
             _mxu.SCATTER_FORMULATIONS.index(
                 _mxu.active_scatter_formulation()))
+        self.log.info(
+            "worker kernel=%s on %s (%d in-host device(s))",
+            "blocked-onehot" if self._blocked_device() else "scalar",
+            self.device, self.host_devices)
 
         self._peers: Dict[Tuple[str, int], WorkerStub] = {}
         # bounded fire-and-forget gossip per peer (and to the master):

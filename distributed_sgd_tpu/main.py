@@ -399,6 +399,38 @@ def _finish(cfg: Config, res, evaluator=None, saved: bool = False) -> None:
         Checkpointer(cfg.checkpoint_dir).save(res.epochs_run, w)
 
 
+def _package_version(name: str) -> str:
+    from importlib import metadata
+
+    try:
+        return metadata.version(name)
+    except metadata.PackageNotFoundError:
+        return "absent"
+
+
+def _log_devices() -> None:
+    """Name the device this process computes on, once, at start-up."""
+    devs = jax.devices()
+    log.info("device: platform=%s kind=%s count=%d jax=%s jaxlib=%s libtpu=%s",
+             devs[0].platform, devs[0].device_kind, len(devs),
+             jax.__version__, _package_version("jaxlib"),
+             _package_version("libtpu"))
+
+
+def _log_run_summary() -> None:
+    """Exit-time facts a run leaves behind: what the persistent compile
+    cache did for this process and how much device memory it peaked at
+    (memory_stats() is None on backends that do not report it)."""
+    from distributed_sgd_tpu import compile_cache
+
+    log.info("compile cache: dir=%s hits=%d misses=%d",
+             compile_cache.cache_dir(), *compile_cache.counts())
+    peaks = [(d.id, (d.memory_stats() or {}).get("peak_bytes_in_use"))
+             for d in jax.local_devices()]
+    log.info("device memory peak: %s", " ".join(
+        f"{i}:{p if p is not None else 'unreported'}" for i, p in peaks))
+
+
 def main() -> None:
     setup_logging()
     cfg = Config.from_env()
@@ -406,15 +438,19 @@ def main() -> None:
     log.info("config: %s", cfg.to_json())
     np.random.seed(cfg.seed)  # Main.scala:32 Random.setSeed(0)
 
-    # elastic spin-up fast path (compile_cache.py): point jax's persistent
-    # compilation cache at the shared directory BEFORE the first jit of
-    # the process, so every XLA compile below — warmup thunks and live
-    # traffic alike — reads/writes the cache.  Unset: nothing happens (no
-    # config touch, no files; asserted by tests/test_compile_cache.py).
-    if cfg.compile_cache:
-        from distributed_sgd_tpu import compile_cache
+    # place jax's persistent compilation cache BEFORE the first jit of the
+    # process (compile_cache.py: JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache), so every XLA compile below — warmup thunks
+    # and live traffic alike — reads/writes it.  DSGD_COMPILE_CACHE arms
+    # the AOT warmup pass on top.
+    from distributed_sgd_tpu import compile_cache
 
-        compile_cache.configure(cfg.compile_cache)
+    compile_cache.place(warmup=cfg.compile_cache)
+    # the router computes on no device unless its canary gate fires, and
+    # must not claim the chip a co-located replica process needs
+    on_device = cfg.role != "route"
+    if on_device:
+        _log_devices()
 
     # observability plumbing (docs/OBSERVABILITY.md), BEFORE any channel or
     # server exists so every RPC edge is covered:
@@ -492,6 +528,8 @@ def main() -> None:
         # metrics (incl. metrics.push.errors) are the ones that matter —
         # same for the trace buffer
         trace_mod.flush()
+        if on_device:
+            _log_run_summary()
         if probe is not None:
             probe.stop()
         if exporter is not None:

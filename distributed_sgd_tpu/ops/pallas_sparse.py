@@ -34,7 +34,9 @@ carry y=0, val=0 and are inert (val=0 zeroes the scatter side).
 
 CPU/testing: pass interpret=True (tests/test_pallas_kernels.py) — the same
 kernel runs under the Pallas interpreter on the CPU test mesh
-(SURVEY.md §4 strategy).
+(SURVEY.md §4 strategy).  On the chip Mosaic compiles it as written
+(interpret=False): chip_smoke.py checks one flagship-shape step against
+kernel='mxu' every run.
 """
 
 from __future__ import annotations
@@ -49,37 +51,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 SAMPLE_TILE = 32  # samples per in-kernel tile; 32*P entries per matmul
-
-_SUPPORTED: "bool | None" = None
-
-
-def pallas_supported() -> bool:
-    """Capability probe: can `worker_grads` run under THIS jax?
-
-    The kernel targets a newer pallas surface (`jax.typeof` vma plumbing
-    in out_shape) than some images ship; on those, every call raises at
-    trace time.  The probe runs one tiny interpreter-mode `worker_grads`
-    and caches the verdict — tests/test_pallas*.py skip on False (unless
-    forced with DSGD_PALLAS=1), so tier-1 reflects the supported surface
-    instead of failing 22 known-incompatible tests (ROADMAP item 2; the
-    kernel itself is measured-rejected per BASELINE.md / config.py
-    `_CHOICES['kernel']`, kept only for kernel work).
-    """
-    global _SUPPORTED
-    if _SUPPORTED is None:
-        try:
-            w2 = jnp.zeros((2, LANES), jnp.float32)
-            idx = jnp.zeros((1, 4, 2), jnp.int32)
-            val = jnp.ones((1, 4, 2), jnp.float32)
-            y = jnp.ones((1, 4), jnp.int32)
-            worker_grads(w2, idx, val, y,
-                         coeff_fn=lambda m, yy: yy.astype(jnp.float32),
-                         interpret=True)
-            _SUPPORTED = True
-        except Exception:  # noqa: BLE001 - any trace-time failure = unsupported
-            _SUPPORTED = False
-    return _SUPPORTED
-
 
 def _worker_grad_kernel(idx_ref, val_ref, y_ref, w2_ref, g2_ref, g2_acc, *, coeff_fn, p):
     """One grid step = one worker's fused gradient (see module docstring)."""

@@ -23,37 +23,13 @@ from distributed_sgd_tpu.data.rcv1 import Dataset
 WORKER_AXIS = "workers"
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """`jax.shard_map` across jax versions.
-
-    The engines target the stable `jax.shard_map` API (jax >= 0.6); on the
-    older jax in some images it lives at `jax.experimental.shard_map` and
-    spells the replication-check kwarg `check_rep` instead of `check_vma`
-    (same meaning: trust the callee's declared out_specs for unmapped
-    outputs).  Single chokepoint so every engine works on both.
-    """
-    if hasattr(jax, "shard_map"):
-        sm, kw = jax.shard_map, "check_vma"
-    else:  # pragma: no cover - exercised on jax < 0.6 images
-        from jax.experimental.shard_map import shard_map as sm  # type: ignore
-
-        kw = "check_rep"
-    kwargs = {} if check_vma is None else {kw: check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+shard_map = jax.shard_map
 
 
 def pcast_varying(x, axes: Tuple[str, ...]):
-    """`jax.lax.pcast(x, axes, to="varying")` where available.
-
-    New-jax shard_map tracks varying-mesh-axes (VMA) types and requires
-    replicated values to be cast before entering per-device control flow;
-    older jax has no VMA tracking (check_rep infers replication), so the
-    cast is an identity there.  Same chokepoint rationale as `shard_map`
-    above.
-    """
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axes, to="varying")
-    return x  # pragma: no cover - exercised on jax < 0.6 images
+    """Cast a replicated value to varying over `axes`: shard_map's
+    varying-mesh-axes typing requires it before per-device control flow."""
+    return jax.lax.pcast(x, axes, to="varying")
 
 
 def make_mesh(n_workers: Optional[int] = None, devices=None) -> Mesh:

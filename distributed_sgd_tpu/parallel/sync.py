@@ -116,10 +116,13 @@ class BoundSync:
             )
         self.kernel = kernel
         # the Pallas kernel needs the interpreter off-TPU (tests, CPU mesh).
-        # vma (varying-mesh-axes) typing is disabled for the pallas backend
-        # everywhere: the interpreter cannot type vma through its grid
-        # emulation, and on TPU the vma-typed closed_call around pallas_call
-        # trips a lowering-cache KeyError inside jax (observed on jax 0.8)
+        # vma (varying-mesh-axes) typing is off for the pallas backend on
+        # both sides, re-established on jax 0.9.0 / libtpu 0.0.34: the
+        # interpreter cannot type vma through its grid emulation
+        # ("dynamic_slice requires varying manual axes to match"), and on
+        # the chip vma typing plants `pvary` inside the kernel body, which
+        # Mosaic does not lower ("Unimplemented primitive in Pallas TPU
+        # lowering for KernelType.TC: pvary")
         self._pallas_interpret = jax.default_backend() != "tpu"
         self._check_vma = kernel != "pallas"
         # scatter formulation override (ops/mxu.py, DSGD_SCATTER): None
@@ -481,15 +484,24 @@ class BoundSync:
         return [("epoch", epoch), ("eval", evaluate)]
 
     def _maybe_warmup(self) -> None:
-        """Kick the background warmup at bind time when the compile cache
-        is configured (no-op — not even an import of jax state — when the
-        knob is off)."""
+        """Kick the background warmup at bind time when the entry point
+        armed it (DSGD_COMPILE_CACHE); a no-op otherwise."""
         from distributed_sgd_tpu import compile_cache
 
-        if compile_cache.enabled():
+        if compile_cache.warmup_enabled():
             compile_cache.warmup_async(
                 f"mesh[{self.n_workers}x{self.kernel}]",
                 self.warmup_thunks())
+
+    def placement(self):
+        """[(device id, resident rows, device bytes_in_use)] for the bound
+        split — where the rows actually sit, as the runtime reports it
+        (bytes are None on backends without memory_stats)."""
+        return [
+            (s.device.id, s.data.shape[0],
+             (s.device.memory_stats() or {}).get("bytes_in_use"))
+            for s in self.data.indices.addressable_shards
+        ]
 
     def epoch(self, w: jax.Array, key: jax.Array) -> jax.Array:
         self._check_trainable()

@@ -97,8 +97,9 @@ class ModelStore:
         self._current = (int(step), weights)
         if self._metrics is not None:
             self._metrics.gauge(metrics_mod.SERVE_MODEL_VERSION).set(step)
-        log.info("serving model swapped to step %d (%d features, %s)",
-                 step, weights.shape[0], reason)
+        log.info("serving model swapped to step %d (%d features, %s) on %s",
+                 step, weights.shape[0], reason,
+                 ",".join(sorted(str(d) for d in weights.devices())))
 
     # -- the file poll -------------------------------------------------------
 

@@ -332,7 +332,7 @@ class ServingServer:
         traffic burst.  No-op when the knob is off."""
         from distributed_sgd_tpu import compile_cache
 
-        if not compile_cache.enabled():
+        if not compile_cache.warmup_enabled():
             return
         self._warm_stop = threading.Event()
 

@@ -159,7 +159,7 @@ def sample_resources() -> Dict[str, float]:
     out[metrics_mod.PROC_PRESSURE_FLIGHT_RING] = float(flight.get().ring_len())
     from distributed_sgd_tpu import compile_cache
 
-    if compile_cache.enabled():
+    if compile_cache.cache_dir() is not None:
         try:
             out[metrics_mod.PROC_PRESSURE_COMPILE_CACHE] = float(
                 compile_cache.cache_file_count())

@@ -1,10 +1,14 @@
 """Compile-cache semantics (compile_cache.py, DSGD_COMPILE_CACHE).
 
-The contracts under test (ISSUE 13 satellites):
+The contracts under test:
 
-- knobs-off writes ZERO files and the math stays byte-identical with the
-  cache on or off (subprocess A/B — in-process runs would share jax's jit
-  cache and prove nothing);
+- placement: JAX_COMPILATION_CACHE_DIR wins and no code path sets another
+  directory; absent, every process resolves the same fixed
+  `<checkout>/.jax_cache` whatever its working directory;
+- the library is passive — without an entry point's `place()` it writes
+  ZERO files — and the math stays byte-identical with the cache on or
+  off (subprocess A/B — in-process runs would share jax's jit cache and
+  prove nothing);
 - the warmup pass populates the real dispatch cache: the first dispatch
   after warmup performs no tracing at all (poisoned-trace spy), and a
   dispatch racing the warmup thread is safe;
@@ -27,8 +31,9 @@ from distributed_sgd_tpu.models.linear import make_model
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# one tiny spin-up: build a worker, (optionally) configure + warm, answer
-# one gradient.  argv[1] is the cache dir or "-" for knobs-off.
+# one tiny spin-up: build a worker, (optionally) place the cache + warm,
+# answer one gradient.  argv[1] is "place" or "-" for the passive library;
+# the directory arrives the only way it can: JAX_COMPILATION_CACHE_DIR.
 _CHILD = """
 import hashlib, json, sys
 import jax
@@ -40,9 +45,9 @@ from distributed_sgd_tpu.data.synthetic import rcv1_like
 from distributed_sgd_tpu.models.linear import make_model
 from distributed_sgd_tpu.utils import metrics as mm
 
-cache = None if sys.argv[1] == "-" else sys.argv[1]
+cache = sys.argv[1] == "place"
 if cache:
-    compile_cache.configure(cache)
+    compile_cache.place(warmup=True)
 data = rcv1_like(64, n_features=256, nnz=4, seed=0)
 model = make_model("hinge", 1e-5, 256)
 w = WorkerNode("127.0.0.1", 0, "127.0.0.1", 1, data, model)
@@ -61,14 +66,22 @@ print(json.dumps({
 """
 
 
-def _spinup_child(cache_arg: str) -> dict:
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+def _run(code: str, *argv: str, cache_dir=None, cwd=REPO) -> dict:
+    """Run `code` in a fresh CPU process; last stdout line is its JSON."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
     env.pop("DSGD_COMPILE_CACHE", None)
+    env.pop(compile_cache.ENV_DIR, None)
+    if cache_dir is not None:
+        env[compile_cache.ENV_DIR] = cache_dir
     out = subprocess.run(
-        [sys.executable, "-c", _CHILD, cache_arg],
-        capture_output=True, text=True, env=env, cwd=REPO, check=False)
+        [sys.executable, "-c", code, *argv],
+        capture_output=True, text=True, env=env, cwd=str(cwd), check=False)
     assert out.returncode == 0, out.stderr[-4000:]
     return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _spinup_child(cache_dir) -> dict:
+    return _run(_CHILD, "place" if cache_dir else "-", cache_dir=cache_dir)
 
 
 @pytest.fixture(scope="module")
@@ -77,7 +90,7 @@ def spinup_runs(tmp_path_factory):
     per module (each child pays a jax import)."""
     tmp = tmp_path_factory.mktemp("compile-cache")
     cache = str(tmp / "cc")
-    off = _spinup_child("-")
+    off = _spinup_child(None)
     assert not os.path.exists(cache)
     cold = _spinup_child(cache)
     warm = _spinup_child(cache)
@@ -86,8 +99,8 @@ def spinup_runs(tmp_path_factory):
 
 def test_knobs_off_writes_zero_files_and_is_byte_identical(spinup_runs):
     off, cold, warm = (spinup_runs[k] for k in ("off", "cold", "warm"))
-    # knobs-off: no cache dir, no files, no warmup thread, no hit/miss
-    # events (the listener is only installed by configure())
+    # passive library: no cache dir, no files, no warmup thread, no
+    # hit/miss events (the listener is only installed by place())
     assert off["files"] == 0
     assert off["warmed"] == 0
     assert off["hits"] == 0 and off["misses"] == 0
@@ -167,10 +180,62 @@ def test_empty_slice_worker_has_no_thunks():
     assert compile_cache.warmup_async("empty", w.warmup_thunks(8, 2)) is None
 
 
-def test_knob_is_off_in_this_process():
-    """Tier-1 runs with the knob unset: nothing in the suite may have
-    configured the process-global cache (it would silently change every
-    other test's compile path)."""
-    assert not compile_cache.enabled()
-    assert compile_cache.configured_dir() is None
+def test_library_is_passive_in_this_process():
+    """Only entry points place the cache: nothing in the suite may have
+    done so in the test process (it would silently change every other
+    test's compile path)."""
+    assert compile_cache.cache_dir() is None
+    assert not compile_cache.warmup_enabled()
     assert compile_cache.cache_file_count() == 0
+
+
+# placement only — reports what place() resolved, compiles nothing
+_PLACE = """
+import json
+import jax
+from distributed_sgd_tpu import compile_cache
+placed = compile_cache.place()
+print(json.dumps({"placed": placed,
+                  "jax": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def test_env_var_places_the_cache(spinup_runs, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins: place() reports jax's own reading
+    of it, and the spin-up children's entries landed there."""
+    want = str(tmp_path / "from-env")
+    got = _run(_PLACE, cache_dir=want)
+    assert got["placed"] == got["jax"] == want
+    assert os.listdir(spinup_runs["cache"])
+
+
+def test_default_is_one_fixed_dir_from_any_cwd(tmp_path):
+    """No env var: two fresh processes started from different working
+    directories resolve the same <checkout>/.jax_cache."""
+    a = _run(_PLACE, cwd=tmp_path)
+    b = _run(_PLACE, cwd=REPO)
+    assert a == b
+    assert a["placed"] == os.path.join(REPO, ".jax_cache")
+    assert compile_cache.DEFAULT_DIR == a["placed"]
+
+
+def test_no_other_code_sets_the_cache_dir():
+    """The directory is set in exactly one place — compile_cache.place —
+    and no cache path is built from a temp dir, a pid or the clock."""
+    setters = []
+    for root in ("distributed_sgd_tpu", "benches", "examples"):
+        for dirpath, _dirs, files in os.walk(os.path.join(REPO, root)):
+            setters += [os.path.join(dirpath, f) for f in files
+                        if f.endswith(".py")]
+    setters += [os.path.join(REPO, f) for f in
+                ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+    hits = []
+    for path in setters:
+        with open(path) as f:
+            src = f.read()
+        if '"jax_compilation_cache_dir"' in src:
+            hits.append(os.path.relpath(path, REPO))
+    assert hits == [os.path.join("distributed_sgd_tpu", "compile_cache.py")]
+    with open(os.path.join(REPO, hits[0])) as f:
+        src = f.read()
+    assert not any(w in src for w in ("tempfile", "getpid", "mkdtemp"))

@@ -6,7 +6,6 @@ below one lane (D < 128), exactly on a block edge (D = 128k), one-past
 shape) pair must agree with the scalar-path kernels.
 """
 
-import os
 
 import jax.numpy as jnp
 import numpy as np
@@ -163,12 +162,6 @@ def test_bf16_accumulation_bound_is_real():
         "bf16 scatter is bit-identical to f32 — it is not accumulating in bf16"
 
 
-@pytest.mark.skipif(
-    os.environ.get("DSGD_PALLAS", "") != "1"
-    and not pallas_sparse.pallas_supported(),
-    reason="pallas kernel unsupported on this jax (pallas_supported() "
-    "probe failed) and DSGD_PALLAS=1 not set; measured-rejection record "
-    "in BASELINE.md / ROADMAP item 2")
 @pytest.mark.parametrize("d", [1, 127, 129, 1025])
 @pytest.mark.parametrize("bp", BATCHES)
 def test_pallas_kernel_all_shapes(d, bp):

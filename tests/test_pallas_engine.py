@@ -1,26 +1,10 @@
 """Engine-level check: the 'pallas' kernel backend produces the same
-training trajectory as the 'mxu' backend (interpreter on the CPU mesh).
-
-Gated like tests/test_pallas_kernels.py: skips when the
-`pallas_supported()` capability probe fails (this image's jax predates
-the kernel's pallas surface) unless forced with DSGD_PALLAS=1 — see the
-measured-rejection record (BASELINE.md, ROADMAP item 2)."""
-
-import os
+training trajectory as the 'mxu' backend (interpreter on the CPU mesh;
+chip_smoke.py repeats the one-step check compiled on the chip)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
-
-from distributed_sgd_tpu.ops import pallas_sparse
-
-pytestmark = pytest.mark.skipif(
-    os.environ.get("DSGD_PALLAS", "") != "1"
-    and not pallas_sparse.pallas_supported(),
-    reason="pallas kernel unsupported on this jax (ops/pallas_sparse.py "
-    "pallas_supported() probe failed) and DSGD_PALLAS=1 not set; the "
-    "kernel is measured-rejected anyway (BASELINE.md, ROADMAP item 2)")
 
 from distributed_sgd_tpu.data.synthetic import rcv1_like
 from distributed_sgd_tpu.models.linear import SparseSVM

@@ -1,18 +1,7 @@
 """The fused Pallas worker-gradient kernel (ops/pallas_sparse.py) must
 match the model's blocked-XLA gradient path.  Runs under the Pallas
-interpreter on the CPU test mesh.
+interpreter on the CPU test mesh."""
 
-Gated (ROADMAP item 2, measured-rejection record in BASELINE.md +
-config.py _CHOICES['kernel']): the kernel is measured-rejected from the
-config surface AND targets a pallas API (`jax.typeof` vma plumbing) some
-images' jax lacks — there every call fails at trace time.  The suite
-runs when the `pallas_supported()` capability probe passes, or when
-forced with DSGD_PALLAS=1; otherwise it SKIPS so tier-1 reflects the
-supported surface instead of 22 known-incompatible failures."""
-
-import os
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -20,13 +9,6 @@ import pytest
 from distributed_sgd_tpu.models.linear import LeastSquares, LogisticRegression, SparseSVM
 from distributed_sgd_tpu.ops import mxu, pallas_sparse
 from distributed_sgd_tpu.ops.sparse import SparseBatch
-
-pytestmark = pytest.mark.skipif(
-    os.environ.get("DSGD_PALLAS", "") != "1"
-    and not pallas_sparse.pallas_supported(),
-    reason="pallas kernel unsupported on this jax (ops/pallas_sparse.py "
-    "pallas_supported() probe failed) and DSGD_PALLAS=1 not set; the "
-    "kernel is measured-rejected anyway (BASELINE.md, ROADMAP item 2)")
 
 
 def _batches(k=3, b=10, p=6, d=700, seed=0):

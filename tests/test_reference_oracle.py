@@ -9,8 +9,6 @@ suite: every kernel (scalar take/scatter, one-hot MXU, Pallas) must land
 on the same numbers as the boxed-map algorithm.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +16,6 @@ import pytest
 
 from distributed_sgd_tpu.data.synthetic import rcv1_like
 from distributed_sgd_tpu.models.linear import SparseSVM
-from distributed_sgd_tpu.ops import pallas_sparse
 from distributed_sgd_tpu.parallel.mesh import make_mesh
 from distributed_sgd_tpu.parallel.sync import SyncEngine
 
@@ -67,12 +64,7 @@ def oracle_step(w: dict, rows, ys, ids_per_worker, ds: dict):
     # accumulation bound, the exact formulations within float-order noise
     ("mxu", "onehot"), ("mxu", "segment"), ("mxu", "twostage"),
     ("mxu", "bf16"),
-    pytest.param("pallas", None, marks=pytest.mark.skipif(
-        os.environ.get("DSGD_PALLAS", "") != "1"
-        and not pallas_sparse.pallas_supported(),
-        reason="pallas kernel unsupported on this jax (pallas_supported() "
-        "probe failed) and DSGD_PALLAS=1 not set; measured-rejection "
-        "record in BASELINE.md / ROADMAP item 2")),
+    ("pallas", None),
 ])
 def test_engine_matches_boxed_map_oracle(kernel, scatter):
     data = rcv1_like(64, n_features=D, nnz=8, seed=3)
