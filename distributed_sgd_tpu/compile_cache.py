@@ -17,12 +17,17 @@ worker already compiled.
   runs hit the same entries, so it never contains a temp dir, pid or
   time).  The min-compile-time/min-size floors are dropped so every
   training/serving kernel is eligible.  XLA backend compiles are keyed by
-  the lowered HLO, so a joining worker, a restarted master, or a fresh
+  the lowered HLO — its metadata (scope names, source lines; file names
+  from the checkout's root) included, so an executable read back never
+  shows another version's names in a profile — so a joining worker, a restarted master, or a fresh
   serve replica re-compiling a known flagship shape reads the executable
   from disk instead of re-running XLA.  jax's own monitoring events feed
   the ``compile.cache.hits``/``compile.cache.misses`` counters
   (utils/metrics.py), so the instruments cover every compile in the
-  process — not just the warmed ones.
+  process — not just the warmed ones.  jax's duration events say WHICH
+  function compiled and for how long: each backend compile (or cache
+  retrieval) is one sample of the ``compile.seconds`` histogram, one INFO
+  record on ``dsgd.compile`` and one entry of ``compiles()``.
 - **AOT warmup** (``DSGD_COMPILE_CACHE=1``) — ``warmup_async(name,
   thunks)`` runs a role's flagship compile thunks on ONE background
   daemon thread at bind/build time (worker ``_grad_fn``/``_window_fn``
@@ -53,22 +58,28 @@ from __future__ import annotations
 
 import logging
 import os
+import re
 import threading
 import time
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
 
 log = logging.getLogger("dsgd.compile_cache")
+compile_log = logging.getLogger("dsgd.compile")  # one record per compile
 
 # one warmup thunk: (label, zero-arg callable that triggers the compile)
 WarmupThunk = Tuple[str, Callable[[], object]]
 
 ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
-DEFAULT_DIR = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache")
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+DEFAULT_DIR = os.path.join(CHECKOUT, ".jax_cache")
 
 _placed_dir: Optional[str] = None
 _warmup = False
 _listener_installed = False
+# (perf_counter at its end, function, seconds, served from the persistent
+# cache) of the process's first MAX_COMPILES compiles since place()
+MAX_COMPILES = 256
+_compiles: List[Tuple[float, str, float, bool]] = []
 
 
 def cache_dir() -> Optional[str]:
@@ -100,6 +111,18 @@ def place(warmup: bool = False, metrics=None) -> str:
     # individually, the whole set is what a join waits on) are eligible
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # An executable read back from the cache carries the metadata (the
+    # jax.named_scope paths, the source lines) of whichever checkout
+    # compiled it first, and by default jax leaves metadata out of the key:
+    # a profile of THIS code would then show another version's names (seen
+    # on the v5e, PR 24: the parent commit's trace held this commit's
+    # scopes).  The profiler and the benchmark's by-name metrics read those
+    # names, so they are part of the key; the same code still hits.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    # ... but not WHERE the checkout lies: source files are named from its
+    # root, so the same code unpacked at another path still hits
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      re.escape(CHECKOUT + os.sep))
     _placed_dir = jax.config.jax_compilation_cache_dir
     _warmup = _warmup or bool(warmup)
     _install_listener(metrics or metrics_mod.global_metrics())
@@ -118,9 +141,17 @@ def counts(metrics=None) -> Tuple[int, int]:
             m.counter(metrics_mod.COMPILE_CACHE_MISSES).value)
 
 
+def compiles() -> List[Tuple[float, str, float, bool]]:
+    """Which functions compiled so far, in order: (perf_counter at the
+    compile's end, function as jax names it — ``jit(_epoch_shard)`` —,
+    seconds, True where the persistent cache served it)."""
+    return list(_compiles)
+
+
 def _install_listener(metrics) -> None:
-    """Feed jax's compilation-cache monitoring events into our counters.
-    Registered once per process."""
+    """Feed jax's compilation-cache monitoring events into our counters,
+    and its compile durations into `compiles()`.  Registered once per
+    process."""
     global _listener_installed
     if _listener_installed:
         return
@@ -131,13 +162,31 @@ def _install_listener(metrics) -> None:
     hits = metrics.counter(metrics_mod.COMPILE_CACHE_HITS)
     misses = metrics.counter(metrics_mod.COMPILE_CACHE_MISSES)
 
+    seconds = metrics.histogram(metrics_mod.COMPILE_SECONDS)
+    served = threading.local()  # this thread's compile was a cache hit
+
     def _on_event(event: str, **kwargs) -> None:
         if event.endswith("/cache_hits"):
             hits.increment()
+            served.hit = True
         elif event.endswith("/cache_misses"):
             misses.increment()
 
+    def _on_duration(event: str, secs: float, **kwargs) -> None:
+        # jax 0.9.0: one backend_compile_duration per compile, around the
+        # cache look-up too, so a hit's event precedes it on the same thread
+        if not event.endswith("/backend_compile_duration"):
+            return
+        hit, served.hit = getattr(served, "hit", False), False
+        fun = str(kwargs.get("fun_name", "?"))
+        seconds.record(secs)
+        if len(_compiles) < MAX_COMPILES:
+            _compiles.append((time.perf_counter(), fun, float(secs), hit))
+        compile_log.info("compiled %s in %.3fs (%s)", fun, secs,
+                         "persistent-cache hit" if hit else "miss")
+
     monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
     _listener_installed = True
 
 

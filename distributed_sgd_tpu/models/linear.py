@@ -83,7 +83,8 @@ class LinearModel:
     def margins(self, w: jax.Array, batch: SparseBatch) -> jax.Array:
         if batch.is_dense:
             return self.margins_dense(w, batch.values)
-        return matvec(batch, w)
+        with jax.named_scope("dsgd.margins"):
+            return matvec(batch, w)
 
     def sample_losses(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """Per-sample losses (no regularization term), vectorized."""
@@ -111,12 +112,17 @@ class LinearModel:
         """Sum of per-sample backward over the batch (Slave.scala:147-153)."""
         if batch.is_dense:
             return self.grad_dense(w, batch.values, y, reduce="sum")
-        coeff = self.grad_coeff(self.margins(w, batch), y)
-        return scatter_add(batch, coeff, self.n_features)
+        margins = self.margins(w, batch)
+        with jax.named_scope("dsgd.coeff"):
+            coeff = self.grad_coeff(margins, y)
+        with jax.named_scope("dsgd.scatter"):
+            return scatter_add(batch, coeff, self.n_features)
 
     def grad_mean(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """Mean of per-sample backward (async path, Slave.scala:93-98)."""
-        return self.grad_sum(w, batch, y) / batch.batch_size
+        g = self.grad_sum(w, batch, y)
+        with jax.named_scope("dsgd.scatter"):
+            return g / batch.batch_size
 
     # -- dense fast path ----------------------------------------------------
     #
@@ -132,34 +138,39 @@ class LinearModel:
         precision would truncate operands to bf16), preserving the
         invariant that every kernel backend produces identical updates up
         to float summation order (sync.py docstring)."""
-        return jnp.dot(
-            x.astype(jnp.float32), w.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        with jax.named_scope("dsgd.margins"):
+            return jnp.dot(
+                x.astype(jnp.float32), w.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
 
     def grad_dense(
         self, w: jax.Array, x: jax.Array, y: jax.Array, reduce: str = "sum"
     ) -> jax.Array:
         """Batched backward for dense rows: coeff[B] @ x[B, D] — one MXU
         matmul replacing gather + scatter (Slave.scala:147-153 semantics)."""
-        coeff = self.grad_coeff(self.margins_dense(w, x), y)
-        if reduce == "mean":
-            coeff = coeff / x.shape[0]
-        return jnp.dot(
-            coeff.astype(jnp.float32), x.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        margins = self.margins_dense(w, x)
+        with jax.named_scope("dsgd.coeff"):
+            coeff = self.grad_coeff(margins, y)
+            if reduce == "mean":
+                coeff = coeff / x.shape[0]
+        with jax.named_scope("dsgd.scatter"):
+            return jnp.dot(
+                coeff.astype(jnp.float32), x.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST,
+            )
 
     def regularize(self, grad: jax.Array, w: jax.Array) -> jax.Array:
         """SparseSVM.scala:31 semantics (see module docstring)."""
-        if self.regularizer == "dim_sparsity":
-            scalar = self.lam * 2.0 * jnp.dot(
-                w.astype(jnp.float32), self.dim_sparsity
-            )
-            return grad + jnp.where(grad != 0, scalar, 0.0)
-        if self.regularizer == "l2":
-            return grad + 2.0 * self.lam * w
-        return grad
+        with jax.named_scope("dsgd.regularize"):
+            if self.regularizer == "dim_sparsity":
+                scalar = self.lam * 2.0 * jnp.dot(
+                    w.astype(jnp.float32), self.dim_sparsity
+                )
+                return grad + jnp.where(grad != 0, scalar, 0.0)
+            if self.regularizer == "l2":
+                return grad + 2.0 * self.lam * w
+            return grad
 
     # -- blocked (MXU one-hot) fast path -----------------------------------
     #
@@ -193,9 +204,11 @@ class LinearModel:
         reduce='mean' is the async local step (Slave.scala:93-98).
         """
         oh = mxu.OneHotBatch(batch, w2.shape[0])
-        coeff = self.grad_coeff(oh.margins(w2), y)
-        if reduce == "mean":
-            coeff = coeff / batch.batch_size
+        margins = oh.margins(w2)
+        with jax.named_scope("dsgd.coeff"):
+            coeff = self.grad_coeff(margins, y)
+            if reduce == "mean":
+                coeff = coeff / batch.batch_size
         return oh.scatter_add(coeff)
 
     def grad_regularized(
@@ -225,14 +238,15 @@ class LinearModel:
     def regularize_blocked(self, g2: jax.Array, w2: jax.Array) -> jax.Array:
         """`regularize` on the blocked view; zero pad lanes stay zero
         because the scalar is only added where g2 != 0."""
-        if self.regularizer == "dim_sparsity":
-            scalar = self.lam * 2.0 * jnp.sum(
-                w2.astype(jnp.float32) * self.dim_sparsity_blocked
-            )
-            return g2 + jnp.where(g2 != 0, scalar, 0.0)
-        if self.regularizer == "l2":
-            return g2 + 2.0 * self.lam * w2
-        return g2
+        with jax.named_scope("dsgd.regularize"):
+            if self.regularizer == "dim_sparsity":
+                scalar = self.lam * 2.0 * jnp.sum(
+                    w2.astype(jnp.float32) * self.dim_sparsity_blocked
+                )
+                return g2 + jnp.where(g2 != 0, scalar, 0.0)
+            if self.regularizer == "l2":
+                return g2 + 2.0 * self.lam * w2
+            return g2
 
 
 class SparseSVM(LinearModel):

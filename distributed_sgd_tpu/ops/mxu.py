@@ -231,12 +231,14 @@ def n_blocks(n_features: int) -> int:
 def to_blocked(w: jax.Array, n_features: int) -> jax.Array:
     """[D] -> [R, 128] (zero-padded).  Cheap: pad + reshape."""
     r = n_blocks(n_features)
-    return jnp.pad(w, (0, r * LANES - n_features)).reshape(r, LANES)
+    with jax.named_scope("dsgd.layout"):
+        return jnp.pad(w, (0, r * LANES - n_features)).reshape(r, LANES)
 
 
 def from_blocked(w2: jax.Array, n_features: int) -> jax.Array:
     """[R, 128] -> [D]."""
-    return w2.reshape(-1)[:n_features]
+    with jax.named_scope("dsgd.layout"):
+        return w2.reshape(-1)[:n_features]
 
 
 def to_blocked_np(w: np.ndarray, n_features: int) -> np.ndarray:
@@ -250,25 +252,29 @@ class OneHotBatch:
     the iota-compare builds into the consuming matmuls."""
 
     def __init__(self, batch: SparseBatch, n_rows: int, dtype=jnp.float32):
-        flat_idx = batch.indices.reshape(-1)
-        self.flat_idx = flat_idx  # [T] flat feature ids (segment formulations)
-        self.n_rows = n_rows
-        self.values = batch.values.astype(jnp.float32).reshape(-1)  # [T]
-        self.ohr = jax.nn.one_hot(flat_idx // LANES, n_rows, dtype=dtype)  # [T, R]
-        self.ohc = jax.nn.one_hot(flat_idx % LANES, LANES, dtype=dtype)  # [T, L]
+        with jax.named_scope("dsgd.onehot"):
+            flat_idx = batch.indices.reshape(-1)
+            self.flat_idx = flat_idx  # [T] flat feature ids (segment formulations)
+            self.n_rows = n_rows
+            self.values = batch.values.astype(jnp.float32).reshape(-1)  # [T]
+            self.ohr = jax.nn.one_hot(flat_idx // LANES, n_rows, dtype=dtype)  # [T, R]
+            self.ohc = jax.nn.one_hot(flat_idx % LANES, LANES, dtype=dtype)  # [T, L]
         self.batch_size = batch.batch_size
         self.pad_width = batch.pad_width
 
     def gathered_products(self, w2: jax.Array) -> jax.Array:
         """[T] of values[t] * w[idx[t]] — the gather, via MXU."""
-        m1 = jax.lax.dot(
-            self.ohr, w2.astype(self.ohr.dtype), preferred_element_type=jnp.float32
-        )  # [T, L]
-        return jnp.sum(m1 * self.ohc.astype(jnp.float32), axis=-1) * self.values
+        with jax.named_scope("dsgd.margins"):
+            m1 = jax.lax.dot(
+                self.ohr, w2.astype(self.ohr.dtype), preferred_element_type=jnp.float32
+            )  # [T, L]
+            return jnp.sum(m1 * self.ohc.astype(jnp.float32), axis=-1) * self.values
 
     def margins(self, w2: jax.Array) -> jax.Array:
         """Per-sample dots x_b . w  (ops.sparse.matvec equivalent)."""
-        return self.gathered_products(w2).reshape(self.batch_size, self.pad_width).sum(-1)
+        products = self.gathered_products(w2)
+        with jax.named_scope("dsgd.margins"):
+            return products.reshape(self.batch_size, self.pad_width).sum(-1)
 
     def scatter_add(self, coeff: jax.Array) -> jax.Array:
         """Blocked sum_b coeff[b] * x_b -> [R, 128] (scatter_add equivalent).
@@ -286,22 +292,24 @@ class OneHotBatch:
         round-6 formulations stay selectable for the next hardware
         rematch (`--fused-ab`).
         """
-        cv = (
-            self.values.reshape(self.batch_size, self.pad_width)
-            * coeff.astype(jnp.float32)[:, None]
-        ).reshape(-1)
-        form = _active_scatter
-        if form == "segment":
-            return _scatter_segment(self.flat_idx, cv, self.n_rows)
-        if form == "twostage":
-            return _scatter_twostage(
-                self.flat_idx, self.ohc.astype(jnp.float32), cv, self.n_rows)
-        if form == "bf16":
-            return _scatter_bf16(self.ohr, self.ohc, cv)
-        contrib = self.ohc.astype(jnp.float32) * cv[:, None]  # [T, L]
-        return jax.lax.dot(
-            self.ohr.T, contrib.astype(self.ohr.dtype), preferred_element_type=jnp.float32
-        )
+        with jax.named_scope("dsgd.scatter"):  # every formulation, named once
+            cv = (
+                self.values.reshape(self.batch_size, self.pad_width)
+                * coeff.astype(jnp.float32)[:, None]
+            ).reshape(-1)
+            form = _active_scatter
+            if form == "segment":
+                return _scatter_segment(self.flat_idx, cv, self.n_rows)
+            if form == "twostage":
+                return _scatter_twostage(
+                    self.flat_idx, self.ohc.astype(jnp.float32), cv, self.n_rows)
+            if form == "bf16":
+                return _scatter_bf16(self.ohr, self.ohc, cv)
+            contrib = self.ohc.astype(jnp.float32) * cv[:, None]  # [T, L]
+            return jax.lax.dot(
+                self.ohr.T, contrib.astype(self.ohr.dtype),
+                preferred_element_type=jnp.float32
+            )
 
 
 def _scatter_segment(flat_idx: jax.Array, cv: jax.Array, n_rows: int) -> jax.Array:

@@ -57,6 +57,7 @@ from distributed_sgd_tpu.models.linear import LinearModel
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import make_mesh
 from distributed_sgd_tpu.parallel.sync import SyncEngine
+from distributed_sgd_tpu.utils import measure
 from distributed_sgd_tpu.utils import metrics as metrics_mod
 
 log = logging.getLogger("dsgd.hogwild")
@@ -150,17 +151,20 @@ class _Worker:
 
             def body(carry, kk):
                 w_t, opt_s, acc = carry
-                ids = jax.random.randint(kk, (bs,), 0, shard_n)
+                with jax.named_scope("dsgd.draw"):  # as BoundSync._one_step names it
+                    ids = jax.random.randint(kk, (bs,), 0, shard_n)
+                    bi = None if dense else idx[ids]
+                    bv, by = val[ids], y[ids]
                 if dense:
-                    g = model.grad_dense(w_t, val[ids], y[ids], reduce="mean")
+                    g = model.grad_dense(w_t, bv, by, reduce="mean")
                     g = model.regularize(g, w_t)
                 elif blocked:
                     # MEAN (Slave.scala:93-98) + regularize (Slave.scala:99)
                     g = model.grad_blocked(
-                        w_t, SparseBatch(idx[ids], val[ids]), y[ids], reduce="mean")
+                        w_t, SparseBatch(bi, bv), by, reduce="mean")
                     g = model.regularize_blocked(g, w_t)
                 else:
-                    g = model.grad_mean(w_t, SparseBatch(idx[ids], val[ids]), y[ids])
+                    g = model.grad_mean(w_t, SparseBatch(bi, bv), by)
                     g = model.regularize(g, w_t)
                 from distributed_sgd_tpu.parallel.sync import local_update
 
@@ -256,43 +260,65 @@ class _Worker:
         return [by_wid[w] for w in sel]
 
     def _loop(self) -> None:
+        node = f"w{self.wid}"
         while self._running.is_set():
+            # one span per iteration (its histogram's max is what an
+            # operator reads after a stall; its count equals the dispatches)
+            # and one per phase, all under the iteration's dispatch number
+            with measure.span("slave.async.iteration", metrics=self.metrics,
+                              node=node, worker=self.wid,
+                              dispatch=self._dispatch_no):
+                self._iteration()
+
+    def _iteration(self) -> None:
+        span = measure.span
+        # phases of the iteration's span: no histogram, no trace of their own
+        phase = {"histogram": False, "root": False, "worker": self.wid,
+                 "dispatch": self._dispatch_no}
+        with span("slave.async.drain", **phase):
             self._drain_inbox()
+        with span("slave.async.step", **phase):
             self._key, k = jax.random.split(self._key)
             snapshot = self.w  # stale-read is the algorithm (Hogwild)
             delta, self._opt_state = self._step(
                 snapshot, self._opt_state, self._idx, self._val, self._y, k)
+        with span("slave.async.apply", **phase):
             with self._lock:
                 self.w = self._apply(self.w, delta)
-            self.metrics.counter("slave.async.batch").increment(self.k)
+        self.metrics.counter("slave.async.batch").increment(self.k)
+        with span("slave.async.pull", **phase):  # the wait for the device
             delta_np = np.asarray(delta)  # host hop = the wire serialization
-            self._dispatch_no += 1
-            peers = self._gossip_peers()
-            if self._compressor is None:
-                for peer in peers:
-                    peer.push_delta(delta_np)
-                if self._master is not None:
-                    self._master._update_grad(delta_np, n_steps=self.k)
-            else:
-                # the in-process engine models the wire faithfully: each
-                # destination receives the DECODED lossy delta its own
-                # encode would have produced (per-dest EF residuals), and
-                # the real proto message is built so comms.* accounting
-                # measures actual serialized bytes.  Local weights above
-                # already absorbed the full delta; what a destination
-                # doesn't get now, its residual ships later — merges stay
-                # the commutative subtractions Hogwild needs.
-                from distributed_sgd_tpu.rpc import codec as _codec  # cached after first loop
+        self._dispatch_no += 1
+        with span("slave.async.push", **phase):
+            self._push(delta_np)
+        self._t += self.k
 
-                for peer in peers:
-                    msg = self._compressor.compress(
-                        delta_np, dest=("peer", peer.wid))
-                    peer.push_delta(_codec.decode_grad(msg))
-                if self._master is not None:
-                    msg = self._compressor.compress(delta_np, dest="master")
-                    self._master._update_grad(
-                        _codec.decode_grad(msg), n_steps=self.k)
-            self._t += self.k
+    def _push(self, delta_np: np.ndarray) -> None:
+        peers = self._gossip_peers()
+        if self._compressor is None:
+            for peer in peers:
+                peer.push_delta(delta_np)
+            if self._master is not None:
+                self._master._update_grad(delta_np, n_steps=self.k)
+        else:
+            # the in-process engine models the wire faithfully: each
+            # destination receives the DECODED lossy delta its own
+            # encode would have produced (per-dest EF residuals), and
+            # the real proto message is built so comms.* accounting
+            # measures actual serialized bytes.  Local weights above
+            # already absorbed the full delta; what a destination
+            # doesn't get now, its residual ships later — merges stay
+            # the commutative subtractions Hogwild needs.
+            from distributed_sgd_tpu.rpc import codec as _codec  # cached after first loop
+
+            for peer in peers:
+                msg = self._compressor.compress(
+                    delta_np, dest=("peer", peer.wid))
+                peer.push_delta(_codec.decode_grad(msg))
+            if self._master is not None:
+                msg = self._compressor.compress(delta_np, dest="master")
+                self._master._update_grad(
+                    _codec.decode_grad(msg), n_steps=self.k)
 
 
 class HogwildEngine:
@@ -525,8 +551,11 @@ class HogwildEngine:
                 if updates - last_step < self.check_every:
                     self._stop.wait(self.backoff_s)
                     continue
-                raw_loss, raw_acc = eval_bound.evaluate(w_now)
-                stop = checker.check(raw_loss, raw_acc, w_now, step=updates)
+                # the loss check shares the chip with the workers
+                with measure.span("master.async.check", metrics=self.metrics,
+                                  node="master", updates=updates):
+                    raw_loss, raw_acc = eval_bound.evaluate(w_now)
+                    stop = checker.check(raw_loss, raw_acc, w_now, step=updates)
                 # counter with the reference's toLong truncation quirk
                 # (MasterAsync.scala:126) + a real-valued histogram for
                 # dashboards (int() flatlines any loss < 1)

@@ -66,6 +66,7 @@ from distributed_sgd_tpu.models.linear import LinearModel
 from distributed_sgd_tpu.ops import mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import WORKER_AXIS, pcast_varying, shard_map
+from distributed_sgd_tpu.utils import measure
 
 AXIS = WORKER_AXIS
 
@@ -296,31 +297,44 @@ class BoundSync:
         """One sync DP step on weights in the kernel's native layout:
         dense [D] for 'scalar'/'dense', lane-blocked [R, 128] for
         'mxu'/'pallas'.  Returns (w', opt_state')."""
-        ids = self._sample_ids(key, step)  # [K, B]
+        # The jax.named_scope names (dsgd.draw, dsgd.allreduce, dsgd.update
+        # here; dsgd.onehot / margins / coeff / scatter / regularize where
+        # the kernels are defined) are HLO metadata only: the benchmark's
+        # per-piece device metrics find each piece of the step by them
+        # (benchmark/program_spans.py, PERF.md section 3).
+        one = self.virtual_workers == 1 and self.kernel != "pallas"
+        with jax.named_scope("dsgd.draw"):
+            ids = self._sample_ids(key, step)  # [K, B]
+            if one:
+                ids = ids[0]
+            bi, bv, by = idx[ids], val[ids], y[ids]  # the resident-row gathers
         if self.kernel == "pallas":
             from distributed_sgd_tpu.ops import pallas_sparse
 
             gk = pallas_sparse.worker_grads(
-                w, idx[ids], val[ids], y[ids], self.model.grad_coeff,
+                w, bi, bv, by, self.model.grad_coeff,
                 interpret=self._pallas_interpret,
             )  # [K, R, 128], one fused launch for every worker
             gk = jax.vmap(lambda g: self.model.regularize_blocked(g, w))(gk)
-            g = jnp.sum(gk, axis=0)
-        elif self.virtual_workers == 1:
-            g = self._worker_grad(w, SparseBatch(idx[ids[0]], val[ids[0]]), y[ids[0]])
+        elif one:
+            g = self._worker_grad(w, SparseBatch(bi, bv), by)
         else:
             gk = jax.vmap(
                 lambda bi, bv, by: self._worker_grad(w, SparseBatch(bi, bv), by)
-            )(idx[ids], val[ids], y[ids])
-            g = jnp.sum(gk, axis=0)  # summed here, mean-normalized below
-        # master mean over ALL workers (Master.scala:194)
-        g = jax.lax.psum(g, AXIS) / (self.n_workers * self.virtual_workers)
-        if self.opt is None:  # reference update (Master.scala:197)
-            return w - self.learning_rate * g, opt_state
-        import optax
+            )(bi, bv, by)
+        with jax.named_scope("dsgd.allreduce"):
+            if not one:
+                g = jnp.sum(gk, axis=0)  # summed here, mean-normalized below
+            g = jax.lax.psum(g, AXIS)
+        with jax.named_scope("dsgd.update"):
+            # master mean over ALL workers (Master.scala:194)
+            g = g / (self.n_workers * self.virtual_workers)
+            if self.opt is None:  # reference update (Master.scala:197)
+                return w - self.learning_rate * g, opt_state
+            import optax
 
-        updates, opt_state = self.opt.update(g, opt_state, w)
-        return optax.apply_updates(w, updates), opt_state
+            updates, opt_state = self.opt.update(g, opt_state, w)
+            return optax.apply_updates(w, updates), opt_state
 
     @property
     def _blocked_layout(self) -> bool:
@@ -399,9 +413,12 @@ class BoundSync:
             hits = (preds == cy.astype(jnp.float32)).astype(jnp.float32)
             return (loss_acc + jnp.sum(losses * mask), hit_acc + jnp.sum(hits * mask)), ()
 
-        init = pcast_varying((jnp.float32(0), jnp.float32(0)), (AXIS,))
-        (loss_sum, hit_sum), _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
-        return jax.lax.psum(jnp.stack([loss_sum, hit_sum]), AXIS)
+        with jax.named_scope("dsgd.eval"):  # the margins inside keep dsgd.margins
+            init = pcast_varying((jnp.float32(0), jnp.float32(0)), (AXIS,))
+            (loss_sum, hit_sum), _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
+            sums = jnp.stack([loss_sum, hit_sum])
+        with jax.named_scope("dsgd.allreduce"):
+            return jax.lax.psum(sums, AXIS)
 
     def _predict_shard(self, w, idx, val) -> jax.Array:
         chunk = self.eval_chunk
@@ -416,8 +433,9 @@ class BoundSync:
                 self._chunk_margins(w_layout, SparseBatch(ci, cv))
             )
 
-        _, preds = jax.lax.scan(body, (), jnp.arange(n_chunks))
-        return preds.reshape(-1)
+        with jax.named_scope("dsgd.eval"):
+            _, preds = jax.lax.scan(body, (), jnp.arange(n_chunks))
+            return preds.reshape(-1)
 
     def _multi_epoch_shard(self, n_epochs, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
@@ -582,10 +600,14 @@ class BoundSync:
         objective = lam*||w||^2 + mean sample loss (SparseSVM.scala:20-23);
         accuracy = fraction(forward == y) (Master.scala:98-101).
         """
-        sums = self._eval(w, self.data.indices, self.data.values, self.data.labels)
-        loss_sum, hit_sum = float(sums[0]), float(sums[1])
-        n = self.data.n_true
-        reg = self.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
+        # phases of the caller's span (trainer.evaluate, master.async.check):
+        # no histogram, and no span of their own outside one
+        with measure.span("trainer.evaluate.dispatch", histogram=False, root=False):
+            sums = self._eval(w, self.data.indices, self.data.values, self.data.labels)
+        with measure.span("trainer.evaluate.pull", histogram=False, root=False):
+            loss_sum, hit_sum = float(sums[0]), float(sums[1])
+            n = self.data.n_true
+            reg = self.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
         return reg + loss_sum / n, hit_sum / n
 
 
@@ -598,11 +620,12 @@ def local_update(opt, learning_rate: float, g, w, opt_state):
     so peer merges stay the commutative subtractions Hogwild needs
     (Slave.scala:101,180), regardless of the optimizer.
     """
-    if opt is None:
-        delta = learning_rate * g  # the reference update (Slave.scala:99)
-        return w - delta, opt_state, delta
-    updates, opt_state = opt.update(g, opt_state, w)
-    return w + updates, opt_state, -updates
+    with jax.named_scope("dsgd.update"):
+        if opt is None:
+            delta = learning_rate * g  # the reference update (Slave.scala:99)
+            return w - delta, opt_state, delta
+        updates, opt_state = opt.update(g, opt_state, w)
+        return w + updates, opt_state, -updates
 
 
 def resolve_optimizer(optimizer, learning_rate: float, momentum: float = 0.9):

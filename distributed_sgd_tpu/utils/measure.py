@@ -2,11 +2,13 @@
 
 TPU-native equivalent of the reference's ``Measure`` helpers
 (utils/Measure.scala:11-35): `duration` returns (result, seconds),
-`duration_log` logs a named span, and `span` is a context manager that
-feeds the metrics registry — and, when the distributed tracer is active
-(trace/, DSGD_TRACE), ALSO opens a trace span, so one instrumentation
-point serves both the aggregate surface (histograms -> exporters) and the
-causal one (span timelines -> Perfetto).  For device work, callers must
+`duration_log` logs a named span, and `span` is a context manager with
+three sinks: it feeds the metrics registry; when the distributed tracer
+is active (trace/, DSGD_TRACE) it ALSO opens a trace span; and inside a
+`jax.profiler` session it is a `TraceAnnotation` on the profiler's clock.
+One instrumentation point serves the aggregate surface (histograms ->
+exporters), the causal one (span timelines -> Perfetto) and the device
+trace (host spans against device gaps).  For device work, callers must
 account for JAX async dispatch themselves (block_until_ready) — the
 trainer does this at epoch boundaries.
 
@@ -19,7 +21,6 @@ grow the exporter payload without bound.
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import threading
 import time
@@ -46,6 +47,18 @@ SPAN_NAME_ALLOWLIST = frozenset({
     "ckpt.save",
     "ckpt.restore",
     "trainer.epoch",
+    "trainer.evaluate",
+    "trainer.evaluate.dispatch",
+    "trainer.evaluate.pull",
+    "trainer.bookkeeping",
+    "trainer.criterion",
+    "slave.async.iteration",
+    "slave.async.drain",
+    "slave.async.step",
+    "slave.async.apply",
+    "slave.async.pull",
+    "slave.async.push",
+    "master.async.check",
 })
 MAX_DISTINCT_SPAN_NAMES = 64
 SPAN_OVERFLOW_NAME = "other"
@@ -153,28 +166,88 @@ def duration_log(name: str, fn: Callable[[], T], logger=None) -> T:
     return out
 
 
-@contextlib.contextmanager
-def span(name: str, logger=None, metrics=None, root: bool = True,
-         **trace_args):
-    """Context-manager span: logs elapsed, records a histogram sample, and
-    — when tracing is active — opens a trace span (child of the thread's
-    current trace context, or a new sampled root).  `trace_args` (e.g.
-    ``node="w0:4001"``) become span attributes; with tracing off they cost
-    nothing beyond the kwargs dict.  Pass ``root=False`` for helper spans
-    that only make sense INSIDE a trace (e.g. the worker's compute/encode
-    breakdown of a Gradient call): with no active context they stay no-op
-    instead of fabricating an orphan one-span trace per unsampled call
-    (the histogram sample is recorded either way)."""
-    t0 = time.perf_counter()
-    tspan = trace_mod.span(name, root=root, **trace_args)  # NOOP_SPAN when off
-    try:
-        with tspan:
-            yield tspan
-    finally:
-        secs = time.perf_counter() - t0
-        (logger or log).debug("%s (%.3fs)", name, secs)
-        if metrics is None:
-            from distributed_sgd_tpu.utils.metrics import global_metrics
+_TraceAnnotation = None
 
-            metrics = global_metrics()
-        metrics.histogram(f"span.{_bounded_name(name)}").record(secs)
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation`, imported on first use: launchers
+    import this module and must not import jax (PR 21)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation
+
+
+class span:
+    """Context-manager span, the program's one instrumentation point, with
+    three sinks:
+
+    - a histogram sample ``span.<name>`` (always, unless ``histogram=False``)
+      and a debug log line;
+    - when the distributed tracer is active (trace/, DSGD_TRACE), a trace
+      span: child of the thread's current trace context, or a new sampled
+      root.  Pass ``root=False`` for helper spans that only make sense
+      INSIDE a trace (e.g. the worker's compute/encode breakdown of a
+      Gradient call): with no active context they stay no-op instead of
+      fabricating an orphan one-span trace per unsampled call;
+    - inside a ``jax.profiler`` session, a ``TraceAnnotation`` on the
+      profiler's clock: an event on the calling thread's line of
+      ``/host:CPU``, nested by time under whatever span the thread already
+      holds, so the span can be laid against the device's gaps.
+
+    `trace_args` (``epoch=3``, ``worker=1``, ``node="w0:4001"``) become the
+    trace span's attributes and the annotation's stats.  ``histogram=False``
+    is for spans that are PHASES of a loop iteration whose whole already
+    has a histogram: with the tracer and the profiler off such a span
+    allocates nothing beyond itself and records nothing (budget in
+    PERF.md: under 1.5 us; a full span under 5 us).  Names never start
+    with ``bench.`` or ``$``: the benchmark's reducer reads those prefixes
+    as its own marks and as python frames.
+
+    ``with span(...) as s`` binds the trace span (``NOOP_SPAN`` when the
+    tracer is off)."""
+
+    __slots__ = ("name", "_logger", "_metrics", "_root", "_histogram",
+                 "_args", "_tspan", "_annotation", "_t0")
+
+    def __init__(self, name: str, logger=None, metrics=None, root: bool = True,
+                 histogram: bool = True, **trace_args):
+        self.name = name
+        self._logger = logger
+        self._metrics = metrics
+        self._root = root
+        self._histogram = histogram
+        self._args = trace_args
+
+    def __enter__(self):
+        if self._histogram:
+            self._t0 = time.perf_counter()
+        annotation = _TraceAnnotation or _profiler_annotation()
+        if annotation.is_enabled():
+            self._annotation = annotation(self.name, **self._args)
+            self._annotation.__enter__()
+        else:
+            self._annotation = None
+        if trace_mod._TRACER is None:  # the tracer's own zero-cost gate
+            self._tspan = None
+            return trace_mod.NOOP_SPAN
+        self._tspan = trace_mod.span(self.name, root=self._root, **self._args)
+        return self._tspan.__enter__()
+
+    def __exit__(self, etype, evalue, tb):
+        if self._tspan is not None:
+            self._tspan.__exit__(etype, evalue, tb)
+        if self._annotation is not None:
+            self._annotation.__exit__(etype, evalue, tb)
+        if self._histogram:
+            secs = time.perf_counter() - self._t0
+            (self._logger or log).debug("%s (%.3fs)", self.name, secs)
+            metrics = self._metrics
+            if metrics is None:
+                from distributed_sgd_tpu.utils.metrics import global_metrics
+
+                metrics = global_metrics()
+            metrics.histogram("span." + _bounded_name(self.name)).record(secs)
+        return False

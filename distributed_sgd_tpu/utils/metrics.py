@@ -217,15 +217,27 @@ class Metrics:
         self._gauges: Dict[str, Gauge] = {}
         self._lock = threading.Lock()
 
+    # look-ups of an instrument that exists take no lock and build nothing
+    # (a GIL-atomic dict read): hot loops ask by name on every iteration
+
     def counter(self, name: str) -> Counter:
+        found = self._counters.get(name)
+        if found is not None:
+            return found
         with self._lock:
             return self._counters.setdefault(name, Counter(name))
 
     def histogram(self, name: str) -> Histogram:
+        found = self._hists.get(name)
+        if found is not None:
+            return found
         with self._lock:
             return self._hists.setdefault(name, Histogram(name))
 
     def gauge(self, name: str) -> Gauge:
+        found = self._gauges.get(name)
+        if found is not None:
+            return found
         with self._lock:
             return self._gauges.setdefault(name, Gauge(name))
 
@@ -536,6 +548,7 @@ COMPILE_CACHE_MISSES = "compile.cache.misses"    # counter: XLA compiles paid in
 COMPILE_WARMUP_KERNELS = "compile.warmup.kernels"  # counter: flagship shapes pre-compiled
 COMPILE_WARMUP_SECONDS = "compile.warmup.seconds"  # gauge: background warmup wall clock
 COMPILE_WARMUP_ERRORS = "compile.warmup.errors"  # counter: thunks that failed (logged)
+COMPILE_SECONDS = "compile.seconds"              # histogram: one sample per backend compile / cache retrieval
 # The data plane (DSGD_HOST_OVERPROVISION + RowReader reload): an elastic
 # resplit that lands outside the worker's resident slice re-loads ONLY the
 # delta row range through its reader — reload.rows is the O(delta) proof

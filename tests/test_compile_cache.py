@@ -121,6 +121,60 @@ def test_cache_dir_reuse_across_processes_hits(spinup_runs):
     assert warm["files"] == cold["files"]
 
 
+# one epoch program compiled through place(); says whether the persistent
+# cache served it (compile_cache.compiles(): function, seconds, hit)
+_EPOCH_CHILD = """
+import json
+import jax
+jax.config.update("jax_platforms", "cpu")
+import jax.numpy as jnp
+from distributed_sgd_tpu import compile_cache
+compile_cache.place()
+from distributed_sgd_tpu.data.synthetic import rcv1_like
+from distributed_sgd_tpu.models.linear import make_model
+from distributed_sgd_tpu.parallel.mesh import make_mesh
+from distributed_sgd_tpu.parallel.sync import SyncEngine
+
+data = rcv1_like(256, n_features=512, nnz=4, seed=0)
+bound = SyncEngine(make_model("hinge", 1e-5, 512), make_mesh(1), 16, 0.5,
+                   kernel="mxu", virtual_workers=2).bind(data)
+jax.block_until_ready(bound.epoch(jnp.zeros((512,)), jax.random.PRNGKey(0)))
+print(json.dumps({"epoch": [hit for _at, fun, _s, hit in compile_cache.compiles()
+                            if fun == "jit(_epoch_shard)"]}))
+"""
+
+
+def test_cache_is_keyed_by_scope_names_but_not_by_where_the_checkout_lies(tmp_path):
+    """An executable read back from the cache carries the names (the
+    jax.named_scope paths a profile shows) of the code that compiled it,
+    so another version's names must MISS; the same code unpacked at
+    another path must still HIT (paths are named from the checkout's
+    root)."""
+    import shutil
+
+    package = os.path.join(REPO, "distributed_sgd_tpu")
+    for name in ("a", "b", "renamed"):
+        shutil.copytree(package, tmp_path / name / "distributed_sgd_tpu",
+                        ignore=shutil.ignore_patterns("__pycache__", "*.so*"))
+    sync = tmp_path / "renamed" / "distributed_sgd_tpu" / "parallel" / "sync.py"
+    text = sync.read_text()
+    assert '"dsgd.draw"' in text
+    sync.write_text(text.replace('"dsgd.draw"', '"dsgd.drawn"'))
+    cache = str(tmp_path / "cc")
+
+    def epoch_hits(name):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(tmp_path / name))
+        env[compile_cache.ENV_DIR] = cache
+        out = subprocess.run([sys.executable, "-c", _EPOCH_CHILD], capture_output=True,
+                             text=True, env=env, cwd=str(tmp_path), check=False)
+        assert out.returncode == 0, out.stderr[-4000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])["epoch"]
+
+    assert epoch_hits("a") == [False]        # cold
+    assert epoch_hits("b") == [True]         # the same code, elsewhere
+    assert epoch_hits("renamed") == [False]  # other names: never a stale executable
+
+
 def _mini_worker(seed=0):
     data = rcv1_like(64, n_features=128, nnz=4, seed=seed)
     model = make_model("hinge", 1e-5, 128)
