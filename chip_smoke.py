@@ -18,6 +18,10 @@ lambda 1e-5) through the entry point a user runs,
            the direct dot product to 1e-4
 - pallas   SyncEngine(kernel='pallas') compiled by Mosaic (no interpreter),
            one flagship-shape step against kernel='mxu' to 1e-4
+- placement  SyncEngine.bind on dense 2,000-wide rows and on 76-wide ones:
+           the wide rows sit row-major (padded to whole lanes) and the
+           compiled epoch program holds no copy of them, the narrow ones
+           stay as they come
 
 One process per chip: this parent never imports jax (nor the package, whose
 submodules do); it runs its children one after another, each with
@@ -54,9 +58,11 @@ DEADLINE_S = 1150  # the whole run, compilation included
 PHASE_TIMEOUT_S = 420
 
 FULL = dict(rows=804_414, rpc_rows=100_000, gossip_rows=2_500,
-            pallas_rows=20_000, pallas_dim=47_236, pallas_nnz=76)
+            pallas_rows=20_000, pallas_dim=47_236, pallas_nnz=76,
+            placement_rows=32_768)
 TINY = dict(rows=3_000, rpc_rows=2_000, gossip_rows=600,
-            pallas_rows=2_000, pallas_dim=512, pallas_nnz=8)
+            pallas_rows=2_000, pallas_dim=512, pallas_nnz=8,
+            placement_rows=512)
 # sanity band for the full-width mesh runs (correctness, not speed): the
 # last full-width ltc record is 0.364 / 0.826 after 2 epochs — at 3 workers.
 # Every-device on a one-chip machine is ONE worker: a third of the samples
@@ -115,6 +121,55 @@ def child_pallas(rows: int, dim: int, nnz: int) -> None:
         "max_abs_diff": float(np.max(np.abs(out["pallas"] - out["mxu"]))),
         "moved": float(np.max(np.abs(out["mxu"] - np.asarray(w0)))),
         "hits": hits, "misses": misses,
+    }))
+
+
+def child_placement(rows: int) -> None:
+    """Where SyncEngine.bind puts resident rows (parallel/mesh.put_rows):
+    2,000-wide dense rows and 76-wide sparse ones, and what the dense
+    binding's compiled epoch program does with them at its entry."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_sgd_tpu import compile_cache
+    from distributed_sgd_tpu.data.rcv1 import Dataset
+    from distributed_sgd_tpu.data.synthetic import rcv1_like
+    from distributed_sgd_tpu.models.linear import make_model
+    from distributed_sgd_tpu.parallel.mesh import make_mesh
+    from distributed_sgd_tpu.parallel.sync import SyncEngine
+    from distributed_sgd_tpu.utils import metrics
+
+    compile_cache.place()
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((rows, 2000), dtype=np.float32)
+    dense = Dataset.dense(x, np.where(rng.random(rows) < 0.5, 1, -1).astype(np.int32))
+    sparse = rcv1_like(rows, n_features=47_236, nnz=76, seed=0)
+    mesh = make_mesh(1)
+    bound = {
+        "dense": SyncEngine(make_model("logistic", 1e-6, 2000, regularizer="l2"),
+                            mesh, batch_size=100, learning_rate=0.05,
+                            virtual_workers=4).bind(dense),
+        "sparse": SyncEngine(make_model("hinge", 1e-5, 47_236), mesh,
+                             batch_size=100, learning_rate=0.5,
+                             virtual_workers=4).bind(sparse)}
+
+    b, d = bound["dense"], bound["dense"].data
+    w0, key = jnp.zeros((2000,), jnp.float32), jax.random.PRNGKey(7)
+    text = b._epoch.lower(w0, b._opt_state, d.indices, d.values, d.labels,
+                          key).compile().as_text()
+    resident = re.escape("f32[%d,%d]" % d.values.shape)
+    w = np.asarray(b.epoch(w0, key))
+    print(json.dumps({
+        "platform": jax.devices()[0].platform,
+        "dense_stored": [list(p[2]) for p in b.placement()],
+        "dense_width": d.values.shape[1],
+        "resident_copies": len(re.findall(rf"= {resident}\S* copy\(", text)),
+        "sparse_widths": [bound["sparse"].data.indices.shape[1],
+                          bound["sparse"].data.values.shape[1]],
+        "row_major": metrics.counter("bind.rows.row_major").value,
+        "default": metrics.counter("bind.rows.default").value,
+        "finite": bool(np.isfinite(w).all()), "moved": float(np.max(np.abs(w))),
     }))
 
 
@@ -384,6 +439,8 @@ def main(argv: list) -> int:
             child_pallas(int(argv[2]), int(argv[3]), int(argv[4]))
         elif argv[1] == "client":
             child_client(argv[2], int(argv[3]))
+        elif argv[1] == "placement":
+            child_placement(int(argv[2]))
         else:
             raise SystemExit(f"unknown child {argv[1]!r}")
         return 0
@@ -543,19 +600,25 @@ def main(argv: list) -> int:
         out.update(cache=run["cache"], peak_bytes=run["peak_bytes"])
         return out
 
-    def pallas() -> dict:
+    def child(name: str, *args) -> dict:
+        """One `--child <name>` of this file on the phase's platform: the
+        JSON object it printed last."""
         rc, text = _run(
-            "pallas", [os.path.abspath(__file__), "--child", "pallas",
-                       str(size["pallas_rows"]), str(size["pallas_dim"]),
-                       str(size["pallas_nnz"])],
+            name, [os.path.abspath(__file__), "--child", name,
+                   *(str(a) for a in args)],
             _child_env(platform, {}), min(PHASE_TIMEOUT_S, left()))
         if rc != 0:
-            print(f"--- pallas (exit {rc}), log tail:\n{_tail(text)}",
+            print(f"--- {name} (exit {rc}), log tail:\n{_tail(text)}",
                   file=sys.stderr)
             raise PhaseFailed(f"exit code {rc}")
         out = _last_json(text)
         need(out["platform"] == device["platform"],
              f"ran on {out['platform']}")
+        return out
+
+    def pallas() -> dict:
+        out = child("pallas", size["pallas_rows"], size["pallas_dim"],
+                    size["pallas_nnz"])
         need(out["interpret"] == rehearsal,
              f"interpret={out['interpret']}: the chip run must compile the "
              f"kernel, the rehearsal must interpret it")
@@ -565,10 +628,25 @@ def main(argv: list) -> int:
         out["cache"] = {"hits": out.pop("hits"), "misses": out.pop("misses")}
         return out
 
+    def placement() -> dict:
+        out = child("placement", size["placement_rows"])
+        need(out["finite"] and out["moved"] > 0, f"degenerate epoch: {out}")
+        need(out["sparse_widths"] == [76, 76], f"76-wide rows were padded: {out}")
+        need(out["resident_copies"] == 0,
+             f"the epoch program copies its resident rows: {out}")
+        # on the chip the one wide array is stored padded to whole lanes,
+        # which the backend keeps row-major; the CPU's rule leaves all six
+        # arrays of the two bindings as they come
+        need(out["dense_stored"] == [[0, 1]], f"dense rows stored {out}")
+        need((out["row_major"], out["default"], out["dense_width"])
+             == ((0, 6, 2000) if rehearsal else (1, 5, 2048)),
+             f"placement by the wrong rule: {out}")
+        return out
+
     try:
         for name, fn in (("mesh1", mesh1), ("meshN", mesh_n), ("rpc", rpc),
                          ("gossip", gossip), ("serve", serve),
-                         ("pallas", pallas)):
+                         ("pallas", pallas), ("placement", placement)):
             phase(name, fn)
     finally:
         _stop_all()

@@ -113,9 +113,12 @@ class SyncTrainer:
     ) -> FitResult:
         bound_train = self.engine.bind(train)
         bound_test = self.engine.bind(test)
-        log.info("train split: %d rows, per device %s", len(train), " ".join(
-            f"[id={d} rows={r} bytes_in_use={b}]"
-            for d, r, b in bound_train.placement()))
+        placed = bound_train.placement()
+        stored = placed[0][2]  # one layout for every device's rows
+        log.info("train split: %d rows stored major_to_minor=%s, per device %s",
+                 len(train), stored, " ".join(
+                     f"[id={d} rows={r} bytes_in_use={b}]"
+                     for d, r, _stored, b in placed))
         w = (
             jnp.zeros((self.model.n_features,), dtype=jnp.float32)
             if initial_weights is None

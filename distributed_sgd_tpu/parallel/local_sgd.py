@@ -115,15 +115,16 @@ class LocalSGDEngine:
             def body(carry, t):
                 wl, opt_s = carry
                 ids = jax.random.randint(jax.random.fold_in(key, t), (bs,), 0, shard_n)
+                bi, bv = bound.rows(idx, ids), bound.rows(val, ids)
                 if dense:
-                    g = model.grad_dense(wl, val[ids], y[ids], reduce="mean")
+                    g = model.grad_dense(wl, bv, y[ids], reduce="mean")
                     g = model.regularize(g, wl)
                 elif blocked:
-                    g = model.grad_blocked(wl, SparseBatch(idx[ids], val[ids]),
+                    g = model.grad_blocked(wl, SparseBatch(bi, bv),
                                            y[ids], reduce="mean")
                     g = model.regularize_blocked(g, wl)
                 else:
-                    g = model.grad_mean(wl, SparseBatch(idx[ids], val[ids]), y[ids])
+                    g = model.grad_mean(wl, SparseBatch(bi, bv), y[ids])
                     g = model.regularize(g, wl)
                 from distributed_sgd_tpu.parallel.sync import local_update
 

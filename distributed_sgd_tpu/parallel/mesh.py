@@ -12,13 +12,17 @@ the collectives ride ICI, across slices DCN.
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
+from distributed_sgd_tpu.utils import measure, metrics
 
 WORKER_AXIS = "workers"
 
@@ -94,6 +98,73 @@ def shard_dataset(data: Dataset, mesh: Mesh) -> Tuple[jax.Array, jax.Array, jax.
     val = jax.device_put(data.values, sharding)
     y = jax.device_put(data.labels, sharding)
     return idx, val, y, n_true
+
+
+# Where resident rows are placed (put_rows).  A TPU stores a [rows, width]
+# array whose width is not whole 128-lane tiles with the ROWS as the minor
+# dimension (the layout that pads least: width rounded up to 8 sublanes),
+# while every step gathers whole rows and reads them row-major; the
+# compiler bridges the two with a copy of the WHOLE resident array at the
+# entry of every program run (PERF.md section 6, PR 25: 12.6 ms of a
+# 42.5 ms epoch program at f32[491520, 2000]).  An array of whole lanes is
+# stored row-major, so rows are zero-padded to whole lanes on a TPU when
+# that is nearly free: when the lane padding (width rounded up to 128)
+# costs at most this much more than the default's sublane padding.  2,000
+# wide: 2048 / 2000 = 1.024, padded; 76 wide: 128 / 80 = 1.6, left as it is
+# (+60 % of the rows' HBM to save a copy that long epoch programs
+# amortise).  Zero-width and 1-D arrays have no rows to gather; whole-lane
+# widths and the CPU are row-major already.  The padding is the array's
+# SHAPE and not a jax.experimental.layout.Format on it: an executable
+# compiled for a non-default parameter layout comes back from the
+# persistent compile cache expecting the default one (jax 0.9.0 / libtpu
+# 0.0.34, PERF.md section 6).
+ROW_MAJOR_MAX_PADDING = 1.125
+_LANES, _SUBLANES = 128, 8
+_PAD_CHUNK = 4096  # rows re-laid-out per step of the one-off padding program
+
+
+def lane_width(shape: Tuple[int, ...], platform: str) -> Optional[int]:
+    """The width `put_rows` pads an array of `shape` to: whole lanes where
+    the rule above says it pays, None (as it comes) everywhere else."""
+    if platform != "tpu" or len(shape) != 2 or shape[1] % _LANES == 0:
+        return None
+    lanes = -(-shape[1] // _LANES) * _LANES
+    sublanes = -(-shape[1] // _SUBLANES) * _SUBLANES
+    return lanes if lanes <= ROW_MAJOR_MAX_PADDING * sublanes else None
+
+
+def _pad_lanes(shard: jax.Array, width: int) -> jax.Array:
+    """One device's rows, zero-padded to `width`, a chunk of rows at a time
+    so that the re-layout needs no second copy of the shard."""
+    rows = shard.shape[0]
+    chunk = math.gcd(rows, _PAD_CHUNK)
+
+    def body(out, t):
+        piece = jax.lax.dynamic_slice_in_dim(shard, t * chunk, chunk, 0)
+        return jax.lax.dynamic_update_slice(out, piece, (t * chunk, 0)), ()
+
+    zeros = pcast_varying(jnp.zeros((rows, width), shard.dtype), (WORKER_AXIS,))
+    out, _ = jax.lax.scan(body, zeros, jnp.arange(rows // chunk))
+    return out
+
+
+def put_rows(arr, sharding: NamedSharding) -> jax.Array:
+    """Place one resident array, rows sharded over the workers, so that it
+    is stored in the layout the step gathers from: as it comes, or (where
+    `lane_width` says so) zero-padded to whole lanes, which the backend
+    stores row-major.  Readers take the true width back off (BoundSync.rows
+    / .chunk): the padding is never read.  The padding runs once, on the
+    devices, from the default placement."""
+    width = lane_width(arr.shape, next(iter(sharding.device_set)).platform)
+    name = "default" if width is None else "row_major"
+    with measure.span("sync.bind.place", layout=name, bytes=arr.nbytes):
+        placed = jax.device_put(arr, sharding)
+        if width is not None:
+            placed = jax.jit(shard_map(
+                functools.partial(_pad_lanes, width=width), mesh=sharding.mesh,
+                in_specs=sharding.spec, out_specs=sharding.spec))(placed)
+    metrics.counter(f"bind.rows.{name}").increment()
+    return placed
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

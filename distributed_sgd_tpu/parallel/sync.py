@@ -65,7 +65,12 @@ from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
 from distributed_sgd_tpu.ops import mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
-from distributed_sgd_tpu.parallel.mesh import WORKER_AXIS, pcast_varying, shard_map
+from distributed_sgd_tpu.parallel.mesh import (
+    WORKER_AXIS,
+    pcast_varying,
+    put_rows,
+    shard_map,
+)
 from distributed_sgd_tpu.utils import measure
 
 AXIS = WORKER_AXIS
@@ -76,6 +81,9 @@ class ShardedData(NamedTuple):
     values: jax.Array  # f32[N_pad, P], sharded over workers
     labels: jax.Array  # [N_pad], sharded over workers; 0 = padding mask
     n_true: int  # real sample count (host-side)
+    # the rows' true width: bind() may store indices / values zero-padded
+    # to whole lanes (mesh.put_rows); None: the arrays are as wide as the rows
+    width: Optional[int] = None
 
     @property
     def is_dense(self) -> bool:
@@ -162,6 +170,10 @@ class BoundSync:
         self.virtual_workers = int(virtual_workers)
         if self.virtual_workers < 1:
             raise ValueError("virtual_workers must be >= 1")
+        # rows stored wider than the dataset holds them (mesh.put_rows):
+        # every read takes the true width back off (rows / chunk)
+        padded = data.width is not None and data.width < data.values.shape[1]
+        self._width = data.width if padded else None
         n_pad = data.indices.shape[0]
         self.shard_n = n_pad // self.n_workers
         self.eval_chunk = min(eval_chunk, self.shard_n)
@@ -307,7 +319,8 @@ class BoundSync:
             ids = self._sample_ids(key, step)  # [K, B]
             if one:
                 ids = ids[0]
-            bi, bv, by = idx[ids], val[ids], y[ids]  # the resident-row gathers
+            # the resident-row gathers
+            bi, bv, by = self.rows(idx, ids), self.rows(val, ids), y[ids]
         if self.kernel == "pallas":
             from distributed_sgd_tpu.ops import pallas_sparse
 
@@ -336,6 +349,17 @@ class BoundSync:
             updates, opt_state = self.opt.update(g, opt_state, w)
             return optax.apply_updates(w, updates), opt_state
 
+    def rows(self, resident, ids):
+        """Rows `ids` of a resident [rows, width] array as the dataset holds
+        them: without the lane padding bind() may have stored them with."""
+        rows = resident[ids]  # whole stored rows: the compiler's fast gather
+        return rows if self._width is None else rows[..., :self._width]
+
+    def chunk(self, resident, start):
+        """The evaluation's rows [start, start + eval_chunk), likewise."""
+        rows = jax.lax.dynamic_slice_in_dim(resident, start, self.eval_chunk, 0)
+        return rows if self._width is None else rows[:, :self._width]
+
     @property
     def _blocked_layout(self) -> bool:
         return self.kernel in ("mxu", "pallas")
@@ -350,9 +374,20 @@ class BoundSync:
             return mxu.from_blocked(w, self.model.n_features)
         return w
 
+    def _loop_labels(self, y):
+        """The labels a scan over steps gathers from.  Where the rows are
+        read in place there is no copy of them at the program's entry for
+        the compiler to hide a fetch behind, and it then fetches the WHOLE
+        label array into fast memory again in every step (1.6 us of a
+        24.7 us step at 491,520 labels, PERF.md section 6, PR 25); a float32
+        copy made once before the loop it keeps there.  Every grad_coeff
+        casts its labels to float32 first, so the step computes the same."""
+        return y if self._width is None else y.astype(jnp.float32)
+
     def _epoch_shard(self, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
         w = self._to_kernel_layout(w)
+        y = self._loop_labels(y)
 
         def body(carry, step):
             return self._one_step(*carry, idx, val, y, key, step), ()
@@ -403,8 +438,7 @@ class BoundSync:
         def body(acc, t):
             loss_acc, hit_acc = acc
             s = t * chunk
-            ci = jax.lax.dynamic_slice_in_dim(idx, s, chunk, 0)
-            cv = jax.lax.dynamic_slice_in_dim(val, s, chunk, 0)
+            ci, cv = self.chunk(idx, s), self.chunk(val, s)
             cy = jax.lax.dynamic_slice_in_dim(y, s, chunk, 0)
             mask = (cy != 0).astype(jnp.float32)
             margins = self._chunk_margins(w_layout, SparseBatch(ci, cv))
@@ -427,8 +461,7 @@ class BoundSync:
 
         def body(_, t):
             s = t * chunk
-            ci = jax.lax.dynamic_slice_in_dim(idx, s, chunk, 0)
-            cv = jax.lax.dynamic_slice_in_dim(val, s, chunk, 0)
+            ci, cv = self.chunk(idx, s), self.chunk(val, s)
             return (), self.model.predict(
                 self._chunk_margins(w_layout, SparseBatch(ci, cv))
             )
@@ -440,6 +473,7 @@ class BoundSync:
     def _multi_epoch_shard(self, n_epochs, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
         w = self._to_kernel_layout(w)
+        y = self._loop_labels(y)
 
         def epoch_body(c, e):
             ke = jax.random.fold_in(key, e)
@@ -512,11 +546,15 @@ class BoundSync:
                 self.warmup_thunks())
 
     def placement(self):
-        """[(device id, resident rows, device bytes_in_use)] for the bound
-        split — where the rows actually sit, as the runtime reports it
-        (bytes are None on backends without memory_stats)."""
+        """[(device id, resident rows, the values' major_to_minor, device
+        bytes_in_use)] for the bound split — where and how the rows actually
+        sit, as the runtime reports it: (0, 1) is row-major, what the step's
+        gather reads (mesh.put_rows); the layout and the bytes are None on
+        backends that do not report them."""
+        layout = self.data.values.format.layout
+        stored = None if layout is None else layout.major_to_minor
         return [
-            (s.device.id, s.data.shape[0],
+            (s.device.id, s.data.shape[0], stored,
              (s.device.memory_stats() or {}).get("bytes_in_use"))
             for s in self.data.indices.addressable_shards
         ]
@@ -718,12 +756,13 @@ class SyncEngine:
             local = _pad_to_exact(data, total)
 
             def put(arr):
-                return jax.device_put(arr, sharding)
+                return put_rows(arr, sharding)
         sharded = ShardedData(
             indices=put(local.indices),
             values=put(local.values),
             labels=put(local.labels),
             n_true=n_true,
+            width=local.values.shape[1],
         )
         bound = BoundSync(
             self.model,

@@ -59,6 +59,7 @@ SPAN_NAME_ALLOWLIST = frozenset({
     "slave.async.pull",
     "slave.async.push",
     "master.async.check",
+    "sync.bind.place",
 })
 MAX_DISTINCT_SPAN_NAMES = 64
 SPAN_OVERFLOW_NAME = "other"

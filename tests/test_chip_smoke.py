@@ -87,7 +87,7 @@ def test_rehearsal_passes_every_phase_and_says_it_is_one(rehearsal):
     result = json.loads(summary[len("summary: "):])
     assert result["rehearsal"] is True
     assert list(result["phases"]) == [
-        "mesh1", "meshN", "rpc", "gossip", "serve", "pallas"]
+        "mesh1", "meshN", "rpc", "gossip", "serve", "pallas", "placement"]
     assert all(p["ok"] for p in result["phases"].values())
     # the every-device phase really split the rows, and ran its reference
     mesh_n = result["phases"]["meshN"]

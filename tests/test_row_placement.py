@@ -1,0 +1,230 @@
+"""Where `SyncEngine.bind` places the resident rows (parallel/mesh.py
+`lane_width` / `put_rows`, PERF.md section 6, PR 25).
+
+On a TPU a [rows, width] array whose width is not whole 128-lane tiles is
+stored rows-minor while the step gathers whole rows, so wide-enough rows are
+stored zero-padded to whole lanes (row-major by default) and every reader
+takes the true width back off.  The chip is out of tier-1's reach, so these
+tests hold what the CPU can: (a) the rule as a pure function; (b) with the
+rule made to pad on the CPU too, the padded arrays hold the rows and zeros,
+and every program of a binding gives bit-identical results to the unpadded
+placement; (c) the counters and the span that say it engaged.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_sgd_tpu import trace as trace_mod
+from distributed_sgd_tpu.data.rcv1 import Dataset
+from distributed_sgd_tpu.data.synthetic import rcv1_like
+from distributed_sgd_tpu.models.linear import make_model
+from distributed_sgd_tpu.parallel import mesh as mesh_mod
+from distributed_sgd_tpu.parallel.local_sgd import LocalSGDEngine
+from distributed_sgd_tpu.parallel.mesh import lane_width, make_mesh
+from distributed_sgd_tpu.parallel.sync import SyncEngine
+from distributed_sgd_tpu.utils import metrics as metrics_mod
+
+
+# -- (a) the rule --------------------------------------------------------------
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize("shape,on_tpu", [
+    ((4096, 76), None),      # rcv1: 128 / 80 = 1.6x the bytes, left alone
+    ((4096, 2000), 2048),    # epsilon: 2048 / 2000 = 1.024x
+    ((4096, 128), None),     # whole lanes: stored row-major as it comes
+    ((4096, 0), None),       # dense data's index array: no rows to gather
+    ((4096,), None),         # labels
+])
+def test_rule_reads_only_shape_and_platform(shape, on_tpu, platform):
+    assert lane_width(shape, platform) == (on_tpu if platform == "tpu" else None)
+
+
+def test_rule_turns_over_at_one_eighth_more_bytes():
+    # 120 wide: 128 / 120 = 1.067 pays; 112 wide: 128 / 112 = 1.143 does not
+    assert lane_width((8, 120), "tpu") == 128
+    assert lane_width((8, 112), "tpu") is None
+    assert lane_width((8, 1930), "tpu") == 2048
+    assert mesh_mod.ROW_MAJOR_MAX_PADDING == 1.125
+
+
+# -- (b) readers never see the padding -----------------------------------------
+
+def _pad_everywhere(monkeypatch):
+    """The rule as on a TPU with no byte limit: every 2-D array with columns
+    is stored padded to whole lanes, on the CPU too."""
+    monkeypatch.setattr(mesh_mod, "ROW_MAJOR_MAX_PADDING", float("inf"))
+    rule = mesh_mod.lane_width
+    monkeypatch.setattr(mesh_mod, "lane_width", lambda shape, platform: rule(shape, "tpu"))
+
+
+def _data(kind: str) -> Dataset:
+    if kind == "sparse":
+        return rcv1_like(256, n_features=64, nnz=6, seed=3)
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(256, 24)).astype(np.float32)
+    return Dataset.dense(x, np.where(rng.random(256) < 0.5, 1, -1).astype(np.int32))
+
+
+def _bind(kind: str, devices: int):
+    data = _data(kind)
+    model = make_model("hinge", 1e-3, data.n_features)
+    engine = SyncEngine(model, make_mesh(devices), batch_size=8,
+                        learning_rate=0.1, eval_chunk=32, virtual_workers=2)
+    return engine.bind(data)
+
+
+def _everything(bound):
+    w0 = jnp.asarray(np.random.default_rng(5).normal(
+        size=bound.model.n_features) * 0.1, jnp.float32)
+    key = jax.random.PRNGKey(11)
+    w_epoch = bound.epoch(w0, key)
+    return {"epoch": np.asarray(w_epoch), "step": np.asarray(bound.step(w0, key)),
+            "evaluate": np.asarray(bound.evaluate(w_epoch)),
+            "predict": bound.predict(w_epoch)}
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("kind", ["dense", "sparse"])
+def test_programs_read_padded_rows_bit_for_bit(monkeypatch, kind, devices):
+    plain = _bind(kind, devices)
+    want = _everything(plain)
+
+    _pad_everywhere(monkeypatch)
+    bound = _bind(kind, devices)
+    d, width = bound.data, plain.data.values.shape[1]
+    assert (d.width, d.values.shape, d.labels.shape) == (width, (256, 128), (256,))
+    assert d.indices.shape == ((256, 0) if kind == "dense" else (256, 128))
+    for name in ("indices", "values"):  # the rows, then zeros
+        stored, rows = np.asarray(getattr(d, name)), np.asarray(getattr(plain.data, name))
+        np.testing.assert_array_equal(stored[:, :rows.shape[1]], rows)
+        assert not stored[:, rows.shape[1]:].any()
+    assert d.values.sharding == plain.data.values.sharding
+    assert bound.placement()[0][2] == (0, 1)
+
+    got = _everything(bound)
+    for name in want:
+        np.testing.assert_array_equal(got[name], want[name], err_msg=name)
+
+    # the entry computation's parameters are the bound arrays as stored:
+    # nothing is left for the compiler to bridge with a copy
+    w0 = jnp.zeros((bound.model.n_features,), jnp.float32)
+    args, _kwargs = bound._epoch.lower(
+        w0, bound._opt_state, d.indices, d.values, d.labels,
+        jax.random.PRNGKey(0)).compile().input_formats
+    compiled_for = dict(zip(("indices", "values", "labels"), args[-4:-1]))
+    if kind == "dense":
+        del compiled_for["indices"]  # zero-width: the program never reads it
+    assert compiled_for == {name: getattr(d, name).format for name in compiled_for}
+
+
+def test_padding_program_walks_uneven_shards(monkeypatch):
+    # 3 devices x 28 rows: gcd(28, 4096) = 4, seven pieces a shard
+    _pad_everywhere(monkeypatch)
+    x = np.arange(84 * 5, dtype=np.float32).reshape(84, 5)
+    sharding = jax.sharding.NamedSharding(make_mesh(3), jax.sharding.PartitionSpec("workers"))
+    stored = mesh_mod.put_rows(x, sharding)
+    assert stored.shape == (84, 128) and stored.sharding == sharding
+    np.testing.assert_array_equal(np.asarray(stored), np.pad(x, ((0, 0), (0, 123))))
+
+
+def test_local_sgd_reads_padded_rows_bit_for_bit(monkeypatch):
+    def fit():
+        data = _data("dense")
+        engine = LocalSGDEngine(make_model("hinge", 1e-3, data.n_features), make_mesh(2),
+                                batch_size=8, learning_rate=0.1, sync_period=4,
+                                check_every=16, seed=1)
+        return np.asarray(engine.fit(data, data, max_epochs=1).weights)
+
+    want = fit()
+    _pad_everywhere(monkeypatch)
+    np.testing.assert_array_equal(fit(), want)
+
+
+# -- (c) the counters and the span ---------------------------------------------
+
+def _counts():
+    return {name: metrics_mod.counter(f"bind.rows.{name}").value
+            for name in ("row_major", "default")}
+
+
+def test_one_counter_and_one_span_per_placed_array(monkeypatch, tmp_path):
+    tracer = trace_mod.configure(enabled=True, dir=str(tmp_path), sample=1.0,
+                                 service="t")
+    try:
+        spans_before = metrics_mod.histogram("span.sync.bind.place").count
+        before = _counts()
+        bound = _bind("dense", 1)  # the CPU's rule: every array as it comes
+        after = _counts()
+        assert (after["default"] - before["default"],
+                after["row_major"] - before["row_major"]) == (3, 0)
+
+        _pad_everywhere(monkeypatch)
+        _bind("dense", 1)  # values padded; zero-width indices and labels not
+        last = _counts()
+        assert (last["default"] - after["default"],
+                last["row_major"] - after["row_major"]) == (2, 1)
+        assert metrics_mod.histogram("span.sync.bind.place").count - spans_before == 6
+
+        spans = [e["args"] for e in tracer.events() if e.get("name") == "sync.bind.place"]
+        assert [s["layout"] for s in spans] == ["default"] * 4 + ["row_major", "default"]
+        d = bound.data
+        assert [s["bytes"] for s in spans[:3]] == [
+            d.indices.nbytes, d.values.nbytes, d.labels.nbytes]
+    finally:
+        trace_mod.configure(enabled=False)
+
+
+# -- (d) compiled for the chip, without one --------------------------------------
+# The TPU's compiler is installed here and compiles for a described v5e (no
+# device, no time).  Only a fixture may describe the topology: the process
+# that does so holds libtpu's lock until it exits.
+
+@pytest.fixture(scope="module")
+def v5e():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: nothing to hold
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+def _resident_copies(v5e, rows, width, stored_width):
+    """Copies of the whole resident values array in the epoch program
+    compiled for one v5e chip, with the values stored `stored_width` wide."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    mesh = Mesh(np.array(v5e.devices[:1]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+    data = ShardedData(shape((rows, 0), jnp.int32, sharding=over_rows),
+                       shape((rows, stored_width), jnp.float32, sharding=over_rows),
+                       shape((rows,), jnp.int32, sharding=over_rows), rows, width)
+    bound = BoundSync(make_model("logistic", 1e-6, width, regularizer="l2"), mesh,
+                      data, 100, 0.05, kernel="dense", virtual_workers=4)
+    compiled = bound._epoch.lower(
+        shape((width,), jnp.float32, sharding=everywhere), (), data.indices,
+        data.values, data.labels, shape((2,), jnp.uint32, sharding=everywhere)).compile()
+    stored = compiled.input_formats[0][3].layout.major_to_minor
+    text = compiled.as_text()
+    resident = re.escape(f"f32[{rows},") + r"\d+\]\S* copy\("
+    # a whole array fetched into fast memory piecewise INSIDE the loop
+    # (BoundSync._loop_labels: the labels, once a step)
+    return stored, len(re.findall(resident, text)), text.count(" slice-start(")
+
+
+def test_on_a_v5e_padded_rows_are_row_major_and_never_copied(v5e):
+    rows, width = 491520, 2000  # epsilon-sync-1chip's train split
+    assert lane_width((rows, width), "tpu") == 2048
+    assert _resident_copies(v5e, rows, width, 2048) == ((0, 1), 0, 0)
+    # what the rule is there for; the day this fails the compiler has
+    # changed and mesh.ROW_MAJOR_MAX_PADDING can go
+    assert _resident_copies(v5e, rows, width, width) == ((1, 0), 1, 0)
