@@ -173,7 +173,14 @@ class Config:
     influx_url: Optional[str] = None
     profile_dir: Optional[str] = None  # jax.profiler trace output
     pad_width: Optional[int] = None  # sparse-batch nnz padding (None = auto)
-    kernel: str = "mxu"  # mxu | scalar (sync-engine sparse kernels)
+    # sparse kernel family of every engine: 'auto' (default) asks the one
+    # rule on feature count, row width and platform (ops/kernels.py: one-hot
+    # matmuls up to ~2e5 features, the true gather beyond; off the TPU
+    # Hogwild and the rpc worker stay scalar); a family's name pins it
+    kernel: str = "auto"  # auto | mxu | scalar | gather
+    # the model's regulariser (models/linear.py): None = 'dim_sparsity'
+    # where the data brings its sidecar (reference parity), else 'l2'
+    regularizer: Optional[str] = None  # dim_sparsity | l2 | none
     # sparse-scatter formulation inside the blocked MXU kernels
     # (ops/mxu.py, ROADMAP item 2): 'onehot' (default — the measured
     # round-4/6 winner, knobs-off training byte-identical to prior
@@ -409,7 +416,8 @@ class Config:
         # measured slower than 'mxu' at every swept shape and VMEM-OOMs at
         # large batches (benches/pallas_sweep.py; BASELINE.md) — but stays
         # reachable through SyncEngine(kernel='pallas') for kernel work
-        "kernel": ("mxu", "scalar"),
+        "kernel": ("auto", "mxu", "scalar", "gather"),
+        "regularizer": (None, "dim_sparsity", "l2", "none"),
         # 'auto' defers to a runtime rematch on the actual device
         # (ops/mxu.resolve_scatter_formulation); the rest select directly
         "scatter": ("auto", "onehot", "segment", "twostage", "bf16"),
@@ -763,6 +771,7 @@ class Config:
             profile_dir=_env("DSGD_PROFILE_DIR", None, str),
             pad_width=_env("DSGD_PAD_WIDTH", None, int),
             kernel=_env("DSGD_KERNEL", cls.kernel, str),
+            regularizer=_env("DSGD_REGULARIZER", None, str),
             scatter=_env("DSGD_SCATTER", cls.scatter, str),
             virtual_workers=_env("DSGD_VIRTUAL_WORKERS", cls.virtual_workers, int),
             exact_topology=_env("DSGD_EXACT_TOPOLOGY", cls.exact_topology, bool),

@@ -29,6 +29,7 @@ from distributed_sgd_tpu.core.early_stopping import Criterion
 from distributed_sgd_tpu.core.grad_state import GradState
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
+from distributed_sgd_tpu.ops import kernels
 from distributed_sgd_tpu.parallel.sync import BoundSync, SyncEngine
 from distributed_sgd_tpu.utils import measure
 from distributed_sgd_tpu.utils import metrics as metrics_mod
@@ -83,7 +84,7 @@ class SyncTrainer:
         profile_dir: Optional[str] = None,
         checkpointer=None,
         checkpoint_every: int = 1,
-        kernel: str = "mxu",
+        kernel: str = kernels.AUTO,
         virtual_workers: int = 1,
         optimizer=None,
         momentum: float = 0.9,
@@ -115,8 +116,8 @@ class SyncTrainer:
         bound_test = self.engine.bind(test)
         placed = bound_train.placement()
         stored = placed[0][2]  # one layout for every device's rows
-        log.info("train split: %d rows stored major_to_minor=%s, per device %s",
-                 len(train), stored, " ".join(
+        log.info("train split: %d rows kernel=%s stored major_to_minor=%s, per device %s",
+                 len(train), bound_train.kernel, stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
         w = (

@@ -72,6 +72,22 @@ class _Resident(NamedTuple):
     host: Optional[Dataset]  # host copy for reload overlap reuse (reader set)
 
 
+def _kernel_of(node, row_width: int = 1) -> str:
+    """The kernel family of a worker's compiled bodies: the one rule on the
+    model's feature count, the resident rows' width and the pinned device
+    (ops/kernels.py `resolve`), asked once a node and kept on it.  `node` is
+    a WorkerNode or anything with `.model`, as the bare hosts of the tests
+    are: those have no device (the process default backend answers) and
+    sparse rows (a dense batch routes itself, models/linear.py)."""
+    kernel = getattr(node, "kernel", None)
+    if kernel is None:
+        from distributed_sgd_tpu.ops import kernels
+
+        kernel = node.kernel = kernels.resolve(
+            None, node.model.n_features, row_width, getattr(node, "device", None))
+    return kernel
+
+
 class WorkerNode:
     def __init__(
         self,
@@ -259,9 +275,10 @@ class WorkerNode:
         self.metrics.gauge(metrics_mod.SCATTER_FORMULATION).set(
             _mxu.SCATTER_FORMULATIONS.index(
                 _mxu.active_scatter_formulation()))
+        kernel = _kernel_of(self, data.indices.shape[1])
         self.log.info(
             "worker kernel=%s on %s (%d in-host device(s))",
-            "blocked-onehot" if self._blocked_device() else "scalar",
+            "blocked-onehot" if kernel == "mxu" else kernel,
             self.device, self.host_devices)
 
         self._peers: Dict[Tuple[str, int], WorkerStub] = {}
@@ -522,14 +539,14 @@ class WorkerNode:
     def _grad_fn(self, capacity: int):
         """Sync Gradient RPC body (sum + regularize), jitted per capacity.
 
-        On a TPU-pinned worker the body runs on the lane-blocked MXU path
-        (ops/mxu.py, the same kernels as the mesh engines); on CPU workers
-        the scalar gather/scatter is faster than one-hot matmuls, so it
-        stays.  The async step compiles its own mean-reduced variant
-        (_async_loop).
+        On a TPU-pinned worker the body runs on the lane-blocked kernels
+        the shape rule picks (ops/kernels.py: one-hot matmuls or the true
+        gather, the same families as the mesh engines); on CPU workers
+        the scalar gather/scatter is faster, so it stays.  The async step
+        compiles its own mean-reduced variant (_async_loop).
         """
         model = self.model
-        blocked = self._blocked_device()
+        kernel = _kernel_of(self)
         if capacity not in self._grad_cache:
 
             def fn(w, idx, val, y, ids, valid):
@@ -537,7 +554,7 @@ class WorkerNode:
                 rows_v = val[ids] * valid[:, None]  # zero rows for pads
                 batch = SparseBatch(rows_i, rows_v)
                 by = y[ids] * valid.astype(y.dtype)
-                return model.grad_regularized(w, batch, by, blocked=blocked)
+                return model.grad_regularized(w, batch, by, kernel=kernel)
 
             # donate the request's weight buffer (ROADMAP item 2): the
             # wrapper creates it from the wire/replica numpy array per
@@ -546,12 +563,6 @@ class WorkerNode:
             # allocating a fresh dim-sized output every window
             self._grad_cache[capacity] = jax.jit(fn, donate_argnums=(0,))
         return self._grad_cache[capacity]
-
-    def _blocked_device(self) -> bool:
-        """Blocked MXU kernels pay off on this worker's pinned device?"""
-        from distributed_sgd_tpu.ops import mxu
-
-        return mxu.blocked_pays_off(self.device)
 
     def _pad_ids(self, ids: np.ndarray) -> Tuple[jax.Array, jax.Array]:
         cap = _next_pow2(len(ids))
@@ -839,7 +850,7 @@ class WorkerNode:
         lr * compute_gradient(w, ids), so the master recovers the same
         pseudo-gradient the one-batch window would have produced."""
         model = self.model
-        blocked = self._blocked_device()
+        kernel = _kernel_of(self)
         key = ("window", steps, capacity)
         if key not in self._grad_cache:
 
@@ -850,7 +861,7 @@ class WorkerNode:
                     rows_v = val[ids_t] * valid_t[:, None]  # zero rows for pads
                     batch = SparseBatch(rows_i, rows_v)
                     by = y[ids_t] * valid_t.astype(y.dtype)
-                    g = model.grad_regularized(w_t, batch, by, blocked=blocked)
+                    g = model.grad_regularized(w_t, batch, by, kernel=kernel)
                     return w_t - lr * g, None
 
                 w_end, _ = jax.lax.scan(body, w, (ids, valid))
@@ -1114,7 +1125,7 @@ class WorkerNode:
         # (the only path that re-shards mid-async) replaces this loop too
         res = self._resident
 
-        blocked = self._blocked_device()
+        kernel = _kernel_of(self)
         opt = self._async_opt
 
         def kstep(w, opt_state, assignment, idx, val, y, key):
@@ -1130,7 +1141,7 @@ class WorkerNode:
                 batch = SparseBatch(idx[ids], val[ids])
                 # MEAN reduce (Slave.scala:93-98) + regularize (Slave:99)
                 g = model.grad_regularized(
-                    w_t, batch, y[ids], reduce="mean", blocked=blocked
+                    w_t, batch, y[ids], reduce="mean", kernel=kernel
                 )
                 from distributed_sgd_tpu.parallel.sync import local_update
 

@@ -67,7 +67,8 @@ def build(cfg: Config):
     data = measure.duration_log("data loaded", lambda: load_data(cfg), log)
     train, test = train_test_split(data)
     ds = measure.duration_log("dim sparsity", lambda: dim_sparsity(train), log)
-    model = make_model(cfg.model, cfg.lam, train.n_features, dim_sparsity=ds)
+    model = make_model(cfg.model, cfg.lam, train.n_features, dim_sparsity=ds,
+                       regularizer=cfg.regularizer)
     return train, test, model
 
 
@@ -635,7 +636,7 @@ def _autopilot_stream_build(cfg: Config):
                            at=cfg.autopilot_window)
     ds = dim_sparsity(train)
     model = make_model(cfg.model, cfg.lam, train.n_features,
-                       dim_sparsity=ds)
+                       dim_sparsity=ds, regularizer=cfg.regularizer)
     return train, test, model
 
 
@@ -744,7 +745,7 @@ def _build_worker_row_store(cfg: Config):
         log.warning("row store has no dim-sparsity sidecar: the model "
                     "falls back to the plain l2 regularizer")
     model = make_model(cfg.model, cfg.lam, store.n_features,
-                       dim_sparsity=ds)
+                       dim_sparsity=ds, regularizer=cfg.regularizer)
     n_train = store.train_rows
     if cfg.host_index is None:
         # full train split resident, straight off the mmap — no parse,

@@ -34,14 +34,17 @@ LogisticRegression and LeastSquares are documented capability supersets
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_sgd_tpu.ops import mxu
+from distributed_sgd_tpu.ops import gather, kernels, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch, matvec, scatter_add
+
+REGULARIZERS = ("dim_sparsity", "l2", "none")
 
 
 class LinearModel:
@@ -59,6 +62,9 @@ class LinearModel:
         dim_sparsity: Optional[jax.Array] = None,
         regularizer: str = "dim_sparsity",
     ):
+        if regularizer not in REGULARIZERS:
+            raise ValueError(
+                f"unknown regularizer {regularizer!r}; choose from {REGULARIZERS}")
         self.lam = float(lam)
         self.n_features = int(n_features)
         self.regularizer = regularizer
@@ -79,12 +85,72 @@ class LinearModel:
     def grad_coeff(self, margins: jax.Array, y: jax.Array) -> jax.Array:
         raise NotImplementedError
 
-    # -- shared ------------------------------------------------------------
-    def margins(self, w: jax.Array, batch: SparseBatch) -> jax.Array:
+    # -- shared: the one dispatch on the kernel family (ops/kernels.py) -----
+    #
+    # `kernel` names the family and with it the layout `w` is in: flat [D]
+    # for 'scalar' and 'dense', lane-blocked [R, 128] for 'mxu' and 'gather'
+    # (`to_layout` / `from_layout`).  Engines carry the layout across their
+    # compiled loops and call `margins` and `grad` in it; callers that hold
+    # flat weights call `grad_regularized`.  Dense-layout batches take the
+    # plain products whatever `kernel` says.
+
+    def to_layout(self, w: jax.Array, kernel: str) -> jax.Array:
+        return mxu.to_blocked(w, self.n_features) if kernel in kernels.BLOCKED else w
+
+    def from_layout(self, w: jax.Array, kernel: str) -> jax.Array:
+        return mxu.from_blocked(w, self.n_features) if kernel in kernels.BLOCKED else w
+
+    def margins(self, w: jax.Array, batch: SparseBatch, kernel: str = "scalar") -> jax.Array:
+        """Per-sample dots x_b . w, `w` in `kernel`'s layout."""
         if batch.is_dense:
             return self.margins_dense(w, batch.values)
+        if kernel == "gather":
+            return gather.matvec(batch, w)
+        if kernel in kernels.BLOCKED:
+            return mxu.matvec_chunked(batch, w)
         with jax.named_scope("dsgd.margins"):
             return matvec(batch, w)
+
+    def grad(self, w: jax.Array, batch: SparseBatch, y: jax.Array,
+             kernel: str = "scalar", reduce: str = "sum") -> jax.Array:
+        """One worker's regularised gradient (backward reduce + regularize,
+        Slave.scala:142-157), `w` and the result in `kernel`'s layout.
+        reduce='sum' is the sync reply (Slave.scala:147-153), 'mean' the
+        async local step (Slave.scala:93-98)."""
+        if batch.is_dense:
+            return self.regularize(self.grad_dense(w, batch.values, y, reduce=reduce), w)
+        if kernel in kernels.BLOCKED:
+            g = self.grad_blocked(w, batch, y, reduce=reduce, kernel=kernel)
+            return self.regularize_blocked(g, w)
+        g = self.grad_sum(w, batch, y) if reduce == "sum" else self.grad_mean(w, batch, y)
+        return self.regularize(g, w)
+
+    def grad_workers(self, w: jax.Array, indices: jax.Array, values: jax.Array,
+                     y: jax.Array, kernel: str = "scalar") -> jax.Array:
+        """The SUM over K workers of their sync replies (`grad`, reduce
+        'sum'), for batches stacked [K, B, P] / [K, B]: what a device that
+        holds K virtual workers hands the all-reduce (Master.scala:194's
+        mean divides it later).
+
+        Each reply is computed on its own and the K are added.  A family
+        whose scatter walks its entries (`kernels.ONE_ACCUMULATOR`) under a
+        regulariser that is linear in the gradient ('l2', 'none') takes the
+        other order, which sums the same terms: one scatter of all K
+        batches into ONE accumulator, plus K times the regulariser's term —
+        no [K, R, 128] of replies to zero, fill and reduce (16 MB a step at
+        D = 1e6; PERF.md section 6, PR 26).  'dim_sparsity' masks by each
+        worker's own support, so it keeps the replies apart."""
+        k, b = y.shape
+        if (kernel in kernels.ONE_ACCUMULATOR and self.regularizer != "dim_sparsity"
+                and indices.shape[-1] != 0):
+            merged = SparseBatch(indices.reshape(k * b, -1), values.reshape(k * b, -1))
+            g = self.grad_blocked(w, merged, y.reshape(k * b), kernel=kernel)
+            return self.regularize_blocked(g, w, workers=k)
+        gk = jax.vmap(
+            lambda bi, bv, by: self.grad(w, SparseBatch(bi, bv), by, kernel=kernel)
+        )(indices, values, y)
+        with jax.named_scope("dsgd.allreduce"):
+            return jnp.sum(gk, axis=0)  # summed here, mean-normalized by the caller
 
     def sample_losses(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """Per-sample losses (no regularization term), vectorized."""
@@ -195,21 +261,27 @@ class LinearModel:
         return mxu.matvec(batch, w2)
 
     def grad_blocked(
-        self, w2: jax.Array, batch: SparseBatch, y: jax.Array, reduce: str = "sum"
+        self, w2: jax.Array, batch: SparseBatch, y: jax.Array, reduce: str = "sum",
+        kernel: str = "mxu",
     ) -> jax.Array:
-        """Batched backward on blocked weights: one fused gather + coeff +
-        scatter with the one-hot operands built once (ops/mxu.py).
+        """Batched backward on blocked weights: gather + coeff + scatter, as
+        one-hot matmuls with the operands built once (ops/mxu.py) or, for
+        kernel='gather', as a true gather and scatter (ops/gather.py).
 
         reduce='sum' is the sync worker reply (Slave.scala:147-153);
         reduce='mean' is the async local step (Slave.scala:93-98).
         """
-        oh = mxu.OneHotBatch(batch, w2.shape[0])
-        margins = oh.margins(w2)
+        if kernel == "gather":
+            margins, scatter = gather.matvec(batch, w2), functools.partial(
+                gather.scatter_add, batch, n_rows=w2.shape[0])
+        else:
+            oh = mxu.OneHotBatch(batch, w2.shape[0])
+            margins, scatter = oh.margins(w2), oh.scatter_add
         with jax.named_scope("dsgd.coeff"):
             coeff = self.grad_coeff(margins, y)
             if reduce == "mean":
                 coeff = coeff / batch.batch_size
-        return oh.scatter_add(coeff)
+        return scatter(coeff)
 
     def grad_regularized(
         self,
@@ -217,35 +289,32 @@ class LinearModel:
         batch: SparseBatch,
         y: jax.Array,
         reduce: str = "sum",
-        blocked: bool = False,
+        kernel: str = "scalar",
     ) -> jax.Array:
-        """Dense-in/dense-out worker gradient (backward reduce + regularize,
-        Slave.scala:142-157): one entry point for callers that hold dense
-        weights, routed through the blocked MXU kernels when `blocked`.
-        Engines that carry blocked weights across a scan call the blocked
-        methods directly instead.  Dense-layout batches route to the
-        plain-matmul fast path regardless of `blocked`."""
+        """`grad` for callers that hold FLAT weights: flat [D] in, flat [D]
+        out, through `kernel`'s layout in between.  Engines that carry a
+        layout across a scan call `grad` directly instead."""
         if batch.is_dense:
-            g = self.grad_dense(w, batch.values, y, reduce=reduce)
-            return self.regularize(g, w)
-        if blocked:
-            w2 = mxu.to_blocked(w, self.n_features)
-            g2 = self.grad_blocked(w2, batch, y, reduce=reduce)
-            return mxu.from_blocked(self.regularize_blocked(g2, w2), self.n_features)
-        g = self.grad_sum(w, batch, y) if reduce == "sum" else self.grad_mean(w, batch, y)
-        return self.regularize(g, w)
+            kernel = "dense"
+        g = self.grad(self.to_layout(w, kernel), batch, y, kernel=kernel, reduce=reduce)
+        return self.from_layout(g, kernel)
 
-    def regularize_blocked(self, g2: jax.Array, w2: jax.Array) -> jax.Array:
+    def regularize_blocked(self, g2: jax.Array, w2: jax.Array,
+                           workers: int = 1) -> jax.Array:
         """`regularize` on the blocked view; zero pad lanes stay zero
-        because the scalar is only added where g2 != 0."""
+        because the scalar is only added where g2 != 0.  `workers` > 1:
+        `g2` is the sum of that many workers' gradients and gets that many
+        times the term (`grad_workers`; linear regularisers only)."""
         with jax.named_scope("dsgd.regularize"):
             if self.regularizer == "dim_sparsity":
+                if workers != 1:
+                    raise ValueError("dim_sparsity masks by each worker's own support")
                 scalar = self.lam * 2.0 * jnp.sum(
                     w2.astype(jnp.float32) * self.dim_sparsity_blocked
                 )
                 return g2 + jnp.where(g2 != 0, scalar, 0.0)
             if self.regularizer == "l2":
-                return g2 + 2.0 * self.lam * w2
+                return g2 + 2.0 * self.lam * workers * w2
             return g2
 
 

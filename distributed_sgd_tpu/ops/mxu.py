@@ -211,10 +211,12 @@ def resolve_scatter_formulation(
 
 
 def blocked_pays_off(device=None) -> bool:
-    """One shared policy for 'should this device use the blocked one-hot
-    MXU kernels?': yes on TPU (where they beat scalar scatter ~10x), no on
-    CPU (where the scalar gather/scatter wins).  Pass the pinned device
-    when there is one; falls back to the process default backend."""
+    """One shared policy for 'do the blocked kernels pay off on this
+    device?': yes on TPU (where they beat scalar scatter ~10x), no on CPU
+    (where the scalar gather/scatter wins).  It is the platform probe of
+    the kernel rule (ops/kernels.py `resolve`), which decides WHICH
+    blocked family from the shape.  Pass the pinned device when there is
+    one; falls back to the process default backend."""
     platform = getattr(device, "platform", None)
     if platform is None:
         platform = jax.default_backend()
@@ -362,6 +364,29 @@ def _scatter_bf16(ohr: jax.Array, ohc: jax.Array, cv: jax.Array) -> jax.Array:
 def matvec(batch: SparseBatch, w2: jax.Array) -> jax.Array:
     """Standalone blocked matvec (margins) for eval-style uses."""
     return OneHotBatch(batch, w2.shape[0]).margins(w2)
+
+
+MATVEC_SUB = 512  # samples per sub-scan step of matvec_chunked
+
+
+def matvec_chunked(batch: SparseBatch, w2: jax.Array) -> jax.Array:
+    """`matvec` for batches of any size: whole multiples of MATVEC_SUB
+    samples run as a sub-scan over MATVEC_SUB at a time, which bounds the
+    [T, R] one-hot working set while keeping the matmuls large (the
+    evaluation's 4,096-sample chunks); smaller or ragged batches go to
+    `matvec` in one piece."""
+    sub = MATVEC_SUB
+    n = batch.batch_size
+    if n <= sub or n % sub != 0:
+        return matvec(batch, w2)
+
+    def body(_, t):
+        ci = jax.lax.dynamic_slice_in_dim(batch.indices, t * sub, sub, 0)
+        cv = jax.lax.dynamic_slice_in_dim(batch.values, t * sub, sub, 0)
+        return (), matvec(SparseBatch(ci, cv), w2)
+
+    _, m = jax.lax.scan(body, (), jnp.arange(n // sub))
+    return m.reshape(-1)
 
 
 def scatter_add(batch: SparseBatch, coeff: jax.Array, n_rows: int) -> jax.Array:

@@ -179,7 +179,7 @@ class FeatureShardedEngine:
 
     def _chunk_margins(self, w2_local, ci, cv):
         """512-sample sub-scan bound on the one-hot working set (the same
-        bound parallel/sync.py _chunk_margins applies to the 1-D engine)."""
+        bound ops/mxu.py matvec_chunked applies to the 1-D engine)."""
         sub = 512
         n = ci.shape[0]
         if n <= sub or n % sub != 0:

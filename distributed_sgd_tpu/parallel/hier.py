@@ -79,7 +79,7 @@ class HostMeshEngine:
         self.y = jax.device_put(data.labels, rep)
         self.n_rows = len(data)
         # blocked MXU kernels pay off on TPU, not CPU — same selection as
-        # the flat worker's _blocked_device, probed on the first device
+        # the flat worker's (core/worker.py), probed on the first device
         self._blocked = (not data.is_dense
                          and mxu.blocked_pays_off(devices[0]))
         self._cache: Dict[Tuple, callable] = {}

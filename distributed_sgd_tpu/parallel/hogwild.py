@@ -114,19 +114,19 @@ class _Worker:
         shard_n = len(shard)
         bs = batch_size
 
-        from distributed_sgd_tpu.ops import mxu
+        from distributed_sgd_tpu.ops import kernels
 
         dense = shard.is_dense
-        blocked = (not dense) and mxu.blocked_pays_off(device)
+        # the one rule on shape and this worker's device (ops/kernels.py)
+        kernel = self.kernel = kernels.resolve(
+            None, model.n_features, shard.indices.shape[1], device)
 
         k = self.k
-
-        n_features = self._n_features = model.n_features
 
         from distributed_sgd_tpu.parallel.sync import resolve_optimizer
 
         opt = self._opt = resolve_optimizer(optimizer, learning_rate, momentum)
-        self._blocked = blocked
+        self._model = model
         self._opt_state = None  # carried across dispatches (set in start_async)
 
         def kstep(w, opt_state, idx, val, y, key):
@@ -144,28 +144,17 @@ class _Worker:
             # across dispatches (opt_state threads through the carry); the
             # gossiped quantity stays a weight-space delta, so merges remain
             # the commutative subtractions the algorithm needs.
-            if blocked:
-                from distributed_sgd_tpu.ops import mxu as _mxu
-
-                w = _mxu.to_blocked(w, n_features)
+            w = model.to_layout(w, kernel)
 
             def body(carry, kk):
                 w_t, opt_s, acc = carry
                 with jax.named_scope("dsgd.draw"):  # as BoundSync._one_step names it
                     ids = jax.random.randint(kk, (bs,), 0, shard_n)
-                    bi = None if dense else idx[ids]
+                    bi = jnp.zeros((bs, 0), jnp.int32) if dense else idx[ids]
                     bv, by = val[ids], y[ids]
-                if dense:
-                    g = model.grad_dense(w_t, bv, by, reduce="mean")
-                    g = model.regularize(g, w_t)
-                elif blocked:
-                    # MEAN (Slave.scala:93-98) + regularize (Slave.scala:99)
-                    g = model.grad_blocked(
-                        w_t, SparseBatch(bi, bv), by, reduce="mean")
-                    g = model.regularize_blocked(g, w_t)
-                else:
-                    g = model.grad_mean(w_t, SparseBatch(bi, bv), by)
-                    g = model.regularize(g, w_t)
+                # MEAN (Slave.scala:93-98) + regularize (Slave.scala:99)
+                g = model.grad(w_t, SparseBatch(bi, bv), by,
+                               kernel=kernel, reduce="mean")
                 from distributed_sgd_tpu.parallel.sync import local_update
 
                 w_t, opt_s, delta = local_update(opt, learning_rate, g, w_t, opt_s)
@@ -174,9 +163,7 @@ class _Worker:
             keys = jax.random.split(key, k)
             (_, opt_state, acc), _ = jax.lax.scan(
                 body, (w, opt_state, jnp.zeros_like(w)), keys)
-            if blocked:
-                acc = _mxu.from_blocked(acc, n_features)
-            return acc, opt_state
+            return model.from_layout(acc, kernel), opt_state
 
         self._step = jax.jit(kstep)
         self._apply = jax.jit(lambda w, d: w - d)
@@ -202,19 +189,22 @@ class _Worker:
                 pass
             self.metrics.counter("slave.async.grad.dropped").increment()
 
+    @property
+    def _blocked(self) -> bool:
+        """Whether `kernel` keeps w lane-blocked: the name the benchmark's
+        Hogwild driver reads (benchmark/drivers/hogwild.py)."""
+        from distributed_sgd_tpu.ops import kernels
+
+        return self.kernel in kernels.BLOCKED
+
     def start_async(self, w0: np.ndarray) -> None:
         """StartAsync RPC (Slave.scala:159-175)."""
         self.w = jax.device_put(jnp.asarray(w0, dtype=jnp.float32), self.device)
         if self._opt is not None:
-            from distributed_sgd_tpu.ops import mxu as _mxu
-
-            # same layout derivation as kstep (n_features, not len(w0)):
-            # the state must mirror the scan carry's structure exactly
-            model_w = (
-                _mxu.to_blocked(self.w, self._n_features)
-                if self._blocked else self.w
-            )
-            self._opt_state = self._opt.init(model_w)
+            # same layout derivation as kstep: the state must mirror the
+            # scan carry's structure exactly
+            self._opt_state = self._opt.init(
+                self._model.to_layout(self.w, self.kernel))
         self._running.set()
         self._thread = threading.Thread(target=self._loop, name=f"hogwild-{self.wid}", daemon=True)
         self._thread.start()
@@ -487,9 +477,8 @@ class HogwildEngine:
         for w in workers:
             w.connect(workers, self)
         self._workers = workers
-        fallback = "dense" if train.is_dense else "scalar"
         log.info("hogwild kernel=%s, workers on %s",
-                 "/".join(sorted({"blocked-onehot" if w._blocked else fallback
+                 "/".join(sorted({"blocked-onehot" if w.kernel == "mxu" else w.kernel
                                   for w in workers})),
                  " ".join(str(w.device) for w in workers))
 

@@ -33,7 +33,7 @@ from distributed_sgd_tpu.core.loss_check import LossChecker, async_fit_result
 from distributed_sgd_tpu.core.trainer import FitResult
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
-from distributed_sgd_tpu.ops import mxu
+from distributed_sgd_tpu.ops import kernels
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import WORKER_AXIS as AXIS, pcast_varying, shard_map
 from distributed_sgd_tpu.parallel.sync import SyncEngine
@@ -54,15 +54,17 @@ class LocalSGDEngine:
         leaky_loss: float = 0.9,
         seed: int = 0,
         metrics: Optional[metrics_mod.Metrics] = None,
-        kernel: str = "mxu",
+        kernel: str = kernels.AUTO,
         checkpointer=None,
         optimizer=None,
         momentum: float = 0.9,
     ):
         if not (0.0 <= leaky_loss <= 1.0):
             raise ValueError("leaking coefficient must be between 0 and 1")
-        if kernel not in ("mxu", "scalar"):
-            raise ValueError(f"kernel must be 'mxu' or 'scalar', got {kernel!r}")
+        if kernel not in (kernels.AUTO, "mxu", "scalar", "gather"):
+            raise ValueError(
+                f"kernel must be {kernels.AUTO!r} (the shape rule, "
+                f"ops/kernels.py), 'mxu', 'scalar' or 'gather', got {kernel!r}")
         self.kernel = kernel
         # optimizer for the replicas' local steps; state rides the scan
         # carry within a round and, like the weights, is pmean-averaged at
@@ -91,7 +93,8 @@ class LocalSGDEngine:
         criterion: Optional[Criterion] = None,
         initial_weights: Optional[np.ndarray] = None,
     ) -> FitResult:
-        engine = SyncEngine(self.model, self.mesh, self.batch_size, self.learning_rate)
+        engine = SyncEngine(self.model, self.mesh, self.batch_size,
+                            self.learning_rate, kernel=self.kernel)
         bound = engine.bind(train)  # reuse dataset sharding + eval/compile plumbing
         eval_bound = engine.bind(test)
         data = bound.data
@@ -99,9 +102,9 @@ class LocalSGDEngine:
         bs, lr, h = self.batch_size, self.learning_rate, self.sync_period
         model = self.model
 
-        dense = train.is_dense  # dense layout routes to plain-matmul kernels
-        blocked = self.kernel == "mxu" and not dense
-        n_features = model.n_features
+        # what the bind chose: the one rule on shape and platform
+        # (ops/kernels.py) unless a kernel was named; dense rows run 'dense'
+        kernel = bound.kernel
 
         from distributed_sgd_tpu.parallel.sync import resolve_optimizer
 
@@ -109,23 +112,14 @@ class LocalSGDEngine:
 
         def round_shard(w, opt_state, idx, val, y, key):
             key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
-            if blocked:
-                w = mxu.to_blocked(w, n_features)
+            w = model.to_layout(w, kernel)
 
             def body(carry, t):
                 wl, opt_s = carry
                 ids = jax.random.randint(jax.random.fold_in(key, t), (bs,), 0, shard_n)
-                bi, bv = bound.rows(idx, ids), bound.rows(val, ids)
-                if dense:
-                    g = model.grad_dense(wl, bv, y[ids], reduce="mean")
-                    g = model.regularize(g, wl)
-                elif blocked:
-                    g = model.grad_blocked(wl, SparseBatch(bi, bv),
-                                           y[ids], reduce="mean")
-                    g = model.regularize_blocked(g, wl)
-                else:
-                    g = model.grad_mean(wl, SparseBatch(bi, bv), y[ids])
-                    g = model.regularize(g, wl)
+                bi, bv = bound.batch_rows(idx, val, ids)
+                g = model.grad(wl, SparseBatch(bi, bv), y[ids],
+                               kernel=kernel, reduce="mean")
                 from distributed_sgd_tpu.parallel.sync import local_update
 
                 wl, opt_s, _delta = local_update(opt, lr, g, wl, opt_s)
@@ -145,7 +139,7 @@ class LocalSGDEngine:
                 if jnp.issubdtype(x.dtype, jnp.floating) else jax.lax.pmax(x, AXIS),
                 opt_state,
             )
-            return mxu.from_blocked(wl, n_features) if blocked else wl, opt_state
+            return model.from_layout(wl, kernel), opt_state
 
         round_fn = jax.jit(
             shard_map(
@@ -166,7 +160,7 @@ class LocalSGDEngine:
         # optimizer state lives in the kernel's layout (like the weights
         # inside a round); initialized once, averaged at every sync point
         opt_state = (
-            opt.init(mxu.to_blocked(w, self.model.n_features) if blocked else w)
+            opt.init(model.to_layout(w, kernel))
             if opt is not None else None
         )
         key = jax.random.PRNGKey(self.seed)

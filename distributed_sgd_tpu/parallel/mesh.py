@@ -133,19 +133,26 @@ def lane_width(shape: Tuple[int, ...], platform: str) -> Optional[int]:
     return lanes if lanes <= ROW_MAJOR_MAX_PADDING * sublanes else None
 
 
-def _pad_lanes(shard: jax.Array, width: int) -> jax.Array:
-    """One device's rows, zero-padded to `width`, a chunk of rows at a time
-    so that the re-layout needs no second copy of the shard."""
-    rows = shard.shape[0]
+def _fill_by_chunks(rows: int, width: int, dtype, piece_at) -> jax.Array:
+    """Zeros [rows, width] with `piece_at(start, count)` written over rows
+    [start, start + count), a chunk of rows at a time so that a re-layout
+    needs no second copy of the shard."""
     chunk = math.gcd(rows, _PAD_CHUNK)
 
     def body(out, t):
-        piece = jax.lax.dynamic_slice_in_dim(shard, t * chunk, chunk, 0)
-        return jax.lax.dynamic_update_slice(out, piece, (t * chunk, 0)), ()
+        return jax.lax.dynamic_update_slice(
+            out, piece_at(t * chunk, chunk), (t * chunk, 0)), ()
 
-    zeros = pcast_varying(jnp.zeros((rows, width), shard.dtype), (WORKER_AXIS,))
+    zeros = pcast_varying(jnp.zeros((rows, width), dtype), (WORKER_AXIS,))
     out, _ = jax.lax.scan(body, zeros, jnp.arange(rows // chunk))
     return out
+
+
+def _pad_lanes(shard: jax.Array, width: int) -> jax.Array:
+    """One device's rows, zero-padded to `width`."""
+    return _fill_by_chunks(
+        shard.shape[0], width, shard.dtype,
+        lambda start, count: jax.lax.dynamic_slice_in_dim(shard, start, count, 0))
 
 
 def put_rows(arr, sharding: NamedSharding) -> jax.Array:
@@ -165,6 +172,61 @@ def put_rows(arr, sharding: NamedSharding) -> jax.Array:
                 in_specs=sharding.spec, out_specs=sharding.spec))(placed)
     metrics.counter(f"bind.rows.{name}").increment()
     return placed
+
+
+# Narrow sparse rows (put_packed).  Rows too narrow for the rule above stay
+# rows-minor, and a step then gathers its batch out of that layout: 43 us
+# for 400 rows of 39 entries at criteo-logistic's shape, where a gather of
+# whole row-major rows of the same bytes takes a tenth (PERF.md section 6,
+# PR 26).  Where a row's indices AND values fit one 128-lane row of 32-bit
+# words, bind() stores them side by side in one such row (lanes [0, P) the
+# indices, [P, 2P) the values' bits), which the backend stores row-major:
+# one gather a step instead of two, out of a layout that needs no copy.
+# It costs HBM (512 B a row where 39-wide rows take 320), so it is done
+# only while that is at most this factor: from 25 entries a row up to 64.
+PACKED_MAX_PADDING = 2.0
+
+
+def packed_width(width: int, platform: str) -> Optional[int]:
+    """The lane count `put_packed` stores a row of `width` index / value
+    pairs in, or None where rows stay two arrays."""
+    if platform != "tpu" or width == 0 or 2 * width > _LANES:
+        return None
+    stored = 2 * (-(-width // _SUBLANES) * _SUBLANES)
+    return _LANES if _LANES <= PACKED_MAX_PADDING * stored else None
+
+
+def _pack_lanes(idx: jax.Array, val: jax.Array, lanes: int) -> jax.Array:
+    """One device's rows as int32 [rows, lanes]: indices, then the values'
+    bits, then zeros."""
+    def piece_at(start, count):
+        return jnp.concatenate([
+            jax.lax.dynamic_slice_in_dim(idx, start, count, 0).astype(jnp.int32),
+            jax.lax.bitcast_convert_type(
+                jax.lax.dynamic_slice_in_dim(val, start, count, 0).astype(jnp.float32),
+                jnp.int32)], axis=1)
+
+    return _fill_by_chunks(idx.shape[0], lanes, jnp.int32, piece_at)
+
+
+def unpack_rows(packed: jax.Array, width: int):
+    """(indices int32[..., width], values f32[..., width]) of packed rows."""
+    return packed[..., :width], jax.lax.bitcast_convert_type(
+        packed[..., width:2 * width], jnp.float32)
+
+
+def put_packed(indices, values, lanes: int, sharding: NamedSharding) -> jax.Array:
+    """Place a split's indices and values as ONE resident array of `lanes`
+    32-bit lanes a row (`packed_width`), rows sharded over the workers.
+    The packing runs once, on the devices, from the default placement."""
+    with measure.span("sync.bind.place", layout="packed",
+                      bytes=indices.shape[0] * lanes * 4):
+        packed = jax.jit(shard_map(
+            functools.partial(_pack_lanes, lanes=lanes), mesh=sharding.mesh,
+            in_specs=(sharding.spec, sharding.spec), out_specs=sharding.spec))(
+                jax.device_put(indices, sharding), jax.device_put(values, sharding))
+    metrics.counter("bind.rows.packed").increment()
+    return packed
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -17,13 +17,17 @@ The whole epoch is ONE compiled program: no host round-trips, no
 serialization of the 47k-dim weight vector per batch per worker (the
 reference ships it over gRPC every batch, Master.scala:184-189).
 
-Kernel backends (`kernel=`): 'mxu' (default) keeps weights in the
-lane-blocked [R, 128] view across the epoch scan and runs the sparse
-gather/scatter as one-hot MXU matmuls (ops/mxu.py — ~32 us vs ~310 us per
-3-worker step at RCV1 shapes on v5e, benches/step_bench.py); 'scalar' is
-the reference-shaped take/scatter path (ops/sparse.py); 'dense' runs
-dense-layout datasets (Dataset.dense — no index array) as plain [B, D]
-matmuls, auto-selected at bind().  'pallas' — the hand-fused single-launch
+Kernel backends (`kernel=`): `SyncEngine.bind` asks the one rule on shape
+and platform (ops/kernels.py) unless a family is named.  'mxu' keeps
+weights in the lane-blocked [R, 128] view across the epoch scan and runs
+the sparse gather/scatter as one-hot MXU matmuls (ops/mxu.py — ~32 us vs
+~310 us per 3-worker step at RCV1 shapes on v5e, benches/step_bench.py);
+'gather' keeps the same view and runs them as a row gather and a
+scatter-add whose cost does not grow with D (ops/gather.py: the rule's
+choice from 200,000 features on); 'scalar' is the reference-shaped
+take/scatter path (ops/sparse.py); 'dense' runs dense-layout datasets
+(Dataset.dense — no index array) as plain [B, D] matmuls, auto-selected
+at bind().  'pallas' — the hand-fused single-launch
 version of the one-hot formulation (ops/pallas_sparse.py) — is an
 EXPERIMENT, not offered via Config: the regime sweep
 (benches/pallas_sweep.py, v5e) measured it 1.5-4.3x slower than 'mxu' at
@@ -63,13 +67,16 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
-from distributed_sgd_tpu.ops import mxu
+from distributed_sgd_tpu.ops import kernels, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
     WORKER_AXIS,
+    packed_width,
     pcast_varying,
+    put_packed,
     put_rows,
     shard_map,
+    unpack_rows,
 )
 from distributed_sgd_tpu.utils import measure
 
@@ -84,6 +91,10 @@ class ShardedData(NamedTuple):
     # the rows' true width: bind() may store indices / values zero-padded
     # to whole lanes (mesh.put_rows); None: the arrays are as wide as the rows
     width: Optional[int] = None
+    # narrow sparse rows bind() stored as ONE array (mesh.put_packed):
+    # `indices` then holds int32[N_pad, 128] rows of `width` indices and
+    # `width` values' bits, and `values` is a zero-width placeholder
+    packed: bool = False
 
     @property
     def is_dense(self) -> bool:
@@ -113,9 +124,10 @@ class BoundSync:
     ):
         if sampling not in ("fresh", "epoch"):
             raise ValueError(f"sampling must be 'fresh' or 'epoch', got {sampling!r}")
-        if kernel not in ("mxu", "scalar", "pallas", "dense"):
+        if kernel not in kernels.KERNELS + ("pallas",):
             raise ValueError(
-                f"kernel must be 'mxu', 'scalar', 'pallas' or 'dense', got {kernel!r}"
+                f"kernel must be one of {kernels.KERNELS + ('pallas',)} (bind() "
+                f"resolves {kernels.AUTO!r} by shape), got {kernel!r}"
             )
         dense_data = data.is_dense
         if (kernel == "dense") != dense_data:
@@ -172,8 +184,10 @@ class BoundSync:
             raise ValueError("virtual_workers must be >= 1")
         # rows stored wider than the dataset holds them (mesh.put_rows):
         # every read takes the true width back off (rows / chunk)
-        padded = data.width is not None and data.width < data.values.shape[1]
+        padded = (not data.packed and data.width is not None
+                  and data.width < data.values.shape[1])
         self._width = data.width if padded else None
+        self._packed = data.width if data.packed else None
         n_pad = data.indices.shape[0]
         self.shard_n = n_pad // self.n_workers
         self.eval_chunk = min(eval_chunk, self.shard_n)
@@ -293,18 +307,6 @@ class BoundSync:
         sel = sel % wrap.astype(sel.dtype)[:, None]
         return sel + jnp.asarray(starts, dtype=sel.dtype)[:, None]
 
-    def _worker_grad(self, w, batch, by):
-        """One reference worker's Gradient reply: per-sample backward SUM +
-        regularize at this worker's grad support (Slave.scala:142-157)."""
-        if self.kernel == "dense":
-            g = self.model.grad_dense(w, batch.values, by)
-            return self.model.regularize(g, w)
-        if self.kernel == "mxu":
-            g = self.model.grad_blocked(w, batch, by)
-            return self.model.regularize_blocked(g, w)
-        g = self.model.grad_sum(w, batch, by)
-        return self.model.regularize(g, w)
-
     def _one_step(self, w, opt_state, idx, val, y, key, step):
         """One sync DP step on weights in the kernel's native layout:
         dense [D] for 'scalar'/'dense', lane-blocked [R, 128] for
@@ -320,7 +322,7 @@ class BoundSync:
             if one:
                 ids = ids[0]
             # the resident-row gathers
-            bi, bv, by = self.rows(idx, ids), self.rows(val, ids), y[ids]
+            (bi, bv), by = self.batch_rows(idx, val, ids), y[ids]
         if self.kernel == "pallas":
             from distributed_sgd_tpu.ops import pallas_sparse
 
@@ -329,15 +331,13 @@ class BoundSync:
                 interpret=self._pallas_interpret,
             )  # [K, R, 128], one fused launch for every worker
             gk = jax.vmap(lambda g: self.model.regularize_blocked(g, w))(gk)
-        elif one:
-            g = self._worker_grad(w, SparseBatch(bi, bv), by)
-        else:
-            gk = jax.vmap(
-                lambda bi, bv, by: self._worker_grad(w, SparseBatch(bi, bv), by)
-            )(bi, bv, by)
-        with jax.named_scope("dsgd.allreduce"):
-            if not one:
+            with jax.named_scope("dsgd.allreduce"):
                 g = jnp.sum(gk, axis=0)  # summed here, mean-normalized below
+        elif one:  # one worker's Gradient reply (Slave.scala:142-157)
+            g = self.model.grad(w, SparseBatch(bi, bv), by, kernel=self.kernel)
+        else:  # the K virtual workers' replies, summed (mean-normalized below)
+            g = self.model.grad_workers(w, bi, bv, by, kernel=self.kernel)
+        with jax.named_scope("dsgd.allreduce"):
             g = jax.lax.psum(g, AXIS)
         with jax.named_scope("dsgd.update"):
             # master mean over ALL workers (Master.scala:194)
@@ -360,19 +360,24 @@ class BoundSync:
         rows = jax.lax.dynamic_slice_in_dim(resident, start, self.eval_chunk, 0)
         return rows if self._width is None else rows[:, :self._width]
 
-    @property
-    def _blocked_layout(self) -> bool:
-        return self.kernel in ("mxu", "pallas")
+    def batch_rows(self, idx, val, ids):
+        """(indices, values) of rows `ids`: two gathers, or one where bind()
+        packed a row's indices and values into one stored row."""
+        if self._packed is not None:
+            return unpack_rows(idx[ids], self._packed)
+        return self.rows(idx, ids), self.rows(val, ids)
+
+    def chunk_rows(self, idx, val, start):
+        """(indices, values) of the evaluation's chunk at `start`, likewise."""
+        if self._packed is not None:
+            return unpack_rows(self.chunk(idx, start), self._packed)
+        return self.chunk(idx, start), self.chunk(val, start)
 
     def _to_kernel_layout(self, w):
-        if self._blocked_layout:
-            return mxu.to_blocked(w, self.model.n_features)
-        return w
+        return self.model.to_layout(w, self.kernel)
 
     def _from_kernel_layout(self, w):
-        if self._blocked_layout:
-            return mxu.from_blocked(w, self.model.n_features)
-        return w
+        return self.model.from_layout(w, self.kernel)
 
     def _loop_labels(self, y):
         """The labels a scan over steps gathers from.  Where the rows are
@@ -403,31 +408,6 @@ class BoundSync:
         w, opt_state = self._one_step(w, opt_state, idx, val, y, key, jnp.int32(0))
         return self._from_kernel_layout(w), opt_state
 
-    def _chunk_margins(self, w_layout, batch: SparseBatch) -> jax.Array:
-        """Per-sample margins with the kernel matching the weight layout.
-
-        The blocked path computes the gather as one-hot MXU matmuls over a
-        512-sample sub-scan (bounds the [T, R] one-hot working set while
-        keeping matmuls large); the scalar path is a plain take-gather; the
-        dense path is one [B, D] @ [D] matmul.
-        """
-        if self.kernel == "dense":
-            return self.model.margins_dense(w_layout, batch.values)
-        if not self._blocked_layout:
-            return self.model.margins(w_layout, batch)
-        sub = 512
-        n = batch.batch_size
-        if n <= sub or n % sub != 0:
-            return mxu.matvec(batch, w_layout)
-
-        def body(_, t):
-            ci = jax.lax.dynamic_slice_in_dim(batch.indices, t * sub, sub, 0)
-            cv = jax.lax.dynamic_slice_in_dim(batch.values, t * sub, sub, 0)
-            return (), mxu.matvec(SparseBatch(ci, cv), w_layout)
-
-        _, m = jax.lax.scan(body, (), jnp.arange(n // sub))
-        return m.reshape(-1)
-
     def _eval_shard(self, w, idx, val, y) -> Tuple[jax.Array, jax.Array]:
         # chunked scan so the working set stays small; pads (label 0) masked;
         # bind() padded each shard to a multiple of eval_chunk
@@ -438,10 +418,11 @@ class BoundSync:
         def body(acc, t):
             loss_acc, hit_acc = acc
             s = t * chunk
-            ci, cv = self.chunk(idx, s), self.chunk(val, s)
+            ci, cv = self.chunk_rows(idx, val, s)
             cy = jax.lax.dynamic_slice_in_dim(y, s, chunk, 0)
             mask = (cy != 0).astype(jnp.float32)
-            margins = self._chunk_margins(w_layout, SparseBatch(ci, cv))
+            # the same gather the step runs (models/linear.py `margins`)
+            margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel)
             losses = self.model.losses_from_margins(margins, cy)
             preds = self.model.predict(margins)
             hits = (preds == cy.astype(jnp.float32)).astype(jnp.float32)
@@ -461,10 +442,9 @@ class BoundSync:
 
         def body(_, t):
             s = t * chunk
-            ci, cv = self.chunk(idx, s), self.chunk(val, s)
+            ci, cv = self.chunk_rows(idx, val, s)
             return (), self.model.predict(
-                self._chunk_margins(w_layout, SparseBatch(ci, cv))
-            )
+                self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel))
 
         with jax.named_scope("dsgd.eval"):
             _, preds = jax.lax.scan(body, (), jnp.arange(n_chunks))
@@ -551,7 +531,8 @@ class BoundSync:
         sit, as the runtime reports it: (0, 1) is row-major, what the step's
         gather reads (mesh.put_rows); the layout and the bytes are None on
         backends that do not report them."""
-        layout = self.data.values.format.layout
+        rows = self.data.indices if self.data.packed else self.data.values
+        layout = rows.format.layout
         stored = None if layout is None else layout.major_to_minor
         return [
             (s.device.id, s.data.shape[0], stored,
@@ -697,13 +678,15 @@ class SyncEngine:
         learning_rate: float,
         sampling: str = "fresh",
         eval_chunk: int = 4096,
-        kernel: str = "mxu",
+        kernel: str = kernels.AUTO,
         virtual_workers: int = 1,
         optimizer=None,
         momentum: float = 0.9,
         scatter: Optional[str] = None,
         donate: bool = False,
     ):
+        # kernel: AUTO (the default) lets bind() ask the shape rule
+        # (ops/kernels.py); a family's name pins it
         self.model = model
         self.mesh = mesh
         self.batch_size = batch_size
@@ -717,14 +700,22 @@ class SyncEngine:
         self.scatter = scatter
         self.donate = donate
 
+    def _resolve(self, n_features: int, row_width: int) -> str:
+        """The kernel a binding of this shape runs: the one rule on shape
+        and platform unless a family was named; off the TPU the sync
+        engines run the blocked families too (ops/kernels.py `off_tpu`)."""
+        return kernels.resolve(self.kernel, n_features, row_width,
+                               self.mesh.devices.flat[0], off_tpu="mxu")
+
     def bind(self, data: Dataset, steps_per_epoch: Optional[int] = None) -> BoundSync:
         n_workers = self.mesh.shape[AXIS]
         n_true = len(data)
         if n_true < n_workers:
             raise ValueError(f"dataset of {n_true} rows < {n_workers} workers")
+        # the one rule on shape and platform, unless a kernel was named;
         # dense-layout data can only run the dense matmul kernels (there is
-        # no index array to gather with), so auto-route it there
-        kernel = "dense" if data.is_dense else self.kernel
+        # no index array to gather with)
+        kernel = self._resolve(data.n_features, data.indices.shape[1])
         total, chunk = padded_layout(n_true, n_workers, self.eval_chunk)
         sharding = NamedSharding(self.mesh, P(AXIS))
         if jax.process_count() > 1 and self.mesh.size == jax.device_count():
@@ -752,17 +743,26 @@ class SyncEngine:
                 return jax.make_array_from_process_local_data(
                     sharding, arr, (total,) + arr.shape[1:]
                 )
+            lanes = None  # every process places its own rows, as they come
         else:
             local = _pad_to_exact(data, total)
+            lanes = packed_width(
+                local.indices.shape[1], self.mesh.devices.flat[0].platform)
 
             def put(arr):
                 return put_rows(arr, sharding)
+        if lanes is not None:
+            indices = put_packed(local.indices, local.values, lanes, sharding)
+            values = put(np.zeros((total, 0), np.float32))
+        else:
+            indices, values = put(local.indices), put(local.values)
         sharded = ShardedData(
-            indices=put(local.indices),
-            values=put(local.values),
+            indices=indices,
+            values=values,
             labels=put(local.labels),
             n_true=n_true,
             width=local.values.shape[1],
+            packed=lanes is not None,
         )
         bound = BoundSync(
             self.model,
@@ -818,7 +818,7 @@ class SyncEngine:
             sampling=self.sampling,
             steps_per_epoch=steps_per_epoch,
             eval_chunk=chunk,
-            kernel="dense" if pad_width == 0 else self.kernel,
+            kernel=self._resolve(n_features, pad_width),
             virtual_workers=self.virtual_workers,
             optimizer=self.optimizer,
             momentum=self.momentum,

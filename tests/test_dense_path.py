@@ -69,9 +69,9 @@ def test_dense_model_math_matches_sparse(model_name, labels):
         np.testing.assert_allclose(np.asarray(g_dense), np.asarray(g_sparse),
                                    rtol=1e-4, atol=1e-5)
 
-    # grad_regularized auto-routes dense batches regardless of `blocked`
+    # grad_regularized auto-routes dense batches regardless of `kernel`
     db = SparseBatch(jnp.asarray(dense.indices), jnp.asarray(dense.values))
-    g_auto = model.grad_regularized(w, db, y, blocked=True)
+    g_auto = model.grad_regularized(w, db, y, kernel="mxu")
     g_ref = model.regularize(model.grad_sum(w, sb, y), w)
     np.testing.assert_allclose(np.asarray(g_auto), np.asarray(g_ref),
                                rtol=1e-4, atol=1e-5)

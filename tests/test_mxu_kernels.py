@@ -103,8 +103,8 @@ def test_grad_regularized_blocked_matches_scalar():
     model = _model(d)
     w = jnp.asarray(np.random.default_rng(12).normal(size=d) * 0.1, dtype=jnp.float32)
     for reduce in ("sum", "mean"):
-        got = model.grad_regularized(w, batch, y, reduce=reduce, blocked=True)
-        want = model.grad_regularized(w, batch, y, reduce=reduce, blocked=False)
+        got = model.grad_regularized(w, batch, y, reduce=reduce, kernel="mxu")
+        want = model.grad_regularized(w, batch, y, reduce=reduce, kernel="scalar")
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-5
         )

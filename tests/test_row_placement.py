@@ -228,3 +228,118 @@ def test_on_a_v5e_padded_rows_are_row_major_and_never_copied(v5e):
     # what the rule is there for; the day this fails the compiler has
     # changed and mesh.ROW_MAJOR_MAX_PADDING can go
     assert _resident_copies(v5e, rows, width, width) == ((1, 0), 1, 0)
+
+
+# -- (e) narrow sparse rows: indices and values packed into one stored row ------
+# (parallel/mesh.py `packed_width` / `put_packed`, PERF.md section 6, PR 26)
+
+@pytest.mark.parametrize("width,on_tpu", [
+    (39, 128),    # criteo-logistic: 512 B a row where two 40-sublane arrays take 320
+    (64, 128),    # the widest pair that fits 128 lanes
+    (25, 128),    # 128 <= 2.0 x 2 x 32
+    (24, None),   # 128 > 2.0 x 2 x 24: the padding would cost more than the rows
+    (65, None),   # two arrays of 65 do not fit one row
+    (76, None),   # rcv1-hinge keeps its two arrays
+    (0, None),    # dense rows have no indices to pack
+])
+def test_packing_rule_reads_only_width_and_platform(width, on_tpu):
+    assert mesh_mod.packed_width(width, "tpu") == on_tpu
+    assert mesh_mod.packed_width(width, "cpu") is None
+    assert mesh_mod.PACKED_MAX_PADDING == 2.0
+
+
+def _narrow(n=512, d=3000, p=39, seed=5):
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, d, (n, p)).astype(np.int32)
+    idx[:, 0] = 7  # one id in every row
+    val = rng.normal(size=(n, p)).astype(np.float32)
+    return Dataset(idx, val, rng.choice([-1, 1], n).astype(np.int32), d)
+
+
+def _pack_everywhere(monkeypatch):
+    from distributed_sgd_tpu.parallel import sync as sync_mod
+
+    monkeypatch.setattr(sync_mod, "packed_width",
+                        lambda width, platform: mesh_mod.packed_width(width, "tpu"))
+
+
+@pytest.mark.parametrize("kernel", ["gather", "scalar", "mxu"])
+def test_a_packed_binding_computes_what_two_arrays_compute(kernel, monkeypatch):
+    data = _narrow()
+    model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
+    w = jnp.asarray(np.random.default_rng(6).normal(size=data.n_features).astype(np.float32))
+    key = jax.random.PRNGKey(2)
+
+    def run():
+        bound = SyncEngine(model, make_mesh(2), 16, 0.1, kernel=kernel,
+                           virtual_workers=2).bind(data)
+        return bound, (np.asarray(bound.step(w, key)), np.asarray(bound.epoch(w, key)),
+                       bound.evaluate(w), bound.predict(w))
+
+    plain, want = run()
+    before = metrics_mod.counter("bind.rows.packed").value
+    _pack_everywhere(monkeypatch)
+    packed, got = run()
+    assert not plain.data.packed and packed.data.packed
+    assert metrics_mod.counter("bind.rows.packed").value == before + 1
+    assert packed.data.indices.shape == (512, 128) and packed.data.indices.dtype == jnp.int32
+    assert packed.data.values.shape == (512, 0) and packed.data.width == 39
+    stored = np.asarray(packed.data.indices)
+    np.testing.assert_array_equal(stored[:, :39], data.indices)
+    np.testing.assert_array_equal(stored[:, 39:78].view(np.float32), data.values)
+    assert not stored[:, 78:].any()
+    for a, b in zip(got[:2], want[:2]):
+        np.testing.assert_array_equal(a, b)
+    assert got[2] == want[2]
+    np.testing.assert_array_equal(got[3], want[3])
+
+
+def test_local_sgd_reads_packed_rows_too(monkeypatch):
+    data = _narrow(seed=8)
+    model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
+
+    def fit():
+        return LocalSGDEngine(model, make_mesh(2), 8, 0.1, sync_period=2,
+                              check_every=8, seed=1).fit(data, data, max_epochs=1).weights
+
+    want = fit()
+    _pack_everywhere(monkeypatch)
+    np.testing.assert_array_equal(fit(), want)
+
+
+def test_on_a_v5e_packed_rows_are_row_major_never_copied_and_drawn_in_one_gather(v5e):
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    rows, width, d = 4096 * 64, 39, 1_000_000  # criteo-logistic's shape, fewer rows
+    mesh = Mesh(np.array(v5e.devices[:1]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+
+    def compiled(packed):
+        data = ShardedData(
+            shape((rows, 128 if packed else width), jnp.int32, sharding=over_rows),
+            shape((rows, 0 if packed else width), jnp.float32, sharding=over_rows),
+            shape((rows,), jnp.int32, sharding=over_rows), rows, width, packed)
+        bound = BoundSync(make_model("logistic", 1e-7, d, regularizer="l2"), mesh, data,
+                          100, 0.05, kernel="gather", virtual_workers=4)
+        return bound._epoch.lower(
+            shape((d,), jnp.float32, sharding=everywhere), (), data.indices, data.values,
+            data.labels, shape((2,), jnp.uint32, sharding=everywhere)).compile()
+
+    program = compiled(True)
+    assert program.input_formats[0][2].layout.major_to_minor == (0, 1)
+    text = program.as_text()
+    assert not re.findall(re.escape(f"[{rows},") + r"\d+\]\S* copy\(", text)
+    def draws(t):  # the gather fusions of the step's draw
+        return sum(1 for line in t.split("\n")
+                   if "kind=kCustom" in line and 'dsgd.draw/gather"' in line)
+
+    # the packed rows and the labels; two arrays' rows and the labels
+    assert (draws(text), draws(compiled(False).as_text())) == (2, 3)
+    # the step's scatter keeps its scope in the compiled program (one
+    # accumulator for the four virtual workers: models/linear.py grad_workers)
+    assert 'dsgd.scatter/scatter-add"' in text and 'dsgd.margins/gather"' in text
