@@ -132,23 +132,38 @@ class LinearModel:
         holds K virtual workers hands the all-reduce (Master.scala:194's
         mean divides it later).
 
-        Each reply is computed on its own and the K are added.  A family
-        whose scatter walks its entries (`kernels.ONE_ACCUMULATOR`) under a
-        regulariser that is linear in the gradient ('l2', 'none') takes the
-        other order, which sums the same terms: one scatter of all K
-        batches into ONE accumulator, plus K times the regulariser's term —
-        no [K, R, 128] of replies to zero, fill and reduce (16 MB a step at
-        D = 1e6; PERF.md section 6, PR 26).  'dim_sparsity' masks by each
-        worker's own support, so it keeps the replies apart."""
+        The K workers share `w`, so their MARGINS are one call on the merged
+        batch [K*B, P] (`kernels.merges_margins`): batched over the workers
+        by `vmap` the one-hot gather compiles to K thin matmuls one after
+        the other, each too small for the layout in which the chip runs it
+        at 0.65 ns an entry and not at 1.2-1.9 (`mxu.lane_minor_rows`;
+        PERF.md section 6, PR 27).  A gathered product has one non-zero
+        term, so the margins are the per-worker ones bit for bit.  The
+        REPLIES stay apart where 'dim_sparsity' masks each by its worker's
+        own support: coefficient, scatter and regulariser per worker, then
+        the sum.  A family whose
+        scatter walks its entries (`kernels.ONE_ACCUMULATOR`) under a
+        regulariser that is linear in the gradient ('l2', 'none') merges
+        the scatter too, which sums the same terms in another order: one
+        scatter of all K batches into ONE accumulator, plus K times the
+        regulariser's term — no [K, R, 128] of replies to zero, fill and
+        reduce (16 MB a step at D = 1e6; PERF.md section 6, PR 26)."""
         k, b = y.shape
-        if (kernel in kernels.ONE_ACCUMULATOR and self.regularizer != "dim_sparsity"
-                and indices.shape[-1] != 0):
+        if kernels.merges_margins(kernel, indices.shape[-1]):
             merged = SparseBatch(indices.reshape(k * b, -1), values.reshape(k * b, -1))
-            g = self.grad_blocked(w, merged, y.reshape(k * b), kernel=kernel)
-            return self.regularize_blocked(g, w, workers=k)
-        gk = jax.vmap(
-            lambda bi, bv, by: self.grad(w, SparseBatch(bi, bv), by, kernel=kernel)
-        )(indices, values, y)
+            if kernel in kernels.ONE_ACCUMULATOR and self.regularizer != "dim_sparsity":
+                g = self.grad_blocked(w, merged, y.reshape(k * b), kernel=kernel)
+                return self.regularize_blocked(g, w, workers=k)
+            # one piece: matvec_chunked's sub-scan would bring the calls back
+            matvec = gather.matvec if kernel == "gather" else mxu.matvec
+            gk = jax.vmap(
+                lambda bi, bv, by, m: self.regularize_blocked(self.grad_blocked(
+                    w, SparseBatch(bi, bv), by, kernel=kernel, margins=m), w)
+            )(indices, values, y, matvec(merged, w).reshape(k, b))
+        else:
+            gk = jax.vmap(
+                lambda bi, bv, by: self.grad(w, SparseBatch(bi, bv), by, kernel=kernel)
+            )(indices, values, y)
         with jax.named_scope("dsgd.allreduce"):
             return jnp.sum(gk, axis=0)  # summed here, mean-normalized by the caller
 
@@ -262,21 +277,26 @@ class LinearModel:
 
     def grad_blocked(
         self, w2: jax.Array, batch: SparseBatch, y: jax.Array, reduce: str = "sum",
-        kernel: str = "mxu",
+        kernel: str = "mxu", margins: Optional[jax.Array] = None,
     ) -> jax.Array:
         """Batched backward on blocked weights: gather + coeff + scatter, as
-        one-hot matmuls with the operands built once (ops/mxu.py) or, for
-        kernel='gather', as a true gather and scatter (ops/gather.py).
+        one-hot matmuls (ops/mxu.py) or, for kernel='gather', as a true
+        gather and scatter (ops/gather.py).  `margins`: the batch's, where
+        the caller has them already (`grad_workers` computes every worker's
+        in one call); the gather side is then not run.
 
         reduce='sum' is the sync worker reply (Slave.scala:147-153);
         reduce='mean' is the async local step (Slave.scala:93-98).
         """
         if kernel == "gather":
-            margins, scatter = gather.matvec(batch, w2), functools.partial(
-                gather.scatter_add, batch, n_rows=w2.shape[0])
+            scatter = functools.partial(gather.scatter_add, batch, n_rows=w2.shape[0])
+            if margins is None:
+                margins = gather.matvec(batch, w2)
         else:
             oh = mxu.OneHotBatch(batch, w2.shape[0])
-            margins, scatter = oh.margins(w2), oh.scatter_add
+            scatter = oh.scatter_add
+            if margins is None:
+                margins = oh.margins(w2)
         with jax.named_scope("dsgd.coeff"):
             coeff = self.grad_coeff(margins, y)
             if reduce == "mean":

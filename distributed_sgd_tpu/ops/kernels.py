@@ -70,6 +70,17 @@ def choose_kernel(n_features: int, row_width: int, platform: str,
     return family
 
 
+def merges_margins(kernel: str, row_width: int) -> bool:
+    """Whether the virtual workers of a device get their margins from ONE
+    call on their merged batches (`LinearModel.grad_workers`): they all read
+    the same `w`, so for sparse rows (`row_width` stored entries; 0: the
+    dense layout) through the blocked kernels of ours nothing keeps them
+    apart, and K calls batched over the workers cost K calls.  'scalar' and
+    'dense' keep XLA's own batching, 'pallas' its one fused launch.  Static
+    per binding: `BoundSync` counts it under `bind.margins.merged`."""
+    return row_width != 0 and kernel in ("mxu", "gather")
+
+
 def resolve(kernel: Optional[str], n_features: int, row_width: int,
             device=None, off_tpu: str = "scalar") -> str:
     """What an engine binds: an explicit `kernel` as given (dense rows can

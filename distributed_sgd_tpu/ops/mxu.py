@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import math
 import threading
 from typing import Dict, Tuple
 
@@ -249,9 +250,15 @@ def to_blocked_np(w: np.ndarray, n_features: int) -> np.ndarray:
 
 
 class OneHotBatch:
-    """The per-batch one-hot operands, built once and shared by the gather
-    and scatter sides of a step.  All members are traced arrays; XLA fuses
-    the iota-compare builds into the consuming matmuls."""
+    """The per-batch one-hot operands of the gather and scatter sides of a
+    step.  All members are traced arrays, written once here; what compiles
+    is NOT one shared build: XLA fuses an iota-compare build into each
+    consuming matmul, so a step holds one `iota_compare_fusion` for the
+    gather and one for the scatter, and a caller that makes the two sides
+    from two `OneHotBatch`es (`LinearModel.grad_workers`: the margins of K
+    workers in one call, their scatters apart) pays no second build.
+    Which way round the compiler lays the gather's operand out decides what
+    an entry costs (`lane_minor_rows`)."""
 
     def __init__(self, batch: SparseBatch, n_rows: int, dtype=jnp.float32):
         with jax.named_scope("dsgd.onehot"):
@@ -361,9 +368,45 @@ def _scatter_bf16(ohr: jax.Array, ohc: jax.Array, cv: jax.Array) -> jax.Array:
     return jnp.sum(g.astype(jnp.float32), axis=0)
 
 
+# A call of the gather costs 0.63-0.67 ns a stored entry where the compiler
+# builds its one-hot operand with the ENTRIES along the lanes, and 1.2-1.9 ns
+# where it builds it entries-major (v5e, R = 376; PERF.md section 6, PR 27).
+# Compiled for a described v5e, it takes the first layout when the entries
+# are whole 128-lane tiles and more than 32,768 of them (at R = 376; the
+# evaluation's 512 x 76 = 38,912 always were).  So a one-piece `matvec` pads
+# its batch with empty rows up to such a count, where that costs at most 1/8
+# more entries: 4 workers x 100 rows x 76 run as 448 rows in 24.0 us, not
+# 49.2 (and not the 44.0 of four calls batched over the workers).
+# tests/test_row_placement.py holds the layout; the day it fails the
+# compiler has changed and these two constants can go.
+LANE_MINOR_MIN_ENTRIES = 32_768
+MATVEC_MAX_PADDING = 1.125
+
+
+def lane_minor_rows(n_rows: int, width: int) -> int:
+    """The rows a one-piece `matvec` of `n_rows` rows of `width` entries
+    runs on: the next count whose entries are whole lanes and more than
+    LANE_MINOR_MIN_ENTRIES, or `n_rows` where that is beyond
+    MATVEC_MAX_PADDING times the entries."""
+    whole = LANES // math.gcd(width, LANES)  # rows whose entries fill whole lanes
+    enough = LANE_MINOR_MIN_ENTRIES // width + 1
+    rows = -(-max(n_rows, enough) // whole) * whole
+    return rows if rows <= MATVEC_MAX_PADDING * n_rows else n_rows
+
+
 def matvec(batch: SparseBatch, w2: jax.Array) -> jax.Array:
-    """Standalone blocked matvec (margins) for eval-style uses."""
-    return OneHotBatch(batch, w2.shape[0]).margins(w2)
+    """Blocked matvec (margins) in ONE call of the gather, for the
+    evaluation's chunks and the merged batches of a device's virtual
+    workers; empty rows pad the batch where `lane_minor_rows` says so (a
+    pad is entry 0 with value 0: it adds 0 * w[0] to a margin cut off)."""
+    n = batch.batch_size
+    rows = lane_minor_rows(n, batch.pad_width)
+    if rows != n:
+        with jax.named_scope("dsgd.onehot"):
+            pad = ((0, rows - n), (0, 0))
+            batch = SparseBatch(jnp.pad(batch.indices, pad), jnp.pad(batch.values, pad))
+    margins = OneHotBatch(batch, w2.shape[0]).margins(w2)
+    return margins if rows == n else margins[:n]
 
 
 MATVEC_SUB = 512  # samples per sub-scan step of matvec_chunked

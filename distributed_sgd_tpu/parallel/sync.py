@@ -78,7 +78,7 @@ from distributed_sgd_tpu.parallel.mesh import (
     shard_map,
     unpack_rows,
 )
-from distributed_sgd_tpu.utils import measure
+from distributed_sgd_tpu.utils import measure, metrics
 
 AXIS = WORKER_AXIS
 
@@ -182,6 +182,13 @@ class BoundSync:
         self.virtual_workers = int(virtual_workers)
         if self.virtual_workers < 1:
             raise ValueError("virtual_workers must be >= 1")
+        # whether the step computes the K workers' margins in one call on
+        # their merged batches (LinearModel.grad_workers; K = 1 and the
+        # Pallas kernel never go through it): static per binding
+        self.margins_merged = self.virtual_workers > 1 and kernels.merges_margins(
+            kernel, data.indices.shape[1])
+        if self.margins_merged:
+            metrics.counter("bind.margins.merged").increment()
         # rows stored wider than the dataset holds them (mesh.put_rows):
         # every read takes the true width back off (rows / chunk)
         padded = (not data.packed and data.width is not None

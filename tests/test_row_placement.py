@@ -20,6 +20,7 @@ from distributed_sgd_tpu import trace as trace_mod
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.data.synthetic import rcv1_like
 from distributed_sgd_tpu.models.linear import make_model
+from distributed_sgd_tpu.ops import mxu as mxu_mod
 from distributed_sgd_tpu.parallel import mesh as mesh_mod
 from distributed_sgd_tpu.parallel.local_sgd import LocalSGDEngine
 from distributed_sgd_tpu.parallel.mesh import lane_width, make_mesh
@@ -343,3 +344,75 @@ def test_on_a_v5e_packed_rows_are_row_major_never_copied_and_drawn_in_one_gather
     # the step's scatter keeps its scope in the compiled program (one
     # accumulator for the four virtual workers: models/linear.py grad_workers)
     assert 'dsgd.scatter/scatter-add"' in text and 'dsgd.margins/gather"' in text
+
+
+# -- (f) K virtual workers: ONE flat gather for their margins --------------------
+# (models/linear.py `grad_workers`, ops/mxu.py `lane_minor_rows`; PERF.md
+# section 6, PR 27)
+
+def _onehot_matmuls(v5e, workers, batch):
+    """(scope, dim_labels, left operand's shape, its minor-most dimension)
+    of every convolution in the `mxu` epoch program of `rcv1-hinge`'s shape
+    compiled for one v5e chip."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    rows, width, d = 4096 * 64, 76, 47_236
+    mesh = Mesh(np.array(v5e.devices[:1]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+    data = ShardedData(shape((rows, width), jnp.int32, sharding=over_rows),
+                       shape((rows, width), jnp.float32, sharding=over_rows),
+                       shape((rows,), jnp.int32, sharding=over_rows), rows, width)
+    model = make_model("hinge", 1e-5, d, dim_sparsity=jnp.ones((d,), jnp.float32))
+    bound = BoundSync(model, mesh, data, batch, 0.5, kernel="mxu", virtual_workers=workers)
+    text = bound._epoch.lower(
+        shape((d,), jnp.float32, sharding=everywhere), (), data.indices, data.values,
+        data.labels, shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
+    stored = {name: (tuple(int(n) for n in dims.split(",")), int(minor))
+              for name, dims, minor in re.findall(
+                  r"^\s*(%\S+) = \w+\[([\d,]+)\]\{(\d+)", text, re.M)}
+    return sorted(
+        (scope, labels) + stored[left] for left, labels, scope in re.findall(
+            r" convolution\((%[^,]+), [^)]*\).*?dim_labels=(\S+?),.*?op_name=\"[^\"]*"
+            r"(dsgd\.[a-z]+)", text))
+
+
+@pytest.mark.parametrize("batch,gathered_rows", [(100, 448), (200, 800)])
+def test_on_a_v5e_the_virtual_workers_margins_are_one_flat_gather(v5e, batch, gathered_rows):
+    k, entries, r = 4, batch * 76, 376
+    assert mxu_mod.lane_minor_rows(k * batch, 76) == gathered_rows
+    # the gather: ONE [T, R] x [R, 128] with no worker dimension, its one-hot
+    # operand built with the entries along the lanes (dimension 0 minor: what
+    # `lane_minor_rows` pads 400 rows to 448 for; the day this fails the
+    # compiler has changed and the rule's constants can go); the scatter
+    # keeps its workers apart ('dim_sparsity' masks each reply by its
+    # worker's own support)
+    assert _onehot_matmuls(v5e, k, batch) == [
+        ("dsgd.margins", "bf_io->bf", (gathered_rows * 76, r), 0),
+        ("dsgd.scatter", "0fb_0io->0bf", (k, entries, r), 1)]
+    # one worker a device never goes through grad_workers: two plain
+    # matmuls, entries-major as they were
+    assert _onehot_matmuls(v5e, 1, batch) == [
+        ("dsgd.margins", "bf_io->bf", (entries, r), 1),
+        ("dsgd.scatter", "fb_io->bf", (entries, r), 1)]
+
+
+@pytest.mark.parametrize("rows,width,runs_on", [
+    (400, 76, 448),    # rcv1-sync-1chip: 34,048 entries, 266 whole lanes
+    (800, 76, 800),    # rcv1-sync-b200: 60,800 = 475 lanes as it comes
+    (512, 76, 512),    # the evaluation's piece
+    (600, 76, 608),    # whole lanes cost 1.3 % here
+    (300, 76, 300),    # 448 rows would be 1.49 x the entries
+    (100, 76, 100),    # one worker's batch
+    (400, 39, 400),    # 15,600 entries: 32,768 is out of reach
+    (16, 6, 16),
+    (280, 128, 280),   # 128-wide rows are whole lanes at any count
+])
+def test_matvec_pads_to_whole_lanes_only_where_an_eighth_more_entries_buys_them(
+        rows, width, runs_on):
+    assert mxu_mod.lane_minor_rows(rows, width) == runs_on
+    assert (mxu_mod.LANE_MINOR_MIN_ENTRIES, mxu_mod.MATVEC_MAX_PADDING) == (32_768, 1.125)
