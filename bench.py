@@ -334,16 +334,6 @@ def main() -> None:
 
         bench_comms.main()
         return
-    if "--kernels" in sys.argv:
-        # kernel gate (ROADMAP item 2): interleaved fused A/B of the four
-        # scatter formulations (DSGD_SCATTER) at the flagship step shape,
-        # slope-timed and gated round-over-round like every other
-        # subsystem; --smoke additionally hard-asserts knobs-off
-        # byte-identity and per-formulation parity vs 'onehot'
-        from benches import bench_kernels
-
-        bench_kernels.main(smoke="--smoke" in sys.argv)
-        return
     if "--rpc" in sys.argv:
         # pipelined sync-engine wire bench (docs/SYNC_PIPELINE.md):
         # broadcast bytes + rounds per epoch on a 2-worker loopback RPC

@@ -23,7 +23,9 @@ fresh cluster and must clear the same bar on its own).
 
 Gates (hard asserts, smoke and full):
 
-- scaled rounds/s >= 1.5x serialized rounds/s at N=32;
+- scaled rounds/s >= 1.5x serialized rounds/s at N=32 (a wall-clock
+  ratio: held by `main`, the `bench.py --scale` path, with the tree's
+  >= 2x bar; `run_bench` returns both as readings);
 - weight drift exactly 0.0 between the two configs at EVERY swept N (the
   lanes keep one send-ordered f32 accumulation chain; the stager replays
   the serial sample stream; streams are bit-identical since PR 12);
@@ -530,8 +532,8 @@ def run_bench(smoke: bool = False) -> dict:
     if gate["speedup"] < SPEEDUP_GATE_X:
         # best-of-reps ratios sit within scheduler noise of the bar on a
         # loaded 1-core box (observed 1.48-1.63x across identical code).
-        # ONE re-measure on a fresh cluster — the fresh point must clear
-        # the same bar on its own, so a real regression still fails twice
+        # ONE re-measure on a fresh cluster — the fresh point is the one
+        # `main` holds to the bar, so a real regression still fails twice
         log(f"gate: {gate['speedup']:.2f}x at N={gate_n} below the "
             f"{SPEEDUP_GATE_X}x bar — re-measuring once on a fresh cluster")
         gate = _sweep_point(train, test, make, cfg, gate_n,
@@ -543,30 +545,13 @@ def run_bench(smoke: bool = False) -> dict:
         points = [gate if p["n"] == gate_n else p for p in points]
     log(f"gate: {gate['speedup']:.2f}x at N={gate_n} "
         f"(bar >= {SPEEDUP_GATE_X}x), drift 0.0 at every N")
-    assert gate["speedup"] >= SPEEDUP_GATE_X, (
-        f"scaled master {gate['speedup']:.2f}x at N={gate_n} — below the "
-        f">= {SPEEDUP_GATE_X}x bar over the serialized master")
-    # tree gate: >= TREE_GATE_X over the scaled master at N=64 (or the
-    # largest tree point the sweep has).  Multi-core hosts only: the tree
-    # moves fan-in work OFF the master onto concurrently-running reduce
-    # nodes, and with one core there is nowhere to move it — there the
-    # rows are recorded (history catches a collapse) and the bar is
-    # logged as skipped, not faked
+    # the tree's reading: against the scaled master at N=64 (or the
+    # largest tree point the sweep has)
     tree_gate_n = (TREE_GATE_N if TREE_GATE_N in tree_ns
                    else max(tree_ns))
     tgate = by_n[tree_gate_n]
-    cores = os.cpu_count() or 1
     log(f"tree gate: {tgate['tree_speedup']:.2f}x vs scaled at "
-        f"N={tree_gate_n} (bar >= {TREE_GATE_X}x on multi-core; "
-        f"{cores} core(s) here)")
-    if cores > 1:
-        assert tgate["tree_speedup"] >= TREE_GATE_X, (
-            f"aggregation tree {tgate['tree_speedup']:.2f}x at "
-            f"N={tree_gate_n} — below the >= {TREE_GATE_X}x bar over the "
-            f"scaled master")
-    else:
-        log("tree gate SKIPPED: single-core host (workers and master "
-            "share one CPU, so off-master reduce cannot speed the round)")
+        f"N={tree_gate_n} (bar >= {TREE_GATE_X}x on multi-core)")
     chaos = _chaos_row(train, test, make, cfg)
     # feature-sharded master plane: bytes-per-process sweep + chaos row
     shard_rows = {}
@@ -661,6 +646,25 @@ def split_shard_series(result: dict) -> tuple:
 
 def main(smoke: bool = False) -> None:
     result = run_bench(smoke=smoke)
+    # The wall-clock bars, held here and not in `run_bench`: a ratio of CPU
+    # times is a reading of the box it ran on, which tier-1 (six xdist
+    # workers sharing the cores) only records.
+    speedup, gate_n = result["speedup_gate_info"], result["speedup_gate_n"]
+    assert speedup >= SPEEDUP_GATE_X, (
+        f"scaled master {speedup:.2f}x at N={gate_n} — below the "
+        f">= {SPEEDUP_GATE_X}x bar over the serialized master")
+    # tree gate, multi-core hosts only: the tree moves fan-in work OFF the
+    # master onto concurrently-running reduce nodes, and with one core
+    # there is nowhere to move it — there the rows are recorded (history
+    # catches a collapse) and the bar is logged as skipped, not faked
+    if (os.cpu_count() or 1) > 1:
+        tree, tree_n = result["tree_gate_info"], result["tree_gate_n"]
+        assert tree >= TREE_GATE_X, (
+            f"aggregation tree {tree:.2f}x at N={tree_n} — below the "
+            f">= {TREE_GATE_X}x bar over the scaled master")
+    else:
+        log("tree gate SKIPPED: single-core host (workers and master "
+            "share one CPU, so off-master reduce cannot speed the round)")
     try:
         from benches import regress
 

@@ -44,11 +44,12 @@ Long-horizon mode (ISSUE 20, telemetry/resources.py): the soak is also
 the leak proof.  A real ``ResourceProbe`` thread samples the process
 across the whole chaos run while a ``LeakSentinel`` with CALIBRATED
 absolute slope bars (rss bytes/s, fds/s, threads/s — the bench_flywheel
-PR 16 calibration) watches the series; the bench hard-asserts the
-sentinel never tripped AND the final Theil–Sen slopes sit under the
-bars, then measures probe overhead on the canonical ``--rpc`` workload
-(interleaved base/probe-on, per-config minimum, the bench_telemetry
-pattern) against a <5% bar.  ``soak_rss_slope`` / ``soak_fd_slope``
+PR 16 calibration) watches the series; ``run_bench`` hard-asserts the
+sentinel never tripped, ``main`` (the ``bench.py --soak`` path) that the
+final Theil–Sen slopes sit under the bars; the bench then measures probe
+overhead on the canonical ``--rpc`` workload (interleaved base/probe-on,
+per-config minimum, the bench_telemetry pattern) against a <5% bar.
+``soak_rss_slope`` / ``soak_fd_slope``
 rows land in benches/history.json so the trend across rounds is
 watchable even while each run's absolute bar passes.
 
@@ -419,12 +420,6 @@ def run_bench(smoke: bool = False) -> dict:
     assert rss_slope == rss_slope and fd_slope == fd_slope, (
         f"the probe never accumulated a judgeable window "
         f"({soak['probe_ticks']} ticks) — the leak gate measured nothing")
-    assert rss_slope <= MAX_RSS_SLOPE[label], (
-        f"rss slope {rss_slope:g} B/s over the {MAX_RSS_SLOPE[label]:g} "
-        f"B/s bar across the chaos soak")
-    assert fd_slope <= MAX_FD_SLOPE, (
-        f"fd slope {fd_slope:g}/s over the {MAX_FD_SLOPE:g}/s bar across "
-        f"the chaos soak")
 
     overhead = _probe_overhead(label)
 
@@ -466,10 +461,20 @@ def run_bench(smoke: bool = False) -> dict:
 def main(smoke: bool = False) -> None:
     result = run_bench(smoke=smoke)
     label = "smoke" if smoke else "full"
+    # The slope bars on the final Theil–Sen fit, held here and not in
+    # `run_bench`: bytes a second of a 17 s smoke run is a reading of the
+    # box's allocator warm-up, which tier-1 only records.
+    rss_slope, fd_slope = result["rss_slope_info"], result["fd_slope_info"]
+    assert rss_slope <= MAX_RSS_SLOPE[label], (
+        f"rss slope {rss_slope:g} B/s over the {MAX_RSS_SLOPE[label]:g} "
+        f"B/s bar across the chaos soak")
+    assert fd_slope <= MAX_FD_SLOPE, (
+        f"fd slope {fd_slope:g}/s over the {MAX_FD_SLOPE:g}/s bar across "
+        f"the chaos soak")
     # dedicated slope series (ISSUE 20): thin rows whose `*_slope` fields
     # regress.py gates lower-is-better at the 100% slope band (skipping
     # non-positive values) — the cross-round leak trend, beside the
-    # per-run absolute bars run_bench already hard-asserted
+    # per-run absolute bars asserted above
     slope_rows = [
         {"metric": f"soak_rss_slope_{label}", "unit": "bytes_per_s",
          "rss_slope": result["rss_slope_info"],

@@ -14,9 +14,8 @@ row):
   the workers (today's step), as K plain calls one after the other, and as
   one flat call on all entries (what a linear regulariser would allow).
 
-Timing: the slope of a chained `lax.scan` between two trip counts, as
-`mxu.resolve_scatter_formulation` does: each iteration's carry depends on
-the call's output, and the indices are arguments, not constants, so the
+Timing: the slope of a chained `lax.scan` between two trip counts: each
+iteration's carry depends on the call's output, and the indices are arguments, not constants, so the
 one-hot operands are built in the fusion as in the step.
 
     python benches/onehot_call_sweep.py [--rehearse]

@@ -1,4 +1,4 @@
-"""Sync-step kernel-backend microbenchmark: scalar vs mxu vs pallas.
+"""Sync-step kernel-backend microbenchmark: scalar vs mxu.
 
 Times one full sync DP step (sample + per-worker gradient sum + regularize
 + mean + update) at RCV1 shapes for each kernel backend of
@@ -26,7 +26,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("n_samples", nargs="?", type=int, default=100_000)
     ap.add_argument("--workers", type=int, default=3)
-    ap.add_argument("--kernels", type=str, default="scalar,mxu,pallas")
+    ap.add_argument("--kernels", type=str, default="scalar,mxu")
     args = ap.parse_args()
 
     import jax

@@ -16,8 +16,6 @@ lambda 1e-5) through the entry point a user runs,
 - gossip   DSGD_ASYNC=true DSGD_ASYNC_MODE=gossip: Hogwild workers
 - serve    DSGD_ROLE=serve over mesh1's checkpoint; Predict margins must equal
            the direct dot product to 1e-4
-- pallas   SyncEngine(kernel='pallas') compiled by Mosaic (no interpreter),
-           one flagship-shape step against kernel='mxu' to 1e-4
 - placement  SyncEngine.bind on dense 2,000-wide rows and on 76-wide ones:
            the wide rows sit row-major (padded to whole lanes) and the
            compiled epoch program holds no copy of them, the narrow ones
@@ -58,10 +56,8 @@ DEADLINE_S = 1150  # the whole run, compilation included
 PHASE_TIMEOUT_S = 420
 
 FULL = dict(rows=804_414, rpc_rows=100_000, gossip_rows=2_500,
-            pallas_rows=20_000, pallas_dim=47_236, pallas_nnz=76,
             placement_rows=32_768)
 TINY = dict(rows=3_000, rpc_rows=2_000, gossip_rows=600,
-            pallas_rows=2_000, pallas_dim=512, pallas_nnz=8,
             placement_rows=512)
 # sanity band for the full-width mesh runs (correctness, not speed): the
 # last full-width ltc record is 0.364 / 0.826 after 2 epochs — at 3 workers.
@@ -86,42 +82,6 @@ print(json.dumps({"platform": d[0].platform, "kind": d[0].device_kind,
                   "count": len(d), "jax": jax.__version__,
                   "libtpu": metadata.version("libtpu")}))
 """
-
-
-def child_pallas(rows: int, dim: int, nnz: int) -> None:
-    """One sync step at the given shape under kernel='pallas' and 'mxu'."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from distributed_sgd_tpu import compile_cache
-    from distributed_sgd_tpu.data.rcv1 import dim_sparsity
-    from distributed_sgd_tpu.data.synthetic import rcv1_like
-    from distributed_sgd_tpu.models.linear import make_model
-    from distributed_sgd_tpu.parallel.mesh import make_mesh
-    from distributed_sgd_tpu.parallel.sync import SyncEngine
-
-    compile_cache.place()
-    data = rcv1_like(rows, n_features=dim, nnz=nnz, seed=0, idf_values=True)
-    model = make_model("hinge", 1e-5, dim, dim_sparsity=dim_sparsity(data))
-    w0 = jnp.asarray(np.random.default_rng(2).normal(size=dim) * 0.05,
-                     dtype=jnp.float32)
-    key = jax.random.PRNGKey(7)
-    bound = {
-        kernel: SyncEngine(model, make_mesh(1), batch_size=100,
-                           learning_rate=0.5, kernel=kernel,
-                           virtual_workers=3).bind(data)
-        for kernel in ("mxu", "pallas")}
-    out = {kernel: np.asarray(b.step(w0, key)) for kernel, b in bound.items()}
-    hits, misses = compile_cache.counts()
-    print(json.dumps({
-        "platform": jax.devices()[0].platform,
-        "interpret": bool(bound["pallas"]._pallas_interpret),
-        "finite": bool(np.isfinite(out["pallas"]).all()),
-        "max_abs_diff": float(np.max(np.abs(out["pallas"] - out["mxu"]))),
-        "moved": float(np.max(np.abs(out["mxu"] - np.asarray(w0)))),
-        "hits": hits, "misses": misses,
-    }))
 
 
 def child_placement(rows: int) -> None:
@@ -435,9 +395,7 @@ def check_gossip(text: str, device: dict) -> dict:
 
 def main(argv: list) -> int:
     if argv and argv[0] == "--child":
-        if argv[1] == "pallas":
-            child_pallas(int(argv[2]), int(argv[3]), int(argv[4]))
-        elif argv[1] == "client":
+        if argv[1] == "client":
             child_client(argv[2], int(argv[3]))
         elif argv[1] == "placement":
             child_placement(int(argv[2]))
@@ -616,18 +574,6 @@ def main(argv: list) -> int:
              f"ran on {out['platform']}")
         return out
 
-    def pallas() -> dict:
-        out = child("pallas", size["pallas_rows"], size["pallas_dim"],
-                    size["pallas_nnz"])
-        need(out["interpret"] == rehearsal,
-             f"interpret={out['interpret']}: the chip run must compile the "
-             f"kernel, the rehearsal must interpret it")
-        need(out["finite"] and out["moved"] > 0, f"degenerate step: {out}")
-        need(out["max_abs_diff"] <= 1e-4,
-             f"pallas vs mxu differ by {out['max_abs_diff']}")
-        out["cache"] = {"hits": out.pop("hits"), "misses": out.pop("misses")}
-        return out
-
     def placement() -> dict:
         out = child("placement", size["placement_rows"])
         need(out["finite"] and out["moved"] > 0, f"degenerate epoch: {out}")
@@ -646,7 +592,7 @@ def main(argv: list) -> int:
     try:
         for name, fn in (("mesh1", mesh1), ("meshN", mesh_n), ("rpc", rpc),
                          ("gossip", gossip), ("serve", serve),
-                         ("pallas", pallas), ("placement", placement)):
+                         ("placement", placement)):
             phase(name, fn)
     finally:
         _stop_all()
@@ -669,9 +615,7 @@ def main(argv: list) -> int:
              if p.get("peak_bytes") is not None), default=None),
         "kernels": {"mesh": phases["mesh1"]["kernel"],
                     "rpc": phases["rpc"]["kernel"],
-                    "gossip": phases["gossip"]["kernel"],
-                    "pallas": "interpreted" if phases["pallas"]["interpret"]
-                    else "compiled"},
+                    "gossip": phases["gossip"]["kernel"]},
         "phases": phases,
     }
     if rehearsal:

@@ -181,16 +181,6 @@ class Config:
     # the model's regulariser (models/linear.py): None = 'dim_sparsity'
     # where the data brings its sidecar (reference parity), else 'l2'
     regularizer: Optional[str] = None  # dim_sparsity | l2 | none
-    # sparse-scatter formulation inside the blocked MXU kernels
-    # (ops/mxu.py, ROADMAP item 2): 'onehot' (default — the measured
-    # round-4/6 winner, knobs-off training byte-identical to prior
-    # rounds), 'segment' / 'twostage' / 'bf16' (the round-6 sweep,
-    # selectable for hardware rematches), or 'auto' — measure all four at
-    # the loaded dataset's step shape (batch x pad_width x n_features) on
-    # THIS device once per process and run the winner.  Read at trace
-    # time; main.py resolves it after the data loads, before any engine
-    # is built.
-    scatter: str = "onehot"
     virtual_workers: int = 1  # reference workers emulated per mesh device
     exact_topology: bool = False  # insist on exactly node_count workers
     optimizer: str = "sgd"  # sgd (reference) | momentum | adam (sync engine)
@@ -411,16 +401,9 @@ class Config:
         "model": ("hinge", "svm", "logistic", "least_squares"),
         "engine": ("mesh", "rpc"),
         "async_mode": ("gossip", "local_sgd"),
-        # 'dense' is auto-selected from the data layout, never configured;
-        # 'pallas' is an experiment demoted from the config surface — it
-        # measured slower than 'mxu' at every swept shape and VMEM-OOMs at
-        # large batches (benches/pallas_sweep.py; BASELINE.md) — but stays
-        # reachable through SyncEngine(kernel='pallas') for kernel work
+        # 'dense' is auto-selected from the data layout, never configured
         "kernel": ("auto", "mxu", "scalar", "gather"),
         "regularizer": (None, "dim_sparsity", "l2", "none"),
-        # 'auto' defers to a runtime rematch on the actual device
-        # (ops/mxu.resolve_scatter_formulation); the rest select directly
-        "scatter": ("auto", "onehot", "segment", "twostage", "bf16"),
         "optimizer": ("sgd", "momentum", "adam"),
         "compress": ("none", "topk", "qint8"),
     }
@@ -772,7 +755,6 @@ class Config:
             pad_width=_env("DSGD_PAD_WIDTH", None, int),
             kernel=_env("DSGD_KERNEL", cls.kernel, str),
             regularizer=_env("DSGD_REGULARIZER", None, str),
-            scatter=_env("DSGD_SCATTER", cls.scatter, str),
             virtual_workers=_env("DSGD_VIRTUAL_WORKERS", cls.virtual_workers, int),
             exact_topology=_env("DSGD_EXACT_TOPOLOGY", cls.exact_topology, bool),
             optimizer=_env("DSGD_OPTIMIZER", cls.optimizer, str),

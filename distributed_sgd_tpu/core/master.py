@@ -1953,19 +1953,6 @@ class MasterNode:
         grad_bytes = self.metrics.counter(metrics_mod.SYNC_GRAD_BYTES)
         rounds = self.metrics.counter(metrics_mod.SYNC_ROUNDS)
         window_span = batch_size * local_steps
-        # scatter-formulation attribution (ROADMAP item 2 follow-up: the
-        # DSGD_SCATTER=auto rematch outcome was only ever logged): a gauge
-        # on this fit's registry — scraped onto the cluster /metrics
-        # endpoint under telemetry — plus a flight record, and a trace
-        # event inside the first window's span below, so a bench run or a
-        # post-mortem can attribute which formulation the fit actually ran
-        from distributed_sgd_tpu.ops import mxu
-
-        scatter_form = mxu.active_scatter_formulation()
-        self.metrics.gauge(metrics_mod.SCATTER_FORMULATION).set(
-            mxu.SCATTER_FORMULATIONS.index(scatter_form))
-        flight.record("scatter.formulation", formulation=scatter_form)
-        scatter_evented = False
         # quorum bookkeeping (all inert when quorum is None):
         # ef_rollback[worker] = broadcast version whose reply the quorum
         # barrier discarded — the NEXT request to that worker carries it so
@@ -2182,10 +2169,6 @@ class MasterNode:
                         trace_mod.SPAN_SYNC_WINDOW, node="master", epoch=epoch,
                         batch=int(batch), version=bcast.version)
                     with wspan:
-                        if not scatter_evented:
-                            trace_mod.event(trace_mod.EVENT_SCATTER_SELECTED,
-                                            formulation=scatter_form)
-                            scatter_evented = True
                         futs = []
                         agg_round_seq += 1  # fresh tree round per attempt
                         ids_by_key: Dict[Tuple[str, int], np.ndarray] = {}

@@ -266,15 +266,6 @@ class WorkerNode:
         self._resident = _Resident(
             data_offset, len(data), res_idx, res_val, res_y,
             data if row_reader is not None else None)
-        # which scatter formulation this node's kernels run, as a
-        # scrapeable gauge (ROADMAP item: the DSGD_SCATTER=auto pick was
-        # only logged; the cluster /metrics endpoint now attributes it —
-        # value indexes ops/mxu.SCATTER_FORMULATIONS)
-        from distributed_sgd_tpu.ops import mxu as _mxu
-
-        self.metrics.gauge(metrics_mod.SCATTER_FORMULATION).set(
-            _mxu.SCATTER_FORMULATIONS.index(
-                _mxu.active_scatter_formulation()))
         kernel = _kernel_of(self, data.indices.shape[1])
         self.log.info(
             "worker kernel=%s on %s (%d in-host device(s))",

@@ -552,32 +552,6 @@ def _install_chaos(cfg: Config) -> None:
     log.warning("chaos plan active on this node: %s", cfg.chaos)
 
 
-def _select_scatter(cfg: Config, data: Dataset) -> None:
-    """DSGD_SCATTER -> the process-wide scatter formulation (ops/mxu.py),
-    resolved after the data loads but BEFORE any engine or jitted
-    function is built — the formulation is read at trace time.  'auto'
-    runs the one-time runtime rematch at THIS dataset's step shape
-    (batch x pad_width x n_features — the T depth and R block count that
-    decide the race) on this process's device; the default 'onehot' is a
-    no-op (knobs-off training byte-identical)."""
-    if cfg.scatter == "onehot":
-        return
-    from distributed_sgd_tpu.ops import mxu
-
-    if cfg.scatter == "auto" and data.is_dense:
-        # dense-layout data runs plain matmuls — there is no sparse
-        # scatter to rematch (and pad_width is 0)
-        log.info("DSGD_SCATTER=auto: dense-layout data has no sparse "
-                 "scatter; keeping 'onehot'")
-        return
-    resolved = mxu.resolve_scatter_formulation(
-        cfg.scatter, batch_size=cfg.batch_size, nnz=max(1, data.pad_width),
-        n_features=data.n_features)
-    mxu.set_scatter_formulation(resolved)
-    log.info("scatter formulation: %s%s", resolved,
-             " (DSGD_SCATTER=auto rematch)" if cfg.scatter == "auto" else "")
-
-
 def _load_probe(cfg: Config):
     """DSGD_SERVE_PROBE -> canary probe rows (None when unset)."""
     if not cfg.serve_probe:
@@ -905,7 +879,6 @@ def _run_role(cfg: Config, role: str) -> None:
             _run_dev_flywheel(cfg)
             return
         train, test, model = build(cfg)
-        _select_scatter(cfg, train)
         distributor = _serve_distributor(cfg)
         try:
             if cfg.engine == "rpc":
@@ -925,7 +898,6 @@ def _run_role(cfg: Config, role: str) -> None:
             train, test, model = _autopilot_stream_build(cfg)
         else:
             train, test, model = build(cfg)
-        _select_scatter(cfg, train)
         master = MasterNode(
             cfg.host, cfg.port, train, test, model,
             expected_workers=cfg.node_count, seed=cfg.seed,
@@ -999,7 +971,6 @@ def _run_role(cfg: Config, role: str) -> None:
             train, model, extra = _build_worker_row_store(cfg)
         else:
             train, _, model = build(cfg)
-        _select_scatter(cfg, train)
         worker = WorkerNode(
             cfg.host, cfg.port, cfg.master_host, cfg.master_port, train, model,
             seed=cfg.seed, steps_per_dispatch=cfg.steps_per_dispatch,

@@ -6,9 +6,7 @@ from distributed_sgd_tpu.ops.sparse import (  # noqa: F401
 )
 
 # Kernel families live in submodules (import explicitly; none are loaded
-# eagerly so production imports stay lean and Pallas stays off the import
-# path until an engine selects it):
+# eagerly so production imports stay lean):
 # - ops.mxu           lane-blocked one-hot MXU kernels (default hot path)
-# - ops.pallas_sparse fused Pallas worker-gradient kernel
 # - ops.flat_sparse   flat CSR-style layout (SparseArrayVector parity)
 # - ops.gradcheck     central-difference gradient checking (F parity)

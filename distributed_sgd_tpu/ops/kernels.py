@@ -27,7 +27,7 @@ from distributed_sgd_tpu.utils import metrics
 AUTO = "auto"
 KERNELS = ("mxu", "scalar", "gather", "dense")
 # the families that keep w in the lane-blocked [R, 128] view (ops/mxu.py)
-BLOCKED = ("mxu", "gather", "pallas")
+BLOCKED = ("mxu", "gather")
 # the families whose scatter walks its entries one after the other, so K
 # workers' batches scattered into ONE accumulator cost what they cost apart
 # and the K accumulators' zero-fill and reduce are saved
@@ -76,8 +76,8 @@ def merges_margins(kernel: str, row_width: int) -> bool:
     the same `w`, so for sparse rows (`row_width` stored entries; 0: the
     dense layout) through the blocked kernels of ours nothing keeps them
     apart, and K calls batched over the workers cost K calls.  'scalar' and
-    'dense' keep XLA's own batching, 'pallas' its one fused launch.  Static
-    per binding: `BoundSync` counts it under `bind.margins.merged`."""
+    'dense' keep XLA's own batching.  Static per binding: `BoundSync` counts
+    it under `bind.margins.merged`."""
     return row_width != 0 and kernel in ("mxu", "gather")
 
 

@@ -27,16 +27,8 @@ scatter-add whose cost does not grow with D (ops/gather.py: the rule's
 choice from 200,000 features on); 'scalar' is the reference-shaped
 take/scatter path (ops/sparse.py); 'dense' runs dense-layout datasets
 (Dataset.dense — no index array) as plain [B, D] matmuls, auto-selected
-at bind().  'pallas' — the hand-fused single-launch
-version of the one-hot formulation (ops/pallas_sparse.py) — is an
-EXPERIMENT, not offered via Config: the regime sweep
-(benches/pallas_sweep.py, v5e) measured it 1.5-4.3x slower than 'mxu' at
-every shape tried (D in {4k, 47k}, B in {100, 1024}, K in {1, 3}) and it
-VMEM-OOMs once the flat per-worker batch outgrows VMEM (B=1024, K=3
-needed 162M of 128M) because its inputs are VMEM-resident by
-construction; XLA's own fusion of the same matmuls pipelines HBM better.
-All backends produce identical updates up to float summation order
-(tests/test_mxu_kernels.py, tests/test_pallas_kernels.py,
+at bind().  All backends produce identical updates up to float summation
+order (tests/test_mxu_kernels.py, tests/test_gather_kernels.py,
 tests/test_dense_path.py).
 
 Batch sampling mirrors Master.scala:184 (`split.map(Random.shuffle(_))`
@@ -67,7 +59,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
-from distributed_sgd_tpu.ops import kernels, mxu
+from distributed_sgd_tpu.ops import kernels
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
     WORKER_AXIS,
@@ -119,14 +111,13 @@ class BoundSync:
         virtual_workers: int = 1,
         optimizer=None,
         momentum: float = 0.9,
-        scatter: Optional[str] = None,
         donate: bool = False,
     ):
         if sampling not in ("fresh", "epoch"):
             raise ValueError(f"sampling must be 'fresh' or 'epoch', got {sampling!r}")
-        if kernel not in kernels.KERNELS + ("pallas",):
+        if kernel not in kernels.KERNELS:
             raise ValueError(
-                f"kernel must be one of {kernels.KERNELS + ('pallas',)} (bind() "
+                f"kernel must be one of {kernels.KERNELS} (bind() "
                 f"resolves {kernels.AUTO!r} by shape), got {kernel!r}"
             )
         dense_data = data.is_dense
@@ -136,26 +127,6 @@ class BoundSync:
                 f"vice versa; got kernel={kernel!r}, dense data={dense_data}"
             )
         self.kernel = kernel
-        # the Pallas kernel needs the interpreter off-TPU (tests, CPU mesh).
-        # vma (varying-mesh-axes) typing is off for the pallas backend on
-        # both sides, re-established on jax 0.9.0 / libtpu 0.0.34: the
-        # interpreter cannot type vma through its grid emulation
-        # ("dynamic_slice requires varying manual axes to match"), and on
-        # the chip vma typing plants `pvary` inside the kernel body, which
-        # Mosaic does not lower ("Unimplemented primitive in Pallas TPU
-        # lowering for KernelType.TC: pvary")
-        self._pallas_interpret = jax.default_backend() != "tpu"
-        self._check_vma = kernel != "pallas"
-        # scatter formulation override (ops/mxu.py, DSGD_SCATTER): None
-        # inherits the process-wide selection; a name pins THIS engine's
-        # compiled programs to it (applied as a trace-time scope around
-        # each body, so two engines with different formulations coexist —
-        # the fused A/B harness builds them side by side)
-        if scatter is not None and scatter not in mxu.SCATTER_FORMULATIONS:
-            raise ValueError(
-                f"scatter must be one of {mxu.SCATTER_FORMULATIONS} or None "
-                f"(process default), got {scatter!r}")
-        self._scatter = scatter
         # buffer donation (ROADMAP item 2): donate=True marks the weights
         # and optimizer-state arguments of the TRAINING dispatches (step /
         # epoch / fused multi-epoch) as donated, so XLA reuses their HBM
@@ -183,8 +154,8 @@ class BoundSync:
         if self.virtual_workers < 1:
             raise ValueError("virtual_workers must be >= 1")
         # whether the step computes the K workers' margins in one call on
-        # their merged batches (LinearModel.grad_workers; K = 1 and the
-        # Pallas kernel never go through it): static per binding
+        # their merged batches (LinearModel.grad_workers; K = 1 never goes
+        # through it): static per binding
         self.margins_merged = self.virtual_workers > 1 and kernels.merges_margins(
             kernel, data.indices.shape[1])
         if self.margins_merged:
@@ -219,21 +190,19 @@ class BoundSync:
         dspec = (P(AXIS), P(AXIS), P(AXIS))
         self._epoch = jax.jit(
             shard_map(
-                self._scoped(self._epoch_shard),
+                self._epoch_shard,
                 mesh=mesh,
                 in_specs=(P(), sspec) + dspec + (P(),),
                 out_specs=(P(), sspec),
-                check_vma=self._check_vma,
             ),
             donate_argnums=self._donate,
         )
         self._step = jax.jit(
             shard_map(
-                self._scoped(self._step_shard),
+                self._step_shard,
                 mesh=mesh,
                 in_specs=(P(), sspec) + dspec + (P(),),
                 out_specs=(P(), sspec),
-                check_vma=self._check_vma,
             ),
             donate_argnums=self._donate,
         )
@@ -244,7 +213,6 @@ class BoundSync:
                 mesh=mesh,
                 in_specs=(P(),) + dspec,
                 out_specs=P(),
-                check_vma=self._check_vma,
             )
         )
         self._predict = jax.jit(
@@ -253,26 +221,10 @@ class BoundSync:
                 mesh=mesh,
                 in_specs=(P(),) + dspec[:2],
                 out_specs=P(AXIS),
-                check_vma=self._check_vma,
             )
         )
 
     # -- per-device bodies (run under shard_map) ---------------------------
-
-    def _scoped(self, fn):
-        """Wrap a shard body so TRACING runs under this engine's scatter
-        formulation (dispatch happens at trace time; see ops/mxu.py).
-        None = inherit the process-wide selection unwrapped."""
-        if self._scatter is None:
-            return fn
-        import functools
-
-        @functools.wraps(fn)
-        def wrapped(*args):
-            with mxu.scatter_formulation(self._scatter):
-                return fn(*args)
-
-        return wrapped
 
     def _subshards(self):
         """(sub, starts, sizes): the per-virtual-worker ceil-split of this
@@ -317,30 +269,20 @@ class BoundSync:
     def _one_step(self, w, opt_state, idx, val, y, key, step):
         """One sync DP step on weights in the kernel's native layout:
         dense [D] for 'scalar'/'dense', lane-blocked [R, 128] for
-        'mxu'/'pallas'.  Returns (w', opt_state')."""
+        'mxu'/'gather'.  Returns (w', opt_state')."""
         # The jax.named_scope names (dsgd.draw, dsgd.allreduce, dsgd.update
         # here; dsgd.onehot / margins / coeff / scatter / regularize where
         # the kernels are defined) are HLO metadata only: the benchmark's
         # per-piece device metrics find each piece of the step by them
         # (benchmark/program_spans.py, PERF.md section 3).
-        one = self.virtual_workers == 1 and self.kernel != "pallas"
+        one = self.virtual_workers == 1
         with jax.named_scope("dsgd.draw"):
             ids = self._sample_ids(key, step)  # [K, B]
             if one:
                 ids = ids[0]
             # the resident-row gathers
             (bi, bv), by = self.batch_rows(idx, val, ids), y[ids]
-        if self.kernel == "pallas":
-            from distributed_sgd_tpu.ops import pallas_sparse
-
-            gk = pallas_sparse.worker_grads(
-                w, bi, bv, by, self.model.grad_coeff,
-                interpret=self._pallas_interpret,
-            )  # [K, R, 128], one fused launch for every worker
-            gk = jax.vmap(lambda g: self.model.regularize_blocked(g, w))(gk)
-            with jax.named_scope("dsgd.allreduce"):
-                g = jnp.sum(gk, axis=0)  # summed here, mean-normalized below
-        elif one:  # one worker's Gradient reply (Slave.scala:142-157)
+        if one:  # one worker's Gradient reply (Slave.scala:142-157)
             g = self.model.grad(w, SparseBatch(bi, bv), by, kernel=self.kernel)
         else:  # the K virtual workers' replies, summed (mean-normalized below)
             g = self.model.grad_workers(w, bi, bv, by, kernel=self.kernel)
@@ -569,12 +511,10 @@ class BoundSync:
 
             self._multi_cache[n_epochs] = jax.jit(
                 shard_map(
-                    self._scoped(
-                        functools.partial(self._multi_epoch_shard, n_epochs)),
+                    functools.partial(self._multi_epoch_shard, n_epochs),
                     mesh=self.mesh,
                     in_specs=(P(), self._sspec) + (P(AXIS), P(AXIS), P(AXIS)) + (P(),),
                     out_specs=(P(), self._sspec),
-                    check_vma=self._check_vma,
                 ),
                 donate_argnums=self._donate,
             )
@@ -689,7 +629,6 @@ class SyncEngine:
         virtual_workers: int = 1,
         optimizer=None,
         momentum: float = 0.9,
-        scatter: Optional[str] = None,
         donate: bool = False,
     ):
         # kernel: AUTO (the default) lets bind() ask the shape rule
@@ -704,7 +643,6 @@ class SyncEngine:
         self.virtual_workers = virtual_workers
         self.optimizer = optimizer
         self.momentum = momentum
-        self.scatter = scatter
         self.donate = donate
 
     def _resolve(self, n_features: int, row_width: int) -> str:
@@ -784,7 +722,6 @@ class SyncEngine:
             virtual_workers=self.virtual_workers,
             optimizer=self.optimizer,
             momentum=self.momentum,
-            scatter=self.scatter,
             donate=self.donate,
         )
         # spin-up fast path (compile_cache.py, DSGD_COMPILE_CACHE): start
@@ -829,7 +766,6 @@ class SyncEngine:
             virtual_workers=self.virtual_workers,
             optimizer=self.optimizer,
             momentum=self.momentum,
-            scatter=self.scatter,
             donate=self.donate,
         )
         bound._maybe_warmup()

@@ -65,7 +65,6 @@ EVENT_EF_ROLLBACK = "ef.rollback"          # worker rolled back an EF drain
 EVENT_TOPOLOGY_RESELECT = "topology.reselect"  # gossip edge re-routed past a breaker
 EVENT_HEALTH_TRIPPED = "health.tripped"        # training-health watchdog trip
 EVENT_AUTOPILOT_TRANSITION = "autopilot.transition"  # flywheel state change
-EVENT_SCATTER_SELECTED = "kernel.scatter"      # which scatter formulation ran
 EVENT_LEAK_SUSPECT = "leak.suspect"            # resource-slope sentinel trip
 
 

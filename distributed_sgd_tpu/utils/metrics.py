@@ -593,15 +593,6 @@ SHARD_ASSEMBLED = "slave.shard.assembled"        # counter: rendezvous rounds co
 SHARD_ASM_TIMEOUTS = "slave.shard.timeouts"      # counter: rendezvous waits that expired stale
 
 
-# which sparse-scatter formulation the process's kernels run (DSGD_SCATTER,
-# ops/mxu.py; ROADMAP item 2 follow-up): gauge value indexes
-# mxu.SCATTER_FORMULATIONS ('onehot'=0, 'segment'=1, 'twostage'=2,
-# 'bf16'=3), set by the auto rematch, by fit_sync per fit, and by every
-# WorkerNode at build time — so bench runs and the cluster /metrics
-# endpoint attribute which formulation a fit actually ran
-SCATTER_FORMULATION = "kernel.scatter.formulation"  # gauge: formulation index
-
-
 # -- continual-learning autopilot (autopilot/; docs/CONTINUAL.md) -------------
 # Registered only while an AutopilotController runs (DSGD_AUTOPILOT):
 # knobs-off, none of these exist (tests/test_flywheel.py identity gate).

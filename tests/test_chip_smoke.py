@@ -87,16 +87,16 @@ def test_rehearsal_passes_every_phase_and_says_it_is_one(rehearsal):
     result = json.loads(summary[len("summary: "):])
     assert result["rehearsal"] is True
     assert list(result["phases"]) == [
-        "mesh1", "meshN", "rpc", "gossip", "serve", "pallas", "placement"]
+        "mesh1", "meshN", "rpc", "gossip", "serve", "placement"]
     assert all(p["ok"] for p in result["phases"].values())
     # the every-device phase really split the rows, and ran its reference
     mesh_n = result["phases"]["meshN"]
     assert len(mesh_n["rows_per_device"]) == 2
     assert "one_device_same_workers" in mesh_n
-    # off the chip the policy picks the scalar kernels and the interpreter
+    # off the chip the policy picks the scalar kernels
     assert result["kernels"] == {
         "mesh": "mxu (blocked one-hot, XLA)", "rpc": "scalar",
-        "gossip": "scalar", "pallas": "interpreted"}
+        "gossip": "scalar"}
     assert result["phases"]["serve"]["worst_abs_err"] < 1e-4
 
 

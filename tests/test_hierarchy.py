@@ -2,8 +2,8 @@
 
 Covers the in-host mesh engine's parity with the flat worker kernels,
 the end-to-end hierarchical RPC topology on the 8-virtual-device test
-mesh, the host-granular weighted split, host-local id mapping, the
-knobs-off identity discipline, and the DSGD_SCATTER attribution gauge.
+mesh, the host-granular weighted split, host-local id mapping, and the
+knobs-off identity discipline.
 """
 
 import jax
@@ -196,11 +196,8 @@ def test_knobs_off_worker_is_flat_and_wire_is_unchanged(data, model):
 def test_hierarchical_cluster_end_to_end(data, model):
     """2 hosts x 2 devices with host-local slices: the fit converges in
     parity with the flat topology at equal global batch (lr scaled by
-    H/W, docs/HIERARCHY.md), predict spans the host-local slices, the
-    master knows the host shapes, and the scatter gauge attributes the
-    formulation the fit ran."""
-    from distributed_sgd_tpu.utils import metrics as metrics_mod
-
+    H/W, docs/HIERARCHY.md), predict spans the host-local slices, and the
+    master knows the host shapes."""
     with DevCluster(model, data, data, n_workers=4) as c:
         flat = c.master.fit_sync(max_epochs=3, batch_size=10,
                                  learning_rate=0.5)
@@ -222,10 +219,6 @@ def test_hierarchical_cluster_end_to_end(data, model):
         acc_dist = float((preds == data.labels).mean())
         _, acc_local = c.master.local_loss(w_h)
         assert acc_dist == pytest.approx(acc_local, abs=1e-6)
-        # the scatter-formulation gauge attributes the fit (index into
-        # ops/mxu SCATTER_FORMULATIONS; default = 0, 'onehot')
-        g = c.master.metrics.gauge(metrics_mod.SCATTER_FORMULATION)
-        assert g.value == 0.0
     assert hier.losses[-1] <= max(1.02 * flat.losses[-1],
                                   flat.losses[-1] + 0.02)
 
@@ -260,16 +253,3 @@ def test_host_local_worker_rejects_foreign_ids(data, model):
                                np.asarray([205]))
     finally:
         w.stop()
-
-
-def test_scatter_gauge_set_by_resolution(data):
-    """resolve_scatter_formulation surfaces its pick on the global
-    registry (the only-logged gap the telemetry satellite closes)."""
-    from distributed_sgd_tpu.ops import mxu
-    from distributed_sgd_tpu.utils import metrics as metrics_mod
-
-    picked = mxu.resolve_scatter_formulation(
-        "auto", batch_size=4, nnz=3, n_features=DIM, reps=1)
-    assert picked in mxu.SCATTER_FORMULATIONS
-    g = metrics_mod.global_metrics().gauge(metrics_mod.SCATTER_FORMULATION)
-    assert g.value == float(mxu.SCATTER_FORMULATIONS.index(picked))

@@ -3,7 +3,9 @@
 Lane-blocked layouts classically break at boundary shapes: feature dims
 below one lane (D < 128), exactly on a block edge (D = 128k), one-past
 (D = 128k + 1), single-sample and single-nnz batches.  Every (layout,
-shape) pair must agree with the scalar-path kernels.
+shape) pair must agree with the scalar-path kernels, and every sparse
+family (ops/kernels.py) must hand back the same worker reply through the
+model's one dispatch.
 """
 
 
@@ -12,7 +14,7 @@ import numpy as np
 import pytest
 
 from distributed_sgd_tpu.models.linear import SparseSVM
-from distributed_sgd_tpu.ops import flat_sparse, mxu, pallas_sparse
+from distributed_sgd_tpu.ops import flat_sparse, gather, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch, matvec, scatter_add
 
 DIMS = [1, 5, 127, 128, 129, 1024, 1025]
@@ -49,57 +51,82 @@ def test_mxu_kernels_all_shapes(d, bp):
     )
 
 
-# -- selectable scatter formulations (ops/mxu.py DSGD_SCATTER) -------------
+# -- the sparse families' worker reply (models/linear.py's one dispatch) ----
 #
-# Every formulation must agree with the scalar-path scatter on the same
-# boundary shapes as the one-hot layout, PLUS the scatter-specific traps:
-# all-pad (empty) rows, duplicate feature ids within a row (the fancy-
-# indexed += failure mode a segment reduction must not reproduce), pads
-# scattering into feature 0 on top of a REAL feature-0 contribution, B=1
-# and B=1024, and the bf16 accumulation bound.
+# What an engine runs: weights into the family's layout, margins, then one
+# worker's reply (`grad`) or the K virtual workers' replies summed
+# (`grad_workers`: 'mxu' and 'gather' get the K margins from ONE call on the
+# merged batch, which `mxu.lane_minor_rows` may pad, and 'gather' under a
+# linear regulariser scatters all K batches into one accumulator).  Held to
+# the scalar-path kernels on the same rows, under both regularisers.
 
-FORM_TOL = {"onehot": dict(rtol=1e-4, atol=1e-5),
-            "segment": dict(rtol=1e-4, atol=1e-5),
-            "twostage": dict(rtol=1e-4, atol=1e-5),
-            # bf16 partial sums carry ~3 decimal digits, and the error
-            # scales with the ACCUMULATED magnitude (cancellation can make
-            # a final value small while its partial sums were large) — so
-            # the bound is rtol + an atol proportional to the largest
-            # accumulated value (_tol below): the documented accumulation
-            # bound, NOT float-order noise (ops/mxu.py)
-            "bf16": dict(rtol=2e-2, atol=2e-3)}
+FAMILIES = ("mxu", "gather", "scalar")
+TOL = dict(rtol=1e-4, atol=1e-5)
 
 
-def _tol(form, want):
-    tol = dict(FORM_TOL[form])
-    if form == "bf16":
-        tol["atol"] = max(tol["atol"], 3e-3 * float(np.abs(want).max()))
-    return tol
+def _reference_reply(model, w, batch, y):
+    coeff = model.grad_coeff(matvec(batch, w), y)
+    return np.asarray(model.regularize(scatter_add(batch, coeff, model.n_features), w))
 
 
-def _assert_scatter_matches(batch, coeff, d, form):
-    with mxu.scatter_formulation(form):
-        got = mxu.from_blocked(
-            mxu.scatter_add(batch, coeff, mxu.n_blocks(d)), d)
-    want = np.asarray(scatter_add(batch, coeff, d))
-    np.testing.assert_allclose(
-        np.asarray(got), want, err_msg=f"formulation {form}",
-        **_tol(form, want))
-
-
-@pytest.mark.parametrize("form", mxu.SCATTER_FORMULATIONS)
+@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("k", [1, 3])
 @pytest.mark.parametrize("d", DIMS)
 @pytest.mark.parametrize("bp", BATCHES)
-def test_scatter_formulations_all_shapes(form, d, bp):
+def test_family_reply_all_shapes(family, k, d, bp):
     b, p = bp
-    batch, _ = _mk(b, p, d, seed=d * 31 + b)
-    coeff = jnp.asarray(np.random.default_rng(d + 1).normal(size=b),
-                        dtype=jnp.float32)
-    _assert_scatter_matches(batch, coeff, d, form)
+    made = [_mk(b, p, d, seed=d * 31 + b + 7 * j) for j in range(k)]
+    w = jnp.asarray(np.random.default_rng(d).normal(size=d), dtype=jnp.float32)
+    ds = jnp.asarray(np.random.default_rng(d + 2).random(d) * 0.01, dtype=jnp.float32)
+    for regularizer in ("dim_sparsity", "l2"):
+        model = SparseSVM(lam=1e-3, n_features=d, dim_sparsity=ds,
+                          regularizer=regularizer)
+        wl = model.to_layout(w, family)
+        for batch, _ in made:
+            np.testing.assert_allclose(
+                np.asarray(model.margins(wl, batch, kernel=family)),
+                np.asarray(matvec(batch, w)), **TOL)
+        if k == 1:
+            (batch, y), = made
+            got = model.grad(wl, batch, y, kernel=family)
+        else:
+            got = model.grad_workers(
+                wl, jnp.stack([m[0].indices for m in made]),
+                jnp.stack([m[0].values for m in made]),
+                jnp.stack([m[1] for m in made]), kernel=family)
+        want = sum(_reference_reply(model, w, batch, y) for batch, y in made)
+        np.testing.assert_allclose(
+            np.asarray(model.from_layout(got, family)), want,
+            err_msg=f"{family}, {regularizer}", **TOL)
 
 
-@pytest.mark.parametrize("form", mxu.SCATTER_FORMULATIONS)
-def test_scatter_formulations_empty_rows_and_duplicates(form):
+# -- each family's scatter on the scatter-specific traps --------------------
+#
+# All-pad (empty) rows, duplicate feature ids within a row (the fancy-
+# indexed += failure mode), pads scattering into feature 0 on top of a REAL
+# feature-0 contribution, B=1 and B=1024: against a float64 `np.add.at`.
+
+
+def _family_scatter(family, batch, coeff, d):
+    if family == "scalar":
+        return np.asarray(scatter_add(batch, coeff, d))
+    blocked = (mxu if family == "mxu" else gather).scatter_add(
+        batch, coeff, mxu.n_blocks(d))
+    return np.asarray(mxu.from_blocked(blocked, d))
+
+
+def _assert_scatter_matches(batch, coeff, d, family):
+    want = np.zeros(d, np.float64)
+    np.add.at(want, np.asarray(batch.indices).reshape(-1),
+              (np.asarray(batch.values, np.float64)
+               * np.asarray(coeff, np.float64)[:, None]).reshape(-1))
+    np.testing.assert_allclose(
+        _family_scatter(family, batch, coeff, d), want,
+        err_msg=f"family {family}", **TOL)
+
+
+@pytest.mark.parametrize("family", FAMILIES)
+def test_scatter_empty_rows_and_duplicates(family):
     d, b, p = 300, 6, 8
     rng = np.random.default_rng(5)
     idx = rng.integers(0, d, (b, p)).astype(np.int32)
@@ -109,11 +136,11 @@ def test_scatter_formulations_empty_rows_and_duplicates(form):
     idx[3, :4] = 7  # partial duplicates within a row
     batch = SparseBatch(jnp.asarray(idx), jnp.asarray(val))
     coeff = jnp.asarray(rng.normal(size=b), dtype=jnp.float32)
-    _assert_scatter_matches(batch, coeff, d, form)
+    _assert_scatter_matches(batch, coeff, d, family)
 
 
-@pytest.mark.parametrize("form", mxu.SCATTER_FORMULATIONS)
-def test_scatter_formulations_pad_into_real_feature_zero(form):
+@pytest.mark.parametrize("family", FAMILIES)
+def test_scatter_pad_into_real_feature_zero(family):
     # pads are (index 0, value 0); a REAL feature-0 contribution must come
     # through exactly while the pads add nothing to it
     d, b = 130, 3
@@ -122,62 +149,21 @@ def test_scatter_formulations_pad_into_real_feature_zero(form):
                     [0.0, 0.0, 0.0, 0.0]], np.float32)
     batch = SparseBatch(jnp.asarray(idx), jnp.asarray(val))
     coeff = jnp.asarray([1.0, -2.0, 5.0], dtype=jnp.float32)
-    _assert_scatter_matches(batch, coeff, d, form)
-    with mxu.scatter_formulation(form):
-        got = np.asarray(mxu.from_blocked(
-            mxu.scatter_add(batch, coeff, mxu.n_blocks(d)), d))
+    _assert_scatter_matches(batch, coeff, d, family)
+    got = _family_scatter(family, batch, coeff, d)
     # hand-computed: feature 0 gets 1*2.0 + (-2)*3.0 = -4 (pads add 0)
-    np.testing.assert_allclose(got[0], -4.0, **FORM_TOL[form])
-    np.testing.assert_allclose(got[129], -3.0, **FORM_TOL[form])
+    np.testing.assert_allclose(got[0], -4.0, **TOL)
+    np.testing.assert_allclose(got[129], -3.0, **TOL)
 
 
-@pytest.mark.parametrize("form", mxu.SCATTER_FORMULATIONS)
+@pytest.mark.parametrize("family", FAMILIES)
 @pytest.mark.parametrize("b", [1, 1024])
-def test_scatter_formulations_batch_extremes(form, b):
+def test_scatter_batch_extremes(family, b):
     d, p = 512, 5
     batch, _ = _mk(b, p, d, seed=b)
     coeff = jnp.asarray(np.random.default_rng(b + 1).normal(size=b),
                         dtype=jnp.float32)
-    _assert_scatter_matches(batch, coeff, d, form)
-
-
-def test_bf16_accumulation_bound_is_real():
-    """The bf16 bound is a loosened TOLERANCE, not a different result: on
-    an adversarial batch (many near-cancelling contributions into one
-    feature) the bf16 error must stay within FORM_TOL['bf16'] of the f32
-    scatter while being measurably nonzero — i.e. the formulation really
-    accumulates in bf16 (a silent f32 fallback would be bit-exact)."""
-    d, b, p = 256, 64, 16
-    rng = np.random.default_rng(11)
-    idx = np.full((b, p), 3, np.int32)  # everything lands on feature 3
-    val = rng.normal(size=(b, p)).astype(np.float32)
-    batch = SparseBatch(jnp.asarray(idx), jnp.asarray(val))
-    coeff = jnp.asarray(rng.normal(size=b), dtype=jnp.float32)
-    want = np.asarray(scatter_add(batch, coeff, d))
-    with mxu.scatter_formulation("bf16"):
-        got = np.asarray(mxu.from_blocked(
-            mxu.scatter_add(batch, coeff, mxu.n_blocks(d)), d))
-    np.testing.assert_allclose(got, want, **_tol("bf16", want))
-    assert np.any(got != want), \
-        "bf16 scatter is bit-identical to f32 — it is not accumulating in bf16"
-
-
-@pytest.mark.parametrize("d", [1, 127, 129, 1025])
-@pytest.mark.parametrize("bp", BATCHES)
-def test_pallas_kernel_all_shapes(d, bp):
-    b, p = bp
-    batch, y = _mk(b, p, d, seed=d * 17 + b)
-    model = SparseSVM(lam=1e-3, n_features=d,
-                      dim_sparsity=jnp.asarray(np.full(d, 0.01, np.float32)))
-    w2 = mxu.to_blocked(
-        jnp.asarray(np.random.default_rng(d).normal(size=d), dtype=jnp.float32), d
-    )
-    got = pallas_sparse.worker_grads(
-        w2, batch.indices[None], batch.values[None], y[None],
-        model.grad_coeff, interpret=True,
-    )
-    want = model.grad_blocked(w2, batch, y)
-    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want), rtol=1e-4, atol=1e-5)
+    _assert_scatter_matches(batch, coeff, d, family)
 
 
 @pytest.mark.parametrize("d", [1, 128, 129])

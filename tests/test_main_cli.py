@@ -72,7 +72,7 @@ def test_invalid_config_fails_fast(tmp_path):
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO + os.pathsep + env.get("PYTHONPATH", ""),
         "DSGD_SYNTHETIC": "300",
-        "DSGD_KERNEL": "pallas",  # demoted: rejected at config parse
+        "DSGD_KERNEL": "pallas",  # no such family: rejected at config parse
     })
     proc = subprocess.run(
         [sys.executable, "-m", "distributed_sgd_tpu.main"],

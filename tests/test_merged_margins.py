@@ -83,7 +83,6 @@ def test_the_rule_reads_family_and_row_width_only():
     assert not kernels.merges_margins("mxu", 0)        # the dense layout
     assert not kernels.merges_margins("scalar", P)     # vmapped as they were
     assert not kernels.merges_margins("dense", 0)
-    assert not kernels.merges_margins("pallas", P)     # one fused launch of its own
 
 
 def _sparse(n=512):
