@@ -45,9 +45,14 @@ ONE_ACCUMULATOR = ("gather",)
 # 'gather' reads the same at both feature counts and both batches; the
 # one-hot's MACs grow with R, so on the line through its two batch-100
 # points the families cross near D = 3.3e5.  The crossing itself was not
-# measured, nor the one-hot at batch 200 beyond RCV1's D (where it is
-# already super-linear in a step's entries): the constant sits between the
-# two measured feature counts, a factor of four to five from either.
+# measured.  At the constant itself (D = 200,000, R = 1,568; one call of
+# each side by `benches/onehot_call_sweep.py`, PERF.md section 6, PR 29)
+# the one-hot pair costs 5.2 ns an entry at batch 100 and 4.8-5.1 at batch
+# 200 (the batch-200 figure at D = 47,236 above was the scatter's
+# contraction tiled by the compiler, which `mxu.scatter_shards` has ended:
+# a step's entries are no input of this rule), half of what 'gather' pays:
+# the constant sits under the crossing, between the two measured feature
+# counts, a factor of four to five from either.
 GATHER_MIN_FEATURES = 200_000
 
 

@@ -16,18 +16,22 @@ and express both sparse kernels as one-hot matmuls that run on the MXU
 - scatter: sum_t v[t]*e_{idx[t]} = OHR^T @ (OHC * v[:,None])  — one MXU
            matmul producing the blocked gradient [R, 128] directly.
 
-An entry pays R*128 ~ 48k MACs either way and no serial step.  By the
-ledger (TPU v5e, D = 47,236, R = 376; PERF_LEDGER.jsonl, PR 27) the gather
-costs 0.73 ns a stored entry (`rcv1-sync-1chip`, 22.26 us for 4 x 100 x 76
-entries) and the scatter 0.91 ns (27.75 us) at batch 100 and 2.94 ns
-(178.87 us for 60,800) at batch 200: the scatter's cost an entry grows
-with the entries in one contraction (PERF.md section 7).  XLA's own
-scatter-add over the same weights costs 6.9-8.0 ns an entry, one after the
-other (ops/gather.py), and a sort plus `segment_sum` in its place, or the
-contraction accumulated in bf16, lost on the chip (PERF.md section 6,
-PR 28): this file holds one formulation of each side.  The one-hot
-matrices are built in registers by XLA (iota compare) and fuse into the
-consuming matmul.
+An entry pays R*128 ~ 48k MACs either way and no serial step.  On a TPU
+v5e at D = 47,236 (R = 376) the gather costs 0.73 ns a stored entry
+(`rcv1-sync-1chip`, 22.26 us for 4 x 100 x 76 entries; ledger, PR 28) and
+the scatter 0.91 ns at batch 100 (27.76 us) and 0.90 ns at batch 200
+(54.75 us for 60,800; my chip runs, PR 29).  What an entry of the scatter
+costs is decided by the DEPTH of one contraction: the compiler keeps a
+contraction of up to ~8,700 entries in one window and tiles a deeper one
+into windows of 128, each paying a window's fixed cost (the one 15,200-deep
+dot of batch 200 read 178.87 us, 2.94 ns an entry), so `scatter_add` cuts
+its contraction into shards no deeper than that by a rule on the shape
+(`scatter_shards`; PERF.md section 6, PR 29).  XLA's own scatter-add over
+the same weights costs 6.9-8.0 ns an entry, one after the other
+(ops/gather.py), and a sort plus `segment_sum` in its place lost by 10 x on
+the chip (PERF.md section 6, PR 28): this file holds one formulation of
+each side.  The one-hot matrices are built in registers by XLA (iota
+compare) and fuse into the consuming matmul, sharded or not.
 
 These kernels replace the reference's per-sample map arithmetic
 (Sparse.scala:15-46, Slave.scala:147-153) on the training hot path; the
@@ -96,7 +100,8 @@ class OneHotBatch:
     from two `OneHotBatch`es (`LinearModel.grad_workers`: the margins of K
     workers in one call, their scatters apart) pays no second build.
     Which way round the compiler lays the gather's operand out decides what
-    an entry costs (`lane_minor_rows`)."""
+    an entry of the gather costs (`lane_minor_rows`), how deep one
+    contraction runs what an entry of the scatter costs (`scatter_shards`)."""
 
     def __init__(self, batch: SparseBatch, n_rows: int, dtype=jnp.float32):
         with jax.named_scope("dsgd.onehot"):
@@ -104,6 +109,7 @@ class OneHotBatch:
             self.values = batch.values.astype(jnp.float32).reshape(-1)  # [T]
             self.ohr = jax.nn.one_hot(flat_idx // LANES, n_rows, dtype=dtype)  # [T, R]
             self.ohc = jax.nn.one_hot(flat_idx % LANES, LANES, dtype=dtype)  # [T, L]
+        self.flat_idx = flat_idx  # [T], for a scatter that builds its own operands
         self.batch_size = batch.batch_size
         self.pad_width = batch.pad_width
 
@@ -123,27 +129,99 @@ class OneHotBatch:
 
     def scatter_add(self, coeff: jax.Array) -> jax.Array:
         """Blocked sum_b coeff[b] * x_b -> [R, 128] (scatter_add equivalent):
-        ONE dot whose contraction runs over all T entries of the batch.
+        onehot(rows)^T @ (onehot(lanes) * c*v), the contraction over the
+        batch's T entries cut into S = `scatter_shards(T, R)` equal shards:
+        the S partial products come from ONE dot batched over the shard
+        axis, each accumulated in f32, and are summed in f32.  The same
+        products (operands as before: one bf16 pass on the MXU), the same
+        f32 accumulation, the terms in another order; S = 1 is the one plain
+        dot.  Where S does not divide T the entry axis is padded with
+        entries of value 0 on index 0: a pad adds 0 to block 0.
 
-        By the ledger (v5e, R = 376; PR 27) it is the largest piece of the
-        sparse step, and an entry costs more the deeper the contraction:
-        0.91 ns at T = 7,600 a worker (`rcv1-sync-1chip`, 27.75 of a 72.8 us
-        step for 30,400 entries), 2.94 ns at T = 15,200 (`rcv1-sync-b200`,
-        178.87 of 252.2 us for 60,800).  Splitting the contraction into
-        batched shards ran the scatter alone 1.7-4.8x faster and the whole
-        step 8-15 % slower (BASELINE.md round 4): the shards break the
-        iota-compare fusion the one dot keeps.
+        By the ledger (v5e, R = 376; PR 28) it is the largest piece of the
+        sparse step; what an entry costs is decided by whether its
+        contraction fits one of the compiler's windows (`scatter_shards`):
+        the same dot in two f32 shards of 7,600 entries read 54.75 us a step
+        of `rcv1-sync-b200` where the one 15,200-deep dot read 178.89
+        (PERF.md section 6, PR 28).  BASELINE.md round 4's "batched shards
+        run the whole step 8-15 % slower" (July, T = 22,800 in four shards)
+        does not hold on this compiler: the iota-compare build fuses into
+        the sharded dot as into the plain one.
         """
         with jax.named_scope("dsgd.scatter"):
             cv = (
                 self.values.reshape(self.batch_size, self.pad_width)
                 * coeff.astype(jnp.float32)[:, None]
             ).reshape(-1)
-            contrib = self.ohc.astype(jnp.float32) * cv[:, None]  # [T, L]
-            return jax.lax.dot(
-                self.ohr.T, contrib.astype(self.ohr.dtype),
-                preferred_element_type=jnp.float32
+            shards = scatter_shards(cv.shape[0], self.ohr.shape[1])
+            if shards == 1:
+                contrib = self.ohc.astype(jnp.float32) * cv[:, None]  # [T, L]
+                return jax.lax.dot(
+                    self.ohr.T, contrib.astype(self.ohr.dtype),
+                    preferred_element_type=jnp.float32
+                )
+            # the one-hot operands again, from the padded ids: a pad of the
+            # [T, R] operand itself would be written out and read back
+            dtype, n_rows = self.ohr.dtype, self.ohr.shape[1]
+            pad = (0, -cv.shape[0] % shards)
+            idx = jnp.pad(self.flat_idx, pad)
+            ohr = jax.nn.one_hot(idx // LANES, n_rows, dtype=dtype)
+            contrib = jax.nn.one_hot(
+                idx % LANES, LANES, dtype=jnp.float32) * jnp.pad(cv, pad)[:, None]
+            partials = jax.lax.dot_general(  # [S, R, L]
+                ohr.reshape(shards, -1, n_rows),
+                contrib.astype(dtype).reshape(shards, -1, LANES),
+                (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
             )
+            return partials.sum(axis=0)
+
+
+# The chip runs the scatter's dot as a convolution that walks the contraction
+# (the entries) in WINDOWS, one pipeline iteration a window (read off the
+# programs compiled for a described v5e: `window_config` of the fusion).  Up
+# to a depth the whole contraction is ONE window, and a window costs ~0.3 us
+# + 0.9 ns an entry; past it the compiler tiles the contraction, in the sync
+# step down to windows of 128 entries, every one paying the 0.3 us: an
+# entry of `rcv1-sync-b200`'s 15,200-deep scatter cost 2.94 ns against 0.91
+# at 7,600 (ledger, PR 28).  Cut into shards no deeper than one window, the
+# shard axis is what gets tiled and every contraction stays whole.  Measured
+# on a v5e, `BoundSync.epoch` at R = 376, 4 workers, us a step by batch (T
+# entries a worker), the one dot against the best S (PERF.md section 6,
+# PR 29):
+#
+#     batch 100 (T  7,600)  63.5        S = 2:  73.0
+#     batch 110 (T  8,360)  65.5        S = 2:  76.9    <- one window still
+#     batch 115 (T  8,740) 140.4        S = 2:  94.9    <- tiled by 128
+#     batch 200 (T 15,200) 235.5        S = 2: 111.9  (S = 4: 113.6, S = 8: 140.0)
+#     batch 400 (T 30,400) 454.9        S = 4: 210.0  (S = 2: 228.8, S = 3: 288.0)
+#     batch 1,024 (77,824) 1,125.3      S = 9: 529.4  (S = 11: 536.8, S = 8: 562.0)
+#
+# Compiled, the step keeps one window through T = 8,664 at K = 4 and 8,892
+# at K = 1; the constant is the deepest contraction MEASURED in one window.
+# The window holds a fixed count of one-hot elements, not of entries: one
+# window through T = 4,736 at R = 752 and 2,176 at R = 1,568 (compiled),
+# and at R = 1,568 batch 100 reads 204.0 as one dot and 168.2 in four shards
+# of 1,900 (batch 200: 378.0 / 304.9 in eight).  So the depth shrinks with R
+# beyond the R it was measured at.  tests/test_row_placement.py holds the
+# windows on the described v5e; the day it fails the compiler has changed
+# and these two constants can go.
+SCATTER_WINDOW_ENTRIES = 8_360
+SCATTER_WINDOW_ROWS = 376
+
+
+def scatter_depth(n_rows: int) -> int:
+    """The deepest contraction (entries) `OneHotBatch.scatter_add` runs as
+    one dot over `n_rows` blocked rows: SCATTER_WINDOW_ENTRIES up to
+    SCATTER_WINDOW_ROWS rows, fewer in proportion beyond."""
+    return max(1, SCATTER_WINDOW_ENTRIES * min(n_rows, SCATTER_WINDOW_ROWS) // n_rows)
+
+
+def scatter_shards(n_entries: int, n_rows: int) -> int:
+    """The shards S `OneHotBatch.scatter_add` cuts a contraction over
+    `n_entries` entries into: the fewest that are no deeper than
+    `scatter_depth(n_rows)`.  A function of the shape alone, static per
+    binding: `BoundSync` counts S > 1 under `bind.scatter.sharded`."""
+    return max(1, -(-int(n_entries) // scatter_depth(n_rows)))
 
 
 # A call of the gather costs 0.63-0.67 ns a stored entry where the compiler

@@ -59,7 +59,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
-from distributed_sgd_tpu.ops import kernels
+from distributed_sgd_tpu.ops import kernels, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
     WORKER_AXIS,
@@ -160,6 +160,15 @@ class BoundSync:
             kernel, data.indices.shape[1])
         if self.margins_merged:
             metrics.counter("bind.margins.merged").increment()
+        # the shards the step's one-hot scatter cuts a worker's contraction
+        # over its batch's entries into (mxu.scatter_shards; 1: one plain
+        # dot, and every other family's answer): static per binding
+        row_width = data.indices.shape[1] if data.width is None else data.width
+        self.scatter_shards = mxu.scatter_shards(
+            self.batch_size * row_width, mxu.n_blocks(model.n_features)
+        ) if kernel == "mxu" else 1
+        if self.scatter_shards > 1:
+            metrics.counter("bind.scatter.sharded").increment()
         # rows stored wider than the dataset holds them (mesh.put_rows):
         # every read takes the true width back off (rows / chunk)
         padded = (not data.packed and data.width is not None

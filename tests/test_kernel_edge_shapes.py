@@ -18,7 +18,9 @@ from distributed_sgd_tpu.ops import flat_sparse, gather, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch, matvec, scatter_add
 
 DIMS = [1, 5, 127, 128, 129, 1024, 1025]
-BATCHES = [(1, 1), (1, 4), (3, 1), (9, 5)]
+# (113, 75): 8,475 entries, deep enough for `mxu.scatter_shards` to cut the
+# one-hot scatter in two (one pad entry)
+BATCHES = [(1, 1), (1, 4), (3, 1), (9, 5), (113, 75)]
 
 
 def _mk(b, p, d, seed):

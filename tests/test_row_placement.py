@@ -350,17 +350,14 @@ def test_on_a_v5e_packed_rows_are_row_major_never_copied_and_drawn_in_one_gather
 # (models/linear.py `grad_workers`, ops/mxu.py `lane_minor_rows`; PERF.md
 # section 6, PR 27)
 
-def _onehot_matmuls(v5e, workers, batch):
-    """(scope, dim_labels, left operand's shape, its minor-most dimension)
-    of every convolution in the `mxu` epoch program of `rcv1-hinge`'s shape
+def _mxu_epoch_text(v5e, workers, batch, d=47_236):
+    """The `mxu` epoch program of `rcv1-hinge`'s shape (`d` features),
     compiled for one v5e chip."""
-    import re
-
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
 
-    rows, width, d = 4096 * 64, 76, 47_236
+    rows, width = 4096 * 64, 76
     mesh = Mesh(np.array(v5e.devices[:1]), ("workers",))
     over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
     shape = jax.ShapeDtypeStruct
@@ -369,9 +366,17 @@ def _onehot_matmuls(v5e, workers, batch):
                        shape((rows,), jnp.int32, sharding=over_rows), rows, width)
     model = make_model("hinge", 1e-5, d, dim_sparsity=jnp.ones((d,), jnp.float32))
     bound = BoundSync(model, mesh, data, batch, 0.5, kernel="mxu", virtual_workers=workers)
-    text = bound._epoch.lower(
+    return bound._epoch.lower(
         shape((d,), jnp.float32, sharding=everywhere), (), data.indices, data.values,
         data.labels, shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
+
+
+def _onehot_matmuls(v5e, workers, batch):
+    """(scope, dim_labels, left operand's shape, its minor-most dimension)
+    of every convolution in that program."""
+    import re
+
+    text = _mxu_epoch_text(v5e, workers, batch)
     stored = {name: (tuple(int(n) for n in dims.split(",")), int(minor))
               for name, dims, minor in re.findall(
                   r"^\s*(%\S+) = \w+\[([\d,]+)\]\{(\d+)", text, re.M)}
@@ -381,24 +386,30 @@ def _onehot_matmuls(v5e, workers, batch):
             r"(dsgd\.[a-z]+)", text))
 
 
-@pytest.mark.parametrize("batch,gathered_rows", [(100, 448), (200, 800)])
-def test_on_a_v5e_the_virtual_workers_margins_are_one_flat_gather(v5e, batch, gathered_rows):
+@pytest.mark.parametrize("batch,gathered_rows,shards", [(100, 448, 1), (200, 800, 2)])
+def test_on_a_v5e_the_virtual_workers_margins_are_one_flat_gather(
+        v5e, batch, gathered_rows, shards):
     k, entries, r = 4, batch * 76, 376
     assert mxu_mod.lane_minor_rows(k * batch, 76) == gathered_rows
+    assert mxu_mod.scatter_shards(entries, r) == shards
     # the gather: ONE [T, R] x [R, 128] with no worker dimension, its one-hot
     # operand built with the entries along the lanes (dimension 0 minor: what
     # `lane_minor_rows` pads 400 rows to 448 for; the day this fails the
     # compiler has changed and the rule's constants can go); the scatter
     # keeps its workers apart ('dim_sparsity' masks each reply by its
-    # worker's own support)
+    # worker's own support) and, at batch 200, carries the shard axis
+    # beside them, the sum over the shards inside the convolution
+    apart = [("dsgd.scatter", "0fb_0io->0bf", (k, entries, r), 1),
+             ("dsgd.scatter", "01fb_01io->0bf1", (k, 2, entries // 2, r), 2)][shards - 1]
     assert _onehot_matmuls(v5e, k, batch) == [
-        ("dsgd.margins", "bf_io->bf", (gathered_rows * 76, r), 0),
-        ("dsgd.scatter", "0fb_0io->0bf", (k, entries, r), 1)]
+        ("dsgd.margins", "bf_io->bf", (gathered_rows * 76, r), 0), apart]
     # one worker a device never goes through grad_workers: two plain
-    # matmuls, entries-major as they were
+    # matmuls, entries-major as they were (the scatter in two shards at
+    # batch 200)
+    alone = [("dsgd.scatter", "fb_io->bf", (entries, r), 1),
+             ("dsgd.scatter", "0fb_0io->bf0", (2, entries // 2, r), 1)][shards - 1]
     assert _onehot_matmuls(v5e, 1, batch) == [
-        ("dsgd.margins", "bf_io->bf", (entries, r), 1),
-        ("dsgd.scatter", "fb_io->bf", (entries, r), 1)]
+        ("dsgd.margins", "bf_io->bf", (entries, r), 1), alone]
 
 
 @pytest.mark.parametrize("rows,width,runs_on", [
@@ -416,3 +427,44 @@ def test_matvec_pads_to_whole_lanes_only_where_an_eighth_more_entries_buys_them(
         rows, width, runs_on):
     assert mxu_mod.lane_minor_rows(rows, width) == runs_on
     assert (mxu_mod.LANE_MINOR_MIN_ENTRIES, mxu_mod.MATVEC_MAX_PADDING) == (32_768, 1.125)
+
+
+# -- (g) the scatter's contraction stays inside one of the compiler's windows -----
+# (ops/mxu.py `scatter_shards`; PERF.md section 6, PR 29)
+
+def _scatter_windows(text):
+    """(entries a window, iterations) of the scatter's convolution fusion: the
+    compiler walks the contraction in windows of so many sublane tiles of 8
+    entries, one pipeline iteration a window."""
+    import re
+
+    (window, iterations), = re.findall(
+        r'dsgd\.scatter\)?/dot_general".*?"kernel_window_bounds":\[([^\]]+)\].*?'
+        r'"iteration_bounds":\[([^\]]+)\]', text)
+    count = lambda bounds: int(np.prod([int(n.strip('"')) for n in bounds.split(",")]))
+    return 8 * count(window), count(iterations)
+
+
+@pytest.mark.parametrize("workers,batch,d,shards,window,iterations", [
+    (4, 100, 47_236, 1, 7_600, 4),     # rcv1-sync-1chip: a worker's contraction whole
+    (4, 110, 47_236, 1, 8_360, 4),     # SCATTER_WINDOW_ENTRIES: one window still
+    (4, 200, 47_236, 2, 7_600, 8),     # rcv1-sync-b200: a window a shard
+    (1, 100, 47_236, 1, 7_600, 1),     # rcv1-sync-4chip, Hogwild's kstep
+    (1, 400, 47_236, 4, 7_600, 4),
+    (4, 100, 200_000, 4, 1_904, 16),   # R = 1,568: the window holds fewer entries
+])
+def test_on_a_v5e_every_scatter_contraction_is_one_window(
+        v5e, workers, batch, d, shards, window, iterations):
+    assert mxu_mod.scatter_shards(batch * 76, mxu_mod.n_blocks(d)) == shards
+    assert _scatter_windows(_mxu_epoch_text(v5e, workers, batch, d)) == (window, iterations)
+
+
+def test_on_a_v5e_one_deeper_dot_is_tiled_by_128_entries(v5e, monkeypatch):
+    # what the rule is there for: `rcv1-sync-b200`'s 15,200-deep contraction as
+    # ONE dot runs in 4 x 119 windows of 128 entries, each paying a window's
+    # fixed cost (178.9 us a step against 54.75 in two shards); the day this
+    # fails the compiler has changed and mxu.SCATTER_WINDOW_ENTRIES can go
+    monkeypatch.setattr(mxu_mod, "scatter_shards", lambda entries, rows: 1)
+    assert _scatter_windows(_mxu_epoch_text(v5e, 4, 200)) == (128, 476)
+    # and from the first batch past the constant's margin on
+    assert _scatter_windows(_mxu_epoch_text(v5e, 4, 115)) == (128, 276)
