@@ -116,10 +116,11 @@ class SyncTrainer:
         bound_test = self.engine.bind(test)
         placed = bound_train.placement()
         stored = placed[0][2]  # one layout for every device's rows
-        log.info("train split: %d rows kernel=%s margins=%s scatter_shards=%d stored "
-                 "major_to_minor=%s, per device %s", len(train), bound_train.kernel,
+        log.info("train split: %d rows kernel=%s margins=%s scatter_shards=%d update=%s "
+                 "stored major_to_minor=%s, per device %s", len(train), bound_train.kernel,
                  "merged" if bound_train.margins_merged else "per_worker",
-                 bound_train.scatter_shards, stored, " ".join(
+                 bound_train.scatter_shards,
+                 "sparse" if bound_train.update_sparse else "dense", stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
         w = (

@@ -147,7 +147,14 @@ class LinearModel:
         the scatter too, which sums the same terms in another order: one
         scatter of all K batches into ONE accumulator, plus K times the
         regulariser's term — no [K, R, 128] of replies to zero, fill and
-        reduce (16 MB a step at D = 1e6; PERF.md section 6, PR 26)."""
+        reduce (16 MB a step at D = 1e6; PERF.md section 6, PR 26).
+
+        This is the family's step under `kernels.SPARSE_UPDATE_MIN_FEATURES`
+        and wherever a whole gradient is read ('dim_sparsity', an optax
+        optimizer).  From that many features on, with the reference update,
+        `BoundSync` builds NO accumulator: it takes the same entries from
+        `reply_entries` and scatters them into the carried weights
+        (`kernels.sparse_update`; PERF.md section 6, PR 30)."""
         k, b = y.shape
         if kernels.merges_margins(kernel, indices.shape[-1]):
             merged = SparseBatch(indices.reshape(k * b, -1), values.reshape(k * b, -1))
@@ -166,6 +173,27 @@ class LinearModel:
             )(indices, values, y)
         with jax.named_scope("dsgd.allreduce"):
             return jnp.sum(gk, axis=0)  # summed here, mean-normalized by the caller
+
+    def reply_entries(self, v2: jax.Array, batch: SparseBatch, y: jax.Array,
+                      scale: Optional[jax.Array] = None, factor=1.0):
+        """The sync replies of the workers whose batches `batch` merges,
+        without their regulariser term, as ENTRIES: (flat feature ids [T],
+        `factor` x coefficient x value [T]).  Scattered into a zeroed
+        accumulator they are `grad_blocked`'s sum; a binding that
+        `kernels.sparse_update` names never builds that gradient and
+        scatters them into the carried weights (`BoundSync._sparse_step`),
+        after exchanging them as entries where it has more than one device.
+        The blocked weights are `scale * v2` (None: `v2` itself): a gathered
+        margin is linear in them, so the scalar goes on the margins."""
+        margins = gather.matvec(batch, v2)
+        with jax.named_scope("dsgd.update"):
+            if scale is not None:
+                margins = scale * margins
+        with jax.named_scope("dsgd.coeff"):
+            coeff = self.grad_coeff(margins, y) * factor
+        with jax.named_scope("dsgd.scatter"):
+            cv = batch.values.astype(jnp.float32) * coeff.astype(jnp.float32)[:, None]
+        return batch.indices.reshape(-1), cv.reshape(-1)
 
     def sample_losses(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """Per-sample losses (no regularization term), vectorized."""

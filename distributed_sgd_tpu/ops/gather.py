@@ -17,6 +17,14 @@ work on the lane-blocked view `w2 [R, 128]` of ops/mxu.py:
                                              flat view, in entry order:
                                              duplicates of an id accumulate
 
+The scatter comes in two forms.  `scatter_add` fills a fresh [R, 128]
+accumulator (a gradient: what 'dim_sparsity', an optimizer and the async
+engines read).  `scatter_into` adds the entries to the weights it is
+handed, in place where they are a loop's carry: the sync step of a binding
+that `kernels.sparse_update` names (`BoundSync._sparse_step`) has no
+accumulator at all, so no zero-fill, no pass over `w` for the regulariser
+or the update, and its bytes have no term in D (PERF.md section 6, PR 30).
+
 Measured on a v5e at D = 1,000,000, 15,600 entries a step, inside the
 compiled epoch (my chip runs, PR 26): margins 2.7 ns an entry (2.5 ns over
 the evaluation's 4,096-row chunks), scatter 6.9 ns with the four virtual
@@ -67,3 +75,12 @@ def scatter_add(batch: SparseBatch, coeff: jax.Array, n_rows: int) -> jax.Array:
         flat = jnp.zeros((n_rows * LANES,), jnp.float32).at[
             batch.indices.reshape(-1)].add(cv.reshape(-1))
         return flat.reshape(n_rows, LANES)
+
+
+def scatter_into(w2: jax.Array, ids: jax.Array, updates: jax.Array) -> jax.Array:
+    """`w2` with `updates[t]` added at flat feature `ids[t]`: the same
+    scatter-add, into the weights themselves (duplicates of an id
+    accumulate, a pad adds 0.0 to feature 0).  Inside a scan whose carry
+    `w2` is, the compiler updates the carry in place."""
+    with jax.named_scope("dsgd.scatter"):
+        return w2.reshape(-1).at[ids].add(updates).reshape(w2.shape)

@@ -16,6 +16,13 @@ Four families compute the same margins and the same worker reply
 and `core/worker.py` all ask it through `resolve`, which reads the
 platform off the device and counts the answer.  An explicit `kernel=`
 still overrides: the rule answers only for `AUTO`.
+
+Three more rules on a binding's shape live here, each asked once a
+binding by `BoundSync`: `merges_margins` (the K virtual workers' margins
+in one call), `ONE_ACCUMULATOR` (their entries scattered into one
+gradient) and `sparse_update` (no gradient at all: the entries scattered
+into the carried weights, the regulariser a scalar on them, so that a
+step's bytes have no term in the feature count).
 """
 
 from __future__ import annotations
@@ -84,6 +91,54 @@ def merges_margins(kernel: str, row_width: int) -> bool:
     'dense' keep XLA's own batching.  Static per binding: `BoundSync` counts
     it under `bind.margins.merged`."""
     return row_width != 0 and kernel in ("mxu", "gather")
+
+
+# From this many features on, a sync binding of a walking family takes the
+# sparse step (`sparse_update`).  Measured on a v5e, `BoundSync.epoch` at 4
+# workers x batch 100, rows of 11 one-hot entries, each form forced
+# (`benches/sparse_update_sweep.py`; PERF.md section 6, PR 30; us a step):
+#
+#     D = 1,000,000    sparse  64.7   dense    65.1
+#     D = 2,000,000            66.2            68.0
+#     D = 4,000,000            69.0            74.2
+#     D = 8,000,000           384.4           402.6
+#     D = 16,000,000          384.7           613.6
+#     D = 54,686,452          430.8         1,722.3
+#
+# The sparse step is never behind.  Up to 4e6 features `w` is served from
+# on-chip memory and the dense passes (zero-fill, regulariser, update: 16 B
+# a feature) cost 0.4 to 5 us of a 65-74 us step; from 8e6 on every word
+# access of either form goes to HBM (+320 us a step) and the dense passes
+# add 27.5 us a million features.  Under the constant the family keeps the
+# dense step for what it buys in float32: it sums a hot id's increments
+# (one id in ~200 of a step's 400 rows) in a zeroed accumulator before they
+# meet the id's weight, where the sparse step adds them to the weight one by
+# one, each rounded at the weight's ulp.  `criteo-logistic`'s step check
+# (D = 1e6, limit 2e-5 on the update's relative error) reads 4.4e-7..2.3e-6
+# dense and 2.4e-5 sparse.  So the constant sits where the dense passes
+# start to cost more than a twentieth of the step.
+SPARSE_UPDATE_MIN_FEATURES = 4_000_000
+
+
+def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
+                  decay: float, n_features: int) -> bool:
+    """Whether a sync binding's step never materialises a gradient: the
+    workers' replies stay (id, coefficient x value) entries up to the
+    reduction and are scattered straight into the carried weights, and the
+    regulariser's term is a scalar on them (`BoundSync._sparse_step`), so
+    the step's device bytes have no term in the feature count.  That is the
+    update `w' = (1 - decay) w - (lr / n) sum of the entries` and nothing
+    else, so it holds for a family whose scatter walks entries
+    (`ONE_ACCUMULATOR`), under a regulariser that is linear in `w` ('l2':
+    `decay` = 2 lr lam a step; 'none': 0), with the reference update
+    (`plain_sgd`: an optax optimizer reads a whole gradient) and a decay
+    that leaves the sign of `w` (under 1).  'dim_sparsity' masks each reply
+    by its own support and keeps the dense step, as the one-hot, dense and
+    scalar families do.  Static per binding: `BoundSync` counts it under
+    `bind.update.sparse`."""
+    return (kernel in ONE_ACCUMULATOR and regularizer in ("l2", "none")
+            and plain_sgd and 0.0 <= decay < 1.0
+            and n_features >= SPARSE_UPDATE_MIN_FEATURES)
 
 
 def resolve(kernel: Optional[str], n_features: int, row_width: int,

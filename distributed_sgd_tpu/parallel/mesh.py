@@ -36,6 +36,16 @@ def pcast_varying(x, axes: Tuple[str, ...]):
     return jax.lax.pcast(x, axes, to="varying")
 
 
+def gather_replicated(x, axis: str):
+    """Every device's `x` stacked along a new leading axis, typed as the
+    same on every device (an all-gather; `jax.lax.all_gather` types its
+    result as varying, and what is scattered into replicated weights has
+    to be known replicated).  jax 0.9 keeps this form under `_src`."""
+    from jax._src.lax.parallel import all_gather_invariant
+
+    return all_gather_invariant(x, axis)
+
+
 def make_mesh(n_workers: Optional[int] = None, devices=None) -> Mesh:
     """A 1-D mesh of `n_workers` devices along the `workers` axis."""
     devices = list(devices if devices is not None else jax.devices())
@@ -182,9 +192,16 @@ def put_rows(arr, sharding: NamedSharding) -> jax.Array:
 # words, bind() stores them side by side in one such row (lanes [0, P) the
 # indices, [P, 2P) the values' bits), which the backend stores row-major:
 # one gather a step instead of two, out of a layout that needs no copy.
-# It costs HBM (512 B a row where 39-wide rows take 320), so it is done
-# only while that is at most this factor: from 25 entries a row up to 64.
-PACKED_MAX_PADDING = 2.0
+# It costs HBM (512 B a row where 39-wide rows take 320, 11-wide rows 128),
+# so it is done only while that is at most this factor: from 9 entries a
+# row (16 sublanes) up to 64.  The narrowest rows measured are 11 wide
+# (kdd2012-logistic's 6,488,064 train rows, the whole step of
+# `BoundSync.epoch` over 2,000 steps, benches/sparse_update_sweep.py,
+# PERF.md section 6, PR 30): one packed row a row 428.6 us a step, two
+# rows-minor arrays 444.0.  The packed draw wins by 15.4 us at 4 x the
+# bytes; rows of 8 entries and fewer (8 x) were not measured and stay two
+# arrays.
+PACKED_MAX_PADDING = 4.0
 
 
 def packed_width(width: int, platform: str) -> Optional[int]:

@@ -24,7 +24,9 @@ the sparse gather/scatter as one-hot MXU matmuls (ops/mxu.py — ~32 us vs
 ~310 us per 3-worker step at RCV1 shapes on v5e, benches/step_bench.py);
 'gather' keeps the same view and runs them as a row gather and a
 scatter-add whose cost does not grow with D (ops/gather.py: the rule's
-choice from 200,000 features on); 'scalar' is the reference-shaped
+choice from 200,000 features on; where ops/kernels.sparse_update says so
+its step builds no gradient at all and scatters the workers' entries into
+the carried weights, `_sparse_step` below); 'scalar' is the reference-shaped
 take/scatter path (ops/sparse.py); 'dense' runs dense-layout datasets
 (Dataset.dense — no index array) as plain [B, D] matmuls, auto-selected
 at bind().  All backends produce identical updates up to float summation
@@ -59,10 +61,11 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
-from distributed_sgd_tpu.ops import kernels, mxu
+from distributed_sgd_tpu.ops import gather, kernels, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
     WORKER_AXIS,
+    gather_replicated,
     packed_width,
     pcast_varying,
     put_packed,
@@ -194,6 +197,18 @@ class BoundSync:
         # every compiled loop, replicated over the mesh like the weights.
         self.opt = resolve_optimizer(optimizer, self.learning_rate, momentum)
         self._opt_state = self._init_opt_state()
+        # whether the step scatters the replies' entries straight into the
+        # carried weights and never builds a gradient (_sparse_step): the
+        # one rule of ops/kernels.py, static per binding.  `_decay` is what
+        # a step's regulariser takes off every coordinate: each of the n
+        # workers adds 2 lam w to its reply and the update is lr x their mean
+        self._decay = (2.0 * self.learning_rate * model.lam
+                       if model.regularizer == "l2" else 0.0)
+        self.update_sparse = kernels.sparse_update(
+            kernel, model.regularizer, self.opt is None, self._decay,
+            model.n_features)
+        if self.update_sparse:
+            metrics.counter("bind.update.sparse").increment()
         sspec = jax.tree.map(lambda _: P(), self._opt_state)
 
         dspec = (P(AXIS), P(AXIS), P(AXIS))
@@ -307,6 +322,103 @@ class BoundSync:
             updates, opt_state = self.opt.update(g, opt_state, w)
             return optax.apply_updates(w, updates), opt_state
 
+    # -- the sparse step (kernels.sparse_update) ------------------------------
+    #
+    # The update w' = w - lr (sum_k g_k + 2 lam K w) / n over all n workers is
+    #
+    #     w' = (1 - c) w - (lr / n) sum over the step's entries of
+    #                                 coeff_b v_bp e[i_bp],      c = 2 lr lam
+    #
+    # and a step stores only ~K B P words of it: the loop carries v2 with
+    # w2 = s v2, a step's margins are s x the gathered v2, its entries are
+    # scattered INTO v2 with the factor -(lr / n) / s', s' = (1 - c) s, and
+    # s is folded into v2 where the weights leave the loop.  No [R, 128]
+    # array is produced inside the loop: the step's bytes have no term in D.
+    #
+    # s is never a float32 product: c is ~3e-8 at lambda = 1/n, under half of
+    # float32's epsilon, so s (1 - c) would round to s (as the dense float32
+    # step's w - lr 2 lam w rounds to w on every coordinate a step does not
+    # touch).  The rate is constant, so after t steps s = exp(t log1p(-c)),
+    # from the scan's own step counter: exact to float32, nothing carried.
+
+    # The loop folds s into v2 before t |log1p(-c)| passes this: s stays in
+    # (1/e, 1], so 1 / s never nears float32's range, and the exponent's
+    # float32 rounding (half an ulp of 1 at most) keeps every entry's weight
+    # s_T / s_t exact to ~1e-7.  At lambda = 1/n that is tens of millions of
+    # steps: one fold a program, where the weights are handed out.
+    _FOLD_LOG = 1.0
+
+    def _fold_span(self) -> int:
+        """Steps the sparse loop runs between two folds of s into v2."""
+        if self._decay == 0.0:
+            return self.steps_per_epoch
+        return max(1, int(self._FOLD_LOG / -math.log1p(-self._decay)))
+
+    def _scale(self, since):
+        """s after `since` steps since the last fold (None: no decay)."""
+        if self._decay == 0.0:
+            return None
+        return jnp.exp(since.astype(jnp.float32) * jnp.float32(math.log1p(-self._decay)))
+
+    def _rescale(self, v2, since: int):
+        """The fold: w2 = s v2 after `since` steps, one pass over the
+        weights, as v2 + (s - 1) v2 with s - 1 from expm1 in float64 (a
+        float32 s would be 1.0 and lose the term)."""
+        if self._decay == 0.0:
+            return v2
+        with jax.named_scope("dsgd.rescale"):
+            return v2 + jnp.float32(math.expm1(since * math.log1p(-self._decay))) * v2
+
+    def _sparse_step(self, v2, idx, val, y, key, step, since):
+        """One sync DP step on the carried `v2` (blocked weights = s v2,
+        `since` steps after the last fold): draw, margins and coefficients
+        as `_one_step` has them, the replies kept as entries, exchanged as
+        entries over the mesh, scattered into `v2`."""
+        n = self.n_workers * self.virtual_workers
+        with jax.named_scope("dsgd.draw"):
+            ids = self._sample_ids(key, step)  # [K, B]
+            (bi, bv), by = self.batch_rows(idx, val, ids), y[ids]
+        width = bi.shape[-1]
+        # the K virtual workers share the weights: one call on their merged
+        # batches (kernels.merges_margins), one scatter of all their entries
+        merged = SparseBatch(bi.reshape(-1, width), bv.reshape(-1, width))
+        with jax.named_scope("dsgd.update"):
+            s, s_next = self._scale(since), self._scale(since + 1)
+            # master mean over ALL workers (Master.scala:194) and the
+            # reference update (Master.scala:197), on the entries
+            factor = -self.learning_rate / n
+            if s_next is not None:
+                factor = factor / s_next
+        at, add = self.model.reply_entries(v2, merged, by.reshape(-1), s, factor)
+        # every device scatters every device's entries: the sum a psum of
+        # the dense replies would give, in another order (one device: the
+        # all-gather is the identity, and what types the entries replicated)
+        with jax.named_scope("dsgd.allreduce"):
+            # ids and the updates' bits side by side: one collective a step
+            bits = jax.lax.bitcast_convert_type(add, jnp.int32)
+            both = gather_replicated(jnp.stack([at, bits]), AXIS)  # [devices, 2, T]
+            at = both[:, 0].reshape(-1)
+            add = jax.lax.bitcast_convert_type(both[:, 1], jnp.float32).reshape(-1)
+        return gather.scatter_into(v2, at, add)
+
+    def _sparse_steps(self, v2, idx, val, y, key):
+        """`steps_per_epoch` sparse steps on blocked weights, folded."""
+        steps, span = self.steps_per_epoch, self._fold_span()
+
+        def run(v2, first, count):
+            def body(v2, j):
+                return self._sparse_step(v2, idx, val, y, key, first + j, j), ()
+
+            v2, _ = jax.lax.scan(body, v2, jnp.arange(count))
+            return self._rescale(v2, count)
+
+        if steps <= span:
+            return run(v2, 0, steps)
+        whole, rest = divmod(steps, span)
+        v2, _ = jax.lax.scan(lambda v2, i: (run(v2, i * span, span), ()),
+                             v2, jnp.arange(whole))
+        return run(v2, whole * span, rest) if rest else v2
+
     def rows(self, resident, ids):
         """Rows `ids` of a resident [rows, width] array as the dataset holds
         them: without the lane padding bind() may have stored them with."""
@@ -351,6 +463,8 @@ class BoundSync:
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
         w = self._to_kernel_layout(w)
         y = self._loop_labels(y)
+        if self.update_sparse:
+            return self._from_kernel_layout(self._sparse_steps(w, idx, val, y, key)), opt_state
 
         def body(carry, step):
             return self._one_step(*carry, idx, val, y, key, step), ()
@@ -363,6 +477,10 @@ class BoundSync:
     def _step_shard(self, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
         w = self._to_kernel_layout(w)
+        if self.update_sparse:
+            zero = jnp.int32(0)
+            w = self._rescale(self._sparse_step(w, idx, val, y, key, zero, zero), 1)
+            return self._from_kernel_layout(w), opt_state
         w, opt_state = self._one_step(w, opt_state, idx, val, y, key, jnp.int32(0))
         return self._from_kernel_layout(w), opt_state
 
@@ -415,6 +533,8 @@ class BoundSync:
 
         def epoch_body(c, e):
             ke = jax.random.fold_in(key, e)
+            if self.update_sparse:
+                return (self._sparse_steps(c[0], idx, val, y, ke), c[1]), ()
 
             def body(c2, step):
                 return self._one_step(*c2, idx, val, y, ke, step), ()

@@ -237,8 +237,10 @@ def test_on_a_v5e_padded_rows_are_row_major_and_never_copied(v5e):
 @pytest.mark.parametrize("width,on_tpu", [
     (39, 128),    # criteo-logistic: 512 B a row where two 40-sublane arrays take 320
     (64, 128),    # the widest pair that fits 128 lanes
-    (25, 128),    # 128 <= 2.0 x 2 x 32
-    (24, None),   # 128 > 2.0 x 2 x 24: the padding would cost more than the rows
+    (25, 128),    # 2 x the bytes of two 32-sublane arrays
+    (11, 128),    # kdd2012-logistic: 4 x the bytes, measured (PR 30): 428.6 us a step against 444.0
+    (9, 128),     # 128 <= 4.0 x 2 x 16
+    (8, None),    # 128 > 4.0 x 2 x 8: eight times the bytes, not measured
     (65, None),   # two arrays of 65 do not fit one row
     (76, None),   # rcv1-hinge keeps its two arrays
     (0, None),    # dense rows have no indices to pack
@@ -246,7 +248,7 @@ def test_on_a_v5e_padded_rows_are_row_major_and_never_copied(v5e):
 def test_packing_rule_reads_only_width_and_platform(width, on_tpu):
     assert mesh_mod.packed_width(width, "tpu") == on_tpu
     assert mesh_mod.packed_width(width, "cpu") is None
-    assert mesh_mod.PACKED_MAX_PADDING == 2.0
+    assert mesh_mod.PACKED_MAX_PADDING == 4.0
 
 
 def _narrow(n=512, d=3000, p=39, seed=5):
@@ -468,3 +470,83 @@ def test_on_a_v5e_one_deeper_dot_is_tiled_by_128_entries(v5e, monkeypatch):
     assert _scatter_windows(_mxu_epoch_text(v5e, 4, 200)) == (128, 476)
     # and from the first batch past the constant's margin on
     assert _scatter_windows(_mxu_epoch_text(v5e, 4, 115)) == (128, 276)
+
+
+# -- (h) a step whose device bytes have no term in the feature count --------------
+# (parallel/sync.py `_sparse_step`, ops/kernels.py `sparse_update`; PERF.md
+# section 6, PR 30)
+
+def _kdd2012_epoch(v5e, devices, packed):
+    """The epoch program of `kdd2012-sync-1chip`'s shape (6,488,064 rows a
+    device of 11 entries, D = 54,686,452) compiled for `devices` v5e chips."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    rows, width, d = 6_488_064 * devices, 11, 54_686_452
+    mesh = Mesh(np.array(v5e.devices[:devices]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+    data = ShardedData(
+        shape((rows, 128 if packed else width), jnp.int32, sharding=over_rows),
+        shape((rows, 0 if packed else width), jnp.float32, sharding=over_rows),
+        shape((rows,), jnp.int32, sharding=over_rows), rows, width, packed)
+    bound = BoundSync(make_model("logistic", 1.0 / 6_488_064, d, regularizer="l2"), mesh,
+                      data, 100, 0.1, kernel="gather", virtual_workers=4 // devices)
+    assert bound.update_sparse and bound.steps_per_epoch == {1: 16_221, 4: 64_881}[devices]
+    return bound._epoch.lower(
+        shape((d,), jnp.float32, sharding=everywhere), (), data.indices, data.values,
+        data.labels, shape((2,), jnp.uint32, sharding=everywhere)).compile()
+
+
+def _loop_bodies(text):
+    """{computation name: its lines} of every while loop's body."""
+    import re
+
+    bodies = set(re.findall(r" while\(.*?body=(%[\w.\-]+)", text))
+    out, name = {}, None
+    for line in text.split("\n"):
+        head = re.match(r"(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1) if head.group(1) in bodies else None
+        elif name is not None and " = " in line:
+            out.setdefault(name, []).append(line)
+    return out
+
+
+@pytest.mark.parametrize("devices,packed", [(1, True), (1, False), (4, True)])
+def test_on_a_v5e_no_step_of_the_sparse_epoch_passes_over_the_weights(v5e, devices, packed):
+    import re
+
+    program = _kdd2012_epoch(v5e, devices, packed)
+    r = mxu_mod.n_blocks(54_686_452)
+    w_bytes = r * 128 * 4
+    assert (r, w_bytes) == (427_240, 218_746_880)
+    whole = re.compile(rf"= f32\[(?:{r},128|{r * 128})\]")
+    bodies = _loop_bodies(program.as_text())
+    assert bodies
+    made = [line for lines in bodies.values() for line in lines
+            if whole.search(line) and not re.search(
+                r"\]\S* (?:bitcast|get-tuple-element|parameter)\(", line)]
+    # inside the scan's body ONE operation's result is as large as w: the
+    # scatter into the carry, a custom fusion that updates its operand in
+    # place.  No zero-fill, no regulariser pass, no `w - lr * g`, no copy:
+    # the row gather reads the carry where it lies
+    assert len(made) == 1, made
+    assert "kind=kCustom" in made[0] and 'dsgd.scatter/scatter-add"' in made[0]
+    assert not any(re.search(r"\]\S* copy\(", line) and whole.search(line)
+                   for lines in bodies.values() for line in lines)
+    # and the program holds ONE w-sized temporary (the carry), not three
+    # (a zeroed accumulator, the regularised gradient, the updated weights)
+    memory = program.memory_analysis()
+    assert w_bytes <= memory.temp_size_in_bytes < 1.01 * w_bytes
+    text = program.as_text()
+    # the entries cross the mesh as entries: ONE all-gather a step of ids and
+    # updates side by side, never an all-reduce of a gradient
+    assert text.count(" all-gather(") + text.count(" all-gather-start(") == int(devices > 1)
+    assert " all-reduce(" not in text and " all-reduce-start(" not in text
+    if devices > 1:
+        assert re.search(r"s32\[4,2,1100\]\S* all-gather", text)
+        assert 'dsgd.allreduce' in re.search(r"all-gather\(.*", text).group(0)
+    # the fold, once a program, under its own scope
+    assert "dsgd.rescale" in text
