@@ -14,9 +14,17 @@ rows of 11 one-hot entries: `kdd2012-logistic`'s step) on the chip:
   the step as the rule names it and the other form forced: where a step
   that passes over all of `w` (zero-fill, regulariser, update: 16 B a
   feature) starts to cost more than one that scatters into the carried
-  weights, i.e. what `kernels.SPARSE_UPDATE_MIN_FEATURES` is set from.
+  weights, i.e. what `kernels.SPARSE_UPDATE_MIN_FEATURES` is set from;
+- `variants` (PR 31; not in the default run): ONE call of the scatter of a
+  step's 4,400 entries (the configuration's own draw of 400 rows: nine hot
+  ids in one weight row, 1/r inside the large fields) into carried weights
+  of D = 1e6 and of the cell's D, us a call, by form: XLA's word
+  scatter-add (PR 30's step), XLA's row scatter-add, `gather.scatter_into`
+  with XLA's row write and with the DMA kernel's, and the latter's pieces
+  alone (the sort, the sum by row, the row fetch, the kernel's write-back
+  by the depth of its semaphore ring).
 
-    python benches/sparse_update_sweep.py [--rehearse] [--only layout,crossing]
+    python benches/sparse_update_sweep.py [--rehearse] [--only layout,crossing,variants]
 
 Prints one JSON document (a line a row on stderr as it goes).  Refuses a
 CPU unless `--rehearse` (tiny shapes, no timing worth reading).
@@ -114,8 +122,92 @@ def main(argv) -> int:
                    "dense": step_us(data, 1.5e-7, sparse=False)["us"]}
             out["crossing"].append(row)
             print(json.dumps(row), file=sys.stderr, flush=True)
+    if "variants" in only:
+        out["variants"] = variants(rehearse)
     print(json.dumps(out))
     return 0
+
+
+def variants(rehearse: bool) -> list:
+    """us a call of every form of the scatter, a row a feature count."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import gather, mxu
+
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs",
+                           "kdd2012-logistic.json")) as f:
+        card = np.asarray(list(json.load(f)["data"]["field_cardinalities"].values()))
+    calls, reps = (2, 1) if rehearse else (200, 3)
+    lanes = gather.LANES
+
+    def timed(form, w2):
+        """`form(w2, acc, i) -> (w2, acc)`, `calls` times in one program."""
+        def body(i, carry):
+            return form(*carry, i)
+
+        run = jax.jit(lambda w2: jax.lax.fori_loop(0, calls, body, (w2, jnp.float32(0))),
+                      donate_argnums=0)
+        w2, _ = jax.block_until_ready(run(w2))
+        best = float("inf")
+        for _ in range(reps):
+            t0 = time.perf_counter()
+            w2, _ = jax.block_until_ready(run(w2))
+            best = min(best, time.perf_counter() - t0)
+        return best / calls * 1e6, w2
+
+    rows_out = []
+    for features in (60_000,) if rehearse else (1_000_000, int(card.sum())):
+        # the fields scaled to `features`, the small ones as they are
+        scaled = np.maximum(3, card * features // card.sum())
+        scaled[0] += features - scaled.sum()
+        rng = np.random.default_rng(31)
+        rank = np.floor(np.exp(rng.random((WORKERS * BATCH, NNZ)) * np.log(scaled + 1.0)))
+        at = (np.cumsum(scaled) - scaled) + np.clip(rank, 1, scaled).astype(np.int64) - 1
+        ids0 = jnp.asarray(at.reshape(-1), jnp.int32)
+        upd = jnp.asarray(rng.normal(size=ids0.shape[0]) * 1e-3, jnp.float32)
+        n_rows = mxu.n_blocks(features)
+        w2 = jnp.zeros((n_rows, lanes), jnp.float32)
+
+        def ids_of(i):  # whole rows further every call: nothing hoisted, one structure
+            return (ids0 + lanes * (i % 8)) % (n_rows * lanes)
+
+        def whole(scatter):
+            return lambda w2, acc, i: (scatter(w2, ids_of(i), upd), acc)
+
+        def piece(read):
+            return lambda w2, acc, i: (w2, acc + read(w2, ids_of(i)))
+
+        def entry_rows(ids):
+            lane = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], lanes), 1)
+            return jnp.where(lane == (ids % lanes)[:, None], upd[:, None], 0.0)
+
+        forms = {
+            "words_add": whole(lambda w2, ids, upd: w2.reshape(-1).at[ids].add(upd).reshape(
+                w2.shape)),
+            "rows_add": whole(lambda w2, ids, upd: w2.at[ids // lanes].add(entry_rows(ids))),
+            "scatter_into_xla": whole(gather.scatter_into),
+            "scatter_into_dma": whole(lambda *a: gather.scatter_into(*a, dma=True)),
+            "sort": piece(lambda w2, ids: jnp.sum(jax.lax.sort(
+                (ids, upd), num_keys=1, is_stable=False)[1])),
+            "sum_by_row": piece(lambda w2, ids: jnp.sum(gather._sum_by_row(ids, upd)[2])),
+            "gather_rows": piece(lambda w2, ids: jnp.sum(w2[ids // lanes])),
+        }
+        rows, head, total = jax.jit(gather._sum_by_row)(ids0, upd)
+        for ring in (1, 4, 16, 32, 64, 256):
+            forms[f"write_rows_ring{ring}"] = lambda w2, acc, i, ring=ring: (
+                gather._write_rows(w2, (rows + i % 8) % n_rows, head, total, ring=ring), acc)
+        row = {"n_features": features, "entries": int(ids0.shape[0]),
+               "rows_written": int(jnp.sum(head))}
+        on_chip = jax.devices()[0].platform == "tpu"
+        with contextlib.nullcontext() if on_chip else pltpu.force_tpu_interpret_mode():
+            for name, form in forms.items():
+                row[name], w2 = timed(form, w2)
+                print(json.dumps({"n_features": features, name: row[name]}),
+                      file=sys.stderr, flush=True)
+        rows_out.append(row)
+    return rows_out
 
 
 if __name__ == "__main__":

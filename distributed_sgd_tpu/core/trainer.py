@@ -117,10 +117,12 @@ class SyncTrainer:
         placed = bound_train.placement()
         stored = placed[0][2]  # one layout for every device's rows
         log.info("train split: %d rows kernel=%s margins=%s scatter_shards=%d update=%s "
-                 "stored major_to_minor=%s, per device %s", len(train), bound_train.kernel,
+                 "scatter=%s stored major_to_minor=%s, per device %s", len(train),
+                 bound_train.kernel,
                  "merged" if bound_train.margins_merged else "per_worker",
                  bound_train.scatter_shards,
-                 "sparse" if bound_train.update_sparse else "dense", stored, " ".join(
+                 "sparse" if bound_train.update_sparse else "dense",
+                 "rows" if bound_train.scatter_rows else "words", stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
         w = (

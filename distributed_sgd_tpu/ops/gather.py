@@ -13,17 +13,35 @@ work on the lane-blocked view `w2 [R, 128]` of ops/mxu.py:
     margins  rows = w2[i // 128]             one 512-byte row an entry,
              m_b  = sum_p v_bp * rows[b, p, i_bp % 128]   the lane picked
                                              by a compare on the VPU
-    scatter  g[i] += c_b * v_bp              XLA's scatter-add over the
-                                             flat view, in entry order:
-                                             duplicates of an id accumulate
+    scatter  g[i] += c_b * v_bp              duplicates of an id accumulate
 
 The scatter comes in two forms.  `scatter_add` fills a fresh [R, 128]
 accumulator (a gradient: what 'dim_sparsity', an optimizer and the async
-engines read).  `scatter_into` adds the entries to the weights it is
-handed, in place where they are a loop's carry: the sync step of a binding
-that `kernels.sparse_update` names (`BoundSync._sparse_step`) has no
-accumulator at all, so no zero-fill, no pass over `w` for the regulariser
-or the update, and its bytes have no term in D (PERF.md section 6, PR 30).
+engines read): XLA's scatter-add over the flat view, in entry order.
+`scatter_into` adds the entries to the weights it is handed, in place where
+they are a loop's carry: the sync step of a binding that
+`kernels.sparse_update` names (`BoundSync._sparse_step`) has no accumulator
+at all, so no zero-fill, no pass over `w` for the regulariser or the
+update, and its bytes have no term in D (PERF.md section 6, PR 30).  Those
+weights live in HBM (219 MB at D = 54,686,452), where every form XLA has of
+a scatter walks its updates one after the other at 72-90 ns each, whatever
+it is told about them, while its gather of the same rows is pipelined
+(4 ns a row).  So `scatter_into` is built from what is fast (PR 31):
+
+    sort     the step's (id, update) pairs by id              7 us for 4,480
+    sum      by weight row, on the MXU: chunks of 128 sorted entries, a
+             0 / 1 "same row" matrix a chunk times the entries laid out
+             as [128 entries, 128 lanes], HIGHEST precision (exact on a
+             0 / 1 operand: float32 sums of float32 updates)    16 us
+    fetch    the touched rows: XLA's row gather                 18 us
+    write    row + sum back, each touched row ONCE: a kernel of ours that
+             issues one 512-byte DMA a row itself, 32 in flight
+             (`_write_rows`)      6 us (a sort) + 28 us for 1,327 rows
+
+(my chip runs, PR 31: `kdd2012-sync-1chip`'s step, 4,400 entries on 1,327
+rows, 76 us where XLA's word scatter took 384).  A hot id's increments are
+summed before they meet its weight, as the dense step's accumulator sums
+them.  Off the TPU the same rows are written by XLA's scatter.
 
 Measured on a v5e at D = 1,000,000, 15,600 entries a step, inside the
 compiled epoch (my chip runs, PR 26): margins 2.7 ns an entry (2.5 ns over
@@ -36,7 +54,10 @@ scatter 8.0 ns with the four workers' replies kept apart, as
 written and timed against these in isolation and lost (gather 10.6 ns an
 entry against 4.2-4.6, scatter 19 ns against 8-12): the walk is bound by
 the scalar core's address arithmetic and, in the scatter, by the load that
-has to wait for the previous entry's store.
+has to wait for the previous entry's store.  `_write_rows` walks nothing in
+VMEM: its scalar loop only starts DMAs (17-20 ns a row eight starts a turn,
+29 one; a turn that tests a flag first costs 31 ns whether or not it then
+writes) and waits for a row only when its ring of semaphores comes round.
 Everything is float32: a gather rounds nothing.
 """
 
@@ -77,10 +98,142 @@ def scatter_add(batch: SparseBatch, coeff: jax.Array, n_rows: int) -> jax.Array:
         return flat.reshape(n_rows, LANES)
 
 
-def scatter_into(w2: jax.Array, ids: jax.Array, updates: jax.Array) -> jax.Array:
-    """`w2` with `updates[t]` added at flat feature `ids[t]`: the same
-    scatter-add, into the weights themselves (duplicates of an id
-    accumulate, a pad adds 0.0 to feature 0).  Inside a scan whose carry
-    `w2` is, the compiler updates the carry in place."""
+# Entries a chunk of the grouping: the sorted entries are summed by row in
+# chunks of this many (one [CHUNK, CHUNK] equality matrix a chunk: the MXU's
+# own width), and what a row's run holds beyond its chunk comes in a second,
+# tiny product over the chunks.
+CHUNK = 128
+
+# Row writes the kernel keeps in flight (a ring of this many DMA semaphores)
+# and starts a turn of its loop (`_write_rows`).  Timed on a v5e
+# (benches/sparse_update_sweep.py `--only variants`; PERF.md section 6,
+# PR 31), 1,327 rows into 219 MB: one in flight 369 us, 4: 100, 16: 31,
+# 32: 23, 64: 23, 256: 25; eight starts a turn cost 20 ns a row, one 29.
+DMA_RING = 32
+DMA_UNROLL = 8
+
+# Entries a call of the kernel: their positions and rows lie in scalar
+# memory (1 MiB on a v5e: two int32 a entry), so a longer step is written
+# in several calls, one after the other.
+DMA_BLOCK = 32_768
+
+
+def _sum_by_row(ids: jax.Array, updates: jax.Array):
+    """A step's entries grouped by weight row: (rows int32[T'], head
+    bool[T'], total f32[T', 128]) with the entries sorted by id, T' = T
+    padded to whole chunks with the pad entry (0.0 at feature 0), `rows`
+    their weight rows in ascending order, `head` the first entry of every
+    row's run and `total[t]`, at a head, the dense sum of ALL the run's
+    entries (duplicates of an id and other lanes of the row alike; off the
+    heads it is not a run's whole sum).  Float32 throughout: the 0 / 1
+    equality operand is exact in every pass of a HIGHEST-precision product,
+    so the products only ever add float32 updates."""
+    pad = -ids.shape[0] % CHUNK
+    ids, updates = jnp.pad(ids, (0, pad)), jnp.pad(updates.astype(jnp.float32), (0, pad))
+    ids, updates = jax.lax.sort((ids, updates), num_keys=1, is_stable=False)
+    rows, lane = ids // LANES, ids % LANES
+    head = jnp.concatenate([jnp.ones((1,), bool), rows[1:] != rows[:-1]])
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], LANES), 1)
+    entry = jnp.where(lanes == lane[:, None], updates[:, None], 0.0)  # [T', 128]
+    exact = jax.lax.Precision.HIGHEST
+    by_chunk = rows.reshape(-1, CHUNK)  # [n, CHUNK]
+    same = (by_chunk[:, :, None] == by_chunk[:, None, :]).astype(jnp.float32)
+    # every entry's row summed over its own chunk
+    local = jnp.einsum("nts,nsl->ntl", same, entry.reshape(-1, CHUNK, LANES), precision=exact)
+    # a run that goes on past its chunk: the later chunks it opens (sorted,
+    # so they start inside it) hold the rest in their first entry's sum
+    first, last = by_chunk[:, 0], by_chunk[:, -1]
+    chunk = jnp.arange(by_chunk.shape[0])
+    goes_on = (chunk[None, :] > chunk[:, None]) & (first[None, :] == last[:, None])
+    rest = jnp.einsum("cd,dl->cl", goes_on.astype(jnp.float32), local[:, 0], precision=exact)
+    total = local + jnp.where((by_chunk == last[:, None])[:, :, None], rest[:, None, :], 0.0)
+    return rows, head, total.reshape(-1, LANES)
+
+
+def _write_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, new: jax.Array,
+                ring: int = DMA_RING) -> jax.Array:
+    """`w2` with `new[t]` written to row `rows[t]` wherever `head[t]`, in
+    place: a TPU kernel that leaves `w2` and `new` in HBM and issues one
+    512-byte DMA a head itself, `ring` of them in flight.  The heads' rows
+    differ, so no write waits for another: what XLA's scatter cannot
+    assume.  The kernel walks the heads alone (one more sort puts their
+    positions and rows first: 6 us for 4,480): a turn of a scalar loop that
+    tests a flag costs more than a row's DMA (31 ns against 17)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(at_ref, to_ref, heads_ref, new_ref, _, out_ref, sems):
+        def write(s, slot):
+            return pltpu.make_async_copy(
+                new_ref.at[pl.ds(at_ref[s], 1)], out_ref.at[pl.ds(to_ref[s], 1)], sems.at[slot])
+
+        def start(s, carry):
+            slot = s % ring
+
+            @pl.when(s >= ring)
+            def _():  # the write that took this semaphore a ring ago
+                write(0, slot).wait()
+
+            write(s, slot).start()
+            return carry
+
+        def turn(b, carry):
+            for k in range(DMA_UNROLL):
+                start(b * DMA_UNROLL + k, carry)
+            return carry
+
+        count = heads_ref[0]
+        whole = count // DMA_UNROLL
+        jax.lax.fori_loop(0, whole, turn, None)
+        jax.lax.fori_loop(whole * DMA_UNROLL, count, start, None)
+
+        def drain(slot, carry):
+            write(0, slot).wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(count, ring), drain, None)
+
+    write_block = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(w2.shape, w2.dtype, vma=jax.typeof(w2).vma),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # positions, rows, their count: scalar memory
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((ring,))],
+        ),
+        input_output_aliases={4: 0},
+        name="scatter_rows",
+    )
+    n = new.shape[0]
+    at, to = jax.lax.sort((jnp.where(head, jnp.arange(n, dtype=jnp.int32), n), rows),
+                          num_keys=1, is_stable=False)
+    heads = jnp.sum(head, dtype=jnp.int32)
+    for lo in range(0, n, DMA_BLOCK):
+        hi = min(lo + DMA_BLOCK, n)
+        count = jnp.clip(heads - lo, 0, hi - lo).reshape(1)
+        w2 = write_block(at[lo:hi], to[lo:hi], count, new, w2)
+    return w2
+
+
+def scatter_into(w2: jax.Array, ids: jax.Array, updates: jax.Array,
+                 dma: bool = False) -> jax.Array:
+    """`w2` with `updates[t]` added at flat feature `ids[t]` (duplicates of
+    an id accumulate, a pad adds 0.0 to feature 0): the entries summed by
+    weight row first, every touched row then fetched, added to and written
+    back ONCE.  `dma`: the write is the kernel's (`_write_rows`, a TPU's);
+    else XLA's scatter of whole rows, told they are unique.  Inside a scan
+    whose carry `w2` is, both update the carry in place."""
     with jax.named_scope("dsgd.scatter"):
-        return w2.reshape(-1).at[ids].add(updates).reshape(w2.shape)
+        rows, head, total = _sum_by_row(ids, updates)
+        # only the heads' rows are written: off them any row will do, and
+        # the gather runs faster over rows that differ than over a run's
+        # repeats (34 -> 18 us for 4,480 rows, 1,200 of them one row)
+        entry = jnp.arange(rows.shape[0])
+        new = w2[jnp.where(head, rows, entry % w2.shape[0])] + total
+        if dma:
+            return _write_rows(w2, rows, head, new)
+        # off the heads: past the last row, each its own index, dropped
+        return w2.at[jnp.where(head, rows, w2.shape[0] + entry)].set(
+            new, mode="drop", unique_indices=True)

@@ -109,14 +109,28 @@ def merges_margins(kernel: str, row_width: int) -> bool:
 # on-chip memory and the dense passes (zero-fill, regulariser, update: 16 B
 # a feature) cost 0.4 to 5 us of a 65-74 us step; from 8e6 on every word
 # access of either form goes to HBM (+320 us a step) and the dense passes
-# add 27.5 us a million features.  Under the constant the family keeps the
-# dense step for what it buys in float32: it sums a hot id's increments
-# (one id in ~200 of a step's 400 rows) in a zeroed accumulator before they
-# meet the id's weight, where the sparse step adds them to the weight one by
-# one, each rounded at the weight's ulp.  `criteo-logistic`'s step check
-# (D = 1e6, limit 2e-5 on the update's relative error) reads 4.4e-7..2.3e-6
-# dense and 2.4e-5 sparse.  So the constant sits where the dense passes
-# start to cost more than a twentieth of the step.
+# add 27.5 us a million features.  The table is PR 30's, when the sparse
+# step scattered single words with XLA's scatter-add; since PR 31 it sums a
+# step's entries by weight row and writes each touched row once
+# (ops/gather.py `scatter_into`: 76 us of `kdd2012-sync-1chip`'s step where
+# the word scatter took 384).  PR 30 set the constant for rounding: the
+# word scatter added a hot id's ~200 increments to its weight one by one,
+# each rounded at the weight's ulp, where the dense step sums them in a
+# zeroed accumulator first (`criteo-logistic`'s step check, D = 1e6, limit
+# 2e-5 on the update's relative error, read 4.4e-7..2.3e-6 dense and 2.4e-5
+# sparse).  The row sums are the accumulator's order of operations, so a
+# hot id's increments no longer separate the forms (what is left of the
+# sparse step's error on `kdd2012-logistic`, 1.9e-5..2.8e-5 of the update,
+# is the scale it carries beside the weights: 0 or 1 ulp on a hot weight);
+# what does, under the constant, is speed: where `w` is on-chip the word
+# scatter-add is the cheaper one (4,400 entries at D = 1e6: 48.3 us a call
+# against the row scatter's 63.5), and `criteo-sync-1chip` with the sparse
+# step forced trains 1,512,447 samples/s against 1,842,109 (its scatter
+# 153.4 us a step against 107.5 + 1.8 of update; `update_rel_err` 1.7e-5,
+# inside its limit of 2e-5 with little room; my chip runs, PR 31).  The
+# constant stays where the dense passes start to cost more than a
+# twentieth of the step; where the forms cross between 4e6 and 8e6
+# features is ROADMAP D4's to measure.
 SPARSE_UPDATE_MIN_FEATURES = 4_000_000
 
 

@@ -209,6 +209,13 @@ class BoundSync:
             model.n_features)
         if self.update_sparse:
             metrics.counter("bind.update.sparse").increment()
+        # whether that scatter writes each touched row back by the DMA
+        # kernel (gather.scatter_into): on a TPU, by the kernel rule's own
+        # platform probe; elsewhere XLA writes the same rows
+        self.scatter_rows = self.update_sparse and mxu.blocked_pays_off(
+            mesh.devices.flat[0])
+        if self.scatter_rows:
+            metrics.counter("bind.scatter.rows").increment()
         sspec = jax.tree.map(lambda _: P(), self._opt_state)
 
         dspec = (P(AXIS), P(AXIS), P(AXIS))
@@ -399,7 +406,7 @@ class BoundSync:
             both = gather_replicated(jnp.stack([at, bits]), AXIS)  # [devices, 2, T]
             at = both[:, 0].reshape(-1)
             add = jax.lax.bitcast_convert_type(both[:, 1], jnp.float32).reshape(-1)
-        return gather.scatter_into(v2, at, add)
+        return gather.scatter_into(v2, at, add, dma=self.scatter_rows)
 
     def _sparse_steps(self, v2, idx, val, y, key):
         """`steps_per_epoch` sparse steps on blocked weights, folded."""

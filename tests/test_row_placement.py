@@ -529,11 +529,23 @@ def test_on_a_v5e_no_step_of_the_sparse_epoch_passes_over_the_weights(v5e, devic
             if whole.search(line) and not re.search(
                 r"\]\S* (?:bitcast|get-tuple-element|parameter)\(", line)]
     # inside the scan's body ONE operation's result is as large as w: the
-    # scatter into the carry, a custom fusion that updates its operand in
-    # place.  No zero-fill, no regulariser pass, no `w - lr * g`, no copy:
-    # the row gather reads the carry where it lies
+    # kernel that writes the step's touched rows into the carry by its own
+    # DMAs (ops/gather.py `_write_rows`, PR 31), its output aliased to the
+    # carry.  No zero-fill, no regulariser pass, no `w - lr * g`, no copy:
+    # the row gathers read the carry where it lies
     assert len(made) == 1, made
-    assert "kind=kCustom" in made[0] and 'dsgd.scatter/scatter-add"' in made[0]
+    assert 'custom_call_target="tpu_custom_call"' in made[0]
+    assert 'dsgd.scatter/scatter_rows' in made[0]
+    assert re.search(r'output_to_operand_aliasing=\{\{\}: \(\d, \{\}\)\}', made[0])
+    # everything the scatter is made of is filed under its scope: the sort
+    # by id, the two products of the sum by row, the sort that puts the
+    # written rows' positions first, the kernel
+    body = [line for lines in bodies.values() for line in lines]
+    mine = [line for line in body if re.search(
+        r" (?:sort|custom-call)\(|dot_general", line)
+        and "AssumeGatherIndicesInBound" not in line]
+    assert len(mine) >= 5 and all("dsgd.scatter/" in line for line in mine), mine
+    assert sum(" sort(" in line for line in mine) == 2
     assert not any(re.search(r"\]\S* copy\(", line) and whole.search(line)
                    for lines in bodies.values() for line in lines)
     # and the program holds ONE w-sized temporary (the carry), not three

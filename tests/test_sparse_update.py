@@ -133,7 +133,21 @@ def test_under_the_floor_the_family_keeps_the_dense_step():
     assert not _bind(_rows(), 1e-4).update_sparse
 
 
-@pytest.mark.parametrize("floor,said", [(0, "update=sparse"), (None, "update=dense")])
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """The platform probe answers "a TPU" and Pallas runs the kernel in its
+    TPU interpret mode: the program a chip runs, on the CPU."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import mxu
+
+    monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.mark.parametrize("floor,said", [
+    (0, "update=sparse scatter=words"), (None, "update=dense scatter=words")])
 def test_the_train_split_record_says_the_update(floor, said, caplog, monkeypatch):
     from distributed_sgd_tpu.core.trainer import SyncTrainer
 
@@ -147,6 +161,187 @@ def test_the_train_split_record_says_the_update(floor, said, caplog, monkeypatch
     record = next(r.getMessage() for r in caplog.records
                   if r.getMessage().startswith("train split:"))
     assert said in record and "kernel=gather" in record and "margins=merged" in record
+
+
+@pytest.mark.parametrize("kw,rows", [({}, True), ({"optimizer": "momentum"}, False)])
+def test_on_a_tpu_a_sparse_binding_writes_rows_and_counts_it(everywhere, as_on_a_tpu, kw, rows):
+    counter = metrics_mod.counter("bind.scatter.rows")
+    before = counter.value
+    bound = _bind(_rows(n=256), 1e-4, **kw)
+    assert bound.scatter_rows is rows and bound.update_sparse is rows
+    assert counter.value - before == int(rows)
+    bound.step(jnp.zeros((D,), jnp.float32), jax.random.PRNGKey(0))
+    assert counter.value - before == int(rows)  # a binding, not a trace or a run
+
+
+def test_off_the_tpu_no_binding_counts_the_kernel(everywhere):
+    counter = metrics_mod.counter("bind.scatter.rows")
+    before = counter.value
+    bound = _bind(_rows(n=256), 1e-4)
+    assert bound.update_sparse and not bound.scatter_rows
+    assert counter.value == before
+
+
+def test_on_a_tpu_the_train_split_record_says_rows(everywhere, as_on_a_tpu, caplog):
+    from distributed_sgd_tpu.core.trainer import SyncTrainer
+
+    rows = _rows(n=256)
+    model = make_model("logistic", 1e-4, D, regularizer="l2")
+    with caplog.at_level(logging.INFO, logger="dsgd.trainer"):
+        SyncTrainer(model, make_mesh(1), 25, LR, kernel="gather", virtual_workers=4).fit(
+            rows, rows, max_epochs=1)
+    record = next(r.getMessage() for r in caplog.records
+                  if r.getMessage().startswith("train split:"))
+    assert "update=sparse scatter=rows" in record
+
+
+# -- the scatter alone: a step's entries summed by row, each row written once --------
+
+SMALL_ROWS = 40  # 5,120 features
+
+
+def _case(name):
+    """(ids, updates) of 4,400 entries into `SMALL_ROWS` x 128 weights."""
+    rng = np.random.default_rng(31)
+    last = SMALL_ROWS * 128 - 1
+    ids = rng.integers(0, last + 1, 4400).astype(np.int32)
+    upd = (rng.normal(size=4400) * 1e-3).astype(np.float32)
+    if name == "hot":  # one id in 200 of 400 rows of 11 entries
+        ids[:2200:11] = 777
+    elif name == "lanes":  # two lanes of one row, nothing else in it
+        ids = np.where(ids // 128 == 9, ids + 128, ids)
+        ids[:2] = 9 * 128 + 3, 9 * 128 + 100
+    elif name == "pads":  # a tenth of the entries are the pad
+        ids[::10], upd[::10] = 0, 0.0
+    elif name == "last_row":  # the last weight row, its last word too
+        ids[:300] = rng.integers(last - 127, last + 1, 300)
+        ids[300] = last
+    elif name == "one_row":  # every entry in ONE row: a run over all 35 chunks
+        ids = (5 * 128 + rng.integers(0, 128, 4400)).astype(np.int32)
+    elif name == "few":  # fewer rows than the ring has semaphores, T no whole chunk
+        ids, upd = ids[:37] // 128 * 128, upd[:37]
+    return ids, upd
+
+
+def _float64_scatter(w2, ids, upd):
+    want = w2.astype(np.float64).reshape(-1)
+    np.add.at(want, ids, upd.astype(np.float64))
+    return want.reshape(w2.shape)
+
+
+CASES = ["hot", "lanes", "pads", "last_row", "one_row", "few"]
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_scatter_into_is_the_float64_scatter_add(case):
+    from distributed_sgd_tpu.ops import gather
+
+    ids, upd = _case(case)
+    w2 = np.random.default_rng(2).normal(size=(SMALL_ROWS, 128)).astype(np.float32) * 3.0
+    got = np.asarray(jax.jit(gather.scatter_into)(jnp.asarray(w2), ids, upd))
+    want = _float64_scatter(w2, ids, upd)
+    # float32's rounding of the new weight and of a row's sum, no more
+    np.testing.assert_allclose(got, want, rtol=2e-7, atol=2e-9)
+    untouched = np.setdiff1d(np.arange(SMALL_ROWS), ids // 128)
+    np.testing.assert_array_equal(got[untouched], w2[untouched])
+    if case == "hot":  # summed before it meets the weight: one rounding, not 200
+        words = np.asarray(jnp.asarray(w2).reshape(-1).at[ids].add(upd)).reshape(w2.shape)
+        assert abs(got[6, 9] - want[6, 9]) <= abs(words[6, 9] - want[6, 9])
+        assert abs(got[6, 9] - want[6, 9]) <= 0.5 * np.spacing(np.float32(abs(want[6, 9]))) * 1.01
+
+
+@pytest.mark.parametrize("ring", [1, 7, 32])
+@pytest.mark.parametrize("case", CASES)
+def test_the_kernel_writes_what_xla_writes(case, ring):
+    """`_write_rows` in Pallas' TPU interpret mode against the XLA write
+    of the same rows, bit for bit: rings the heads do not fill, fill once
+    and wrap many times (1,2xx heads; `few` has 1..37), T over whole chunks."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import gather
+
+    ids, upd = _case(case)
+    w2 = jnp.asarray(np.random.default_rng(2).normal(size=(SMALL_ROWS, 128)), jnp.float32)
+    want = np.asarray(jax.jit(gather.scatter_into)(w2, ids, upd))
+    rows, head, total = jax.jit(gather._sum_by_row)(ids, upd)
+    padded = np.append(ids, [0] * (-len(ids) % gather.CHUNK))  # the pad entry: feature 0
+    assert int(np.sum(np.asarray(head))) == len(np.unique(padded // 128))
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(jax.jit(lambda w2: gather._write_rows(
+            w2, rows, head, w2[rows] + total, ring=ring))(w2))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("case", ["hot", "few"])
+def test_a_step_longer_than_scalar_memory_holds_is_written_in_blocks(case, monkeypatch):
+    """More entries than `DMA_BLOCK`: one call of the kernel a block of
+    positions, the heads spread over the first blocks, the last blocks
+    empty."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import gather
+
+    ids, upd = _case(case)
+    w2 = jnp.asarray(np.random.default_rng(2).normal(size=(SMALL_ROWS, 128)), jnp.float32)
+    want = np.asarray(jax.jit(gather.scatter_into)(w2, ids, upd))
+    monkeypatch.setattr(gather, "DMA_BLOCK", 16)
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(jax.jit(lambda w2: gather.scatter_into(w2, ids, upd, dma=True))(w2))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_the_sum_by_row_puts_a_rows_whole_run_at_its_head():
+    from distributed_sgd_tpu.ops import gather
+
+    ids, upd = _case("hot")
+    rows, head, total = (np.asarray(a) for a in jax.jit(gather._sum_by_row)(ids, upd))
+    assert len(rows) % gather.CHUNK == 0 and np.all(np.diff(rows) >= 0)
+    assert head[0] and np.array_equal(head[1:], rows[1:] != rows[:-1])
+    dense = _float64_scatter(np.zeros((SMALL_ROWS, 128), np.float32), ids, upd)
+    np.testing.assert_allclose(total[head], dense[rows[head]], rtol=1e-6, atol=1e-9)
+    # runs longer than a chunk exist here: the second product is exercised
+    assert np.bincount(rows).max() > gather.CHUNK
+
+
+def test_on_a_tpu_the_epoch_is_the_one_xla_writes(everywhere, monkeypatch):
+    """One formulation, one write that differs: the epoch program with the
+    kernel (interpreted) ends on the weights the XLA write ends on, bit for
+    bit."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import mxu
+
+    data, w, key = _rows(n=512), jnp.asarray(_weights()), jax.random.PRNGKey(3)
+    want = np.asarray(_bind(data, 1e-4, steps=3).epoch(w, key))
+    monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
+    bound = _bind(data, 1e-4, steps=3)
+    assert bound.scatter_rows
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(bound.epoch(w, key))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_the_hot_id_is_no_farther_from_the_reference_than_the_dense_steps(
+        everywhere, monkeypatch):
+    """An id in every row of the step: its ~100 increments are summed before
+    they meet its weight, as the dense step's accumulator sums them."""
+    data, key = _rows(), jax.random.PRNGKey(7)
+    w = _weights()
+    w[EVERY_ROW] = -5.75  # a hot weight of the cell's size
+    sparse = _bind(data, 1e-4)
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 10 * D)
+    dense = _bind(data, 1e-4)
+    assert sparse.update_sparse and not dense.update_sparse
+    batches = [(jnp.asarray(data.indices[r]), jnp.asarray(data.values[r]),
+                jnp.asarray(data.labels[r])) for r in _draws(sparse, key, 1)[0]]
+    want = _float64_steps(data, _draws(sparse, key, 1), w, 1e-4, LR)
+    ref = np.asarray(reference.sync_step("logistic", "l2", jnp.asarray(w), batches, 1e-4, LR))
+    got_sparse = np.asarray(sparse.step(jnp.asarray(w), key))
+    got_dense = np.asarray(dense.step(jnp.asarray(w), key))
+    ulp = float(np.spacing(np.float32(5.75)))
+    assert abs(got_sparse[EVERY_ROW] - want[EVERY_ROW]) <= max(
+        abs(got_dense[EVERY_ROW] - want[EVERY_ROW]), 0.5 * ulp * 1.01)
+    assert harness.rel_err(got_sparse - w, ref - w) <= 1e-6
 
 
 # -- (a) one step against the plain reference --------------------------------------
