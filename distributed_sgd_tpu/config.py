@@ -181,6 +181,11 @@ class Config:
     # the model's regulariser (models/linear.py): None = 'dim_sparsity'
     # where the data brings its sidecar (reference parity), else 'l2'
     regularizer: Optional[str] = None  # dim_sparsity | l2 | none
+    # which labels a fit takes from the qrels file: 'ccat', the reference's
+    # one bit a document (Dataset.scala:36-45), or 'topics', every topic
+    # code at once as a model with one output a code (W[D, C]; the mesh
+    # sync engine only, under 'l2' unless `regularizer` says 'none')
+    labels: str = "ccat"  # ccat | topics
     virtual_workers: int = 1  # reference workers emulated per mesh device
     exact_topology: bool = False  # insist on exactly node_count workers
     optimizer: str = "sgd"  # sgd (reference) | momentum | adam (sync engine)
@@ -404,6 +409,7 @@ class Config:
         # 'dense' is auto-selected from the data layout, never configured
         "kernel": ("auto", "mxu", "scalar", "gather"),
         "regularizer": (None, "dim_sparsity", "l2", "none"),
+        "labels": ("ccat", "topics"),
         "optimizer": ("sgd", "momentum", "adam"),
         "compress": ("none", "topk", "qint8"),
     }
@@ -755,6 +761,7 @@ class Config:
             pad_width=_env("DSGD_PAD_WIDTH", None, int),
             kernel=_env("DSGD_KERNEL", cls.kernel, str),
             regularizer=_env("DSGD_REGULARIZER", None, str),
+            labels=_env("DSGD_LABELS", cls.labels, str),
             virtual_workers=_env("DSGD_VIRTUAL_WORKERS", cls.virtual_workers, int),
             exact_topology=_env("DSGD_EXACT_TOPOLOGY", cls.exact_topology, bool),
             optimizer=_env("DSGD_OPTIMIZER", cls.optimizer, str),

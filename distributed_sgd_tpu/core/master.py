@@ -43,7 +43,7 @@ from distributed_sgd_tpu.core.loss_check import LossChecker, async_fit_result
 from distributed_sgd_tpu.core.split import vanilla_split, weighted_split
 from distributed_sgd_tpu.core.trainer import FitResult, record_epoch
 from distributed_sgd_tpu.data.rcv1 import Dataset
-from distributed_sgd_tpu.models.linear import LinearModel
+from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
 from distributed_sgd_tpu.parallel.mesh import make_mesh
 from distributed_sgd_tpu.parallel.sync import SyncEngine
 from distributed_sgd_tpu.rpc import codec, dsgd_pb2 as pb
@@ -840,6 +840,7 @@ class MasterNode:
         metrics: Optional[metrics_mod.Metrics] = None,
         rpc_policy: Optional[RpcPolicy] = None,
     ):
+        require_single_output(model, 'the rpc master')
         self.host, self.port = host, port
         self.log = node_logger(host, port, master=True)
         self.metrics = metrics or metrics_mod.global_metrics()

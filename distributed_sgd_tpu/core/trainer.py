@@ -117,16 +117,17 @@ class SyncTrainer:
         placed = bound_train.placement()
         stored = placed[0][2]  # one layout for every device's rows
         log.info("train split: %d rows kernel=%s margins=%s scatter_shards=%d update=%s "
-                 "scatter=%s stored major_to_minor=%s, per device %s", len(train),
+                 "scatter=%s outputs=%d stored major_to_minor=%s, per device %s", len(train),
                  bound_train.kernel,
                  "merged" if bound_train.margins_merged else "per_worker",
                  bound_train.scatter_shards,
                  "sparse" if bound_train.update_sparse else "dense",
-                 "rows" if bound_train.scatter_rows else "words", stored, " ".join(
+                 "rows" if bound_train.scatter_rows else "words",
+                 self.model.n_outputs, stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
-        w = (
-            jnp.zeros((self.model.n_features,), dtype=jnp.float32)
+        w = (  # [D], or [D, C] for a model with an output axis
+            jnp.zeros(self.model.weight_shape, dtype=jnp.float32)
             if initial_weights is None
             else jnp.asarray(initial_weights, dtype=jnp.float32)
         )

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
-from distributed_sgd_tpu.models.linear import LinearModel
+from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.rpc import codec, dsgd_pb2 as pb
 from distributed_sgd_tpu.rpc.service import (
@@ -119,6 +119,7 @@ class WorkerNode:
         total_rows: Optional[int] = None,
         host_overprovision: float = 0.0,
     ):
+        require_single_output(model, 'the rpc worker')
         self.host, self.port = host, port
         self.log = node_logger(host, port, master=False)
         self.metrics = metrics or metrics_mod.global_metrics()

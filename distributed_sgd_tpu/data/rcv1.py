@@ -43,10 +43,16 @@ class Dataset:
 
     indices: np.ndarray  # int32[N, P], 0-based feature ids, 0-padded
     values: np.ndarray  # f32[N, P], 0.0-padded
-    labels: np.ndarray  # int32[N], +/-1 (or float for regression)
+    # int32[N], +/-1 (or float for regression); or [N, C] (int8), +/-1, one
+    # column an output of a model with an output axis (every topic of the
+    # qrels file: `load_rcv1(labels="topics")`); 0 is padding either way
+    labels: np.ndarray
     n_features: int
 
     def __post_init__(self):
+        if np.ndim(self.labels) not in (1, 2):
+            raise ValueError(
+                f"labels are [N] or [N, C], got shape {np.shape(self.labels)}")
         # a zero-width index array IS the dense-layout discriminator
         # (batches carry no n_features, so width 0 must imply dense
         # everywhere); sparse sets always pad to width >= 1 (pack_csr)
@@ -136,20 +142,46 @@ def parse_svm_file(path: str, index_offset: int = -1, n_threads: int = 0):
     return out
 
 
-def read_labels(path: str) -> Dict[int, int]:
-    """qrels 'topic docid 1' -> {docid: +/-1}, CCAT -> +1, last line wins.
-
-    Reproduces Dataset.scala:36-45,53 including the Iterator.toMap
-    overwrite semantics (see module docstring).
-    """
-    labels: Dict[int, int] = {}
+def read_topics(path: str) -> Dict[int, List[str]]:
+    """qrels 'topic docid 1' -> {docid: its topic codes, in file order}: the
+    ONE parser of the file, which holds every topic code of every document
+    (103 Topic categories in RCV1-v2, 3.24 a document on average)."""
+    topics: Dict[int, List[str]] = {}
     with open(path, "r") as f:
         for line in f:
             parts = line.split()
             if len(parts) < 2:
                 continue
-            labels[int(parts[1])] = 1 if parts[0] == "CCAT" else -1
-    return labels
+            topics.setdefault(int(parts[1]), []).append(parts[0])
+    return topics
+
+
+def read_labels(path: str) -> Dict[int, int]:
+    """qrels 'topic docid 1' -> {docid: +/-1}, CCAT -> +1, last line wins:
+    the binary view of `read_topics`.
+
+    Reproduces Dataset.scala:36-45,53 including the Iterator.toMap
+    overwrite semantics (see module docstring).
+    """
+    return {doc: _ccat(codes) for doc, codes in read_topics(path).items()}
+
+
+def _ccat(codes: List[str]) -> int:
+    """A document's binary label: +1 where the LAST of its qrels lines says
+    CCAT (`Iterator.toMap` keeps the last, Dataset.scala:53)."""
+    return 1 if codes[-1] == "CCAT" else -1
+
+
+def topic_matrix(topics: Dict[int, List[str]], doc_ids) -> Tuple[np.ndarray, List[str]]:
+    """(int8[N, C] of +/-1, the C topic codes in sorted order): row n is
+    document `doc_ids[n]`, +1 in the column of every code the file gives it
+    (no last-line quirk here: every line counts)."""
+    codes = sorted({c for doc in topics.values() for c in doc})
+    column = {c: j for j, c in enumerate(codes)}
+    y = np.full((len(doc_ids), len(codes)), -1, dtype=np.int8)
+    for n, d in enumerate(doc_ids):
+        y[n, [column[c] for c in topics[int(d)]]] = 1
+    return y, codes
 
 
 def pack_csr(
@@ -242,12 +274,18 @@ def load_rcv1(
     n_features: int = N_FEATURES,
     pad_width: Optional[int] = None,
     n_threads: int = 0,
+    labels: str = "ccat",
 ) -> "Dataset":
-    """Load RCV1 from `folder` (same file set as Dataset.scala:47-50)."""
+    """Load RCV1 from `folder` (same file set as Dataset.scala:47-50).
+    `labels`: 'ccat', the reference's one bit a document (`read_labels`),
+    or 'topics', every topic code of the qrels file as [N, C] (`topic_matrix`:
+    one output a code, for a model with `n_outputs` = C)."""
+    if labels not in ("ccat", "topics"):
+        raise ValueError(f"labels must be 'ccat' or 'topics', got {labels!r}")
     files = [os.path.join(folder, "lyrl2004_vectors_train.dat")]
     if full:
         files += [os.path.join(folder, f"lyrl2004_vectors_test_pt{d}.dat") for d in range(4)]
-    labels_map = read_labels(os.path.join(folder, "rcv1-v2.topics.qrels"))
+    topics = read_topics(os.path.join(folder, "rcv1-v2.topics.qrels"))
 
     # With auto threading (n_threads=0) and several files, fan out one parse
     # per file on the shared pool — the native parser releases the GIL
@@ -268,5 +306,9 @@ def load_rcv1(
     doc_ids, row_ptr, col_idx, values = merge_parts(parts)
 
     idx, val = pack_csr(row_ptr, col_idx, values, pad_width=pad_width)
-    y = np.asarray([labels_map[int(d)] for d in doc_ids], dtype=np.int32)
+    if labels == "topics":
+        y, codes = topic_matrix(topics, doc_ids)
+        log.info("labels: %d topic codes a row (%s ... %s)", len(codes), codes[0], codes[-1])
+    else:  # CCAT against the rest, the last qrels line of a document deciding
+        y = np.asarray([_ccat(topics[int(d)]) for d in doc_ids], dtype=np.int32)
     return Dataset(indices=idx, values=val, labels=y, n_features=n_features)

@@ -21,8 +21,10 @@ def rcv1_like(
     noise: float = 0.05,
     seed: int = 0,
     idf_values: bool = False,
+    n_outputs: int = 1,
 ) -> Dataset:
-    """Planted-separator sparse classification data, packed [N, P].
+    """Planted-separator sparse classification data, packed [N, P];
+    `n_outputs` > 1: labels [N, C], one planted separator a column.
 
     `idf_values=True` weights each entry by its feature's inverse document
     frequency (log(N/df)) before the cosine normalization — the ltc
@@ -60,7 +62,25 @@ def rcv1_like(
     y = np.where(margins > np.median(margins), 1, -1).astype(np.int32)
     flip = rng.random(n_samples) < noise
     y[flip] = -y[flip]
+    if n_outputs > 1:
+        y = _topic_labels(rng, idx, val, y, n_outputs, noise)
     return Dataset(indices=idx, values=val, labels=y, n_features=n_features)
+
+
+def _topic_labels(rng, idx, val, first, n_outputs: int, noise: float) -> np.ndarray:
+    """int8 [N, C]: column 0 the binary labels `first`, every further
+    column its own planted separator, positive above the margins' quantile
+    of a prior that halves every third column (0.3, 0.24, 0.19 ...), then
+    flipped with probability `noise` x the prior."""
+    y = np.empty((len(first), n_outputs), np.int8)
+    y[:, 0] = first
+    for c in range(1, n_outputs):
+        prior = 0.3 * 0.5 ** ((c - 1) / 3.0)
+        margins = np.einsum("np,np->n", val, rng.normal(size=idx.max() + 1)[idx])
+        col = np.where(margins > np.quantile(margins, 1.0 - prior), 1, -1)
+        flip = rng.random(len(first)) < noise * prior
+        y[:, c] = np.where(flip, -col, col)
+    return y
 
 
 def dense_regression(

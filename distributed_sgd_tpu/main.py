@@ -48,6 +48,9 @@ from distributed_sgd_tpu.utils.log import setup as setup_logging
 log = logging.getLogger("dsgd.main")
 
 
+SYNTHETIC_TOPICS = 8  # outputs of the synthetic stand-in for labels='topics'
+
+
 def load_data(cfg: Config) -> Dataset:
     """RCV1 from cfg.data_path, or synthetic via DSGD_SYNTHETIC=<n> when the
     corpus is absent (no-egress environments)."""
@@ -59,13 +62,22 @@ def load_data(cfg: Config) -> Dataset:
         # ltc/IDF value weighting, like real RCV1-v2 term weighting — the
         # shipped default lr=0.5 only descends smoothly with it
         # (benches/zipf_oscillation.py, BASELINE.md round 4)
-        return rcv1_like(n, seed=cfg.seed, idf_values=True)
-    return load_rcv1(cfg.data_path, full=cfg.full, pad_width=cfg.pad_width)
+        return rcv1_like(n, seed=cfg.seed, idf_values=True,
+                         n_outputs=SYNTHETIC_TOPICS if cfg.labels == "topics" else 1)
+    return load_rcv1(cfg.data_path, full=cfg.full, pad_width=cfg.pad_width,
+                     labels=cfg.labels)
 
 
 def build(cfg: Config):
     data = measure.duration_log("data loaded", lambda: load_data(cfg), log)
     train, test = train_test_split(data)
+    if data.labels.ndim == 2:
+        # every topic at once: one output a label column; 'dim_sparsity'
+        # masks by one gradient's support and has no form with outputs
+        model = make_model(cfg.model, cfg.lam, train.n_features,
+                           regularizer=cfg.regularizer or "l2",
+                           n_outputs=data.labels.shape[1])
+        return train, test, model
     ds = measure.duration_log("dim sparsity", lambda: dim_sparsity(train), log)
     model = make_model(cfg.model, cfg.lam, train.n_features, dim_sparsity=ds,
                        regularizer=cfg.regularizer)

@@ -53,6 +53,13 @@ class LinearModel:
     Subclasses define `predict(margins)`, `sample_loss(preds, y)` and
     `grad_coeff(margins, y)` as pure jnp functions.  `regularizer` is one of
     'dim_sparsity' (reference parity), 'l2' (standard 2*lam*w), 'none'.
+
+    `n_outputs` = C > 1 is C such models over the same rows at once (one
+    label a row and output, `y[B, C]` in {-1, +1}): weights `W[D, C]`,
+    margins `[B, C]`, the objective `lam ||W||_F^2 + mean over rows of the
+    SUM over outputs of the loss`, accuracy over (row, output) pairs.
+    Nothing couples the columns: column c of every result is the C = 1
+    model on `y[:, c]`.  C = 1 keeps the flat `w[D]` and `y[B]`.
     """
 
     def __init__(
@@ -61,12 +68,22 @@ class LinearModel:
         n_features: int,
         dim_sparsity: Optional[jax.Array] = None,
         regularizer: str = "dim_sparsity",
+        n_outputs: int = 1,
     ):
         if regularizer not in REGULARIZERS:
             raise ValueError(
                 f"unknown regularizer {regularizer!r}; choose from {REGULARIZERS}")
+        if n_outputs < 1:
+            raise ValueError(f"n_outputs must be >= 1, got {n_outputs}")
+        if n_outputs > 1 and regularizer == "dim_sparsity":
+            # its mask is a reply's own support, per (feature, output):
+            # one accumulator a worker and output, which no engine builds
+            raise ValueError(
+                "the 'dim_sparsity' regulariser masks by one gradient's support and "
+                f"has no form with n_outputs={n_outputs}; choose 'l2' or 'none'")
         self.lam = float(lam)
         self.n_features = int(n_features)
+        self.n_outputs = int(n_outputs)
         self.regularizer = regularizer
         if regularizer == "dim_sparsity":
             if dim_sparsity is None:
@@ -74,6 +91,21 @@ class LinearModel:
             self.dim_sparsity = jnp.asarray(dim_sparsity, dtype=jnp.float32)
         else:
             self.dim_sparsity = None
+
+    @property
+    def weight_shape(self) -> tuple:
+        """The flat weights' shape: [D], or [D, C] with an output axis."""
+        if self.n_outputs == 1:
+            return (self.n_features,)
+        return (self.n_features, self.n_outputs)
+
+    def label_lanes(self, kernel: str) -> Optional[int]:
+        """The width an engine stores `y[N, C]` padded to (with 0, the pad
+        mask) so that labels line up with `kernel`'s margins; None: as they
+        come."""
+        if self.n_outputs > 1 and kernel == "gather":
+            return gather.output_lanes(self.n_outputs)
+        return None
 
     # -- abstract ----------------------------------------------------------
     def predict(self, margins: jax.Array) -> jax.Array:
@@ -92,12 +124,28 @@ class LinearModel:
     # (`to_layout` / `from_layout`).  Engines carry the layout across their
     # compiled loops and call `margins` and `grad` in it; callers that hold
     # flat weights call `grad_regularized`.  Dense-layout batches take the
-    # plain products whatever `kernel` says.
+    # plain products whatever `kernel` says.  With an output axis the flat
+    # layout is [D, C] and 'gather' keeps one row a FEATURE, the outputs on
+    # its lanes (ops/gather.py `to_rows`: [D', L], margins and labels [B, L],
+    # pad lanes zero); the one-hot family has no such form.
+
+    def check_kernel(self, kernel: str) -> None:
+        """Refuse the family that has no form with this model's outputs."""
+        if self.n_outputs > 1 and kernel == "mxu":
+            raise ValueError(
+                "the one-hot family ('mxu') pays R x 128 x n_outputs MACs a stored "
+                "entry and carries no output axis; 'gather', 'scalar' and 'dense' do")
 
     def to_layout(self, w: jax.Array, kernel: str) -> jax.Array:
+        if self.n_outputs > 1:
+            self.check_kernel(kernel)
+            return gather.to_rows(w) if kernel == "gather" else w
         return mxu.to_blocked(w, self.n_features) if kernel in kernels.BLOCKED else w
 
     def from_layout(self, w: jax.Array, kernel: str) -> jax.Array:
+        if self.n_outputs > 1:
+            return (gather.from_rows(w, self.n_features, self.n_outputs)
+                    if kernel == "gather" else w)
         return mxu.from_blocked(w, self.n_features) if kernel in kernels.BLOCKED else w
 
     def margins(self, w: jax.Array, batch: SparseBatch, kernel: str = "scalar") -> jax.Array:
@@ -105,7 +153,7 @@ class LinearModel:
         if batch.is_dense:
             return self.margins_dense(w, batch.values)
         if kernel == "gather":
-            return gather.matvec(batch, w)
+            return (gather.matvec_rows if self.n_outputs > 1 else gather.matvec)(batch, w)
         if kernel in kernels.BLOCKED:
             return mxu.matvec_chunked(batch, w)
         with jax.named_scope("dsgd.margins"):
@@ -155,11 +203,12 @@ class LinearModel:
         `BoundSync` builds NO accumulator: it takes the same entries from
         `reply_entries` and scatters them into the carried weights
         (`kernels.sparse_update`; PERF.md section 6, PR 30)."""
-        k, b = y.shape
+        k, b = y.shape[:2]  # y [K, B], or [K, B, C] with an output axis
         if kernels.merges_margins(kernel, indices.shape[-1]):
             merged = SparseBatch(indices.reshape(k * b, -1), values.reshape(k * b, -1))
             if kernel in kernels.ONE_ACCUMULATOR and self.regularizer != "dim_sparsity":
-                g = self.grad_blocked(w, merged, y.reshape(k * b), kernel=kernel)
+                g = self.grad_blocked(w, merged, y.reshape((k * b,) + y.shape[2:]),
+                                      kernel=kernel)
                 return self.regularize_blocked(g, w, workers=k)
             # one piece: matvec_chunked's sub-scan would bring the calls back
             matvec = gather.matvec if kernel == "gather" else mxu.matvec
@@ -195,6 +244,24 @@ class LinearModel:
             cv = batch.values.astype(jnp.float32) * coeff.astype(jnp.float32)[:, None]
         return batch.indices.reshape(-1), cv.reshape(-1)
 
+    def reply_rows(self, v2: jax.Array, batch: SparseBatch, y: jax.Array,
+                   scale: Optional[jax.Array] = None, factor=1.0):
+        """`reply_entries` with an output axis (`v2 [D', L]`, `y [B, L]`):
+        an entry's update is a whole row, `value x coeff[sample]`, and is
+        handed on as its factors: (feature ids [T], values [T], the sample
+        of every entry [T], `factor` x the samples' coefficient rows
+        [B, L]), what `gather.scatter_rows_into` takes."""
+        margins = gather.matvec_rows(batch, v2)
+        with jax.named_scope("dsgd.update"):
+            if scale is not None:
+                margins = scale * margins
+        with jax.named_scope("dsgd.coeff"):
+            coeff = self.grad_coeff(margins, y) * factor
+        with jax.named_scope("dsgd.scatter"):
+            src = jax.lax.broadcasted_iota(jnp.int32, batch.indices.shape, 0)
+        return (batch.indices.reshape(-1), batch.values.astype(jnp.float32).reshape(-1),
+                src.reshape(-1), coeff.astype(jnp.float32))
+
     def sample_losses(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """Per-sample losses (no regularization term), vectorized."""
         return self.losses_from_margins(self.margins(w, batch), y)
@@ -211,7 +278,7 @@ class LinearModel:
         """lambda*||w||^2 + mean sample loss (SparseSVM.scala:20-23)."""
         preds = self.forward(w, batch)
         reg = self.lam * jnp.sum(w.astype(jnp.float32) ** 2)
-        return reg + jnp.mean(self.sample_loss(preds, y))
+        return reg + jnp.mean(_per_row(self.sample_loss(preds, y)))
 
     def accuracy(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """fraction(forward == y) (Master.scala:98-101)."""
@@ -257,13 +324,19 @@ class LinearModel:
         self, w: jax.Array, x: jax.Array, y: jax.Array, reduce: str = "sum"
     ) -> jax.Array:
         """Batched backward for dense rows: coeff[B] @ x[B, D] — one MXU
-        matmul replacing gather + scatter (Slave.scala:147-153 semantics)."""
+        matmul replacing gather + scatter (Slave.scala:147-153 semantics);
+        with an output axis x^T[D, B] @ coeff[B, C]."""
         margins = self.margins_dense(w, x)
         with jax.named_scope("dsgd.coeff"):
             coeff = self.grad_coeff(margins, y)
             if reduce == "mean":
                 coeff = coeff / x.shape[0]
         with jax.named_scope("dsgd.scatter"):
+            if coeff.ndim == 2:
+                return jnp.dot(
+                    x.astype(jnp.float32).T, coeff.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST,
+                )
             return jnp.dot(
                 coeff.astype(jnp.float32), x.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST,
@@ -316,7 +389,11 @@ class LinearModel:
         reduce='sum' is the sync worker reply (Slave.scala:147-153);
         reduce='mean' is the async local step (Slave.scala:93-98).
         """
-        if kernel == "gather":
+        if kernel == "gather" and self.n_outputs > 1:  # rows of outputs
+            scatter = functools.partial(gather.scatter_add_rows, batch, n_rows=w2.shape[0])
+            if margins is None:
+                margins = gather.matvec_rows(batch, w2)
+        elif kernel == "gather":
             scatter = functools.partial(gather.scatter_add, batch, n_rows=w2.shape[0])
             if margins is None:
                 margins = gather.matvec(batch, w2)
@@ -399,7 +476,7 @@ class LogisticRegression(LinearModel):
 
     def objective(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         reg = self.lam * jnp.sum(w.astype(jnp.float32) ** 2)
-        return reg + jnp.mean(self.sample_losses(w, batch, y))
+        return reg + jnp.mean(_per_row(self.sample_losses(w, batch, y)))
 
     def grad_coeff(self, margins: jax.Array, y: jax.Array) -> jax.Array:
         yf = y.astype(jnp.float32)
@@ -424,12 +501,42 @@ class LeastSquares(LinearModel):
         return -jnp.mean((preds - y.astype(jnp.float32)) ** 2)
 
 
+def _per_row(losses: jax.Array) -> jax.Array:
+    """A row's loss: with an output axis the SUM over its outputs."""
+    return losses if losses.ndim == 1 else jnp.sum(losses, axis=-1)
+
+
+def _single_output_only(who: str, n_outputs: int) -> ValueError:
+    return ValueError(
+        f"{who} carries one flat weight vector w[n_features]; a model with "
+        f"n_outputs={n_outputs} fits through SyncTrainer.fit (the mesh sync "
+        f"engine) only")
+
+
+def require_single_output(model: "LinearModel", who: str) -> None:
+    """The ONE refusal of every engine that carries one flat weight vector
+    (Hogwild, local SGD, the rpc master and workers and with them their
+    wire and its compression, feature sharding, the multi-host binds): a
+    model with an output axis trains and evaluates through the mesh sync
+    engine alone."""
+    if getattr(model, "n_outputs", 1) != 1:
+        raise _single_output_only(who, model.n_outputs)
+
+
+def require_flat_weights(weights, who: str) -> None:
+    """The same refusal where only the weights are seen (serving a
+    checkpoint a fit with an output axis wrote)."""
+    if np.ndim(weights) != 1:
+        raise _single_output_only(who, np.shape(weights)[-1])
+
+
 def make_model(
     name: str,
     lam: float,
     n_features: int,
     dim_sparsity: Optional[jax.Array] = None,
     regularizer: Optional[str] = None,
+    n_outputs: int = 1,
 ) -> LinearModel:
     kinds = {
         "hinge": SparseSVM,
@@ -441,4 +548,5 @@ def make_model(
         raise ValueError(f"unknown model {name!r}; choose from {sorted(kinds)}")
     if regularizer is None:
         regularizer = "dim_sparsity" if dim_sparsity is not None else "l2"
-    return kinds[name](lam, n_features, dim_sparsity=dim_sparsity, regularizer=regularizer)
+    return kinds[name](lam, n_features, dim_sparsity=dim_sparsity, regularizer=regularizer,
+                       n_outputs=n_outputs)

@@ -69,6 +69,7 @@ import jax.numpy as jnp
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 
 LANES = 128
+SUBLANES = 8
 
 
 def gathered(w2: jax.Array, indices: jax.Array) -> jax.Array:
@@ -135,11 +136,19 @@ def _sum_by_row(ids: jax.Array, updates: jax.Array):
     head = jnp.concatenate([jnp.ones((1,), bool), rows[1:] != rows[:-1]])
     lanes = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], LANES), 1)
     entry = jnp.where(lanes == lane[:, None], updates[:, None], 0.0)  # [T', 128]
+    return rows, head, _run_sums(rows, entry)
+
+
+def _run_sums(rows: jax.Array, entry: jax.Array) -> jax.Array:
+    """total f32[T', L]: at the first entry of every run of equal `rows`
+    (sorted ascending, T' whole chunks) the sum of the run's `entry[T', L]`
+    rows, on the MXU a chunk at a time."""
+    width = entry.shape[1]
     exact = jax.lax.Precision.HIGHEST
     by_chunk = rows.reshape(-1, CHUNK)  # [n, CHUNK]
     same = (by_chunk[:, :, None] == by_chunk[:, None, :]).astype(jnp.float32)
     # every entry's row summed over its own chunk
-    local = jnp.einsum("nts,nsl->ntl", same, entry.reshape(-1, CHUNK, LANES), precision=exact)
+    local = jnp.einsum("nts,nsl->ntl", same, entry.reshape(-1, CHUNK, width), precision=exact)
     # a run that goes on past its chunk: the later chunks it opens (sorted,
     # so they start inside it) hold the rest in their first entry's sum
     first, last = by_chunk[:, 0], by_chunk[:, -1]
@@ -147,7 +156,7 @@ def _sum_by_row(ids: jax.Array, updates: jax.Array):
     goes_on = (chunk[None, :] > chunk[:, None]) & (first[None, :] == last[:, None])
     rest = jnp.einsum("cd,dl->cl", goes_on.astype(jnp.float32), local[:, 0], precision=exact)
     total = local + jnp.where((by_chunk == last[:, None])[:, :, None], rest[:, None, :], 0.0)
-    return rows, head, total.reshape(-1, LANES)
+    return total.reshape(-1, width)
 
 
 def _write_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, new: jax.Array,
@@ -227,13 +236,93 @@ def scatter_into(w2: jax.Array, ids: jax.Array, updates: jax.Array,
     whose carry `w2` is, both update the carry in place."""
     with jax.named_scope("dsgd.scatter"):
         rows, head, total = _sum_by_row(ids, updates)
-        # only the heads' rows are written: off them any row will do, and
-        # the gather runs faster over rows that differ than over a run's
-        # repeats (34 -> 18 us for 4,480 rows, 1,200 of them one row)
-        entry = jnp.arange(rows.shape[0])
-        new = w2[jnp.where(head, rows, entry % w2.shape[0])] + total
-        if dma:
-            return _write_rows(w2, rows, head, new)
-        # off the heads: past the last row, each its own index, dropped
-        return w2.at[jnp.where(head, rows, w2.shape[0] + entry)].set(
-            new, mode="drop", unique_indices=True)
+        return _add_rows(w2, rows, head, total, dma)
+
+
+def _add_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, total: jax.Array,
+              dma: bool) -> jax.Array:
+    """`w2` with `total[t]` added to row `rows[t]` wherever `head[t]`."""
+    # only the heads' rows are written: off them any row will do, and
+    # the gather runs faster over rows that differ than over a run's
+    # repeats (34 -> 18 us for 4,480 rows, 1,200 of them one row)
+    entry = jnp.arange(rows.shape[0])
+    new = w2[jnp.where(head, rows, entry % w2.shape[0])] + total
+    if dma:
+        return _write_rows(w2, rows, head, new)
+    # off the heads: past the last row, each its own index, dropped
+    return w2.at[jnp.where(head, rows, w2.shape[0] + entry)].set(
+        new, mode="drop", unique_indices=True)
+
+
+# -- weights with an output axis -------------------------------------------------
+#
+# `W[D, C]` (models/linear.py `n_outputs` > 1) is kept with the OUTPUTS on
+# the lanes: `w2 [D', L]`, feature i the row i (D' = D rounded up to whole
+# sublanes, L = C rounded up to whole lanes; the pad rows and lanes are zero
+# and stay zero).  A feature is then the unit every gather and every DMA of
+# this file moves anyway, and all its lanes are needed: no lane pick, no
+# arithmetic on an id.
+
+
+def output_lanes(n_outputs: int) -> int:
+    """Lanes of a weight row that holds `n_outputs` outputs."""
+    return -(-int(n_outputs) // LANES) * LANES
+
+
+def to_rows(w: jax.Array) -> jax.Array:
+    """[D, C] -> [D', L] (zero-padded)."""
+    d, c = w.shape
+    with jax.named_scope("dsgd.layout"):
+        return jnp.pad(w, ((0, -d % SUBLANES), (0, output_lanes(c) - c)))
+
+
+def from_rows(w2: jax.Array, n_features: int, n_outputs: int) -> jax.Array:
+    """[D', L] -> [D, C]."""
+    with jax.named_scope("dsgd.layout"):
+        return w2[:n_features, :n_outputs]
+
+
+def matvec_rows(batch: SparseBatch, w2: jax.Array) -> jax.Array:
+    """Per-sample, per-output dots `x_b . W[:, c]` -> [B, L]: every stored
+    entry gathers its feature's row, a sample's P rows are summed with its
+    values as weights (pads contribute 0 * row 0).  The entries are
+    gathered ENTRY-MAJOR ([P, B] and not [B, P]): the gathered [P B, L] rows
+    then split into [P, B, L] without moving (B whole sublanes), where
+    [B, P, L] with P = 76 is another tiling and cost a copy of all of them
+    (0.88 s of the 2.30 s an evaluation of 7.2 M rows took, my chip run,
+    PR 32)."""
+    with jax.named_scope("dsgd.margins"):
+        entry_major = batch.indices.T  # [P, B]
+        rows = w2.astype(jnp.float32)[entry_major.reshape(-1)]  # [P B, L]: the row gather
+        rows = rows.reshape(entry_major.shape + (w2.shape[1],))
+        return jnp.sum(batch.values.astype(jnp.float32).T[..., None] * rows, axis=0)
+
+
+def scatter_add_rows(batch: SparseBatch, coeff: jax.Array, n_rows: int) -> jax.Array:
+    """sum_b x_b (outer) coeff[b] -> a fresh [D', L] (the gradient an
+    optimizer reads): XLA's scatter-add of whole rows, in entry order."""
+    with jax.named_scope("dsgd.scatter"):
+        cv = batch.values.astype(jnp.float32)[..., None] * coeff.astype(jnp.float32)[:, None, :]
+        return jnp.zeros((n_rows, coeff.shape[1]), jnp.float32).at[
+            batch.indices.reshape(-1)].add(cv.reshape(-1, coeff.shape[1]))
+
+
+def scatter_rows_into(w2: jax.Array, ids: jax.Array, values: jax.Array, src: jax.Array,
+                      coeff: jax.Array, dma: bool = False) -> jax.Array:
+    """`w2` with `values[t] * coeff[src[t]]` added to row `ids[t]`:
+    `scatter_into` for updates that ARE rows, handed over as their factors
+    (an entry's value and the sample it belongs to; the samples'
+    coefficient rows `coeff [S, L]`), so that the sort moves three words an
+    entry and no [T, L] array of updates is written before it.  The entries
+    are sorted by id, each takes its sample's coefficient row (a row gather
+    from a table of S rows), runs of an id are summed on the MXU
+    (`_run_sums`), and every touched row is fetched, added to and written
+    back once, as `scatter_into` does it."""
+    with jax.named_scope("dsgd.scatter"):
+        pad = (0, -ids.shape[0] % CHUNK)  # the pad entry: 0.0 x sample 0 on feature 0
+        ids, src = jnp.pad(ids, pad), jnp.pad(src.astype(jnp.int32), pad)
+        values = jnp.pad(values.astype(jnp.float32), pad)
+        ids, values, src = jax.lax.sort((ids, values, src), num_keys=1, is_stable=False)
+        head = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+        entry = values[:, None] * coeff.astype(jnp.float32)[src]  # [T', L]
+        return _add_rows(w2, ids, head, _run_sums(ids, entry), dma)

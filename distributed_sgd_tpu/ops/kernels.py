@@ -11,7 +11,11 @@ Four families compute the same margins and the same worker reply
 - 'scalar'  XLA's take / scatter-add (ops/sparse.py): what a CPU runs
             fastest, and the reference-shaped fallback everywhere.
 
-`choose_kernel` maps (feature count, row width, platform) to one of them;
+Weights with an output axis (`LinearModel.n_outputs` = C > 1: `W[D, C]`)
+run 'dense', 'gather' (one weight row a FEATURE, the outputs on its lanes)
+or 'scalar'; the one-hot family has no form with outputs.
+
+`choose_kernel` maps (feature count, row width, platform, outputs) to one;
 `SyncEngine.bind` (and through it `LocalSGDEngine`), Hogwild's `_Worker`
 and `core/worker.py` all ask it through `resolve`, which reads the
 platform off the device and counts the answer.  An explicit `kernel=`
@@ -60,13 +64,29 @@ ONE_ACCUMULATOR = ("gather",)
 # a step's entries are no input of this rule), half of what 'gather' pays:
 # the constant sits under the crossing, between the two measured feature
 # counts, a factor of four to five from either.
+#
+# With an output axis the one-hot family is out at any feature count: R x
+# 128 x C MACs an entry, 4.96 M at `rcv1-topics-hinge`'s shape (R = 376,
+# C = 103: 1.5 ms a side of a 30,400-entry step at the bf16 peak, for work
+# that needs 6.3 MFLOP), so it was not built.  Of the two forms that were
+# timed, one call of the whole step's two sides on a v5e
+# (`benches/outputs_step_sweep.py`; PERF.md section 6, PR 32; us a call):
+#
+#     rows gathered and scattered ('gather')            408.0
+#     the batch made dense, X[400, D], and two matmuls
+#       in float32 (HIGHEST)                          1,207.8
+#       in one bf16 pass                                853.9
+#       (building X alone, XLA's scatter of 30,400 words: 903.3)
+#
+# so outputs go to 'gather' wherever the one-hot family would have run.
 GATHER_MIN_FEATURES = 200_000
 
 
 def choose_kernel(n_features: int, row_width: int, platform: str,
-                  off_tpu: str = "scalar") -> str:
+                  off_tpu: str = "scalar", n_outputs: int = 1) -> str:
     """The kernel family for rows of `row_width` stored entries (0: the
-    dense layout) over `n_features` features on `platform`.  `off_tpu` is
+    dense layout) over `n_features` features and `n_outputs` outputs on
+    `platform`.  `off_tpu` is
     the family the asking engine runs off the TPU, where nothing was
     measured: the sync engines the one-hot matmuls (the CPU tests of the
     blocked layout, its optimizer state and checkpoints run through them),
@@ -77,7 +97,7 @@ def choose_kernel(n_features: int, row_width: int, platform: str,
     if row_width == 0:
         return "dense"
     family = "mxu" if platform == "tpu" else off_tpu
-    if family == "mxu" and n_features >= GATHER_MIN_FEATURES:
+    if family == "mxu" and (n_features >= GATHER_MIN_FEATURES or n_outputs > 1):
         return "gather"
     return family
 
@@ -131,11 +151,23 @@ def merges_margins(kernel: str, row_width: int) -> bool:
 # constant stays where the dense passes start to cost more than a
 # twentieth of the step; where the forms cross between 4e6 and 8e6
 # features is ROADMAP D4's to measure.
+#
+# With an output axis the floor is on the WORDS of `W` the dense passes
+# would touch, D x C.  Re-read at `rcv1-topics-hinge`'s shape (D = 47,236,
+# C = 103: 4,865,308 words; a `W2` of 24.2 MB on 128 lanes), `BoundSync.epoch`
+# over 409,600 of its rows, 4 workers x batch 100, each form forced
+# (`benches/outputs_step_sweep.py`; PERF.md section 6, PR 32; us a step):
+#
+#     sparse (the entries' rows into the carry)  428.0
+#     dense (XLA's scatter-add of 30,400 rows into zeros, the passes)  436.7
+#
+# The sparse step is ahead by 2 %, as the table above has it at 4e6 words
+# (69.0 / 74.2): the constant holds for words as it held for features.
 SPARSE_UPDATE_MIN_FEATURES = 4_000_000
 
 
 def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
-                  decay: float, n_features: int) -> bool:
+                  decay: float, n_features: int, n_outputs: int = 1) -> bool:
     """Whether a sync binding's step never materialises a gradient: the
     workers' replies stay (id, coefficient x value) entries up to the
     reduction and are scattered straight into the carried weights, and the
@@ -146,27 +178,29 @@ def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
     (`ONE_ACCUMULATOR`), under a regulariser that is linear in `w` ('l2':
     `decay` = 2 lr lam a step; 'none': 0), with the reference update
     (`plain_sgd`: an optax optimizer reads a whole gradient) and a decay
-    that leaves the sign of `w` (under 1).  'dim_sparsity' masks each reply
+    that leaves the sign of `w` (under 1), from SPARSE_UPDATE_MIN_FEATURES
+    words of weights on (`n_features` x `n_outputs`).  'dim_sparsity' masks each reply
     by its own support and keeps the dense step, as the one-hot, dense and
     scalar families do.  Static per binding: `BoundSync` counts it under
     `bind.update.sparse`."""
     return (kernel in ONE_ACCUMULATOR and regularizer in ("l2", "none")
             and plain_sgd and 0.0 <= decay < 1.0
-            and n_features >= SPARSE_UPDATE_MIN_FEATURES)
+            and n_features * n_outputs >= SPARSE_UPDATE_MIN_FEATURES)
 
 
 def resolve(kernel: Optional[str], n_features: int, row_width: int,
-            device=None, off_tpu: str = "scalar") -> str:
+            device=None, off_tpu: str = "scalar", n_outputs: int = 1) -> str:
     """What an engine binds: an explicit `kernel` as given (dense rows can
     only run 'dense'), the rule's answer on the platform of `device` (None:
-    the process default backend) for AUTO / None.  `mxu.blocked_pays_off`
+    the process default backend) for AUTO / None; `n_outputs` > 1 is never
+    answered with the one-hot family.  `mxu.blocked_pays_off`
     is the platform probe: one policy for "is this a TPU", which tests
     steer.  Counted once a binding under `bind.kernel.<name>`."""
     if kernel in (None, AUTO) or row_width == 0:
         from distributed_sgd_tpu.ops import mxu
 
         platform = "tpu" if mxu.blocked_pays_off(device) else "cpu"
-        chosen = choose_kernel(n_features, row_width, platform, off_tpu)
+        chosen = choose_kernel(n_features, row_width, platform, off_tpu, n_outputs)
     else:
         chosen = kernel
     metrics.counter(f"bind.kernel.{chosen}").increment()

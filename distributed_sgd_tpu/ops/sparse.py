@@ -66,10 +66,14 @@ def matvec(batch: SparseBatch, w: jax.Array) -> jax.Array:
     """Per-row sparse dot product: out[b] = sum_p values[b,p] * w[indices[b,p]].
 
     Pads contribute values 0 * w[0] = 0.  Accumulates in f32 regardless of
-    the dtype of `values`/`w` (bf16-safe).
+    the dtype of `values`/`w` (bf16-safe).  Weights with an output axis
+    `w[D, C]` give `out[b, c]`, the same sum a column.
     """
     gathered = jnp.take(w, batch.indices, axis=0)
-    prod = batch.values.astype(jnp.float32) * gathered.astype(jnp.float32)
+    values = batch.values.astype(jnp.float32)
+    if w.ndim == 2:
+        return jnp.sum(values[..., None] * gathered.astype(jnp.float32), axis=-2)
+    prod = values * gathered.astype(jnp.float32)
     return jnp.sum(prod, axis=-1)
 
 
@@ -78,8 +82,13 @@ def scatter_add(batch: SparseBatch, coeff: jax.Array, n_features: int) -> jax.Ar
 
     out = sum_b coeff[b] * x_b, computed as one flat `.at[].add()` scatter
     (an XLA segment-sum; TPU-friendly).  Pads scatter 0.0 into feature 0.
+    Coefficients with an output axis `coeff[B, C]` give `out[D, C]`.
     """
     flat_idx = batch.indices.reshape(-1)
+    if coeff.ndim == 2:
+        rows = batch.values.astype(jnp.float32)[..., None] * coeff.astype(jnp.float32)[:, None, :]
+        return jnp.zeros((n_features, coeff.shape[1]), jnp.float32).at[flat_idx].add(
+            rows.reshape(-1, coeff.shape[1]))
     flat_val = (batch.values.astype(jnp.float32) * coeff.astype(jnp.float32)[:, None]).reshape(-1)
     return jnp.zeros((n_features,), dtype=jnp.float32).at[flat_idx].add(flat_val)
 

@@ -57,7 +57,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
-from distributed_sgd_tpu.models.linear import LinearModel
+from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
 from distributed_sgd_tpu.ops import mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import WORKER_AXIS, pcast_varying, shard_map
@@ -86,6 +86,7 @@ class FeatureShardedEngine:
         batch_size: int,
         learning_rate: float,
     ):
+        require_single_output(model, 'FeatureShardedEngine')
         self.model = model
         self.mesh = mesh
         self.batch_size = int(batch_size)

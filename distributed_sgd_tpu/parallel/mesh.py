@@ -165,14 +165,19 @@ def _pad_lanes(shard: jax.Array, width: int) -> jax.Array:
         lambda start, count: jax.lax.dynamic_slice_in_dim(shard, start, count, 0))
 
 
-def put_rows(arr, sharding: NamedSharding) -> jax.Array:
+def put_rows(arr, sharding: NamedSharding, width: Optional[int] = None) -> jax.Array:
     """Place one resident array, rows sharded over the workers, so that it
     is stored in the layout the step gathers from: as it comes, or (where
     `lane_width` says so) zero-padded to whole lanes, which the backend
     stores row-major.  Readers take the true width back off (BoundSync.rows
     / .chunk): the padding is never read.  The padding runs once, on the
-    devices, from the default placement."""
-    width = lane_width(arr.shape, next(iter(sharding.device_set)).platform)
+    devices, from the default placement.  `width`: the caller's own padded
+    width on every platform (the labels of a model with an output axis,
+    which its readers WANT lane for lane beside the margins)."""
+    if width is None:
+        width = lane_width(arr.shape, next(iter(sharding.device_set)).platform)
+    elif width == arr.shape[1]:
+        width = None
     name = "default" if width is None else "row_major"
     with measure.span("sync.bind.place", layout=name, bytes=arr.nbytes):
         placed = jax.device_put(arr, sharding)

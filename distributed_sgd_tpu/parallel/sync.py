@@ -60,7 +60,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
-from distributed_sgd_tpu.models.linear import LinearModel
+from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
 from distributed_sgd_tpu.ops import gather, kernels, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
@@ -81,7 +81,10 @@ AXIS = WORKER_AXIS
 class ShardedData(NamedTuple):
     indices: jax.Array  # int32[N_pad, P], sharded over workers
     values: jax.Array  # f32[N_pad, P], sharded over workers
-    labels: jax.Array  # [N_pad], sharded over workers; 0 = padding mask
+    # [N_pad], or [N_pad, C] for a model with C outputs (bind() stores it
+    # zero-padded to the lanes of the kernel's margins,
+    # LinearModel.label_lanes); sharded over workers; 0 = padding mask
+    labels: jax.Array
     n_true: int  # real sample count (host-side)
     # the rows' true width: bind() may store indices / values zero-padded
     # to whole lanes (mesh.put_rows); None: the arrays are as wide as the rows
@@ -129,7 +132,10 @@ class BoundSync:
                 f"kernel='dense' goes with dense-layout data (Dataset.dense) and "
                 f"vice versa; got kernel={kernel!r}, dense data={dense_data}"
             )
+        model.check_kernel(kernel)
         self.kernel = kernel
+        if model.n_outputs > 1:  # one row of labels a sample, W[D, C]
+            metrics.counter("bind.outputs.multi").increment()
         # buffer donation (ROADMAP item 2): donate=True marks the weights
         # and optimizer-state arguments of the TRAINING dispatches (step /
         # epoch / fused multi-epoch) as donated, so XLA reuses their HBM
@@ -206,7 +212,7 @@ class BoundSync:
                        if model.regularizer == "l2" else 0.0)
         self.update_sparse = kernels.sparse_update(
             kernel, model.regularizer, self.opt is None, self._decay,
-            model.n_features)
+            model.n_features, model.n_outputs)
         if self.update_sparse:
             metrics.counter("bind.update.sparse").increment()
         # whether that scatter writes each touched row back by the DMA
@@ -396,6 +402,8 @@ class BoundSync:
             factor = -self.learning_rate / n
             if s_next is not None:
                 factor = factor / s_next
+        if self.model.n_outputs > 1:
+            return self._scatter_reply_rows(v2, merged, by, s, factor)
         at, add = self.model.reply_entries(v2, merged, by.reshape(-1), s, factor)
         # every device scatters every device's entries: the sum a psum of
         # the dense replies would give, in another order (one device: the
@@ -407,6 +415,29 @@ class BoundSync:
             at = both[:, 0].reshape(-1)
             add = jax.lax.bitcast_convert_type(both[:, 1], jnp.float32).reshape(-1)
         return gather.scatter_into(v2, at, add, dma=self.scatter_rows)
+
+    def _scatter_reply_rows(self, v2, merged, by, s, factor):
+        """The rest of `_sparse_step` with an output axis: an entry's
+        update is a row of `v2`, exchanged as its factors (id, value, the
+        sample it belongs to) beside every sample's coefficient row, a
+        hundredth of the bytes of the rows themselves."""
+        at, val, src, coeff = self.model.reply_rows(
+            v2, merged, by.reshape((-1,) + by.shape[2:]), s, factor)
+        t, (samples, lanes) = at.shape[0], coeff.shape
+        with jax.named_scope("dsgd.allreduce"):
+            # all of it as bits in one vector: one collective a step
+            bits = jnp.concatenate([
+                at, jax.lax.bitcast_convert_type(val, jnp.int32), src,
+                jax.lax.bitcast_convert_type(coeff, jnp.int32).reshape(-1)])
+            every = gather_replicated(bits, AXIS)  # [devices, 3 T + samples x lanes]
+            at = every[:, :t].reshape(-1)
+            val = jax.lax.bitcast_convert_type(every[:, t:2 * t], jnp.float32).reshape(-1)
+            # a device's samples follow the devices' before it
+            src = (every[:, 2 * t:3 * t]
+                   + samples * jnp.arange(every.shape[0], dtype=jnp.int32)[:, None]).reshape(-1)
+            coeff = jax.lax.bitcast_convert_type(
+                every[:, 3 * t:], jnp.float32).reshape(-1, lanes)
+        return gather.scatter_rows_into(v2, at, val, src, coeff, dma=self.scatter_rows)
 
     def _sparse_steps(self, v2, idx, val, y, key):
         """`steps_per_epoch` sparse steps on blocked weights, folded."""
@@ -464,7 +495,9 @@ class BoundSync:
         24.7 us step at 491,520 labels, PERF.md section 6, PR 25); a float32
         copy made once before the loop it keeps there.  Every grad_coeff
         casts its labels to float32 first, so the step computes the same."""
-        return y if self._width is None else y.astype(jnp.float32)
+        if self._width is None or y.ndim == 2:  # rows of labels: one gather, no fetch
+            return y
+        return y.astype(jnp.float32)
 
     def _epoch_shard(self, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
@@ -531,7 +564,7 @@ class BoundSync:
 
         with jax.named_scope("dsgd.eval"):
             _, preds = jax.lax.scan(body, (), jnp.arange(n_chunks))
-            return preds.reshape(-1)
+            return preds.reshape((-1,) + preds.shape[2:])
 
     def _multi_epoch_shard(self, n_epochs, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
@@ -587,7 +620,7 @@ class BoundSync:
         ``.compile()`` populates the persistent cache, so the fit's first
         dispatch re-traces cheaply and reads the XLA executable from
         disk instead of re-running the backend compile."""
-        w0 = jnp.zeros((self.model.n_features,), jnp.float32)
+        w0 = jnp.zeros(self.model.weight_shape, jnp.float32)
         key = jax.random.PRNGKey(0)
         d = self.data
 
@@ -672,7 +705,7 @@ class BoundSync:
         if self.opt is None:
             return ()
         return self.opt.init(
-            self._to_kernel_layout(jnp.zeros((self.model.n_features,), jnp.float32))
+            self._to_kernel_layout(jnp.zeros(self.model.weight_shape, jnp.float32))
         )
 
     def reset_optimizer(self) -> None:
@@ -694,13 +727,17 @@ class BoundSync:
         """Model predictions for every (true) sample in the bound split,
         the Master.predict fan-out equivalent (Master.scala:61-75)."""
         preds = self._predict(w, self.data.indices, self.data.values)
-        return np.asarray(preds)[: self.data.n_true]
+        preds = np.asarray(preds)[: self.data.n_true]
+        # with an output axis [n, C]: the margins' pad lanes taken off
+        return preds if preds.ndim == 1 else preds[:, : self.model.n_outputs]
 
     def evaluate(self, w: jax.Array) -> Tuple[float, float]:
         """(objective, accuracy) over the bound split.
 
         objective = lam*||w||^2 + mean sample loss (SparseSVM.scala:20-23);
-        accuracy = fraction(forward == y) (Master.scala:98-101).
+        accuracy = fraction(forward == y) (Master.scala:98-101).  With an
+        output axis a sample's loss is the sum over its outputs and the
+        accuracy is over (sample, output) pairs.
         """
         # phases of the caller's span (trainer.evaluate, master.async.check):
         # no histogram, and no span of their own outside one
@@ -710,7 +747,7 @@ class BoundSync:
             loss_sum, hit_sum = float(sums[0]), float(sums[1])
             n = self.data.n_true
             reg = self.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
-        return reg + loss_sum / n, hit_sum / n
+        return reg + loss_sum / n, hit_sum / (n * self.model.n_outputs)
 
 
 def local_update(opt, learning_rate: float, g, w, opt_state):
@@ -786,7 +823,8 @@ class SyncEngine:
         and platform unless a family was named; off the TPU the sync
         engines run the blocked families too (ops/kernels.py `off_tpu`)."""
         return kernels.resolve(self.kernel, n_features, row_width,
-                               self.mesh.devices.flat[0], off_tpu="mxu")
+                               self.mesh.devices.flat[0], off_tpu="mxu",
+                               n_outputs=self.model.n_outputs)
 
     def bind(self, data: Dataset, steps_per_epoch: Optional[int] = None) -> BoundSync:
         n_workers = self.mesh.shape[AXIS]
@@ -800,6 +838,7 @@ class SyncEngine:
         total, chunk = padded_layout(n_true, n_workers, self.eval_chunk)
         sharding = NamedSharding(self.mesh, P(AXIS))
         if jax.process_count() > 1 and self.mesh.size == jax.device_count():
+            require_single_output(self.model, "the multi-host bind")
             # multi-host global mesh: every process passes the SAME full
             # dataset but pads/copies ONLY its own row range
             # (host_shard_bounds matches padded_layout's per-device
@@ -837,10 +876,12 @@ class SyncEngine:
             values = put(np.zeros((total, 0), np.float32))
         else:
             indices, values = put(local.indices), put(local.values)
+        label_lanes = self.model.label_lanes(kernel)
         sharded = ShardedData(
             indices=indices,
             values=values,
-            labels=put(local.labels),
+            labels=(put(local.labels) if label_lanes is None
+                    else put_rows(local.labels, sharding, width=label_lanes)),
             n_true=n_true,
             width=local.values.shape[1],
             packed=lanes is not None,
@@ -884,6 +925,7 @@ class SyncEngine:
         raises on a lossy mismatch rather than truncating."""
         from distributed_sgd_tpu.parallel.multihost import host_local_sharded
 
+        require_single_output(self.model, "the host-local multi-host bind")
         if labels_dtype is None:
             labels_dtype = np.float32 if pad_width == 0 else np.int32
         sharded, chunk = host_local_sharded(
@@ -933,6 +975,7 @@ def _pad_to_exact(data: Dataset, target: int) -> Dataset:
         values=np.concatenate(
             [data.values, np.zeros((rem, data.values.shape[1]), dtype=data.values.dtype)]
         ),
-        labels=np.concatenate([data.labels, np.zeros((rem,), dtype=data.labels.dtype)]),
+        labels=np.concatenate(
+            [data.labels, np.zeros((rem,) + data.labels.shape[1:], dtype=data.labels.dtype)]),
         n_features=data.n_features,
     )

@@ -45,6 +45,7 @@ from typing import Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_sgd_tpu.models.linear import require_flat_weights
 from distributed_sgd_tpu.utils import metrics as metrics_mod
 
 log = logging.getLogger("dsgd.serving")
@@ -119,6 +120,7 @@ class ModelStore:
                 return False
             step, state = restored
             weights = jnp.asarray(state["weights"], dtype=jnp.float32)
+            require_flat_weights(weights, "the serving model store")
         except Exception as e:  # noqa: BLE001 - keep serving the old snapshot
             log.warning("checkpoint reload failed (serving stays on step %s): %s",
                         cur[0] if cur else None, e)

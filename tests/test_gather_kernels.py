@@ -238,9 +238,9 @@ def asked(monkeypatch):
     calls = []
     rule = kernels.choose_kernel
 
-    def spy(n_features, row_width, platform, off_tpu="scalar"):
+    def spy(n_features, row_width, platform, off_tpu="scalar", n_outputs=1):
         calls.append((n_features, row_width, platform))
-        return rule(n_features, row_width, "tpu", off_tpu)
+        return rule(n_features, row_width, "tpu", off_tpu, n_outputs)
 
     monkeypatch.setattr(kernels, "choose_kernel", spy)
     return calls

@@ -562,3 +562,47 @@ def test_on_a_v5e_no_step_of_the_sparse_epoch_passes_over_the_weights(v5e, devic
         assert 'dsgd.allreduce' in re.search(r"all-gather\(.*", text).group(0)
     # the fold, once a program, under its own scope
     assert "dsgd.rescale" in text
+
+
+# -- (i) weights with an output axis: rows of outputs (PERF.md section 6, PR 32) -----
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices):
+    """`rcv1-topics-hinge`'s programs compile for the chip (nothing ran):
+    the update is the DMA kernel on 128-lane rows of OUTPUTS scattered into
+    the carry, the entries cross the mesh as factors in ONE all-gather, and
+    the evaluation's gathered rows split into [P, B, L] where they lie."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    d, c, rows, width = 47_236, 103, 4096 * 16 * devices, 76
+    mesh = Mesh(np.array(v5e.devices[:devices]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+    data = ShardedData(shape((rows, width), jnp.int32, sharding=over_rows),
+                       shape((rows, width), jnp.float32, sharding=over_rows),
+                       shape((rows, 128), jnp.int8, sharding=over_rows), rows, width)
+    model = make_model("hinge", 1.7e-7, d, regularizer="l2", n_outputs=c)
+    bound = BoundSync(model, mesh, data, 100, 0.25, kernel="gather",
+                      virtual_workers=4 // devices)
+    assert bound.update_sparse and bound.scatter_rows
+    w = shape((d, c), jnp.float32, sharding=everywhere)
+    step = bound._step.lower(w, (), data.indices, data.values, data.labels,
+                             shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
+    kernel = [line for line in step.split("\n") if " custom-call(" in line
+              and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernel) == 1 and "f32[47240,128]" in kernel[0]
+    assert "dsgd.scatter/scatter_rows" in kernel[0]
+    # ONE collective a step, of the entries' factors and the samples'
+    # coefficient rows as one vector of bits (the compiler runs a 1-D
+    # all-gather as an all-reduce of zero-padded pieces), never of a gradient
+    crossing = re.findall(r"= (\S+) all-(?:gather|reduce)(?:-start)?\(", step)
+    assert len(crossing) == int(devices > 1), crossing
+    assert all(c.startswith("s32[142400]") for c in crossing), crossing
+    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
+    assert "f32[311296,128]" in evaluation  # a chunk's 4,096 x 76 gathered rows
+    # entry-major: no [B, P, L] form of them, which was a copy of all of them
+    assert "f32[4096,76,128]" not in evaluation
