@@ -19,7 +19,8 @@ lambda 1e-5) through the entry point a user runs,
 - placement  SyncEngine.bind on dense 2,000-wide rows and on 76-wide ones:
            the wide rows sit row-major (padded to whole lanes) and the
            compiled epoch program holds no copy of them, the narrow ones
-           stay as they come
+           stay as they come; on the chip both carry their label in a spare
+           word of the stored row (lane 2,000; a 77th column)
 
 One process per chip: this parent never imports jax (nor the package, whose
 submodules do); it runs its children one after another, each with
@@ -127,6 +128,7 @@ def child_placement(rows: int) -> None:
         "resident_copies": len(re.findall(rf"= {resident}\S* copy\(", text)),
         "sparse_widths": [bound["sparse"].data.indices.shape[1],
                           bound["sparse"].data.values.shape[1]],
+        "label_slots": [b.data.label_slot for b in bound.values()],
         "row_major": metrics.counter("bind.rows.row_major").value,
         "default": metrics.counter("bind.rows.default").value,
         "finite": bool(np.isfinite(w).all()), "moved": float(np.max(np.abs(w))),
@@ -577,7 +579,11 @@ def main(argv: list) -> int:
     def placement() -> dict:
         out = child("placement", size["placement_rows"])
         need(out["finite"] and out["moved"] > 0, f"degenerate epoch: {out}")
-        need(out["sparse_widths"] == [76, 76], f"76-wide rows were padded: {out}")
+        # on the chip the 76-wide values hold the row's label as a 77th
+        # column (of 80 sublanes), the dense rows in lane 2,000 of 2,048
+        need((out["sparse_widths"], out["label_slots"])
+             == (([76, 76], [None, None]) if rehearsal else ([76, 77], [2000, 76])),
+             f"76-wide rows were padded, or a label is not in its row: {out}")
         need(out["resident_copies"] == 0,
              f"the epoch program copies its resident rows: {out}")
         # on the chip the one wide array is stored padded to whole lanes,

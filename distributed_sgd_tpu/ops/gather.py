@@ -6,9 +6,11 @@ The one-hot formulation (ops/mxu.py) pays R*128 MACs a stored entry, so its
 step grows with D: 4.4x faster than this at RCV1's 47,236 features, 3x
 slower at 1,000,000.  XLA's scalar gather
 (ops/sparse.py: `take` of single words) is independent of D but serial,
-12.6 ns a word on a v5e.  What the chip does fast is gather whole ROWS (the
-step's own draw of resident rows is that operation), so both kernels here
-work on the lane-blocked view `w2 [R, 128]` of ops/mxu.py:
+12.6 ns a word on a v5e (why a row's label left the step's draw for a spare
+word of its stored row, parallel/mesh.py `label_slot`: 400 labels gathered
+word by word cost more than 400 rows).  What the chip does fast is gather
+whole ROWS (the step's own draw of resident rows is that operation), so
+both kernels here work on the lane-blocked view `w2 [R, 128]` of ops/mxu.py:
 
     margins  rows = w2[i // 128]             one 512-byte row an entry,
              m_b  = sum_p v_bp * rows[b, p, i_bp % 128]   the lane picked

@@ -118,8 +118,8 @@ class LocalSGDEngine:
             def body(carry, t):
                 wl, opt_s = carry
                 ids = jax.random.randint(jax.random.fold_in(key, t), (bs,), 0, shard_n)
-                bi, bv = bound.batch_rows(idx, val, ids)
-                g = model.grad(wl, SparseBatch(bi, bv), y[ids],
+                bi, bv, by = bound.draw_rows(idx, val, y, ids)
+                g = model.grad(wl, SparseBatch(bi, bv), by,
                                kernel=kernel, reduce="mean")
                 from distributed_sgd_tpu.parallel.sync import local_update
 

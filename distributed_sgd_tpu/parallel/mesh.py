@@ -127,7 +127,9 @@ def shard_dataset(data: Dataset, mesh: Mesh) -> Tuple[jax.Array, jax.Array, jax.
 # SHAPE and not a jax.experimental.layout.Format on it: an executable
 # compiled for a non-default parameter layout comes back from the
 # persistent compile cache expecting the default one (jax 0.9.0 / libtpu
-# 0.0.34, PERF.md section 6).
+# 0.0.34, PERF.md section 6).  The first lane of the padding holds the
+# row's label where `label_slot` says so (lane 2,000 of 2,048): the gather
+# that brings the row brings it.
 ROW_MAJOR_MAX_PADDING = 1.125
 _LANES, _SUBLANES = 128, 8
 _PAD_CHUNK = 4096  # rows re-laid-out per step of the one-off padding program
@@ -158,14 +160,21 @@ def _fill_by_chunks(rows: int, width: int, dtype, piece_at) -> jax.Array:
     return out
 
 
-def _pad_lanes(shard: jax.Array, width: int) -> jax.Array:
-    """One device's rows, zero-padded to `width`."""
-    return _fill_by_chunks(
-        shard.shape[0], width, shard.dtype,
-        lambda start, count: jax.lax.dynamic_slice_in_dim(shard, start, count, 0))
+def _pad_lanes(shard: jax.Array, *label: jax.Array, width: int) -> jax.Array:
+    """One device's rows, zero-padded to `width`; with `label` ([rows], the
+    rows' labels) each row's label in the first word past its values."""
+    def piece_at(start, count):
+        rows = jax.lax.dynamic_slice_in_dim(shard, start, count, 0)
+        if not label:
+            return rows
+        y = jax.lax.dynamic_slice_in_dim(label[0], start, count, 0)
+        return jnp.concatenate([rows, y.astype(shard.dtype)[:, None]], axis=1)
+
+    return _fill_by_chunks(shard.shape[0], width, shard.dtype, piece_at)
 
 
-def put_rows(arr, sharding: NamedSharding, width: Optional[int] = None) -> jax.Array:
+def put_rows(arr, sharding: NamedSharding, width: Optional[int] = None,
+             label=None) -> jax.Array:
     """Place one resident array, rows sharded over the workers, so that it
     is stored in the layout the step gathers from: as it comes, or (where
     `lane_width` says so) zero-padded to whole lanes, which the backend
@@ -173,18 +182,24 @@ def put_rows(arr, sharding: NamedSharding, width: Optional[int] = None) -> jax.A
     / .chunk): the padding is never read.  The padding runs once, on the
     devices, from the default placement.  `width`: the caller's own padded
     width on every platform (the labels of a model with an output axis,
-    which its readers WANT lane for lane beside the margins)."""
+    which its readers WANT lane for lane beside the margins).  `label`: the
+    rows' labels [rows], stored as the array's dtype in word `arr.shape[1]`
+    of each row (`label_slot`): the first lane of the padding, or one more
+    column of rows that stay rows-minor."""
     if width is None:
         width = lane_width(arr.shape, next(iter(sharding.device_set)).platform)
     elif width == arr.shape[1]:
         width = None
     name = "default" if width is None else "row_major"
+    if label is not None and width is None:
+        width = arr.shape[1] + 1  # rows-minor as before: no lane is padded
     with measure.span("sync.bind.place", layout=name, bytes=arr.nbytes):
         placed = jax.device_put(arr, sharding)
         if width is not None:
+            args = (placed,) if label is None else (placed, jax.device_put(label, sharding))
             placed = jax.jit(shard_map(
                 functools.partial(_pad_lanes, width=width), mesh=sharding.mesh,
-                in_specs=sharding.spec, out_specs=sharding.spec))(placed)
+                in_specs=(sharding.spec,) * len(args), out_specs=sharding.spec))(*args)
     metrics.counter(f"bind.rows.{name}").increment()
     return placed
 
@@ -205,7 +220,8 @@ def put_rows(arr, sharding: NamedSharding, width: Optional[int] = None) -> jax.A
 # PERF.md section 6, PR 30): one packed row a row 428.6 us a step, two
 # rows-minor arrays 444.0.  The packed draw wins by 15.4 us at 4 x the
 # bytes; rows of 8 entries and fewer (8 x) were not measured and stay two
-# arrays.
+# arrays.  Lane 2P, the first after the values' bits, holds the row's label
+# where `label_slot` says so (every packed width but 64).
 PACKED_MAX_PADDING = 4.0
 
 
@@ -218,15 +234,50 @@ def packed_width(width: int, platform: str) -> Optional[int]:
     return _LANES if _LANES <= PACKED_MAX_PADDING * stored else None
 
 
-def _pack_lanes(idx: jax.Array, val: jax.Array, lanes: int) -> jax.Array:
+# Where a row's label lies (label_slot).  A step draws its rows AND their
+# labels, and `y[ids]` is a gather of single 4-byte words, which the chip
+# walks one after the other (12.6 ns a word, ops/gather.py): 400 labels cost
+# 5.8 us where 400 rows of 76 words cost 3.8 (`rcv1-sync-1chip`), half of
+# `kdd2012-sync-1chip`'s draw (ledger, PR 32).  A row gather costs by the
+# row, not by the lanes used, and every placement above leaves a 32-bit
+# word of the stored row unused; a label written there arrives with the row
+# and the step gathers no label at all.  It rides as float32 (the values'
+# dtype; every `grad_coeff` casts its labels to float32 first, so the step
+# computes the same).  What has no such word (64-wide packed rows, whole
+# lanes, whole sublane groups), a label that is itself a row (an output
+# axis) and every platform but the TPU (no padding, no sublane groups: a
+# column more would be a copy more) keep the label the array it is.
+
+
+def label_slot(width: int, lanes: Optional[int], outputs: int, platform: str) -> Optional[int]:
+    """The word of a stored row in which `SyncEngine.bind` writes the row's
+    label, for rows of `width` values stored in `lanes` packed lanes
+    (`packed_width`'s answer; None: indices and values are two arrays) under
+    a model of `outputs` outputs: a lane of the packed row, a column of the
+    values array, or None where the label stays an array of its own."""
+    if platform != "tpu" or outputs > 1 or width == 0:
+        return None
+    if lanes is not None:  # packed: the lane after the values' bits
+        return 2 * width if 2 * width < lanes else None
+    if lane_width((1, width), platform) is not None:  # the padding's first lane
+        return width
+    # rows-minor, `width` rounded up to whole sublane groups: one more
+    # column where the last group has room (76 -> 77 of 80)
+    return width if width % _SUBLANES else None
+
+
+def _pack_lanes(idx: jax.Array, val: jax.Array, *label: jax.Array, lanes: int) -> jax.Array:
     """One device's rows as int32 [rows, lanes]: indices, then the values'
-    bits, then zeros."""
+    bits, then (with `label`, the rows' labels [rows]) the label's bits as
+    float32, then zeros."""
+    def bits(x, start, count):
+        return jax.lax.bitcast_convert_type(
+            jax.lax.dynamic_slice_in_dim(x, start, count, 0).astype(jnp.float32), jnp.int32)
+
     def piece_at(start, count):
-        return jnp.concatenate([
-            jax.lax.dynamic_slice_in_dim(idx, start, count, 0).astype(jnp.int32),
-            jax.lax.bitcast_convert_type(
-                jax.lax.dynamic_slice_in_dim(val, start, count, 0).astype(jnp.float32),
-                jnp.int32)], axis=1)
+        return jnp.concatenate(
+            [jax.lax.dynamic_slice_in_dim(idx, start, count, 0).astype(jnp.int32),
+             bits(val, start, count)] + [bits(y, start, count)[:, None] for y in label], axis=1)
 
     return _fill_by_chunks(idx.shape[0], lanes, jnp.int32, piece_at)
 
@@ -237,16 +288,19 @@ def unpack_rows(packed: jax.Array, width: int):
         packed[..., width:2 * width], jnp.float32)
 
 
-def put_packed(indices, values, lanes: int, sharding: NamedSharding) -> jax.Array:
+def put_packed(indices, values, lanes: int, sharding: NamedSharding, label=None) -> jax.Array:
     """Place a split's indices and values as ONE resident array of `lanes`
     32-bit lanes a row (`packed_width`), rows sharded over the workers.
-    The packing runs once, on the devices, from the default placement."""
+    The packing runs once, on the devices, from the default placement.
+    `label`: the rows' labels [rows], stored in lane `2 * width`
+    (`label_slot`) as float32's bits."""
+    args = (indices, values) if label is None else (indices, values, label)
     with measure.span("sync.bind.place", layout="packed",
                       bytes=indices.shape[0] * lanes * 4):
         packed = jax.jit(shard_map(
             functools.partial(_pack_lanes, lanes=lanes), mesh=sharding.mesh,
-            in_specs=(sharding.spec, sharding.spec), out_specs=sharding.spec))(
-                jax.device_put(indices, sharding), jax.device_put(values, sharding))
+            in_specs=(sharding.spec,) * len(args), out_specs=sharding.spec))(
+                *(jax.device_put(a, sharding) for a in args))
     metrics.counter("bind.rows.packed").increment()
     return packed
 

@@ -66,6 +66,7 @@ from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
     WORKER_AXIS,
     gather_replicated,
+    label_slot,
     packed_width,
     pcast_varying,
     put_packed,
@@ -83,16 +84,24 @@ class ShardedData(NamedTuple):
     values: jax.Array  # f32[N_pad, P], sharded over workers
     # [N_pad], or [N_pad, C] for a model with C outputs (bind() stores it
     # zero-padded to the lanes of the kernel's margins,
-    # LinearModel.label_lanes); sharded over workers; 0 = padding mask
+    # LinearModel.label_lanes); sharded over workers; 0 = padding mask.
+    # What the evaluation, `predict`'s callers and every step whose
+    # `label_slot` is None read
     labels: jax.Array
     n_true: int  # real sample count (host-side)
     # the rows' true width: bind() may store indices / values zero-padded
-    # to whole lanes (mesh.put_rows); None: the arrays are as wide as the rows
+    # to whole lanes (mesh.put_rows), `values` also one column wider for the
+    # label; None: the arrays are as wide as the rows
     width: Optional[int] = None
     # narrow sparse rows bind() stored as ONE array (mesh.put_packed):
     # `indices` then holds int32[N_pad, 128] rows of `width` indices and
     # `width` values' bits, and `values` is a zero-width placeholder
     packed: bool = False
+    # the word of a stored row that ALSO holds the row's label, as float32
+    # (mesh.label_slot): a lane of the packed row (its bits), else a column
+    # of `values` past `width`; the step reads it out of the rows it draws.
+    # None: the step gathers `labels`
+    label_slot: Optional[int] = None
 
     @property
     def is_dense(self) -> bool:
@@ -184,6 +193,18 @@ class BoundSync:
                   and data.width < data.values.shape[1])
         self._width = data.width if padded else None
         self._packed = data.width if data.packed else None
+        # whether the step reads a row's label out of the row it has drawn
+        # (mesh.label_slot) and gathers no label: static per binding
+        self.labels_in_row = data.label_slot is not None
+        if self.labels_in_row:
+            first = 2 * data.width if data.packed else data.width
+            stored = (data.indices if data.packed else data.values).shape[1]
+            if model.n_outputs > 1 or not first <= data.label_slot < stored:
+                raise ValueError(
+                    f"label_slot={data.label_slot} is no spare word of a stored row "
+                    f"(words {first}..{stored - 1}, one output)")
+        metrics.counter(
+            "bind.labels.in_row" if self.labels_in_row else "bind.labels.gathered").increment()
         n_pad = data.indices.shape[0]
         self.shard_n = n_pad // self.n_workers
         self.eval_chunk = min(eval_chunk, self.shard_n)
@@ -317,8 +338,7 @@ class BoundSync:
             ids = self._sample_ids(key, step)  # [K, B]
             if one:
                 ids = ids[0]
-            # the resident-row gathers
-            (bi, bv), by = self.batch_rows(idx, val, ids), y[ids]
+            bi, bv, by = self.draw_rows(idx, val, y, ids)  # the resident-row gathers
         if one:  # one worker's Gradient reply (Slave.scala:142-157)
             g = self.model.grad(w, SparseBatch(bi, bv), by, kernel=self.kernel)
         else:  # the K virtual workers' replies, summed (mean-normalized below)
@@ -390,7 +410,7 @@ class BoundSync:
         n = self.n_workers * self.virtual_workers
         with jax.named_scope("dsgd.draw"):
             ids = self._sample_ids(key, step)  # [K, B]
-            (bi, bv), by = self.batch_rows(idx, val, ids), y[ids]
+            bi, bv, by = self.draw_rows(idx, val, y, ids)
         width = bi.shape[-1]
         # the K virtual workers share the weights: one call on their merged
         # batches (kernels.merges_margins), one scatter of all their entries
@@ -468,12 +488,21 @@ class BoundSync:
         rows = jax.lax.dynamic_slice_in_dim(resident, start, self.eval_chunk, 0)
         return rows if self._width is None else rows[:, :self._width]
 
-    def batch_rows(self, idx, val, ids):
-        """(indices, values) of rows `ids`: two gathers, or one where bind()
-        packed a row's indices and values into one stored row."""
+    def draw_rows(self, idx, val, y, ids):
+        """(indices, values, labels) of rows `ids`, a step's draw: one gather
+        where bind() packed a row's indices and values into one stored row,
+        else two, and a third of single words out of `y` only where the
+        label does not ride in the row (ShardedData.label_slot; float32
+        where it does).  The one place that knows where a label lies."""
+        slot = self.data.label_slot
         if self._packed is not None:
-            return unpack_rows(idx[ids], self._packed)
-        return self.rows(idx, ids), self.rows(val, ids)
+            stored = idx[ids]
+            by = y[ids] if slot is None else jax.lax.bitcast_convert_type(
+                stored[..., slot], jnp.float32)
+            return unpack_rows(stored, self._packed) + (by,)
+        stored = val[ids]  # whole stored rows: the compiler's fast gather
+        bv = stored if self._width is None else stored[..., :self._width]
+        return self.rows(idx, ids), bv, y[ids] if slot is None else stored[..., slot]
 
     def chunk_rows(self, idx, val, start):
         """(indices, values) of the evaluation's chunk at `start`, likewise."""
@@ -488,15 +517,17 @@ class BoundSync:
         return self.model.from_layout(w, self.kernel)
 
     def _loop_labels(self, y):
-        """The labels a scan over steps gathers from.  Where the rows are
-        read in place there is no copy of them at the program's entry for
-        the compiler to hide a fetch behind, and it then fetches the WHOLE
-        label array into fast memory again in every step (1.6 us of a
-        24.7 us step at 491,520 labels, PERF.md section 6, PR 25); a float32
-        copy made once before the loop it keeps there.  Every grad_coeff
-        casts its labels to float32 first, so the step computes the same."""
-        if self._width is None or y.ndim == 2:  # rows of labels: one gather, no fetch
-            return y
+        """The labels a scan over steps gathers from, where it gathers any
+        (a label that rides in its row, ShardedData.label_slot, is never
+        read from here).  Where the rows are read in place there is no copy
+        of them at the program's entry for the compiler to hide a fetch
+        behind, and it then fetches the WHOLE label array into fast memory
+        again in every step (1.6 us of a 24.7 us step at 491,520 labels,
+        PERF.md section 6, PR 25); a float32 copy made once before the loop
+        it keeps there.  Every grad_coeff casts its labels to float32 first,
+        so the step computes the same."""
+        if self.labels_in_row or self._width is None or y.ndim == 2:
+            return y  # (rows of labels: one gather, no fetch)
         return y.astype(jnp.float32)
 
     def _epoch_shard(self, w, opt_state, idx, val, y, key):
@@ -859,23 +890,28 @@ class SyncEngine:
                 data.indices.shape[1], start, end,
                 labels_dtype=data.labels.dtype)
 
-            def put(arr):
+            def put(arr, label=None):
                 return jax.make_array_from_process_local_data(
                     sharding, arr, (total,) + arr.shape[1:]
                 )
-            lanes = None  # every process places its own rows, as they come
+            # every process places its own rows and labels, as they come
+            lanes = slot = None
         else:
             local = _pad_to_exact(data, total)
-            lanes = packed_width(
-                local.indices.shape[1], self.mesh.devices.flat[0].platform)
+            platform = self.mesh.devices.flat[0].platform
+            lanes = packed_width(local.indices.shape[1], platform)
+            # the spare 32-bit word of a stored row the label rides in
+            slot = label_slot(local.values.shape[1], lanes, self.model.n_outputs,
+                              platform) if local.values.dtype == np.float32 else None
 
-            def put(arr):
-                return put_rows(arr, sharding)
+            def put(arr, label=None):
+                return put_rows(arr, sharding, label=label)
+        riding = None if slot is None else local.labels
         if lanes is not None:
-            indices = put_packed(local.indices, local.values, lanes, sharding)
+            indices = put_packed(local.indices, local.values, lanes, sharding, label=riding)
             values = put(np.zeros((total, 0), np.float32))
         else:
-            indices, values = put(local.indices), put(local.values)
+            indices, values = put(local.indices), put(local.values, riding)
         label_lanes = self.model.label_lanes(kernel)
         sharded = ShardedData(
             indices=indices,
@@ -885,6 +921,7 @@ class SyncEngine:
             n_true=n_true,
             width=local.values.shape[1],
             packed=lanes is not None,
+            label_slot=slot,
         )
         bound = BoundSync(
             self.model,

@@ -606,3 +606,309 @@ def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices
     assert "f32[311296,128]" in evaluation  # a chunk's 4,096 x 76 gathered rows
     # entry-major: no [B, P, L] form of them, which was a copy of all of them
     assert "f32[4096,76,128]" not in evaluation
+
+
+# -- (j) a row's label rides in a spare word of the stored row ----------------------
+# (parallel/mesh.py `label_slot`, `BoundSync.draw_rows`; PERF.md section 6, PR 33)
+
+@pytest.mark.parametrize("width,lanes,outputs,on_tpu", [
+    (11, 128, 1, 22),       # kdd2012-logistic: 22 of 128 lanes used
+    (39, 128, 1, 78),       # criteo-logistic
+    (63, 128, 1, 126),      # the widest packed row with a lane to spare
+    (64, 128, 1, None),     # indices and values fill the row
+    (2000, None, 1, 2000),  # epsilon-logistic: the first of 48 padding lanes
+    (120, None, 1, 120),
+    (2048, None, 1, None),  # whole lanes: nothing is padded
+    (76, None, 1, 76),      # rcv1-hinge, rows-minor: 77 of 80 sublanes
+    (80, None, 1, None),    # whole sublane groups: a column more is a group more
+    (112, None, 1, None),
+    (76, None, 103, None),  # rcv1-topics-hinge: its label is a row of its own
+    (39, 128, 2, None),
+    (0, None, 1, None),
+])
+def test_the_labels_word_is_read_off_widths_outputs_and_platform(width, lanes, outputs, on_tpu):
+    if lanes is not None:
+        assert mesh_mod.packed_width(width, "tpu") == lanes
+    assert mesh_mod.label_slot(width, lanes, outputs, "tpu") == on_tpu
+    assert mesh_mod.label_slot(width, lanes, outputs, "cpu") is None
+
+
+_PLACEMENTS = ("packed", "padded", "minor")
+
+
+def _by_hand(data, devices, placement, riding):
+    """(mesh, ShardedData) of `data` placed as `bind` places it on a TPU,
+    built by hand so that the CPU runs it: `packed` one 128-lane row a row,
+    `padded` both arrays zero-padded to 128 lanes, `minor` as they come
+    (the values one column wider where the label rides)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import ShardedData
+
+    mesh = make_mesh(devices)
+    over_rows = NamedSharding(mesh, P("workers"))
+    n, width = data.values.shape
+    label = data.labels if riding else None
+    if placement == "packed":
+        indices = mesh_mod.put_packed(data.indices, data.values, 128, over_rows, label=label)
+        values = jax.device_put(np.zeros((n, 0), np.float32), over_rows)
+    else:
+        lanes = 128 if placement == "padded" else None
+        indices = mesh_mod.put_rows(data.indices, over_rows, width=lanes)
+        values = mesh_mod.put_rows(data.values, over_rows, width=lanes, label=label)
+    slot = (2 * width if placement == "packed" else width) if riding else None
+    return mesh, ShardedData(indices, values, jax.device_put(data.labels, over_rows), n,
+                             width, placement == "packed", slot)
+
+
+def _programs(model, mesh, sharded, workers, kernel="gather"):
+    from distributed_sgd_tpu.parallel.sync import BoundSync
+
+    bound = BoundSync(model, mesh, sharded, 8, 0.1, kernel=kernel, eval_chunk=32,
+                      virtual_workers=workers, steps_per_epoch=5)
+    w = jnp.asarray(np.random.default_rng(6).normal(
+        size=model.n_features).astype(np.float32) * 0.1)
+    key = jax.random.PRNGKey(2)
+    return bound, (np.asarray(bound.step(w, key)), np.asarray(bound.epoch(w, key)),
+                   np.asarray(bound.evaluate(w)), bound.predict(w))
+
+
+@pytest.mark.parametrize("update", ["dense", "sparse"])
+@pytest.mark.parametrize("workers", [1, 4])
+@pytest.mark.parametrize("placement", _PLACEMENTS)
+def test_a_label_in_the_row_is_the_gathered_label_bit_for_bit(
+        placement, workers, update, monkeypatch):
+    from distributed_sgd_tpu.ops import kernels
+
+    if update == "sparse":
+        monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    data = _narrow(n=256, p=11, seed=9)
+    model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
+    gathered, want = _programs(model, *_by_hand(data, 2, placement, False), workers)
+    riding, got = _programs(model, *_by_hand(data, 2, placement, True), workers)
+    assert (gathered.labels_in_row, riding.labels_in_row) == (False, True)
+    assert gathered.update_sparse == riding.update_sparse == (update == "sparse")
+    # the word holds the label as float32, past everything a reader takes
+    stored = np.asarray(riding.data.indices if placement == "packed" else riding.data.values)
+    held = stored[:, riding.data.label_slot]
+    np.testing.assert_array_equal(
+        held.view(np.float32) if placement == "packed" else held,
+        data.labels.astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(riding.data.labels), data.labels)
+    for name, a, b in zip(("step", "epoch", "evaluate", "predict"), got, want):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+@pytest.mark.parametrize("loss,labels", [("logistic", "int"), ("least_squares", "float")])
+def test_dense_rows_carry_their_label_in_the_first_padding_lane(loss, labels, workers):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import ShardedData
+
+    rng = np.random.default_rng(4)
+    x = rng.normal(size=(256, 24)).astype(np.float32)
+    y = (rng.choice([-1, 1], 256).astype(np.int32) if labels == "int"
+         else rng.normal(size=256).astype(np.float32))
+    model = make_model(loss, 1e-3, 24, regularizer="l2")
+    mesh = make_mesh(2)
+    over_rows = NamedSharding(mesh, P("workers"))
+
+    def sharded(riding):
+        return ShardedData(
+            jax.device_put(np.zeros((256, 0), np.int32), over_rows),
+            mesh_mod.put_rows(x, over_rows, width=128, label=y if riding else None),
+            jax.device_put(y, over_rows), 256, 24, False, 24 if riding else None)
+
+    _, want = _programs(model, mesh, sharded(False), workers, kernel="dense")
+    riding, got = _programs(model, mesh, sharded(True), workers, kernel="dense")
+    np.testing.assert_array_equal(np.asarray(riding.data.values)[:, 24], y.astype(np.float32))
+    for name, a, b in zip(("step", "epoch", "evaluate", "predict"), got, want):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+def _draw_gathers(bound):
+    """(gathers under `dsgd.draw`, the entry computation's parameter shapes)
+    of a binding's epoch program, compiled here."""
+    import re
+
+    d = bound.data
+    text = bound._epoch.lower(
+        jnp.zeros((bound.model.n_features,), jnp.float32), bound._opt_state, d.indices,
+        d.values, d.labels, jax.random.PRNGKey(0)).compile().as_text()
+    entry = text[text.index("ENTRY "):]
+    return (sum(1 for line in text.split("\n") if " gather(" in line and "dsgd.draw/" in line),
+            re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(", entry))
+
+
+@pytest.mark.parametrize("placement,gathers", [("packed", 2), ("padded", 3), ("minor", 3)])
+def test_the_epoch_program_of_a_riding_binding_gathers_no_label(placement, gathers):
+    from distributed_sgd_tpu.parallel.sync import BoundSync
+
+    data = _narrow(n=256, p=11, seed=9)
+    model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
+
+    def program(riding):
+        mesh, sharded = _by_hand(data, 1, placement, riding)
+        return _draw_gathers(BoundSync(model, mesh, sharded, 8, 0.1, kernel="gather",
+                                       eval_chunk=32, virtual_workers=4))
+
+    (before, took), (after, takes) = program(False), program(True)
+    # one gather fewer a step, and the label array is no argument at all
+    assert (before, after) == (gathers, gathers - 1)
+    assert "s32[256]" in took and "s32[256]" not in takes
+
+
+def _ride_everywhere(monkeypatch, placement="minor"):
+    """`bind` as on a TPU: the label's word by the TPU's rule, the rows
+    placed as `placement` says."""
+    from distributed_sgd_tpu.parallel import sync as sync_mod
+
+    monkeypatch.setattr(
+        sync_mod, "label_slot",
+        lambda width, lanes, outputs, platform: mesh_mod.label_slot(
+            width, lanes, outputs, "tpu"))
+    if placement == "packed":
+        _pack_everywhere(monkeypatch)
+    elif placement == "padded":
+        _pad_everywhere(monkeypatch)
+
+
+@pytest.mark.parametrize("placement", _PLACEMENTS)
+def test_bind_writes_the_label_where_the_rule_says_and_counts_it(placement, monkeypatch):
+    data = _narrow(n=512, p=11, seed=7)
+    model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
+    w = jnp.asarray(np.random.default_rng(6).normal(size=data.n_features).astype(np.float32))
+    key = jax.random.PRNGKey(2)
+
+    def run():
+        bound = SyncEngine(model, make_mesh(2), 16, 0.1, kernel="gather",
+                           virtual_workers=2).bind(data)
+        return bound, (np.asarray(bound.step(w, key)), np.asarray(bound.epoch(w, key)),
+                       np.asarray(bound.evaluate(w)), bound.predict(w))
+
+    plain, want = run()
+    assert plain.data.label_slot is None and not plain.labels_in_row
+    counters = ("bind.labels.in_row", "bind.labels.gathered")
+    before = [metrics_mod.counter(name).value for name in counters]
+    _ride_everywhere(monkeypatch, placement)
+    bound, got = run()
+    assert [metrics_mod.counter(name).value for name in counters] == [before[0] + 1, before[1]]
+    d = bound.data
+    assert (d.label_slot, d.packed, d.indices.shape, d.values.shape, d.labels.shape) == {
+        "packed": (22, True, (512, 128), (512, 0), (512,)),
+        "padded": (11, False, (512, 128), (512, 128), (512,)),
+        "minor": (11, False, (512, 11), (512, 12), (512,))}[placement]
+    for name, a, b in zip(("step", "epoch", "evaluate", "predict"), got, want):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("why,width,outputs,dtype", [
+    ("an output axis", 11, 3, np.float32),
+    ("whole sublane groups", 16, 1, np.float32),
+    ("values of another width than a label's", 11, 1, np.float16),
+])
+def test_bind_leaves_the_label_an_array_where_no_word_is_spare(
+        why, width, outputs, dtype, monkeypatch):
+    _ride_everywhere(monkeypatch)
+    rng = np.random.default_rng(3)
+    labels = rng.choice([-1, 1], (64, outputs) if outputs > 1 else 64).astype(np.int8)
+    data = Dataset(rng.integers(0, 500, (64, width)).astype(np.int32),
+                   rng.normal(size=(64, width)).astype(dtype), labels, 500)
+    model = make_model("hinge", 1e-3, 500, regularizer="l2", n_outputs=outputs)
+    before = metrics_mod.counter("bind.labels.gathered").value
+    bound = SyncEngine(model, make_mesh(2), 8, 0.1, kernel="scalar").bind(data)
+    assert bound.data.label_slot is None and not bound.labels_in_row, why
+    assert bound.data.values.shape == (64, width)
+    assert metrics_mod.counter("bind.labels.gathered").value == before + 1
+
+
+@pytest.mark.parametrize("slot,outputs", [(10, 1), (12, 1), (11, 2)])
+def test_a_label_slot_that_is_no_spare_word_is_refused(slot, outputs):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    mesh = make_mesh(1)
+    over_rows = NamedSharding(mesh, P("workers"))
+    labels = np.ones((64, outputs) if outputs > 1 else 64, np.int8)
+    data = ShardedData(jax.device_put(np.zeros((64, 11), np.int32), over_rows),
+                       jax.device_put(np.zeros((64, 12), np.float32), over_rows),
+                       jax.device_put(labels, over_rows), 64, 11, False, slot)
+    model = make_model("hinge", 1e-3, 500, regularizer="l2", n_outputs=outputs)
+    with pytest.raises(ValueError, match="no spare word"):
+        BoundSync(model, mesh, data, 8, 0.1, kernel="scalar")
+
+
+@pytest.mark.parametrize("riding,said", [(True, "in_row"), (False, "gathered")])
+def test_the_train_split_record_says_where_the_labels_lie(riding, said, caplog, monkeypatch):
+    import logging
+
+    from distributed_sgd_tpu.core.trainer import SyncTrainer
+
+    if riding:
+        _ride_everywhere(monkeypatch)
+    data = _narrow(n=256, p=11, seed=2)
+    model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
+    with caplog.at_level(logging.INFO, logger="dsgd.trainer"):
+        SyncTrainer(model, make_mesh(1), batch_size=16, learning_rate=0.1).fit(
+            data, data, max_epochs=1)
+    record = next(r.getMessage() for r in caplog.records
+                  if r.getMessage().startswith("train split:"))
+    assert f" labels={said} " in record and " outputs=1 " in record
+
+
+@pytest.mark.parametrize("placement", _PLACEMENTS)
+def test_local_sgd_draws_the_label_with_the_row_too(placement, monkeypatch):
+    data = _narrow(n=256, p=11, seed=8)
+    model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
+
+    def fit():
+        return LocalSGDEngine(model, make_mesh(2), 8, 0.1, sync_period=2,
+                              check_every=8, seed=1).fit(data, data, max_epochs=1).weights
+
+    want = fit()
+    before = metrics_mod.counter("bind.labels.in_row").value
+    _ride_everywhere(monkeypatch, placement)
+    np.testing.assert_array_equal(fit(), want)
+    assert metrics_mod.counter("bind.labels.in_row").value == before + 2  # train, test
+
+
+@pytest.mark.parametrize("cell,rows,width,stored,slot,kernel,d,gathers", [
+    # the row gathers the cell's step keeps; the ledger's `kCustom` label
+    # gather (PR 32: 5.76 / 6.25 / 4.10 us a step) is the one that goes
+    ("rcv1-sync-1chip", 4096 * 64, 76, (76, 77), 76, "mxu", 47_236, 2),
+    ("kdd2012-sync-1chip", 4096 * 64, 11, (128, 0), 22, "gather", 54_686_452, 1),
+    ("epsilon-sync-1chip", 4096 * 32, 2000, (0, 2048), 2000, "dense", 2000, 1),
+])
+def test_on_a_v5e_the_draw_of_a_riding_binding_is_its_row_gathers(
+        v5e, cell, rows, width, stored, slot, kernel, d, gathers):
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    assert mesh_mod.label_slot(width, mesh_mod.packed_width(width, "tpu"), 1, "tpu") == slot
+    mesh = Mesh(np.array(v5e.devices[:1]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+    model = (make_model("hinge", 1e-5, d, dim_sparsity=jnp.ones((d,), jnp.float32))
+             if kernel == "mxu" else make_model("logistic", 1e-7, d, regularizer="l2"))
+
+    def draws(riding):
+        values = stored[1] - (0 if riding or kernel != "mxu" else 1)
+        data = ShardedData(shape((rows, stored[0]), jnp.int32, sharding=over_rows),
+                           shape((rows, values), jnp.float32, sharding=over_rows),
+                           shape((rows,), jnp.int32, sharding=over_rows), rows, width,
+                           kernel == "gather", slot if riding else None)
+        bound = BoundSync(model, mesh, data, 100, 0.1, kernel=kernel, virtual_workers=4)
+        text = bound._epoch.lower(
+            shape((d,), jnp.float32, sharding=everywhere), (), data.indices, data.values,
+            data.labels, shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
+        fusions = [line for line in text.split("\n")
+                   if "kind=kCustom" in line and 'dsgd.draw/gather"' in line]
+        # a 1-D result is a gather of single words: the label's
+        return len(fusions), sum(1 for line in fusions if re.search(r"= \w+\[\d+\]", line))
+
+    assert (draws(False), draws(True)) == ((gathers + 1, 1), (gathers, 0)), cell
