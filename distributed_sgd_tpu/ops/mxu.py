@@ -280,8 +280,14 @@ def matvec_chunked(batch: SparseBatch, w2: jax.Array) -> jax.Array:
         return matvec(batch, w2)
 
     def body(_, t):
-        ci = jax.lax.dynamic_slice_in_dim(batch.indices, t * sub, sub, 0)
-        cv = jax.lax.dynamic_slice_in_dim(batch.values, t * sub, sub, 0)
+        # the evaluation's rows, fetched a second time: on the TPU each
+        # sub-chunk of 76-wide rows is also re-laid-out here (two
+        # `[512,76]` copies), which `eval_rows_ms` is there to read.  No
+        # step of a cell comes through the sub-scan (merged virtual workers
+        # and batches up to MATVEC_SUB take `matvec` in one piece)
+        with jax.named_scope("dsgd.eval_rows"):
+            ci = jax.lax.dynamic_slice_in_dim(batch.indices, t * sub, sub, 0)
+            cv = jax.lax.dynamic_slice_in_dim(batch.values, t * sub, sub, 0)
         return (), matvec(SparseBatch(ci, cv), w2)
 
     _, m = jax.lax.scan(body, (), jnp.arange(n // sub))

@@ -564,18 +564,23 @@ class BoundSync:
 
         def body(acc, t):
             loss_acc, hit_acc = acc
-            s = t * chunk
-            ci, cv = self.chunk_rows(idx, val, s)
-            cy = jax.lax.dynamic_slice_in_dim(y, s, chunk, 0)
-            mask = (cy != 0).astype(jnp.float32)
+            # the chunk's fetch, with whatever re-layout the compiler puts there
+            with jax.named_scope("dsgd.eval_rows"):
+                s = t * chunk
+                ci, cv = self.chunk_rows(idx, val, s)
+                cy = jax.lax.dynamic_slice_in_dim(y, s, chunk, 0)
+                mask = (cy != 0).astype(jnp.float32)
             # the same gather the step runs (models/linear.py `margins`)
             margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel)
-            losses = self.model.losses_from_margins(margins, cy)
-            preds = self.model.predict(margins)
-            hits = (preds == cy.astype(jnp.float32)).astype(jnp.float32)
-            return (loss_acc + jnp.sum(losses * mask), hit_acc + jnp.sum(hits * mask)), ()
+            with jax.named_scope("dsgd.eval_reduce"):
+                losses = self.model.losses_from_margins(margins, cy)
+                preds = self.model.predict(margins)
+                hits = (preds == cy.astype(jnp.float32)).astype(jnp.float32)
+                return (loss_acc + jnp.sum(losses * mask), hit_acc + jnp.sum(hits * mask)), ()
 
-        with jax.named_scope("dsgd.eval"):  # the margins inside keep dsgd.margins
+        # the pieces inside keep their own names (dsgd.eval_rows, dsgd.margins,
+        # dsgd.eval_reduce): the benchmark's boundary metrics read them
+        with jax.named_scope("dsgd.eval"):
             init = pcast_varying((jnp.float32(0), jnp.float32(0)), (AXIS,))
             (loss_sum, hit_sum), _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
             sums = jnp.stack([loss_sum, hit_sum])
@@ -588,10 +593,11 @@ class BoundSync:
         w_layout = self._to_kernel_layout(w)
 
         def body(_, t):
-            s = t * chunk
-            ci, cv = self.chunk_rows(idx, val, s)
-            return (), self.model.predict(
-                self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel))
+            with jax.named_scope("dsgd.eval_rows"):
+                ci, cv = self.chunk_rows(idx, val, t * chunk)
+            margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel)
+            with jax.named_scope("dsgd.eval_reduce"):
+                return (), self.model.predict(margins)
 
         with jax.named_scope("dsgd.eval"):
             _, preds = jax.lax.scan(body, (), jnp.arange(n_chunks))
@@ -771,13 +777,24 @@ class BoundSync:
         accuracy is over (sample, output) pairs.
         """
         # phases of the caller's span (trainer.evaluate, master.async.check):
-        # no histogram, and no span of their own outside one
+        # no histogram, and no span of their own outside one.  They tile the
+        # call, so the device's idle time inside it is one phase's.  The
+        # wait IS the first pull: its slice and its transfer are enqueued
+        # behind the running program and end with it, so the device idles
+        # there only for that transfer and the host's wake-up; the second
+        # pull and the regulariser are round trips the host starts once it
+        # is awake.  (A `block_until_ready(sums)` before the pulls starts the
+        # first one a wake-up and a launch late: 0.8 ms an evaluation on the
+        # v5e, 4 % of an `epsilon` period; PERF.md section 6, PR 34.)
         with measure.span("trainer.evaluate.dispatch", histogram=False, root=False):
             sums = self._eval(w, self.data.indices, self.data.values, self.data.labels)
+        with measure.span("trainer.evaluate.wait", histogram=False, root=False):
+            loss_sum = float(sums[0])
         with measure.span("trainer.evaluate.pull", histogram=False, root=False):
-            loss_sum, hit_sum = float(sums[0]), float(sums[1])
-            n = self.data.n_true
+            hit_sum = float(sums[1])
+        with measure.span("trainer.evaluate.reg", histogram=False, root=False):
             reg = self.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
+        n = self.data.n_true
         return reg + loss_sum / n, hit_sum / (n * self.model.n_outputs)
 
 

@@ -49,7 +49,9 @@ SPAN_NAME_ALLOWLIST = frozenset({
     "trainer.epoch",
     "trainer.evaluate",
     "trainer.evaluate.dispatch",
+    "trainer.evaluate.wait",
     "trainer.evaluate.pull",
+    "trainer.evaluate.reg",
     "trainer.bookkeeping",
     "trainer.criterion",
     "slave.async.iteration",

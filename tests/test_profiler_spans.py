@@ -15,6 +15,10 @@ The contracts under test:
 - `SyncTrainer.fit` and `HogwildEngine.fit` open every span of their loops
   with `epoch=` / `worker=` / `dispatch=`; `DSGD_PROFILE_DIR` traces one
   steady period;
+- `BoundSync.evaluate`'s four phases tile the caller's span in order, and
+  what it returns is the formula of before the phases, bit for bit; the
+  evaluation programs name their pieces (`dsgd.eval_rows`, `dsgd.margins`,
+  `dsgd.eval_reduce`) and the epoch program's scopes are what they were;
 - `SPAN_NAME_ALLOWLIST` holds exactly the names opened.
 """
 
@@ -26,11 +30,12 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from distributed_sgd_tpu import compile_cache
 from distributed_sgd_tpu.core.trainer import SyncTrainer
-from distributed_sgd_tpu.data.rcv1 import dim_sparsity, train_test_split
+from distributed_sgd_tpu.data.rcv1 import Dataset, dim_sparsity, train_test_split
 from distributed_sgd_tpu.data.synthetic import dense_regression, rcv1_like
 from distributed_sgd_tpu.models.linear import make_model
 from distributed_sgd_tpu.parallel.hogwild import HogwildEngine, _Worker
@@ -45,8 +50,11 @@ PACKAGE = os.path.dirname(os.path.dirname(os.path.abspath(measure.__file__)))
 
 STEP_SCOPES = {"dsgd.draw", "dsgd.margins", "dsgd.coeff", "dsgd.scatter",
                "dsgd.regularize", "dsgd.update"}
-SYNC_FIT_SPANS = {"trainer.epoch", "trainer.evaluate", "trainer.evaluate.dispatch",
-                  "trainer.evaluate.pull", "trainer.bookkeeping", "trainer.criterion"}
+# the phases of `BoundSync.evaluate`, in the order it opens them
+EVALUATE_PHASES = ("trainer.evaluate.dispatch", "trainer.evaluate.wait",
+                   "trainer.evaluate.pull", "trainer.evaluate.reg")
+SYNC_FIT_SPANS = {"trainer.epoch", "trainer.evaluate", *EVALUATE_PHASES,
+                  "trainer.bookkeeping", "trainer.criterion"}
 WORKER_PHASES = {"slave.async.drain", "slave.async.step", "slave.async.apply",
                  "slave.async.pull", "slave.async.push"}
 
@@ -176,18 +184,30 @@ def test_allowlist_holds_exactly_the_names_opened():
 def _scopes(lowered) -> set:
     text = lowered.as_text(dialect="hlo", debug_info=True)
     return {s for op in re.findall(r'op_name="([^"]*)"', text)
-            for s in re.findall(r"dsgd\.[a-z]+", op)}
+            for s in re.findall(r"dsgd\.[a-z_]+", op)}
 
 
-def _bound(kernel, virtual_workers, n_devices):
-    if kernel == "dense":
+def _bound(kernel, virtual_workers, n_devices, n_outputs=1, labels=False):
+    """`labels`: dense rows with class labels (the regression's evaluation
+    counts no hit and its `predict` is the margins themselves)."""
+    if n_outputs > 1:  # an output axis: `l2`, a row of labels a sample
+        data = rcv1_like(512, n_features=1000, nnz=8, noise=0.0, seed=3, n_outputs=n_outputs)
+        model = make_model("hinge", 1e-5, 1000, regularizer="l2", n_outputs=n_outputs)
+    elif kernel == "dense" and labels:
+        sparse = rcv1_like(512, n_features=64, nnz=8, noise=0.0, seed=3)
+        x = np.zeros((512, 64), np.float32)
+        np.add.at(x, (np.arange(512)[:, None], sparse.indices), sparse.values)
+        data = Dataset.dense(x, sparse.labels)
+        model = make_model("logistic", 1e-5, 64, regularizer="l2")
+    elif kernel == "dense":
         data = dense_regression(512, 64)
         model = make_model("least_squares", 1e-5, 64)
     else:
         data, model = _sparse_problem()
     engine = SyncEngine(model, make_mesh(n_devices), 16, 0.5, kernel=kernel,
                         virtual_workers=virtual_workers)
-    return engine.bind(data), jnp.zeros((model.n_features,), jnp.float32)
+    shape = (model.n_features,) + ((n_outputs,) if n_outputs > 1 else ())
+    return engine.bind(data), jnp.zeros(shape, jnp.float32)
 
 
 @pytest.mark.parametrize("kernel,virtual_workers,n_devices", [
@@ -209,6 +229,71 @@ def test_epoch_and_eval_programs_carry_every_scope(kernel, virtual_workers, n_de
     # the benchmark finds the programs by these names on `XLA Modules`
     assert "jit__epoch_shard" in epoch.as_text().split("\n")[0]
     assert "jit__eval_shard" in evaluation.as_text().split("\n")[0]
+
+
+@pytest.mark.parametrize("kernel,n_outputs", [
+    ("mxu", 1), ("gather", 1), ("dense", 1), ("gather", 3)])
+def test_evaluation_programs_name_their_pieces_and_the_epochs_scopes_stay(kernel, n_outputs):
+    """The benchmark's boundary metrics read `dsgd.eval_rows` (a chunk's
+    fetch), `dsgd.margins` / `dsgd.onehot` (the model's own) and
+    `dsgd.eval_reduce` (losses, hits, sums) inside `jit__eval_shard`; no
+    scope moves inside the epoch program, so every `*_us_per_step` reads
+    what it read."""
+    b, w = _bound(kernel, 4, 1, n_outputs=n_outputs, labels=True)
+    evaluation = b._eval.lower(w, b.data.indices, b.data.values, b.data.labels)
+    prediction = b._predict.lower(w, b.data.indices, b.data.values)
+    for lowered in (evaluation, prediction):
+        assert {"dsgd.eval", "dsgd.eval_rows", "dsgd.eval_reduce",
+                "dsgd.margins"} <= _scopes(lowered)
+        ops = re.findall(r'op_name="([^"]*)"', lowered.as_text(dialect="hlo", debug_info=True))
+        innermost = {found[-1] for op in ops if (found := re.findall(r"dsgd\.[a-z_]+", op))}
+        # the margins keep their own name innermost: no piece wraps another
+        assert {"dsgd.eval_rows", "dsgd.margins", "dsgd.eval_reduce"} <= innermost
+        assert not [op for op in ops if re.search(
+            r"dsgd\.(margins|onehot)/.*dsgd\.eval_(rows|reduce)"
+            r"|dsgd\.eval_(rows|reduce)/.*dsgd\.(margins|onehot|eval_)", op)]
+        # the chunk's fetch is under eval_rows, the sums under eval_reduce
+        assert [op for op in ops if re.search(r"dsgd\.eval_rows/dynamic_slice", op)]
+        assert [op for op in ops if re.search(r"dsgd\.eval_reduce/", op)]
+        # and all of them under dsgd.eval, not beside it (the compiler joins a
+        # called body's relative path to its caller's)
+        assert re.search(r'op_name="[^"]*dsgd\.eval/[^"]*dsgd\.eval_(rows|reduce)',
+                         lowered.compile().as_text())
+    assert "dsgd.allreduce" in _scopes(evaluation)
+    assert "jit__eval_shard" in evaluation.as_text().split("\n")[0]
+    # the epoch program: the step's scopes and nothing of the evaluation's
+    epoch = b._epoch.lower(w, b._opt_state, b.data.indices, b.data.values,
+                           b.data.labels, jax.random.PRNGKey(0))
+    want = STEP_SCOPES | {"dsgd.allreduce"}
+    if kernel == "mxu":
+        want |= {"dsgd.onehot", "dsgd.layout"}
+    if kernel == "gather":
+        want |= {"dsgd.layout"}
+    assert _scopes(epoch) == want
+
+
+def test_the_one_hot_evaluations_second_fetch_is_eval_rows_too():
+    """A chunk of more than `mxu.MATVEC_SUB` rows goes through the margins'
+    sub-scan, which slices it again (on the TPU: the `[512,76]` re-layout
+    copies): those slices are the evaluation's row fetch by name, the
+    matmul under them is still the margins', and no step comes this way."""
+    from distributed_sgd_tpu.ops import mxu
+
+    data = rcv1_like(4 * mxu.MATVEC_SUB, n_features=1000, nnz=8, noise=0.0, seed=3)
+    model = make_model("hinge", 1e-5, 1000, dim_sparsity=dim_sparsity(data))
+    b = SyncEngine(model, make_mesh(1), 16, 0.5, kernel="mxu", virtual_workers=4,
+                   eval_chunk=2 * mxu.MATVEC_SUB).bind(data)
+    w = jnp.zeros((1000,), jnp.float32)
+    text = b._eval.lower(w, b.data.indices, b.data.values, b.data.labels).as_text(
+        dialect="hlo", debug_info=True)
+    sliced = re.findall(r'(\w+\[[\d,]*\])\S* dynamic-slice\([^\n]*op_name="([^"]*)"', text)
+    rows = f"[{mxu.MATVEC_SUB},8]"
+    assert {op for shape, op in sliced if shape.endswith(rows)} == {
+        "dsgd.eval_rows/dynamic_slice"}
+    assert len([1 for shape, _op in sliced if shape.endswith(rows)]) == 2  # indices, values
+    epoch = b._epoch.lower(w, b._opt_state, b.data.indices, b.data.values,
+                           b.data.labels, jax.random.PRNGKey(0))
+    assert "dsgd.eval_rows" not in _scopes(epoch)
 
 
 @pytest.mark.parametrize("blocked", [False, True])
@@ -258,8 +343,8 @@ def test_sync_fit_yields_every_span_with_its_epoch(tmp_path):
         == [(2, "train"), (2, "test")]
     for name in ("trainer.bookkeeping", "trainer.criterion"):
         assert [int(e[3]["epoch"]) for e in by_name[name]] == [2]
-    # each evaluation holds its dispatch and its pull
-    for phase in ("trainer.evaluate.dispatch", "trainer.evaluate.pull"):
+    # each evaluation holds its four phases
+    for phase in EVALUATE_PHASES:
         assert len(by_name[phase]) == 2
         for child, parent in zip(by_name[phase], by_name["trainer.evaluate"]):
             assert _inside(child, parent)
@@ -280,6 +365,42 @@ def test_a_short_sync_fit_profiles_its_last_epoch(tmp_path):
         ("trainer.epoch", 1), ("trainer.evaluate", 1), ("trainer.evaluate", 1)]
 
 
+def test_the_four_phases_tile_an_evaluation_in_order(tmp_path):
+    """dispatch, wait, pull, reg: one after the other inside `trainer.evaluate`,
+    none overlapping, nothing of the call outside them but the spans' own
+    entries and exits (so the device's idle time inside an evaluation is the
+    four phases' and the benchmark's split of it sums to the whole)."""
+    _sync_fit(tmp_path, 5, criterion=lambda losses: False)
+    (line,) = _host_spans(tmp_path, {"trainer.evaluate", *EVALUATE_PHASES}).values()
+    evaluations = [e for e in line if e[2] == "trainer.evaluate"]
+    assert len(evaluations) == 2
+    for parent in evaluations:
+        phases = [e for e in line if e[2] != "trainer.evaluate" and _inside(e, parent)]
+        assert [e[2] for e in phases] == list(EVALUATE_PHASES)
+        for before, after in zip(phases, phases[1:]):
+            assert before[1] <= after[0]
+        covered = sum(e[1] - e[0] for e in phases)
+        # what lies between them is python entering and leaving eight annotations
+        assert parent[1] - parent[0] - covered < 2_000_000
+
+
+@pytest.mark.parametrize("kernel,n_outputs", [
+    ("mxu", 1), ("gather", 1), ("dense", 1), ("gather", 3)])
+def test_evaluate_returns_the_formula_of_before_the_phases_bit_for_bit(kernel, n_outputs):
+    """The parent's `evaluate`: the program's two sums pulled with `float()`,
+    the eager `lam*||w||^2`, in that order; the phases add no call of their
+    own (the wait is the first pull)."""
+    b, _w = _bound(kernel, 4, 1, n_outputs=n_outputs, labels=True)
+    shape = (b.model.n_features,) + ((n_outputs,) if n_outputs > 1 else ())
+    w = 0.1 * jax.random.normal(jax.random.PRNGKey(7), shape, jnp.float32)
+    sums = b._eval(w, b.data.indices, b.data.values, b.data.labels)
+    loss_sum, hit_sum = float(sums[0]), float(sums[1])
+    n = b.data.n_true
+    reg = b.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
+    assert b.evaluate(w) == (reg + loss_sum / n, hit_sum / (n * b.model.n_outputs))
+    assert 0.0 < hit_sum < n * b.model.n_outputs and loss_sum > 0.0  # a problem, not zeros
+
+
 def test_hogwild_fit_yields_every_span_with_worker_and_dispatch(tmp_path):
     data = rcv1_like(320, n_features=128, nnz=8, noise=0.0, seed=20)
     train, test = train_test_split(data)
@@ -292,7 +413,7 @@ def test_hogwild_fit_yields_every_span_with_worker_and_dispatch(tmp_path):
         result = engine.fit(train, test, max_epochs=2)
     assert result.state.updates > 0
     names = WORKER_PHASES | {"slave.async.iteration", "master.async.check",
-                             "trainer.evaluate.dispatch", "trainer.evaluate.pull"}
+                             *EVALUATE_PHASES}
     spans = _host_spans(tmp_path, names)
     flat = _flat(spans)
     assert {e[2] for e in flat} == names
@@ -311,8 +432,8 @@ def test_hogwild_fit_yields_every_span_with_worker_and_dispatch(tmp_path):
                     parent[3]["worker"], parent[3]["dispatch"])
     checks = [e for e in flat if e[2] == "master.async.check"]
     assert all(int(e[3]["updates"]) >= 0 for e in checks)
-    assert [e for e in flat if e[2] == "trainer.evaluate.pull"
-            and any(_inside(e, c) for c in checks)]
+    for phase in EVALUATE_PHASES:  # the shared evaluation's, inside a check
+        assert [e for e in flat if e[2] == phase and any(_inside(e, c) for c in checks)]
     # only the iteration and the check are filed as histograms
     span_hists = {h for h in metrics._hists if h.startswith("span.")}
     assert span_hists == {"span.slave.async.iteration", "span.master.async.check"}
