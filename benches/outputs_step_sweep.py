@@ -14,12 +14,18 @@ entries a step, the generator's own 1/r draw of feature ids):
   dense passes): the reading beside `SPARSE_UPDATE_MIN_FEATURES`;
 - `forms`: ONE call of each side of the step by formulation, us a call:
   (b) rows of weights gathered (`gather.matvec_rows`) and scattered
-  (`gather.scatter_rows_into` with the DMA write; XLA's row scatter-add
-  into zeros plus one pass over `W`), and (c) the batch made dense
-  (`X[400, D]` built by XLA's scatter) and two real matmuls with the dense
-  pass, in float32 (HIGHEST) and in one bf16 pass.
+  (`gather.scatter_rows_into` with the DMA write and with the merge pass;
+  XLA's row scatter-add into zeros plus one pass over `W`), and (c) the
+  batch made dense (`X[400, D]` built by XLA's scatter) and two real matmuls
+  with the dense pass, in float32 (HIGHEST) and in one bf16 pass;
+- `merge` (PR 35): ONE call of `gather.scatter_rows_into`'s two endings on
+  the same sorted entry rows, us a call with the sort and the entry rows
+  inside: the DMA a touched row (`_run_sums`, `_add_rows`, `_write_rows`)
+  against the merge pass (`_merge_rows`) by its block, piece and product
+  width, then both by the rows of `W2` from 47,240 up: the readings beside
+  `gather.MERGE_*` and `kernels.MERGE_MAX_ROWS_PER_ENTRY`.
 
-    python benches/outputs_step_sweep.py [--rehearse] [--only step,forms]
+    python benches/outputs_step_sweep.py [--rehearse] [--only step,forms,merge]
 
 Prints one JSON document (a line a row on stderr as it goes).  Refuses a
 CPU unless `--rehearse` (tiny shapes, no timing worth reading).
@@ -42,7 +48,8 @@ WORKERS, BATCH = 4, 100
 
 def main(argv) -> int:
     rehearse = "--rehearse" in argv
-    only = argv[argv.index("--only") + 1].split(",") if "--only" in argv else ("step", "forms")
+    only = (argv[argv.index("--only") + 1].split(",") if "--only" in argv
+            else ("step", "forms", "merge"))
     import jax
     import jax.numpy as jnp
 
@@ -92,7 +99,8 @@ def main(argv) -> int:
                 jax.block_until_ready(bound.epoch(w, key))
                 best = min(best, time.perf_counter() - t0)
             out["step"][name] = {"us": best / steps * 1e6, "kernel": bound.kernel,
-                                 "sparse": bound.update_sparse, "dma": bound.scatter_rows}
+                                 "sparse": bound.update_sparse, "dma": bound.scatter_rows,
+                                 "merge": bound.scatter_merge}
             print(json.dumps({name: out["step"][name]}), file=sys.stderr, flush=True)
 
     if "forms" in only:
@@ -114,10 +122,10 @@ def main(argv) -> int:
         def coeff_of(m):
             return model.grad_coeff(m, y) * (-lr / WORKERS)
 
-        def sparse_rows(w2, i):
+        def sparse_rows(w2, i, merge=False):
             b = batch_of(i)
             at, v, src, coeff = model.reply_rows(w2, b, y, None, -lr / WORKERS)
-            return gather.scatter_rows_into(w2, at, v, src, coeff, dma=dma)
+            return gather.scatter_rows_into(w2, at, v, src, coeff, dma=dma, merge=merge)
 
         def dense_rows(w2, i):
             b = batch_of(i)
@@ -136,6 +144,7 @@ def main(argv) -> int:
             "b_margins_rows": lambda w2, i: w2.at[0].add(
                 jnp.sum(gather.matvec_rows(batch_of(i), w2), axis=0)),
             "b_step_rows_into_carry": sparse_rows,
+            "b_step_rows_merged_into_carry": lambda w2, i: sparse_rows(w2, i, merge=dma),
             "b_step_dense_accumulator": dense_rows,
             "c_densify": lambda w2, i: w2.at[0, 0].add(jnp.sum(
                 jnp.zeros((rows, w2.shape[0]), jnp.float32).at[sample, batch_of(i).indices].add(
@@ -155,6 +164,78 @@ def main(argv) -> int:
                 best = min(best, time.perf_counter() - t0)
             out["forms"][name] = best / calls * 1e6
             print(json.dumps({name: out["forms"][name]}), file=sys.stderr, flush=True)
+    if "merge" in only:
+        # the scatter alone, a step's 30,400 entries (ids under the
+        # generator's law at every D') into W2 [D', 128]: today's path (a
+        # DMA a touched row) against the merge pass by its constants, then
+        # both by D' up to where the DMA path wins again
+        from jax.experimental.pallas import tpu as pltpu
+
+        calls, reps = (2, 1) if rehearse else (100, 2)
+        entries, samples = (600, 8) if rehearse else (WORKERS * BATCH * 76, WORKERS * BATCH)
+        rng = np.random.default_rng(35)
+        val = jnp.asarray(rng.normal(size=entries) * 0.1, jnp.float32)
+        src = jnp.asarray(rng.integers(0, samples, entries), jnp.int32)
+        coeff = jnp.asarray(rng.normal(size=(samples, 128)) * 0.01, jnp.float32)
+        dma = device.platform == "tpu"
+        interpreted = contextlib.nullcontext if dma else pltpu.force_tpu_interpret_mode
+
+        def ids_of(rows):  # P(id) ~ ln(1 + 1/r) over the features of `rows` weight rows
+            d = rows - 4  # `to_rows` pads them to whole sublanes
+            return d, jnp.asarray(np.minimum(np.exp(rng.uniform(
+                0.0, np.log(d + 1.0), entries)).astype(np.int64) - 1, d - 1), jnp.int32)
+
+        def timed(rows, after_entry_rows):
+            d, ids0 = ids_of(rows)
+
+            def call(i, w2):  # other ids every call, one law
+                ids, entry = gather._entry_rows((ids0 + i) % d, val, src, coeff)
+                return after_entry_rows(w2, ids, entry)
+
+            run = jax.jit(lambda w2: jax.lax.fori_loop(0, calls, call, w2))
+            w2 = jnp.zeros((rows, 128), jnp.float32)
+            best = float("inf")
+            with interpreted():
+                jax.block_until_ready(run(w2))
+                for _ in range(reps):
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(run(w2))
+                    best = min(best, time.perf_counter() - t0)
+            return best / calls * 1e6
+
+        def today(w2, ids, entry):
+            return gather._add_runs(w2, ids, entry, dma)
+
+        def merged(block, sub, wide):
+            return lambda w2, ids, entry: gather._merge_rows(w2, ids, entry, block, sub, wide)
+
+        rows = 1_000 if rehearse else 47_240
+        out["merge"] = {"calls": calls, "entries": entries, "by_constants": {}, "by_rows": {}}
+        # one call of each on the same weights: the pass against today's path
+        ids, entry = gather._entry_rows(ids_of(rows)[1], val, src, coeff)
+        w2 = jnp.asarray(rng.normal(size=(rows, 128)), jnp.float32)
+        with interpreted():
+            apart = jnp.abs(jax.jit(today)(w2, ids, entry)
+                            - jax.jit(gather._merge_rows)(w2, ids, entry))
+        out["merge"]["max_abs_apart"] = float(jnp.max(apart))
+        out["merge"]["rows_apart"] = int(jnp.sum(jnp.max(apart, axis=1) > 0))
+        print(json.dumps({k: out["merge"][k] for k in ("max_abs_apart", "rows_apart")}),
+              file=sys.stderr, flush=True)
+        table = out["merge"]["by_constants"]
+        table["sort_and_entry_rows_alone"] = timed(rows, lambda w2, ids, entry: w2.at[0].add(
+            jnp.sum(entry, axis=0) + jnp.sum(ids)))
+        table["today"] = timed(rows, today)
+        for block, sub, wide in ((256, 128, 1),) if rehearse else (
+                (512, 128, 1), (2048, 128, 1), (2048, 128, 2), (2048, 128, 4), (2048, 128, 8),
+                (2048, 64, 4), (2048, 256, 2), (4096, 128, 4)):
+            table[f"merge_block{block}_sub{sub}_wide{wide}"] = timed(
+                rows, merged(block, sub, wide))
+        print(json.dumps(table), file=sys.stderr, flush=True)
+        for factor in (1, 2) if rehearse else (1, 2, 3, 4, 8):
+            out["merge"]["by_rows"][rows * factor] = row = {
+                "today": timed(rows * factor, today),
+                "merge": timed(rows * factor, gather._merge_rows)}
+            print(json.dumps({rows * factor: row}), file=sys.stderr, flush=True)
     print(json.dumps(out))
     return 0
 

@@ -123,6 +123,7 @@ class SyncTrainer:
                  "merged" if bound_train.margins_merged else "per_worker",
                  bound_train.scatter_shards,
                  "sparse" if bound_train.update_sparse else "dense",
+                 "merge" if bound_train.scatter_merge else
                  "rows" if bound_train.scatter_rows else "words",
                  self.model.n_outputs,
                  "in_row" if bound_train.labels_in_row else "gathered", stored, " ".join(

@@ -45,6 +45,24 @@ rows, 76 us where XLA's word scatter took 384).  A hot id's increments are
 summed before they meet its weight, as the dense step's accumulator sums
 them.  Off the TPU the same rows are written by XLA's scatter.
 
+With an output axis (`scatter_rows_into`: an entry's update is a whole row
+of `w2 [D', L]`) the same steps cost a row an ENTRY: at `rcv1-topics-hinge`'s
+shape (30,400 entries a step on ~9,230 of 47,240 rows) 286 us after the sort
+and the entry rows, 135 of them the kernel's 9,230 DMAs (PERF.md section 5,
+PR 32).  Where `w2` is that small beside a step's entries
+(`kernels.merges_scatter`: at most 4 rows an entry) the sorted entries are
+MERGED into it instead (PR 35, `_merge_rows`): ONE kernel a step streams
+`w2` through VMEM in 1 MiB blocks and adds the band of sorted entries that
+falls on each block as a 0 / 1 product on the MXU, 128 entries against 128
+to 512 rows a product, the entry rows in three bfloat16 pieces so that
+float32 sums come out (`split3`): 126 + 17 us where the DMA path took 286
+(my chip runs, PR 35, `rcv1-topics-sync-1chip` traced).  What that pass
+pays is a product for every (chunk of entries, piece of rows) pair whether
+it holds one entry or 128, ~0.2 us each, and a pass over `w2`: near 6 rows
+an entry the DMA path is ahead again (the table beside
+`kernels.MERGE_MAX_ROWS_PER_ENTRY`), and `scatter_into`'s words (one lane of
+a row an entry, `w` of 219 MB) never ask.
+
 Measured on a v5e at D = 1,000,000, 15,600 entries a step, inside the
 compiled epoch (my chip runs, PR 26): margins 2.7 ns an entry (2.5 ns over
 the evaluation's 4,096-row chunks), scatter 6.9 ns with the four virtual
@@ -309,22 +327,251 @@ def scatter_add_rows(batch: SparseBatch, coeff: jax.Array, n_rows: int) -> jax.A
             batch.indices.reshape(-1)].add(cv.reshape(-1, coeff.shape[1]))
 
 
+# The merge pass (`_merge_rows`) moves `w2` through VMEM in blocks of
+# MERGE_BLOCK rows of 128 lanes (1 MiB; wider rows, fewer of them), builds
+# its 0 / 1 operand for MERGE_SUB rows at a time and multiplies a chunk of
+# entries against MERGE_WIDE such pieces in one product where its ids reach
+# that many.  Timed on a v5e, one call of `scatter_rows_into`'s whole work
+# (the sort and the entry rows, 88.5 us, inside) on 30,400 entries under
+# the generator's law into `w2 [47,240, 128]`, us a call
+# (`benches/outputs_step_sweep.py --only merge`; my chip runs, PR 35):
+#
+#     the DMA a touched row (`_add_runs`)          354.4
+#     block   512, piece 128, 1 a product          238.1
+#     block 2,048, piece 128, 1 a product          235.2
+#     block 2,048, piece 128, 2 a product          214.6
+#     block 2,048, piece 128, 4 a product          213.2
+#     block 2,048, piece 128, 8 a product          226.3
+#     block 2,048, piece  64, 4 a product          221.1
+#     block 2,048, piece 256, 2 a product          219.5
+#     block 4,096, piece 128, 4 a product          211.4
+#
+# A block costs ~0.2 us of its own (128-row blocks, 370 of them: 308.8 with
+# one piece a product), a product ~0.15 us whatever it holds, and a product
+# of several pieces less than as many apart while a chunk's ids reach that
+# far (the tail of the law; half the chunks lie in the first 128 rows and
+# are one piece each).  What did not pay, all else equal: chunks of 256 /
+# 512 / 1,024 entries (215.5 / 263.4 / 355.7 against 215.4: the 0 / 1
+# operand grows with rows x entries), pieces of 32 rows (235.3), two or
+# three chunks fetched ahead (217.1 / 215.8 against 215.2), the three
+# pieces stacked on the contraction for one result (213.3 against 215.2).
+MERGE_BLOCK = 2048
+MERGE_SUB = 128
+MERGE_WIDE = 4
+
+
+def merge_block(width: int) -> int:
+    """Rows of `width` lanes a block of the merge pass holds: MERGE_BLOCK
+    rows' bytes, in whole widest products."""
+    widest = MERGE_SUB * MERGE_WIDE
+    return max(MERGE_BLOCK * LANES // width // widest, 1) * widest
+
+
+def split3(x: jax.Array):
+    """float32 `x` as three bfloat16 pieces (hi, mid, lo) with
+    hi + mid + lo == x exactly in float32: 24 bits of significand, 8 a
+    piece (from |x| = 2**-103 up: below, the later pieces' bits lie under
+    float32's smallest normal, 2**-126, and are flushed to zero).  What
+    makes a one-pass bfloat16 product on the MXU exact where the other
+    operand is 0 / 1."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _merge_rows(w2: jax.Array, ids: jax.Array, entry: jax.Array, block: int = 0,
+                sub: int = MERGE_SUB, wide: int = MERGE_WIDE) -> jax.Array:
+    """`w2 [D', L]` with `entry[t]` added to row `ids[t]`, `ids` SORTED
+    ascending and T' whole chunks, in ONE streaming pass and in place: a TPU
+    kernel that moves `w2` through VMEM in blocks of `block` rows (0: by
+    `merge_block`; its own DMAs of whole blocks, the next one in and the last
+    one out while one is worked on) and adds to a block the chunks of CHUNK
+    sorted entries whose ids reach it (sorted, so one contiguous range,
+    counted before the call and handed over in scalar memory).  A chunk's
+    rows come from HBM one chunk ahead and are added as
+    `onehot[rows, CHUNK] . entry[CHUNK, L]` on the MXU, `onehot` = (the row
+    numbers == the chunk's ids), over the `sub`-row pieces of the block
+    between the chunk's first and last id, `wide` pieces a product while
+    that many are left.  Nothing is masked: an id outside a piece matches no
+    row of it, so a chunk that straddles two blocks is simply multiplied in
+    both, and the rows of a short last block past D' have no id and are not
+    written.  Float32-exact: the 0 / 1 operand is exact in bfloat16 and the
+    entry rows go in as their three bfloat16 pieces (`split3`), accumulated
+    in float32; a block's sums meet its weights once, when the block
+    leaves."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_rows, width = w2.shape
+    block = block or merge_block(width)
+    n_chunks = ids.shape[0] // CHUNK
+    n_blocks = pl.cdiv(n_rows, block)
+    final = n_blocks - 1  # the last block, of `short` rows
+    short = n_rows - final * block
+    by_chunk = ids.reshape(n_chunks, CHUNK)
+    first, last = by_chunk[:, 0], by_chunk[:, -1]
+    starts = jnp.arange(n_blocks, dtype=jnp.int32)[:, None] * block
+    # a block's chunks: from the first whose last id reaches its first row to
+    # the last whose first id lies before its end (counted, not searched: a
+    # binary search is a loop of its own inside the step's program, and
+    # `benchmark/reduce_trace.steps_of` would count its turns as steps)
+    lo = jnp.sum(last[None, :] < starts, axis=1, dtype=jnp.int32)
+    hi = jnp.sum(first[None, :] < starts + block, axis=1, dtype=jnp.int32)
+
+    def kernel(lo_ref, hi_ref, first_ref, last_ref, ids_ref, entry_ref, w_ref, out_ref,
+               held, summed, landed, pieces, sem_in, sem_out, sem_entry, newest):
+        def at_block(b, rows):
+            return pl.ds(pl.multiple_of(b * block, block), rows)
+
+        def load(b, rows):  # block b of the weights, HBM -> VMEM
+            return pltpu.make_async_copy(
+                w_ref.at[at_block(b, rows)], held.at[b % 2, pl.ds(0, rows)], sem_in.at[b % 2])
+
+        def store(b, rows):  # block b with its sums, VMEM -> HBM
+            return pltpu.make_async_copy(
+                summed.at[b % 2, pl.ds(0, rows)], out_ref.at[at_block(b, rows)],
+                sem_out.at[b % 2])
+
+        def fetch(c):  # chunk c's entry rows, HBM -> VMEM
+            return pltpu.make_async_copy(
+                entry_ref.at[pl.ds(pl.multiple_of(c * CHUNK, CHUNK), CHUNK)],
+                landed.at[c % 2], sem_entry.at[c % 2])
+
+        def add_chunk(b, c):
+            # The chunks come in ascending order over the whole pass, each
+            # the one before or its successor: a chunk seen for the first
+            # time has its DMA in flight since its predecessor was, starts
+            # its successor's, and leaves its three pieces in VMEM for the
+            # next block too, should it straddle the edge.
+            @pl.when(c > newest[0])
+            def _():
+                @pl.when(c + 1 < n_chunks)
+                def _():
+                    fetch(c + 1).start()
+
+                fetch(c).wait()
+                for k, part in enumerate(split3(landed[c % 2])):
+                    pieces[:, k * width:(k + 1) * width] = part
+                newest[0] = c
+
+            base = b * block
+            chunk_ids = ids_ref[pl.ds(c, 1), :]  # [1, CHUNK]
+
+            def add_pieces(p, count):  # `count` pieces from piece p on, one product
+                at = pl.multiple_of(p * sub, sub)
+                rows = jax.lax.broadcasted_iota(jnp.int32, (count * sub, CHUNK), 0)
+                onehot = jnp.where(rows == chunk_ids - (base + at), 1.0, 0.0).astype(
+                    jnp.bfloat16)
+                sums = jnp.dot(onehot, pieces[...], preferred_element_type=jnp.float32)
+                summed[b % 2, pl.ds(at, count * sub), :] += (
+                    sums[:, :width] + sums[:, width:2 * width] + sums[:, 2 * width:])
+
+            # the pieces between the chunk's first and last id: `wide` at a
+            # time while that many are left (the chunk's rows stay in the MXU
+            # for all of them), then one by one
+            p_lo = jnp.maximum(first_ref[c] - base, 0) // sub
+            p_hi = jnp.minimum(last_ref[c] - base, block - 1) // sub + 1
+            groups = (p_hi - p_lo) // wide
+            jax.lax.fori_loop(
+                0, groups, lambda g, carry: add_pieces(p_lo + g * wide, wide), None)
+            jax.lax.fori_loop(p_lo + groups * wide, p_hi,
+                              lambda p, carry: add_pieces(p, 1), None)
+
+        def pass_block(b, rows):
+            if rows == block and final > 0:  # inside the loop: a block follows
+                @pl.when(b + 1 < final)
+                def _():
+                    load(b + 1, block).start()
+
+                @pl.when(b + 1 == final)
+                def _():
+                    load(final, short).start()
+
+            @pl.when(b >= 2)
+            def _():  # the block that left this slot two blocks ago
+                store(b - 2, block).wait()
+
+            summed[b % 2] = jnp.zeros((block, width), jnp.float32)
+            jax.lax.fori_loop(lo_ref[b], hi_ref[b], lambda c, carry: add_chunk(b, c), None)
+            load(b, rows).wait()
+            summed[b % 2] += held[b % 2]
+            store(b, rows).start()
+
+        newest[0] = -1
+        fetch(0).start()
+        load(0, block if final > 0 else short).start()
+        jax.lax.fori_loop(0, final, lambda b, carry: pass_block(b, block), None)
+        pass_block(final, short)
+        if final > 0:
+            store(final - 1, block).wait()
+        store(final, short).wait()
+
+    merge = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(w2.shape, w2.dtype, vma=jax.typeof(w2).vma),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # the blocks' chunk ranges, the chunks' id ranges
+            grid=(1,),
+            in_specs=[
+                pl.BlockSpec((n_chunks, CHUNK), lambda *_: (0, 0)),  # the ids: VMEM
+                pl.BlockSpec(memory_space=pl.ANY),  # the entry rows
+                pl.BlockSpec(memory_space=pl.ANY),  # the weights
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((2, block, width), jnp.float32),  # blocks as they come in
+                pltpu.VMEM((2, block, width), jnp.float32),  # their sums, then they + sums
+                pltpu.VMEM((2, CHUNK, width), jnp.float32),  # chunks as they land
+                pltpu.VMEM((CHUNK, 3 * width), jnp.bfloat16),  # the newest chunk's pieces
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),  # the newest chunk that has landed
+            ],
+        ),
+        input_output_aliases={6: 0},
+        name="scatter_merge",
+    )
+    return merge(lo, hi, first, last, by_chunk, entry, w2)
+
+
 def scatter_rows_into(w2: jax.Array, ids: jax.Array, values: jax.Array, src: jax.Array,
-                      coeff: jax.Array, dma: bool = False) -> jax.Array:
+                      coeff: jax.Array, dma: bool = False, merge: bool = False) -> jax.Array:
     """`w2` with `values[t] * coeff[src[t]]` added to row `ids[t]`:
     `scatter_into` for updates that ARE rows, handed over as their factors
     (an entry's value and the sample it belongs to; the samples'
     coefficient rows `coeff [S, L]`), so that the sort moves three words an
     entry and no [T, L] array of updates is written before it.  The entries
-    are sorted by id, each takes its sample's coefficient row (a row gather
-    from a table of S rows), runs of an id are summed on the MXU
-    (`_run_sums`), and every touched row is fetched, added to and written
-    back once, as `scatter_into` does it."""
+    are sorted by id and each takes its sample's coefficient row (a row
+    gather from a table of S rows: `_entry_rows`, 23 + 39 + 17 us for 30,400
+    entries on a v5e).  Then `merge` (a TPU, `w2` small beside the step's
+    entries: `kernels.merges_scatter`): ONE pass over `w2` adds them all
+    (`_merge_rows`, 126 us at `w2 [47,240, 128]`); else the runs of an id
+    are summed on the MXU and every touched row is fetched, added to and
+    written back once, as `scatter_into` does it (`_add_runs`: 286 us at
+    that shape with the DMA write, which has no term in the rows of `w2`;
+    `dma` False: XLA's scatter writes them, off the TPU)."""
     with jax.named_scope("dsgd.scatter"):
-        pad = (0, -ids.shape[0] % CHUNK)  # the pad entry: 0.0 x sample 0 on feature 0
-        ids, src = jnp.pad(ids, pad), jnp.pad(src.astype(jnp.int32), pad)
-        values = jnp.pad(values.astype(jnp.float32), pad)
-        ids, values, src = jax.lax.sort((ids, values, src), num_keys=1, is_stable=False)
-        head = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
-        entry = values[:, None] * coeff.astype(jnp.float32)[src]  # [T', L]
-        return _add_rows(w2, ids, head, _run_sums(ids, entry), dma)
+        ids, entry = _entry_rows(ids, values, src, coeff)
+        return _merge_rows(w2, ids, entry) if merge else _add_runs(w2, ids, entry, dma)
+
+
+def _add_runs(w2: jax.Array, ids: jax.Array, entry: jax.Array, dma: bool) -> jax.Array:
+    """`w2` with `entry[t]` added to row `ids[t]` (sorted, whole chunks) a
+    row at a time: the runs of an id summed on the MXU, every touched row
+    fetched, added to and written back once."""
+    head = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+    return _add_rows(w2, ids, head, _run_sums(ids, entry), dma)
+
+
+def _entry_rows(ids: jax.Array, values: jax.Array, src: jax.Array, coeff: jax.Array):
+    """(ids int32[T'], entry f32[T', L]): a step's entries sorted by id,
+    padded to whole chunks with the pad entry (0.0 x sample 0 on feature 0),
+    each with its update row `values[t] * coeff[src[t]]`."""
+    pad = (0, -ids.shape[0] % CHUNK)
+    ids, src = jnp.pad(ids, pad), jnp.pad(src.astype(jnp.int32), pad)
+    values = jnp.pad(values.astype(jnp.float32), pad)
+    ids, values, src = jax.lax.sort((ids, values, src), num_keys=1, is_stable=False)
+    return ids, values[:, None] * coeff.astype(jnp.float32)[src]

@@ -21,12 +21,14 @@ and `core/worker.py` all ask it through `resolve`, which reads the
 platform off the device and counts the answer.  An explicit `kernel=`
 still overrides: the rule answers only for `AUTO`.
 
-Three more rules on a binding's shape live here, each asked once a
+Four more rules on a binding's shape live here, each asked once a
 binding by `BoundSync`: `merges_margins` (the K virtual workers' margins
 in one call), `ONE_ACCUMULATOR` (their entries scattered into one
-gradient) and `sparse_update` (no gradient at all: the entries scattered
+gradient), `sparse_update` (no gradient at all: the entries scattered
 into the carried weights, the regulariser a scalar on them, so that a
-step's bytes have no term in the feature count).
+step's bytes have no term in the feature count) and `merges_scatter`
+(with an output axis, whether that scatter is one pass over the weights
+or a DMA a touched row).
 """
 
 from __future__ import annotations
@@ -163,6 +165,8 @@ def merges_margins(kernel: str, row_width: int) -> bool:
 #
 # The sparse step is ahead by 2 %, as the table above has it at 4e6 words
 # (69.0 / 74.2): the constant holds for words as it held for features.
+# Since PR 35 that sparse step ends in the merge pass (`merges_scatter`) and
+# reads 288.3 against the dense form's 434.9 (the same sweep, PR 35).
 SPARSE_UPDATE_MIN_FEATURES = 4_000_000
 
 
@@ -186,6 +190,48 @@ def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
     return (kernel in ONE_ACCUMULATOR and regularizer in ("l2", "none")
             and plain_sgd and 0.0 <= decay < 1.0
             and n_features * n_outputs >= SPARSE_UPDATE_MIN_FEATURES)
+
+
+# Up to this many weight rows a step's entry, an output-axis binding's
+# sparse step ends in the merge pass (`merges_scatter`).  Timed on a v5e,
+# one call of `gather.scatter_rows_into` with each ending on 30,400 entries
+# (ids under the generator's law at every D'), `W2 [D', 128]`, us a call
+# (`benches/outputs_step_sweep.py --only merge`; my chip runs, PR 35):
+#
+#     D' =  47,240 (1.55 rows an entry)   a DMA a row 353.1   merged 215.3
+#     D' =  94,480 (3.1)                              371.7          269.1
+#     D' = 141,720 (4.7)                              403.2          323.3
+#     D' = 188,960 (6.2)                              422.5          434.1
+#     D' = 377,920 (12.4)                             572.5          729.9
+#
+# The DMA path has no term in D' but the ids the law spreads over more rows
+# (and `W2` leaving on-chip memory); the pass moves every row of `W2`
+# through VMEM and multiplies every 128-row piece a chunk's ids reach, ~2 us
+# a thousand rows.  They cross just under 6.2 rows an entry; the constant
+# sits under the crossing, between points where the pass is 28 and 20 % ahead.
+# `rcv1-topics-hinge` asks at 1.55 (47,236 features, 4 x 100 x 76 entries);
+# `kdd2012-logistic`'s words would read 12,400 and never ask (one output).
+MERGE_MAX_ROWS_PER_ENTRY = 4
+# Lanes of a weight row up to which the pass's blocks fit the VMEM a kernel
+# may use (`gather.merge_block`: four buffers of at least 512 rows)
+MERGE_MAX_LANES = 512
+
+
+def merges_scatter(n_features: int, n_outputs: int, n_entries: int) -> bool:
+    """Whether the sparse step of weights with an output axis adds a step's
+    sorted entries to `W2` in ONE streaming pass over it
+    (`gather._merge_rows`: every block of weight rows through VMEM once, its
+    band of the entries placed by a 0 / 1 product on the MXU) instead of
+    fetching, adding to and writing back every touched row by itself
+    (`gather._add_rows`, a DMA a row): where `W2` is small beside what a
+    step touches, `n_features` rows against `n_entries` entries of ALL the
+    mesh's workers.  From shapes alone; a TPU's question (`BoundSync` asks
+    once a binding where the DMA kernel would run and counts the answer
+    under `bind.scatter.merge`)."""
+    from distributed_sgd_tpu.ops import gather
+
+    return (n_features <= MERGE_MAX_ROWS_PER_ENTRY * n_entries
+            and gather.output_lanes(n_outputs) <= MERGE_MAX_LANES)
 
 
 def resolve(kernel: Optional[str], n_features: int, row_width: int,

@@ -236,13 +236,20 @@ class BoundSync:
             model.n_features, model.n_outputs)
         if self.update_sparse:
             metrics.counter("bind.update.sparse").increment()
-        # whether that scatter writes each touched row back by the DMA
-        # kernel (gather.scatter_into): on a TPU, by the kernel rule's own
-        # platform probe; elsewhere XLA writes the same rows
-        self.scatter_rows = self.update_sparse and mxu.blocked_pays_off(
-            mesh.devices.flat[0])
-        if self.scatter_rows:
-            metrics.counter("bind.scatter.rows").increment()
+        # which kernel of ours that scatter ends in, on a TPU (the kernel
+        # rule's own platform probe; elsewhere XLA writes the same rows):
+        # with an output axis and weights small beside a step's entries
+        # (kernels.merges_scatter) ONE pass over the weights that merges the
+        # sorted entries in (gather.scatter_rows_into), else a DMA a touched
+        # row (gather.scatter_into)
+        on_tpu = self.update_sparse and mxu.blocked_pays_off(mesh.devices.flat[0])
+        self.scatter_merge = (on_tpu and model.n_outputs > 1 and kernels.merges_scatter(
+            model.n_features, model.n_outputs,
+            self.n_workers * self.virtual_workers * self.batch_size * row_width))
+        self.scatter_rows = on_tpu and not self.scatter_merge
+        if on_tpu:
+            metrics.counter(
+                "bind.scatter.merge" if self.scatter_merge else "bind.scatter.rows").increment()
         sspec = jax.tree.map(lambda _: P(), self._opt_state)
 
         dspec = (P(AXIS), P(AXIS), P(AXIS))
@@ -457,7 +464,8 @@ class BoundSync:
                    + samples * jnp.arange(every.shape[0], dtype=jnp.int32)[:, None]).reshape(-1)
             coeff = jax.lax.bitcast_convert_type(
                 every[:, 3 * t:], jnp.float32).reshape(-1, lanes)
-        return gather.scatter_rows_into(v2, at, val, src, coeff, dma=self.scatter_rows)
+        return gather.scatter_rows_into(v2, at, val, src, coeff, dma=self.scatter_rows,
+                                        merge=self.scatter_merge)
 
     def _sparse_steps(self, v2, idx, val, y, key):
         """`steps_per_epoch` sparse steps on blocked weights, folded."""

@@ -406,3 +406,252 @@ def test_main_builds_the_model_its_labels_ask_for(monkeypatch):
     assert (binary.n_outputs, binary.regularizer) == (1, "dim_sparsity")
     with pytest.raises(ValueError, match="labels"):
         Config(labels="every")
+
+
+# -- (vi) the merge pass: a step's sorted entries added to W2 in ONE pass (PR 35) ------------------
+#
+# `gather._merge_rows` in Pallas' TPU interpret mode against the float64
+# scatter-add and against the path it replaces (`scatter_rows_into` with
+# XLA's write).  Small blocks, so that a few hundred weight rows hold every
+# kind of block edge: (rows of W2, lanes, entries, block, piece of a block,
+# pieces a product at most).
+
+MERGE_SHAPES = {
+    "default_constants": (3_000, 128, 3_000, gather.merge_block(128), gather.MERGE_SUB,
+                          gather.MERGE_WIDE),
+    "small_blocks": (328, 128, 3_000, 64, 16, 2),    # 328 = 5 x 64 + 8: a short last block
+    "one_piece_a_block": (328, 128, 3_000, 32, 32, 1),
+    "one_product_a_block": (328, 128, 3_000, 64, 16, 4),
+    "one_block": (40, 128, 700, 64, 16, 4),          # D' under one block
+    "two_lane_groups": (200, 256, 1_500, 64, 16, 4),  # C over 128
+}
+MERGE_CASES = ["law", "hot_run", "edges", "empty_blocks", "pads", "last_rows"]
+
+
+def _merge_case(name, n_rows, n_entries, block):
+    """ids of `n_entries` entries into `n_rows` weight rows."""
+    rng = np.random.default_rng(35)
+    # the generator's law: P(r) ~ ln(1 + 1/r), half the entries in the first rows
+    ids = np.minimum(np.exp(rng.uniform(0, np.log(n_rows + 1), n_entries)).astype(np.int64) - 1,
+                     n_rows - 1)
+    if name == "hot_run":  # one id's run over many chunks and a whole block's worth of entries
+        ids[: max(5 * gather.CHUNK, 2 * block)] = min(block + 3, n_rows - 1)
+    elif name == "edges":  # the last row of every block and the first of the next, nothing between
+        edges = np.arange(block, n_rows, block)
+        ids = rng.choice(np.concatenate([edges - 1, edges, [0, n_rows - 1]]), n_entries)
+    elif name == "empty_blocks":  # every other block has no entry; a chunk spans the gap
+        ids = ids[(ids // block) % 2 == 0][: n_entries - 37]
+    elif name == "pads":  # no whole chunk, and a tenth of the entries the pad entry itself
+        ids = ids[: n_entries - 50]
+        ids[::10] = 0
+    elif name == "last_rows":  # the short last block, its last row too
+        ids[:300] = rng.integers(max((n_rows - 1) // block * block - 4, 0), n_rows, 300)
+        ids[300] = n_rows - 1
+    return ids.astype(np.int32)
+
+
+def _merge_inputs(shape, case):
+    n_rows, lanes, n_entries, block, sub, wide = MERGE_SHAPES[shape]
+    rng = np.random.default_rng(36)
+    ids = _merge_case(case, n_rows, n_entries, block)
+    values = rng.normal(size=len(ids)).astype(np.float32)
+    if case == "pads":
+        values[::10] = 0.0
+    samples = 24
+    src = rng.integers(0, samples, len(ids)).astype(np.int32)
+    coeff = (rng.normal(size=(samples, lanes)) * 1e-2).astype(np.float32)
+    w2 = (rng.normal(size=(n_rows, lanes)) * 3.0).astype(np.float32)
+    if case == "hot_run":  # a weight whose ulp is the size of an entry's twentieth
+        w2[min(block + 3, n_rows - 1)] = 4096.0
+    return w2, ids, values, src, coeff, (block, sub, wide)
+
+
+@pytest.mark.parametrize("case", MERGE_CASES)
+@pytest.mark.parametrize("shape", sorted(MERGE_SHAPES))
+def test_the_merge_pass_is_the_float64_scatter_add(shape, case):
+    from jax.experimental.pallas import tpu as pltpu
+
+    w2, ids, values, src, coeff, cut = _merge_inputs(shape, case)
+    block = cut[0]
+    rows = values[:, None].astype(np.float64) * coeff[src].astype(np.float64)
+    want = w2.astype(np.float64)
+    np.add.at(want, ids, rows)
+    size = np.abs(w2).astype(np.float64)  # what float32 may round: every term's size
+    np.add.at(size, ids, np.abs(rows))
+
+    def merged(w2):
+        at, entry = gather._entry_rows(jnp.asarray(ids), jnp.asarray(values),
+                                       jnp.asarray(src), jnp.asarray(coeff))
+        assert at.shape[0] % gather.CHUNK == 0
+        return gather._merge_rows(w2, at, entry, *cut)
+
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(jax.jit(merged)(jnp.asarray(w2)))
+    # float32 sums of float32 rows: a product rounded to bfloat16 would be
+    # off by 4e-3 of its size
+    assert np.all(np.abs(got - want) <= 1e-6 * size)
+    untouched = np.setdiff1d(np.arange(len(w2)), ids)
+    np.testing.assert_array_equal(got[untouched], w2[untouched])
+    # and the path it replaces, which sums the same rows in another order
+    xla = np.asarray(jax.jit(gather.scatter_rows_into)(
+        jnp.asarray(w2), jnp.asarray(ids), jnp.asarray(values), jnp.asarray(src),
+        jnp.asarray(coeff)))
+    assert np.all(np.abs(got - xla) <= 2e-6 * size)
+    if case == "hot_run":  # summed before it meets its weight: ONE rounding at the weight's ulp
+        hot = min(block + 3, len(w2) - 1)
+        assert np.sum(ids == hot) > 4 * gather.CHUNK and np.all(w2[hot] == 4096.0)
+        one_by_one = np.float32(4096.0) + np.zeros_like(w2[hot])
+        for row in rows[ids == hot].astype(np.float32):
+            one_by_one += row
+        assert np.all(np.abs(got[hot] - want[hot])
+                      <= 0.5 * np.spacing(np.float32(4096.0)) + 1e-6 * (size[hot] - 4096.0))
+        assert np.abs(got[hot] - want[hot]).max() < 0.2 * np.abs(one_by_one - want[hot]).max()
+
+
+def test_merged_through_scatter_rows_into_is_the_same_call():
+    from jax.experimental.pallas import tpu as pltpu
+
+    w2, ids, values, src, coeff, cut = _merge_inputs("default_constants", "law")
+    args = tuple(jnp.asarray(a) for a in (w2, ids, values, src, coeff))
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(jax.jit(lambda *a: gather.scatter_rows_into(*a, merge=True))(*args))
+        at, entry = gather._entry_rows(*args[1:])
+        want = np.asarray(gather._merge_rows(args[0], at, entry, *cut))
+    np.testing.assert_array_equal(got, want)
+
+
+def test_the_three_pieces_sum_back_to_the_float32_bit_for_bit():
+    rng = np.random.default_rng(37)
+    x = np.concatenate([
+        rng.normal(size=4096) * np.exp(rng.uniform(-60, 60, 4096)),
+        [0.0, -0.0, 1.0, -1.0, 1 + 2**-23, 1 - 2**-24, 2.0**-103, 3e38, -3e38,
+         np.float32(1 / 3), np.float32(np.pi)]]).astype(np.float32)
+    hi, mid, lo = (np.asarray(p.astype(jnp.float32)) for p in jax.jit(gather.split3)(x))
+    np.testing.assert_array_equal((hi + mid) + lo, x)
+    assert all(p.dtype == jnp.bfloat16 for p in gather.split3(jnp.asarray(x)))
+    # each piece takes its eight bits: the second is under an ulp of the first
+    assert np.all(np.abs(mid) <= np.abs(x) * 2.0**-8)
+    assert np.all(np.abs(lo) <= np.abs(x) * 2.0**-16)
+    # under 2**-103 the later pieces' bits lie under float32's smallest
+    # normal, which is flushed to zero: what is lost is under 2**-126
+    tiny = (rng.normal(size=64) * 2.0**-120).astype(np.float32)
+    hi, mid, lo = (np.asarray(p.astype(jnp.float32)) for p in gather.split3(jnp.asarray(tiny)))
+    assert np.all(np.abs((hi + mid) + lo - tiny) <= 2.0**-126)
+
+
+@pytest.fixture
+def merging(monkeypatch):
+    """The platform probe answers "a TPU", the sparse update has no floor
+    and Pallas runs the kernels in its TPU interpret mode."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import mxu
+
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.mark.parametrize("reg", ["l2", "none"])
+def test_an_epoch_with_the_merge_pass_is_the_epoch_xla_writes(reg, monkeypatch):
+    """Three steps through `BoundSync` with the rule's answer forced on (the
+    kernel interpreted) against the same steps written by XLA's scatter:
+    one formulation up to the order a row's entries are summed in."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import mxu
+
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    data, w, key = _rows(), _weights(), jax.random.PRNGKey(11)
+    model = make_model("hinge", LAM, D, regularizer=reg, n_outputs=C)
+
+    def epoch():
+        bound = SyncEngine(model, make_mesh(1), BATCH, LR, eval_chunk=64,
+                           virtual_workers=4).bind(data, 3)
+        return bound, np.asarray(bound.epoch(w, key))
+
+    bound, want = epoch()
+    assert bound.update_sparse and not bound.scatter_merge and not bound.scatter_rows
+    monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
+    with pltpu.force_tpu_interpret_mode():
+        bound, got = epoch()
+    assert bound.scatter_merge and not bound.scatter_rows
+    moved = np.abs(want - np.asarray(w)).max()
+    assert moved > 1e-3
+    np.testing.assert_allclose(got - np.asarray(w), want - np.asarray(w), rtol=2e-5, atol=2e-7)
+
+
+# -- the rule ----------------------------------------------------------------------------------
+
+@pytest.mark.parametrize("features,outputs,entries,said", [
+    (47_236, 103, 30_400, True),            # rcv1-topics-hinge: 1.55 rows an entry
+    (47_236, 103, 4 * 30_400, True),        # its step over four chips' workers
+    (47_236, 300, 30_400, True),            # three lane groups
+    (30_400 * kernels.MERGE_MAX_ROWS_PER_ENTRY, 103, 30_400, True),
+    (30_400 * kernels.MERGE_MAX_ROWS_PER_ENTRY + 1, 103, 30_400, False),  # past the crossing
+    (54_686_452, 103, 4_400, False),        # kdd2012's feature count with outputs
+    (47_236, 512, 30_400, True),
+    (47_236, 513, 30_400, False),           # five lane groups: rows no block of the pass holds
+])
+def test_the_merge_rule_answers_from_shapes_alone(features, outputs, entries, said):
+    assert kernels.merges_scatter(features, outputs, entries) is said
+
+
+def _count(name):
+    return metrics_mod.counter(name).value
+
+
+def test_on_a_tpu_a_binding_with_outputs_merges_and_counts_it(merging, monkeypatch, caplog):
+    import logging
+
+    asked = []
+    rule = kernels.merges_scatter
+    monkeypatch.setattr(kernels, "merges_scatter", lambda *a: asked.append(a) or rule(*a))
+    merge, rows = _count("bind.scatter.merge"), _count("bind.scatter.rows")
+    bound = _bind(_rows())
+    assert bound.update_sparse and bound.scatter_merge and not bound.scatter_rows
+    assert asked == [(D, C, 4 * BATCH * P)]  # once a binding, the shapes alone
+    assert (_count("bind.scatter.merge"), _count("bind.scatter.rows")) == (merge + 1, rows)
+    bound.step(_weights(), jax.random.PRNGKey(0))
+    assert len(asked) == 1 and _count("bind.scatter.merge") == merge + 1  # no trace, no run
+    # past the crossing the same binding keeps the DMA a row
+    monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 1)
+    bound = _bind(_rows())
+    assert bound.scatter_rows and not bound.scatter_merge
+    assert (_count("bind.scatter.merge"), _count("bind.scatter.rows")) == (merge + 1, rows + 1)
+    # one output: never asked, whatever its shapes would say
+    monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 10**6)
+    asked.clear()
+    flat = _bind(_rows(n_outputs=1), n_outputs=1, kernel="gather")
+    assert flat.update_sparse and flat.scatter_rows and not flat.scatter_merge and not asked
+    assert _count("bind.scatter.merge") == merge + 1
+    # and the train-split record says which
+    model = make_model("hinge", LAM, D, regularizer="l2", n_outputs=C)
+    data = _rows()
+    with caplog.at_level(logging.INFO, logger="dsgd.trainer"):
+        SyncTrainer(model, make_mesh(1), BATCH, LR, virtual_workers=4).fit(
+            data.slice(slice(0, 384)), data.slice(slice(384, None)), max_epochs=1)
+    record = next(r.getMessage() for r in caplog.records
+                  if r.getMessage().startswith("train split:"))
+    assert "update=sparse scatter=merge outputs=5" in record
+
+
+def test_off_the_tpu_no_binding_merges(monkeypatch):
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    monkeypatch.setattr(kernels, "merges_scatter", lambda *a: pytest.fail("asked off the TPU"))
+    merge = _count("bind.scatter.merge")
+    bound = _bind(_rows())
+    assert bound.update_sparse and not bound.scatter_merge and not bound.scatter_rows
+    assert _count("bind.scatter.merge") == merge
+
+
+def test_the_merged_epoch_program_carries_the_scatters_scope(merging):
+    bound = _bind(_rows())
+    assert bound.scatter_merge
+    d, w, key = bound.data, _weights(), jax.random.PRNGKey(0)
+    lowered = bound._epoch.lower(w, bound._opt_state, d.indices, d.values, d.labels, key)
+    assert _scopes(lowered) == {
+        "dsgd.draw", "dsgd.margins", "dsgd.coeff", "dsgd.scatter", "dsgd.update",
+        "dsgd.allreduce", "dsgd.layout", "dsgd.rescale"}
+    assert "dsgd.scatter/scatter_merge" in lowered.as_text(debug_info=True)

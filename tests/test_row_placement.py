@@ -569,9 +569,11 @@ def test_on_a_v5e_no_step_of_the_sparse_epoch_passes_over_the_weights(v5e, devic
 @pytest.mark.parametrize("devices", [1, 4])
 def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices):
     """`rcv1-topics-hinge`'s programs compile for the chip (nothing ran):
-    the update is the DMA kernel on 128-lane rows of OUTPUTS scattered into
-    the carry, the entries cross the mesh as factors in ONE all-gather, and
-    the evaluation's gathered rows split into [P, B, L] where they lie."""
+    the update is ONE kernel a step, the merge pass over the carry's
+    128-lane rows of OUTPUTS (`kernels.merges_scatter`: 47,236 rows against
+    30,400 entries), the entries cross the mesh as factors in ONE
+    all-gather, and the evaluation's gathered rows split into [P, B, L]
+    where they lie."""
     import re
 
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -588,14 +590,16 @@ def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices
     model = make_model("hinge", 1.7e-7, d, regularizer="l2", n_outputs=c)
     bound = BoundSync(model, mesh, data, 100, 0.25, kernel="gather",
                       virtual_workers=4 // devices)
-    assert bound.update_sparse and bound.scatter_rows
+    assert bound.update_sparse and bound.scatter_merge and not bound.scatter_rows
     w = shape((d, c), jnp.float32, sharding=everywhere)
     step = bound._step.lower(w, (), data.indices, data.values, data.labels,
                              shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
     kernel = [line for line in step.split("\n") if " custom-call(" in line
               and 'custom_call_target="tpu_custom_call"' in line]
     assert len(kernel) == 1 and "f32[47240,128]" in kernel[0]
-    assert "dsgd.scatter/scatter_rows" in kernel[0]
+    assert "dsgd.scatter/scatter_merge" in kernel[0]
+    # in place on the carry: the kernel's weights operand is its result
+    assert "output_to_operand_aliasing={{}: (6, {})}" in kernel[0]
     # ONE collective a step, of the entries' factors and the samples'
     # coefficient rows as one vector of bits (the compiler runs a 1-D
     # all-gather as an all-reduce of zero-padded pieces), never of a gradient
