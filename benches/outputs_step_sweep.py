@@ -129,7 +129,7 @@ def main(argv) -> int:
 
         def dense_rows(w2, i):
             b = batch_of(i)
-            g = gather.scatter_add_rows(b, coeff_of(gather.matvec_rows(b, w2)), w2.shape[0])
+            g = gather.scatter_add_rows(b, coeff_of(gather.matvec_rows(b, w2)), w2.shape)
             return w2 + g
 
         def dense_batch(precision):

@@ -184,8 +184,11 @@ class Config:
     # which labels a fit takes from the qrels file: 'ccat', the reference's
     # one bit a document (Dataset.scala:36-45), or 'topics', every topic
     # code at once as a model with one output a code (W[D, C]; the mesh
-    # sync engine only, under 'l2' unless `regularizer` says 'none')
-    labels: str = "ccat"  # ccat | topics
+    # sync engine only, under 'l2' unless `regularizer` says 'none').
+    # 'lists': `data_path`/train.txt in the Extreme Classification
+    # Repository's text format (data/multilabel.py), every label of the
+    # file an output, a row's labels kept as the ids of its positives
+    labels: str = "ccat"  # ccat | topics | lists
     virtual_workers: int = 1  # reference workers emulated per mesh device
     exact_topology: bool = False  # insist on exactly node_count workers
     optimizer: str = "sgd"  # sgd (reference) | momentum | adam (sync engine)
@@ -403,13 +406,13 @@ class Config:
     autopilot_source_refresh_s: float = 2.0
 
     _CHOICES = {
-        "model": ("hinge", "svm", "logistic", "least_squares"),
+        "model": ("hinge", "svm", "logistic", "squared_hinge", "least_squares"),
         "engine": ("mesh", "rpc"),
         "async_mode": ("gossip", "local_sgd"),
         # 'dense' is auto-selected from the data layout, never configured
         "kernel": ("auto", "mxu", "scalar", "gather"),
         "regularizer": (None, "dim_sparsity", "l2", "none"),
-        "labels": ("ccat", "topics"),
+        "labels": ("ccat", "topics", "lists"),
         "optimizer": ("sgd", "momentum", "adam"),
         "compress": ("none", "topk", "qint8"),
     }

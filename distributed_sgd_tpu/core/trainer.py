@@ -117,7 +117,8 @@ class SyncTrainer:
         placed = bound_train.placement()
         stored = placed[0][2]  # one layout for every device's rows
         log.info("train split: %d rows kernel=%s margins=%s scatter_shards=%d update=%s "
-                 "scatter=%s outputs=%d labels=%s stored major_to_minor=%s, per device %s",
+                 "scatter=%s outputs=%d labels=%s eval_rows=%d stored major_to_minor=%s, "
+                 "per device %s",
                  len(train),
                  bound_train.kernel,
                  "merged" if bound_train.margins_merged else "per_worker",
@@ -126,7 +127,7 @@ class SyncTrainer:
                  "merge" if bound_train.scatter_merge else
                  "rows" if bound_train.scatter_rows else "words",
                  self.model.n_outputs,
-                 "in_row" if bound_train.labels_in_row else "gathered", stored, " ".join(
+                 bound_train.labels_as, bound_train.eval_rows, stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
         w = (  # [D], or [D, C] for a model with an output axis

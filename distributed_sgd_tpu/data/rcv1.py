@@ -35,6 +35,8 @@ from distributed_sgd_tpu.data import _native
 log = logging.getLogger("dsgd.data")
 
 N_FEATURES = 47236  # Dataset.scala:16
+LIST_PAD = -1  # an unused slot of a row's label list (Dataset.n_labels)
+LIST_NO_ROW = -2  # every slot of a padding row's list: none of its outputs counts
 
 
 @dataclass
@@ -48,11 +50,21 @@ class Dataset:
     # qrels file: `load_rcv1(labels="topics")`); 0 is padding either way
     labels: np.ndarray
     n_features: int
+    # > 0: `labels` is int32[N, Lw], the row's positive label ids among
+    # `n_labels` outputs in ascending order (LIST_PAD fills a row's unused
+    # slots, LIST_NO_ROW a whole padding row): what a row of thousands of
+    # labels with a handful of positives is stored as (data/multilabel.py)
+    n_labels: int = 0
 
     def __post_init__(self):
         if np.ndim(self.labels) not in (1, 2):
             raise ValueError(
                 f"labels are [N] or [N, C], got shape {np.shape(self.labels)}")
+        if self.n_labels and (self.n_labels < 2 or np.ndim(self.labels) != 2
+                              or not np.issubdtype(self.labels.dtype, np.integer)):
+            raise ValueError(
+                f"label lists are integer ids [N, Lw] among n_labels >= 2 outputs, got "
+                f"n_labels={self.n_labels}, labels {self.labels.dtype}{np.shape(self.labels)}")
         # a zero-width index array IS the dense-layout discriminator
         # (batches carry no n_features, so width 0 must imply dense
         # everywhere); sparse sets always pad to width >= 1 (pack_csr)
@@ -90,7 +102,8 @@ class Dataset:
         )
 
     def slice(self, sel) -> "Dataset":
-        return Dataset(self.indices[sel], self.values[sel], self.labels[sel], self.n_features)
+        return Dataset(self.indices[sel], self.values[sel], self.labels[sel], self.n_features,
+                       self.n_labels)
 
 
 def parse_svm_file_py(path: str, index_offset: int = -1):

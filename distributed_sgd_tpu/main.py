@@ -38,6 +38,7 @@ import numpy as np
 
 from distributed_sgd_tpu.config import Config
 from distributed_sgd_tpu.core.early_stopping import no_improvement
+from distributed_sgd_tpu.data.multilabel import read_multilabel, to_lists
 from distributed_sgd_tpu.data.rcv1 import Dataset, dim_sparsity, load_rcv1, train_test_split
 from distributed_sgd_tpu.data.synthetic import rcv1_like
 from distributed_sgd_tpu.models.linear import make_model
@@ -55,15 +56,24 @@ def load_data(cfg: Config) -> Dataset:
     """RCV1 from cfg.data_path, or synthetic via DSGD_SYNTHETIC=<n> when the
     corpus is absent (no-egress environments)."""
     synthetic = os.environ.get("DSGD_SYNTHETIC")
-    train_file = os.path.join(cfg.data_path, "lyrl2004_vectors_train.dat")
+    lists = cfg.labels == "lists"
+    train_file = os.path.join(
+        cfg.data_path, "train.txt" if lists else "lyrl2004_vectors_train.dat")
     if synthetic or not os.path.exists(train_file):
         n = int(synthetic or 100_000)
-        log.info("RCV1 not found or DSGD_SYNTHETIC set: generating %d synthetic rows", n)
+        log.info("%s not found or DSGD_SYNTHETIC set: generating %d synthetic rows",
+                 "train.txt" if lists else "RCV1", n)
         # ltc/IDF value weighting, like real RCV1-v2 term weighting — the
         # shipped default lr=0.5 only descends smoothly with it
         # (benches/zipf_oscillation.py, BASELINE.md round 4)
-        return rcv1_like(n, seed=cfg.seed, idf_values=True,
-                         n_outputs=SYNTHETIC_TOPICS if cfg.labels == "topics" else 1)
+        data = rcv1_like(n, seed=cfg.seed, idf_values=True,
+                         n_outputs=1 if cfg.labels == "ccat" else SYNTHETIC_TOPICS)
+        if lists:  # the same labels, a row's positives as ids
+            return Dataset(data.indices, data.values, to_lists(data.labels)[0],
+                           data.n_features, n_labels=SYNTHETIC_TOPICS)
+        return data
+    if lists:
+        return read_multilabel(train_file, pad_width=cfg.pad_width)
     return load_rcv1(cfg.data_path, full=cfg.full, pad_width=cfg.pad_width,
                      labels=cfg.labels)
 
@@ -72,11 +82,12 @@ def build(cfg: Config):
     data = measure.duration_log("data loaded", lambda: load_data(cfg), log)
     train, test = train_test_split(data)
     if data.labels.ndim == 2:
-        # every topic at once: one output a label column; 'dim_sparsity'
-        # masks by one gradient's support and has no form with outputs
+        # every topic at once: one output a label column (or a label the
+        # rows' lists index); 'dim_sparsity' masks by one gradient's support
+        # and has no form with outputs
         model = make_model(cfg.model, cfg.lam, train.n_features,
                            regularizer=cfg.regularizer or "l2",
-                           n_outputs=data.labels.shape[1])
+                           n_outputs=data.n_labels or data.labels.shape[1])
         return train, test, model
     ds = measure.duration_log("dim sparsity", lambda: dim_sparsity(train), log)
     model = make_model(cfg.model, cfg.lam, train.n_features, dim_sparsity=ds,

@@ -1,4 +1,5 @@
-"""Linear model family on sparse batches: hinge SVM, logistic, least squares.
+"""Linear model family on sparse batches: hinge SVM, logistic, squared
+hinge, least squares.
 
 ``SparseSVM`` reproduces the reference model exactly, sign quirks included
 (core/ml/SparseSVM.scala:14-31):
@@ -29,7 +30,13 @@ entire backward pass compile to gather + elementwise + segment-sum on TPU,
 replacing the reference's per-sample boxed map loop (Slave.scala:147-152).
 
 LogisticRegression and LeastSquares are documented capability supersets
-(BASELINE.md configs 3 and 5; the reference ships hinge only).
+(BASELINE.md configs 3 and 5; the reference ships hinge only), as is
+SquaredHinge (LIBLINEAR's L2-loss SVC, what DiSMEC fits a label).
+
+Labels come as `y[B]`, as `y[B, C]` with an output axis, or as ID LISTS
+(`Dataset.n_labels`: a row's positive ids among the C outputs, int32
+[B, Lw]), which `expand_labels` turns into the `[B, C]` rows of +1 / -1 / 0
+that every loss here takes: the one place that knows the list format.
 """
 
 from __future__ import annotations
@@ -41,10 +48,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_sgd_tpu.data.rcv1 import LIST_NO_ROW
 from distributed_sgd_tpu.ops import gather, kernels, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch, matvec, scatter_add
 
 REGULARIZERS = ("dim_sparsity", "l2", "none")
+
+
+def expand_labels(lists: jax.Array, n_outputs: int, width: int) -> jax.Array:
+    """Label lists `int32[..., Lw]` (a row's positive ids among `n_outputs`
+    outputs; negative ids are empty slots, LIST_NO_ROW in the first slot a
+    padding row) as the labels the losses take, `f32[..., width]`: +1 at a
+    listed id, -1 at every other output, 0 (the pad mask) on the lanes past
+    `n_outputs` and on all of a padding row.  Lw compares a (row, lane)
+    pair on the VPU; nothing is gathered or scattered."""
+    with jax.named_scope("dsgd.labels"):
+        lane = jax.lax.broadcasted_iota(
+            jnp.int32, lists.shape[:-1] + (width,), lists.ndim - 1)
+        listed = lane == lists[..., :1]
+        for slot in range(1, lists.shape[-1]):
+            listed = listed | (lane == lists[..., slot:slot + 1])
+        counts = (lane < n_outputs) & (lists[..., :1] != LIST_NO_ROW)
+        return jnp.where(counts, jnp.where(listed, 1.0, -1.0), 0.0).astype(jnp.float32)
 
 
 class LinearModel:
@@ -390,7 +415,7 @@ class LinearModel:
         reduce='mean' is the async local step (Slave.scala:93-98).
         """
         if kernel == "gather" and self.n_outputs > 1:  # rows of outputs
-            scatter = functools.partial(gather.scatter_add_rows, batch, n_rows=w2.shape[0])
+            scatter = functools.partial(gather.scatter_add_rows, batch, shape=w2.shape)
             if margins is None:
                 margins = gather.matvec_rows(batch, w2)
         elif kernel == "gather":
@@ -460,27 +485,46 @@ class SparseSVM(LinearModel):
         return jnp.where(activity < 0, 0.0, yf)
 
 
-class LogisticRegression(LinearModel):
-    """Binary logistic loss on +/-1 labels (superset; BASELINE.md config 3)."""
+class _MarginLoss(LinearModel):
+    """A loss of the margin y m itself, not of a prediction: the sign of
+    the margin predicts, subclasses define `losses_from_margins`."""
 
     def predict(self, margins: jax.Array) -> jax.Array:
         return jnp.where(margins >= 0, 1.0, -1.0)
 
     def sample_loss(self, preds: jax.Array, y: jax.Array) -> jax.Array:
-        del preds  # logistic loss is margin-based; see losses_from_margins
+        del preds  # the loss is margin-based; see losses_from_margins
         raise NotImplementedError("use losses_from_margins()/objective()")
-
-    def losses_from_margins(self, margins: jax.Array, y: jax.Array) -> jax.Array:
-        yf = y.astype(jnp.float32)
-        return jnp.logaddexp(0.0, -yf * margins)  # log(1 + exp(-y m)), stable
 
     def objective(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         reg = self.lam * jnp.sum(w.astype(jnp.float32) ** 2)
         return reg + jnp.mean(_per_row(self.sample_losses(w, batch, y)))
 
+
+class LogisticRegression(_MarginLoss):
+    """Binary logistic loss on +/-1 labels (superset; BASELINE.md config 3)."""
+
+    def losses_from_margins(self, margins: jax.Array, y: jax.Array) -> jax.Array:
+        yf = y.astype(jnp.float32)
+        return jnp.logaddexp(0.0, -yf * margins)  # log(1 + exp(-y m)), stable
+
     def grad_coeff(self, margins: jax.Array, y: jax.Array) -> jax.Array:
         yf = y.astype(jnp.float32)
         return -yf * jax.nn.sigmoid(-yf * margins)
+
+
+class SquaredHinge(_MarginLoss):
+    """max(0, 1 - y m)^2 on +/-1 labels: the l2-regularised L2-loss SVM of
+    LIBLINEAR's primal solver, which DiSMEC (Babbar & Schoelkopf, WSDM 2017)
+    fits a label.  Its derivative is continuous at the kink (y m = 1), so a
+    rounding there moves a coefficient by the rounding and flips nothing."""
+
+    def losses_from_margins(self, margins: jax.Array, y: jax.Array) -> jax.Array:
+        return jnp.maximum(0.0, 1.0 - y.astype(jnp.float32) * margins) ** 2
+
+    def grad_coeff(self, margins: jax.Array, y: jax.Array) -> jax.Array:
+        yf = y.astype(jnp.float32)  # 0 (a pad) gives 0
+        return -2.0 * yf * jnp.maximum(0.0, 1.0 - yf * margins)
 
 
 class LeastSquares(LinearModel):
@@ -542,6 +586,7 @@ def make_model(
         "hinge": SparseSVM,
         "svm": SparseSVM,
         "logistic": LogisticRegression,
+        "squared_hinge": SquaredHinge,
         "least_squares": LeastSquares,
     }
     if name not in kinds:

@@ -83,9 +83,12 @@ Everything is float32: a gather rounds nothing.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from distributed_sgd_tpu.ops import kernels
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 
 LANES = 128
@@ -183,7 +186,8 @@ def _write_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, new: jax.Array,
                 ring: int = DMA_RING) -> jax.Array:
     """`w2` with `new[t]` written to row `rows[t]` wherever `head[t]`, in
     place: a TPU kernel that leaves `w2` and `new` in HBM and issues one
-    512-byte DMA a head itself, `ring` of them in flight.  The heads' rows
+    DMA a head itself (a 512-byte row, or a row's tile of lane groups,
+    `to_tiles`: 4 KB at 1,024 lanes), `ring` of them in flight.  The heads' rows
     differ, so no write waits for another: what XLA's scatter cannot
     assume.  The kernel walks the heads alone (one more sort puts their
     positions and rows first: 6 us for 4,480): a turn of a scalar loop that
@@ -266,7 +270,8 @@ def _add_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, total: jax.Array,
     # the gather runs faster over rows that differ than over a run's
     # repeats (34 -> 18 us for 4,480 rows, 1,200 of them one row)
     entry = jnp.arange(rows.shape[0])
-    new = w2[jnp.where(head, rows, entry % w2.shape[0])] + total
+    # (rows that are tiles, `to_tiles`: the sums take the tiles' form here)
+    new = w2[jnp.where(head, rows, entry % w2.shape[0])] + total.reshape((-1,) + w2.shape[1:])
     if dma:
         return _write_rows(w2, rows, head, new)
     # off the heads: past the last row, each its own index, dropped
@@ -302,6 +307,27 @@ def from_rows(w2: jax.Array, n_features: int, n_outputs: int) -> jax.Array:
         return w2[:n_features, :n_outputs]
 
 
+def to_tiles(w2: jax.Array) -> jax.Array:
+    """[D', L] -> [D', L / 128, 128]: a feature's row as ONE tile of lane
+    groups.  A TPU stores `[D', L]` in tiles of 8 rows x 128 lanes, so a row
+    of more than one lane group lies in L / 128 pieces of 512 B a tile apart
+    and is no slice a DMA may name (the chip's compiler refuses
+    `_write_rows` on it: "slice shape along dimension 0 must be aligned to
+    tiling (8)"); with the lane groups a dimension of their own the tiling
+    is over (lane group, lane) and a feature's weights are contiguous, 4 KB
+    at 1,024 lanes.  What carries a binding's wide rows wherever no merge
+    pass runs over them (`BoundSync.rows_tiled`); every function of this
+    file but `_merge_rows` takes weights in either form."""
+    with jax.named_scope("dsgd.layout"):
+        return w2.reshape(w2.shape[0], -1, LANES)
+
+
+def from_tiles(w3: jax.Array) -> jax.Array:
+    """[D', L / 128, 128] -> [D', L]."""
+    with jax.named_scope("dsgd.layout"):
+        return w3.reshape(w3.shape[0], -1)
+
+
 def matvec_rows(batch: SparseBatch, w2: jax.Array) -> jax.Array:
     """Per-sample, per-output dots `x_b . W[:, c]` -> [B, L]: every stored
     entry gathers its feature's row, a sample's P rows are summed with its
@@ -310,21 +336,40 @@ def matvec_rows(batch: SparseBatch, w2: jax.Array) -> jax.Array:
     then split into [P, B, L] without moving (B whole sublanes), where
     [B, P, L] with P = 76 is another tiling and cost a copy of all of them
     (0.88 s of the 2.30 s an evaluation of 7.2 M rows took, my chip run,
-    PR 32)."""
-    with jax.named_scope("dsgd.margins"):
-        entry_major = batch.indices.T  # [P, B]
+    PR 32).  `kernels.margin_rows` says how many samples one gather takes."""
+    tile = w2.shape[1:]  # (L,), or (L / 128, 128) where the rows are tiles
+    lanes = math.prod(tile)
+
+    def dots(indices, values):
+        entry_major = indices.T  # [P, B]
         rows = w2.astype(jnp.float32)[entry_major.reshape(-1)]  # [P B, L]: the row gather
-        rows = rows.reshape(entry_major.shape + (w2.shape[1],))
-        return jnp.sum(batch.values.astype(jnp.float32).T[..., None] * rows, axis=0)
+        rows = rows.reshape(entry_major.shape + tile)
+        weights = values.astype(jnp.float32).T[..., None]
+        if len(tile) == 1:
+            return jnp.sum(weights * rows, axis=0)
+        # tiles are summed as tiles: only a sample's sum is laid out as a row
+        return jnp.sum(weights[..., None] * rows, axis=0).reshape(-1, lanes)
+
+    with jax.named_scope("dsgd.margins"):
+        samples, width = batch.indices.shape
+        piece = kernels.margin_rows(samples, width, lanes)
+        if piece == samples:
+            return dots(batch.indices, batch.values)
+        # wide rows: the gathered rows of a piece stay what the chip has run
+        # well (`kernels.GATHERED_ROWS_MAX_BYTES`), piece after piece
+        m = jax.lax.map(lambda iv: dots(*iv), (batch.indices.reshape(-1, piece, width),
+                                               batch.values.reshape(-1, piece, width)))
+        return m.reshape(samples, lanes)
 
 
-def scatter_add_rows(batch: SparseBatch, coeff: jax.Array, n_rows: int) -> jax.Array:
-    """sum_b x_b (outer) coeff[b] -> a fresh [D', L] (the gradient an
-    optimizer reads): XLA's scatter-add of whole rows, in entry order."""
+def scatter_add_rows(batch: SparseBatch, coeff: jax.Array, shape: tuple) -> jax.Array:
+    """sum_b x_b (outer) coeff[b] -> a fresh array of the weights' `shape`
+    ([D', L], or tiles) (the gradient an optimizer reads): XLA's
+    scatter-add of whole rows, in entry order."""
     with jax.named_scope("dsgd.scatter"):
         cv = batch.values.astype(jnp.float32)[..., None] * coeff.astype(jnp.float32)[:, None, :]
-        return jnp.zeros((n_rows, coeff.shape[1]), jnp.float32).at[
-            batch.indices.reshape(-1)].add(cv.reshape(-1, coeff.shape[1]))
+        return jnp.zeros(shape, jnp.float32).at[
+            batch.indices.reshape(-1)].add(cv.reshape((-1,) + tuple(shape[1:])))
 
 
 # The merge pass (`_merge_rows`) moves `w2` through VMEM in blocks of

@@ -21,14 +21,15 @@ and `core/worker.py` all ask it through `resolve`, which reads the
 platform off the device and counts the answer.  An explicit `kernel=`
 still overrides: the rule answers only for `AUTO`.
 
-Four more rules on a binding's shape live here, each asked once a
+Five more rules on a binding's shape live here, each asked once a
 binding by `BoundSync`: `merges_margins` (the K virtual workers' margins
 in one call), `ONE_ACCUMULATOR` (their entries scattered into one
 gradient), `sparse_update` (no gradient at all: the entries scattered
 into the carried weights, the regulariser a scalar on them, so that a
-step's bytes have no term in the feature count) and `merges_scatter`
+step's bytes have no term in the feature count), `merges_scatter`
 (with an output axis, whether that scatter is one pass over the weights
-or a DMA a touched row).
+or a DMA a touched row) and `margin_rows` (with an output axis, how many
+samples one row gather of the margins takes).
 """
 
 from __future__ import annotations
@@ -232,6 +233,30 @@ def merges_scatter(n_features: int, n_outputs: int, n_entries: int) -> bool:
 
     return (n_features <= MERGE_MAX_ROWS_PER_ENTRY * n_entries
             and gather.output_lanes(n_outputs) <= MERGE_MAX_LANES)
+
+
+# Bytes of gathered weight rows ONE row gather of the output-axis margins
+# holds at most (`margin_rows`).  `gather.matvec_rows` gathers a row an
+# entry, entry-major, and sums a sample's rows: [P B, L] float32 written and
+# read once.  The evaluation's chunk of 4,096 samples is 159 MB of them at
+# `rcv1-topics-hinge`'s shape (76 entries, 128 lanes: one gather, 285 GB/s,
+# PERF.md section 5) and would be 1.2 GB at 72 entries and 1,024 lanes; a
+# step's 400 samples are 118 MB there.  The constant sits just above the
+# largest gather the chip has run well, so neither of those moves, and a
+# wider chunk is cut into pieces of at most that (PERF.md section 6, PR 36).
+GATHERED_ROWS_MAX_BYTES = 160 * 2 ** 20
+
+
+def margin_rows(samples: int, row_width: int, lanes: int) -> int:
+    """Samples a row gather of `gather.matvec_rows` takes at once, for a
+    batch of `samples` rows of `row_width` stored entries against weight
+    rows of `lanes` lanes: `samples` halved until a piece's gathered rows
+    fit GATHERED_ROWS_MAX_BYTES (or it is odd).  From shapes alone; the
+    pieces run one after the other (`lax.map`)."""
+    piece = int(samples)
+    while piece % 2 == 0 and piece * row_width * lanes * 4 > GATHERED_ROWS_MAX_BYTES:
+        piece //= 2
+    return piece
 
 
 def resolve(kernel: Optional[str], n_features: int, row_width: int,

@@ -59,8 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distributed_sgd_tpu.data.rcv1 import Dataset
-from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
+from distributed_sgd_tpu.data.rcv1 import LIST_NO_ROW, Dataset
+from distributed_sgd_tpu.models.linear import LinearModel, expand_labels, require_single_output
 from distributed_sgd_tpu.ops import gather, kernels, mxu
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
@@ -85,6 +85,7 @@ class ShardedData(NamedTuple):
     # [N_pad], or [N_pad, C] for a model with C outputs (bind() stores it
     # zero-padded to the lanes of the kernel's margins,
     # LinearModel.label_lanes); sharded over workers; 0 = padding mask.
+    # Or id lists (`label_lists` below).
     # What the evaluation, `predict`'s callers and every step whose
     # `label_slot` is None read
     labels: jax.Array
@@ -102,6 +103,10 @@ class ShardedData(NamedTuple):
     # of `values` past `width`; the step reads it out of the rows it draws.
     # None: the step gathers `labels`
     label_slot: Optional[int] = None
+    # `labels` is int32[N_pad, Lw]: every row's positive ids among the
+    # model's outputs (Dataset.n_labels), stored as they come; the step and
+    # the evaluation expand what they read (models/linear.expand_labels)
+    label_lists: bool = False
 
     @property
     def is_dense(self) -> bool:
@@ -203,8 +208,14 @@ class BoundSync:
                 raise ValueError(
                     f"label_slot={data.label_slot} is no spare word of a stored row "
                     f"(words {first}..{stored - 1}, one output)")
-        metrics.counter(
-            "bind.labels.in_row" if self.labels_in_row else "bind.labels.gathered").increment()
+        # whether the resident labels are id lists, which every reader expands
+        # (`_labels`): static per binding
+        self.label_lists = data.label_lists
+        if self.label_lists and self.labels_in_row:
+            raise ValueError("a label list rides in no word of a stored row")
+        self.labels_as = ("lists" if self.label_lists else
+                          "in_row" if self.labels_in_row else "gathered")
+        metrics.counter(f"bind.labels.{self.labels_as}").increment()
         n_pad = data.indices.shape[0]
         self.shard_n = n_pad // self.n_workers
         self.eval_chunk = min(eval_chunk, self.shard_n)
@@ -212,6 +223,14 @@ class BoundSync:
             raise ValueError(
                 f"shard size {self.shard_n} not a multiple of eval_chunk {self.eval_chunk}"
             )
+        # the lanes of a weight row that holds the outputs (gather.to_rows;
+        # 0: no such rows), and the samples of an evaluation chunk one row
+        # gather of the margins takes (kernels.margin_rows: the whole chunk
+        # but for wide rows)
+        row_lanes = (gather.output_lanes(model.n_outputs)
+                     if kernel == "gather" and model.n_outputs > 1 else 0)
+        self.eval_rows = (kernels.margin_rows(self.eval_chunk, row_width, row_lanes)
+                          if row_lanes else self.eval_chunk)
         # reference: maxSamples = max shard size; steps = ceil(max/bs)
         # (Master.scala:138,179) computed over true samples and the TOTAL
         # worker count (mesh devices x virtual workers per device)
@@ -223,7 +242,6 @@ class BoundSync:
         # State lives in the kernel's weight layout and is threaded through
         # every compiled loop, replicated over the mesh like the weights.
         self.opt = resolve_optimizer(optimizer, self.learning_rate, momentum)
-        self._opt_state = self._init_opt_state()
         # whether the step scatters the replies' entries straight into the
         # carried weights and never builds a gradient (_sparse_step): the
         # one rule of ops/kernels.py, static per binding.  `_decay` is what
@@ -250,6 +268,12 @@ class BoundSync:
         if on_tpu:
             metrics.counter(
                 "bind.scatter.merge" if self.scatter_merge else "bind.scatter.rows").increment()
+        # whether weight rows of more than one lane group are carried as
+        # tiles [D', L / 128, 128] (gather.to_tiles: a feature's weights
+        # contiguous, what the row DMA can name): everywhere but under the
+        # merge pass, which streams blocks of [D', L]
+        self.rows_tiled = row_lanes > gather.LANES and not self.scatter_merge
+        self._opt_state = self._init_opt_state()
         sspec = jax.tree.map(lambda _: P(), self._opt_state)
 
         dspec = (P(AXIS), P(AXIS), P(AXIS))
@@ -346,6 +370,7 @@ class BoundSync:
             if one:
                 ids = ids[0]
             bi, bv, by = self.draw_rows(idx, val, y, ids)  # the resident-row gathers
+        by = self._labels(by)
         if one:  # one worker's Gradient reply (Slave.scala:142-157)
             g = self.model.grad(w, SparseBatch(bi, bv), by, kernel=self.kernel)
         else:  # the K virtual workers' replies, summed (mean-normalized below)
@@ -418,6 +443,7 @@ class BoundSync:
         with jax.named_scope("dsgd.draw"):
             ids = self._sample_ids(key, step)  # [K, B]
             bi, bv, by = self.draw_rows(idx, val, y, ids)
+        by = self._labels(by)
         width = bi.shape[-1]
         # the K virtual workers share the weights: one call on their merged
         # batches (kernels.merges_margins), one scatter of all their entries
@@ -512,6 +538,16 @@ class BoundSync:
         bv = stored if self._width is None else stored[..., :self._width]
         return self.rows(idx, ids), bv, y[ids] if slot is None else stored[..., slot]
 
+    def _labels(self, y):
+        """The labels the losses take, of labels as they are resident: id
+        lists expanded to rows of +1 / -1 / 0 (under `dsgd.labels`), every
+        other form as it is."""
+        if not self.label_lists:
+            return y
+        model = self.model  # [B, C], or [B, L] beside lane-padded margins
+        return expand_labels(y, model.n_outputs,
+                             model.label_lanes(self.kernel) or model.n_outputs)
+
     def chunk_rows(self, idx, val, start):
         """(indices, values) of the evaluation's chunk at `start`, likewise."""
         if self._packed is not None:
@@ -519,10 +555,12 @@ class BoundSync:
         return self.chunk(idx, start), self.chunk(val, start)
 
     def _to_kernel_layout(self, w):
-        return self.model.to_layout(w, self.kernel)
+        w = self.model.to_layout(w, self.kernel)
+        return gather.to_tiles(w) if self.rows_tiled else w
 
     def _from_kernel_layout(self, w):
-        return self.model.from_layout(w, self.kernel)
+        return self.model.from_layout(gather.from_tiles(w) if self.rows_tiled else w,
+                                      self.kernel)
 
     def _loop_labels(self, y):
         """The labels a scan over steps gathers from, where it gathers any
@@ -576,7 +614,7 @@ class BoundSync:
             with jax.named_scope("dsgd.eval_rows"):
                 s = t * chunk
                 ci, cv = self.chunk_rows(idx, val, s)
-                cy = jax.lax.dynamic_slice_in_dim(y, s, chunk, 0)
+                cy = self._labels(jax.lax.dynamic_slice_in_dim(y, s, chunk, 0))
                 mask = (cy != 0).astype(jnp.float32)
             # the same gather the step runs (models/linear.py `margins`)
             margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel)
@@ -937,7 +975,13 @@ class SyncEngine:
             values = put(np.zeros((total, 0), np.float32))
         else:
             indices, values = put(local.indices), put(local.values, riding)
-        label_lanes = self.model.label_lanes(kernel)
+        lists = bool(data.n_labels)
+        if lists and data.n_labels != self.model.n_outputs:
+            raise ValueError(
+                f"the rows list their labels among {data.n_labels} outputs, the model "
+                f"has n_outputs={self.model.n_outputs}")
+        # lists stay as narrow as they come: the readers expand them
+        label_lanes = None if lists else self.model.label_lanes(kernel)
         sharded = ShardedData(
             indices=indices,
             values=values,
@@ -947,6 +991,7 @@ class SyncEngine:
             width=local.values.shape[1],
             packed=lanes is not None,
             label_slot=slot,
+            label_lists=lists,
         )
         bound = BoundSync(
             self.model,
@@ -1037,7 +1082,10 @@ def _pad_to_exact(data: Dataset, target: int) -> Dataset:
         values=np.concatenate(
             [data.values, np.zeros((rem, data.values.shape[1]), dtype=data.values.dtype)]
         ),
+        # the pad mask: label 0, or a list that says "no row"
         labels=np.concatenate(
-            [data.labels, np.zeros((rem,) + data.labels.shape[1:], dtype=data.labels.dtype)]),
+            [data.labels, np.full((rem,) + data.labels.shape[1:],
+                                  LIST_NO_ROW if data.n_labels else 0, data.labels.dtype)]),
         n_features=data.n_features,
+        n_labels=data.n_labels,
     )

@@ -1,0 +1,414 @@
+"""Labels as ID LISTS and the squared hinge (PERF.md section 6, PR 36): a
+one-vs-rest fit over a RANGE of labels, a row's labels the ids of its
+positives among the C outputs the fit holds (`Dataset.n_labels`), through
+the mesh sync engine.
+
+Three references hold the program: the plain equations of
+`benchmark/reference_lists.py` (one step, the evaluation), the program
+itself on the same labels as a dense `[N, C]` array (weight for weight),
+and the share property DiSMEC rests on (the fits of label ranges, side by
+side, ARE the fit of all labels).  C = 200 and 300: two and three lane
+groups a weight row.  Small sizes, the CPU.
+"""
+
+import logging
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import reference_lists
+from distributed_sgd_tpu.core.trainer import SyncTrainer
+from distributed_sgd_tpu.data.multilabel import read_multilabel, to_lists
+from distributed_sgd_tpu.data.rcv1 import LIST_NO_ROW, LIST_PAD, Dataset
+from distributed_sgd_tpu.data.synthetic import rcv1_like
+from distributed_sgd_tpu.models.linear import expand_labels, make_model
+from distributed_sgd_tpu.ops import gather, kernels
+from distributed_sgd_tpu.parallel.mesh import make_mesh
+from distributed_sgd_tpu.parallel.sync import SyncEngine
+from distributed_sgd_tpu.utils import metrics as metrics_mod
+
+D, N, P = 3000, 512, 6
+LAM, LR, BATCH = 1e-3, 0.02, 8
+LOSSES = ("hinge", "logistic", "squared_hinge", "least_squares")
+
+
+def to_dense(lists, n_labels: int) -> np.ndarray:
+    """int8[N, n_labels] of +/-1 from lists (a LIST_NO_ROW row: all 0)."""
+    lists = np.asarray(lists)
+    y = np.full((len(lists), n_labels), -1, np.int8)
+    rows, slots = np.nonzero(lists >= 0)
+    y[rows, lists[rows, slots]] = 1
+    y[lists[:, 0] == LIST_NO_ROW] = 0
+    return y
+
+
+def _dense(n_outputs: int, seed: int = 3) -> Dataset:
+    return rcv1_like(N, n_features=D, nnz=P, seed=seed, n_outputs=n_outputs)
+
+
+def _listed(data: Dataset, first: int = 0, end=None) -> Dataset:
+    """`data` with the labels of columns [first, end) as lists."""
+    y = data.labels[:, first:end]
+    lists, cut = to_lists(y)
+    assert cut == 0
+    return Dataset(data.indices, data.values, lists, data.n_features, n_labels=y.shape[1])
+
+
+def _weights(n_outputs: int, seed: int = 5):
+    return jnp.asarray(np.random.default_rng(seed).normal(size=(D, n_outputs)) * 0.1,
+                       jnp.float32)
+
+
+def _bind(data, n_outputs, loss="squared_hinge", reg="l2", devices=1, workers=4,
+          kernel=kernels.AUTO):
+    model = make_model(loss, LAM, D, regularizer=reg, n_outputs=n_outputs)
+    return SyncEngine(model, make_mesh(devices), BATCH, LR, eval_chunk=64,
+                      kernel=kernel, virtual_workers=workers).bind(data)
+
+
+def _batches(bound, data, key):
+    """The batches the program's own sampler draws for `key`, a worker each."""
+    draw = jax.jit(lambda k: bound._sample_ids(k, jnp.int32(0)))
+    out = []
+    for d in range(bound.n_workers):
+        drawn = np.asarray(draw(jax.random.fold_in(key, d))) + d * bound.shard_n
+        out += [(jnp.asarray(data.indices[r]), jnp.asarray(data.values[r]),
+                 jnp.asarray(data.labels[r])) for r in drawn]
+    return out
+
+
+# -- the list format ----------------------------------------------------------------
+
+def test_lists_and_dense_labels_are_one_another():
+    y = _dense(200).labels
+    lists, cut = to_lists(y)
+    assert lists.dtype == np.int32 and cut == 0 and lists.shape[1] == (y > 0).sum(1).max()
+    for row, ids in zip(lists[:64], y[:64]):
+        kept = row[row >= 0]
+        np.testing.assert_array_equal(kept, np.flatnonzero(ids > 0))  # ascending, pads last
+        assert np.all(row[len(kept):] == LIST_PAD)
+    np.testing.assert_array_equal(to_dense(lists, 200), y)
+    narrow, cut = to_lists(y, width=2)  # a longer row keeps its lowest ids, and is counted
+    assert cut == int(((y > 0).sum(1) > 2).sum()) > 0
+    np.testing.assert_array_equal(narrow, lists[:, :2])
+
+
+@pytest.mark.parametrize("width", [5, 8, 256])
+def test_expanded_lists_are_the_dense_labels_with_their_masks(width):
+    lists = jnp.asarray([[0, 3, LIST_PAD], [LIST_PAD] * 3, [LIST_NO_ROW] * 3, [4, LIST_PAD, LIST_PAD]],
+                        jnp.int32)
+    want = np.zeros((4, width), np.float32)
+    want[:, :5] = [[1, -1, -1, 1, -1], [-1] * 5, [0] * 5, [-1, -1, -1, -1, 1]]
+    np.testing.assert_array_equal(np.asarray(expand_labels(lists, 5, width)), want)
+
+
+def test_a_dataset_refuses_lists_it_cannot_hold():
+    idx, val = np.zeros((4, 2), np.int32), np.zeros((4, 2), np.float32)
+    with pytest.raises(ValueError, match="label lists"):
+        Dataset(idx, val, np.zeros((4, 3), np.float32), 10, n_labels=5)  # not integer ids
+    with pytest.raises(ValueError, match="label lists"):
+        Dataset(idx, val, np.zeros((4,), np.int32), 10, n_labels=5)  # not [N, Lw]
+    with pytest.raises(ValueError, match="label lists"):
+        Dataset(idx, val, np.zeros((4, 3), np.int32), 10, n_labels=1)  # one output: a flat label
+    kept = Dataset(idx, val, np.zeros((4, 3), np.int32), 10, n_labels=5).slice(slice(1, 3))
+    assert kept.n_labels == 5 and len(kept) == 2
+
+
+def test_a_binding_refuses_lists_of_another_label_count():
+    with pytest.raises(ValueError, match="list their labels among 200"):
+        _bind(_listed(_dense(200)), 300)
+
+
+# -- (i) one step and one evaluation against the plain reference ------------------------
+
+@pytest.mark.parametrize("devices,workers", [(1, 4), (4, 1)])
+@pytest.mark.parametrize("sparse", [False, True])
+@pytest.mark.parametrize("reg", ["l2", "none"])
+@pytest.mark.parametrize("n_outputs", [200, 300])
+def test_one_step_equals_the_reference(n_outputs, reg, sparse, devices, workers, monkeypatch):
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0 if sparse else 10**9)
+    data = _listed(_dense(n_outputs))
+    bound = _bind(data, n_outputs, reg=reg, devices=devices, workers=workers)
+    assert (bound.kernel, bound.update_sparse, bound.labels_as) == ("gather", sparse, "lists")
+    w, key = _weights(n_outputs), jax.random.PRNGKey(7)
+    want = np.asarray(reference_lists.sync_step(
+        "squared_hinge", reg, w, _batches(bound, data, key), LAM, LR))
+    got = np.asarray(bound.step(w, key))
+    assert got.shape == (D, n_outputs)
+    np.testing.assert_allclose(got - np.asarray(w), want - np.asarray(w), rtol=2e-5, atol=2e-7)
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("n_outputs", [200, 300])
+def test_the_evaluation_equals_the_reference(n_outputs, devices):
+    data = _listed(_dense(n_outputs)).slice(slice(0, 500))  # 500 rows: pad rows to mask
+    bound = _bind(data, n_outputs, devices=devices, workers=1)
+    assert bound.data.labels.shape == (512, data.labels.shape[1])  # stored as narrow as they come
+    assert np.all(np.asarray(bound.data.labels)[500:] == LIST_NO_ROW)
+    w = _weights(n_outputs)
+    loss, acc = bound.evaluate(w)
+    ref_loss, ref_acc = reference_lists.evaluate(
+        "squared_hinge", w, data.indices, data.values, data.labels, LAM)
+    assert loss == pytest.approx(ref_loss, rel=1e-5) and acc == pytest.approx(ref_acc, abs=1e-6)
+    preds = bound.predict(w)
+    assert preds.shape == (500, n_outputs) and set(np.unique(preds)) <= {-1.0, 1.0}
+    y = to_dense(data.labels, n_outputs)
+    assert np.mean(preds == y) == pytest.approx(acc, abs=1e-6)
+
+
+# -- (ii) a fit on lists is the fit on the same labels as a dense array --------------------
+
+def _fit(train, test, n_outputs, loss, epochs=2, seed=11):
+    model = make_model(loss, LAM, D, regularizer="l2", n_outputs=n_outputs)
+    trainer = SyncTrainer(model, make_mesh(1), BATCH, LR, seed=seed, virtual_workers=4,
+                          metrics=metrics_mod.Metrics())
+    return trainer.fit(train, test, max_epochs=epochs)
+
+
+@pytest.mark.parametrize("loss", LOSSES)
+def test_a_fit_on_lists_is_the_fit_on_dense_labels(loss):
+    dense = _dense(200)
+    lists = _listed(dense)
+    cut = slice(0, 384), slice(384, None)
+    a = _fit(dense.slice(cut[0]), dense.slice(cut[1]), 200, loss)
+    b = _fit(lists.slice(cut[0]), lists.slice(cut[1]), 200, loss)
+    np.testing.assert_array_equal(np.asarray(a.weights), np.asarray(b.weights))
+    np.testing.assert_allclose(a.test_losses, b.test_losses, rtol=1e-6)
+    np.testing.assert_allclose(a.test_accuracies, b.test_accuracies, rtol=1e-6)
+
+
+# -- (iii) the squared hinge at one output, every family ----------------------------------
+
+@pytest.mark.parametrize("kernel", ["mxu", "gather", "scalar"])
+def test_the_squared_hinge_at_one_output_is_the_references_column(kernel):
+    data = _dense(1)  # flat labels [N]
+    model = make_model("squared_hinge", LAM, D, regularizer="l2")
+    bound = SyncEngine(model, make_mesh(1), BATCH, LR, eval_chunk=64, kernel=kernel,
+                       virtual_workers=4).bind(data)
+    assert bound.kernel == kernel and bound.labels_as == "gathered"
+    w, key = _weights(1), jax.random.PRNGKey(9)
+    as_lists = Dataset(data.indices, data.values,
+                       np.where(data.labels > 0, 0, LIST_PAD).astype(np.int32)[:, None], D)
+    want = reference_lists.sync_step("squared_hinge", "l2", w, _batches(bound, as_lists, key),
+                                     LAM, LR)
+    got = np.asarray(bound.step(w[:, 0], key))
+    np.testing.assert_allclose(got - np.asarray(w[:, 0]), np.asarray(want - w)[:, 0],
+                               rtol=2e-5, atol=2e-7)
+    loss, acc = bound.evaluate(w[:, 0])
+    ref_loss, ref_acc = reference_lists.evaluate(
+        "squared_hinge", w, as_lists.indices, as_lists.values, as_lists.labels, LAM)
+    assert loss == pytest.approx(ref_loss, rel=1e-5) and acc == pytest.approx(ref_acc, abs=1e-6)
+
+
+def test_the_squared_hinges_derivative_is_continuous_at_the_kink():
+    model = make_model("squared_hinge", LAM, D, regularizer="l2")
+    m = jnp.asarray([1.0 - 1e-6, 1.0, 1.0 + 1e-6, -3.0, 0.0])
+    coeff = np.asarray(model.grad_coeff(m, jnp.ones(5)))
+    np.testing.assert_allclose(coeff, [-2e-6, 0.0, 0.0, -8.0, -2.0], atol=3e-7)
+    np.testing.assert_allclose(np.asarray(model.losses_from_margins(m, jnp.ones(5))),
+                               [0.0, 0.0, 0.0, 16.0, 1.0], atol=1e-6)
+    assert np.all(np.asarray(model.grad_coeff(m, jnp.zeros(5))) == 0.0)  # a pad adds nothing
+
+
+# -- (iv) the share test: ranges of labels side by side ARE the fit of all ---------------
+
+def test_the_fits_of_label_ranges_are_the_columns_of_the_fit_of_all():
+    """DiSMEC's Algorithm 1: a node fits its batch of labels over every row
+    and nothing is exchanged.  Under the same draws the three fits of 100
+    labels each, set side by side, equal the fit of all 300 column for
+    column: nothing is computed alike on all shares, so nothing is counted
+    twice."""
+    dense = _dense(300)
+    cut = slice(0, 384), slice(384, None)
+
+    def fit(first, end):
+        part = _listed(dense, first, end)
+        return _fit(part.slice(cut[0]), part.slice(cut[1]), end - first, "squared_hinge")
+
+    whole = fit(0, 300)
+    shares = [fit(first, first + 100) for first in (0, 100, 200)]
+    assert np.shape(whole.weights) == (D, 300)
+    np.testing.assert_allclose(
+        np.concatenate([np.asarray(s.weights) for s in shares], axis=1),
+        np.asarray(whole.weights), rtol=1e-6, atol=1e-8)
+    # the objective is the sum of the shares' (losses and lam ||W||^2 alike)
+    np.testing.assert_allclose(np.sum([s.test_losses for s in shares], axis=0),
+                               whole.test_losses, rtol=1e-5)
+
+
+# -- (v) the DMA-a-row ending on rows of two and eight lane groups ------------------------
+
+@pytest.mark.parametrize("lanes", [256, 1024])
+def test_the_row_write_on_wide_rows_is_the_float64_scatter_add(lanes):
+    """`scatter_rows_into` with the kernel `_write_rows` (Pallas' TPU
+    interpret mode) on weight rows of 1 KB and 4 KB: the sorted entries'
+    runs summed on the MXU, every touched row written once."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rng = np.random.default_rng(36)
+    n_rows, n_entries, samples = 96, 700, 24
+    ids = np.minimum(np.exp(rng.uniform(0, np.log(n_rows + 1), n_entries)).astype(np.int64) - 1,
+                     n_rows - 1).astype(np.int32)
+    ids[:200] = 17  # a run longer than a chunk
+    values = rng.normal(size=n_entries).astype(np.float32)
+    src = rng.integers(0, samples, n_entries).astype(np.int32)
+    coeff = (rng.normal(size=(samples, lanes)) * 1e-2).astype(np.float32)
+    w2 = (rng.normal(size=(n_rows, lanes)) * 3.0).astype(np.float32)
+    want = w2.astype(np.float64)
+    np.add.at(want, ids, values.astype(np.float64)[:, None] * coeff.astype(np.float64)[src])
+    entries = tuple(jnp.asarray(a) for a in (ids, values, src, coeff))
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(
+            w, *entries, dma=True))(jnp.asarray(w2)))
+    xla = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries))(jnp.asarray(w2)))
+    np.testing.assert_array_equal(got, xla)  # one formulation, one write that differs
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=2e-6)
+    assert not kernels.merges_scatter(n_rows, lanes, n_entries) or lanes <= kernels.MERGE_MAX_LANES
+
+
+@pytest.mark.parametrize("samples,width,lanes,piece", [
+    (4096, 76, 128, 4096), (400, 72, 1024, 400), (4096, 72, 1024, 512), (64, 6, 256, 64)])
+def test_the_margins_rule_cuts_a_wide_chunk_and_no_other(samples, width, lanes, piece):
+    assert kernels.margin_rows(samples, width, lanes) == piece
+
+
+def test_margins_in_pieces_are_the_margins_in_one(monkeypatch):
+    data = _dense(200)
+    w2 = gather.to_rows(_weights(200))
+    from distributed_sgd_tpu.ops.sparse import SparseBatch
+
+    batch = SparseBatch(jnp.asarray(data.indices[:64]), jnp.asarray(data.values[:64]))
+    whole = np.asarray(gather.matvec_rows(batch, w2))
+    monkeypatch.setattr(kernels, "GATHERED_ROWS_MAX_BYTES", 16 * P * 256 * 4)
+    assert kernels.margin_rows(64, P, 256) == 16
+    np.testing.assert_allclose(np.asarray(gather.matvec_rows(batch, w2)), whole,
+                               rtol=1e-6, atol=1e-7)
+
+
+# -- (vi) the text format ------------------------------------------------------------------
+
+FILE = """5 10 6
+0,3 1:0.5 4:0.25
+ 2:1.0
+5 7:0.5 9:0.125
+1,2,4 0:1.0
+3 5:2.0 6:0.5 8:0.25
+"""
+
+
+def test_the_reader_keeps_a_label_range_as_lists(tmp_path):
+    path = tmp_path / "train.txt"
+    path.write_text(FILE)
+    every = read_multilabel(str(path))
+    assert (every.n_features, every.n_labels, len(every)) == (10, 6, 5)
+    np.testing.assert_array_equal(every.labels, [
+        [0, 3, -1], [-1, -1, -1], [5, -1, -1], [1, 2, 4], [3, -1, -1]])
+    np.testing.assert_array_equal(every.indices[0], [1, 4, 0])
+    np.testing.assert_allclose(every.values[4], [2.0, 0.5, 0.25])
+    # the batch [2, 5): ids renumbered from 2; rows 0, 3, 4 keep some, rows 1, 2 none
+    part = read_multilabel(str(path), label_range=(2, 5), list_width=2)
+    assert part.n_labels == 3 and part.labels.dtype == np.int32
+    np.testing.assert_array_equal(part.labels, [[1, -1], [-1, -1], [-1, -1], [0, 2], [1, -1]])
+    np.testing.assert_array_equal(part.indices, every.indices)
+    with pytest.raises(ValueError, match="label range"):
+        read_multilabel(str(path), label_range=(4, 9))
+    (tmp_path / "short.txt").write_text(FILE.replace("5 10 6", "6 10 6"))
+    with pytest.raises(ValueError, match="the header says 6 rows"):
+        read_multilabel(str(tmp_path / "short.txt"))
+
+
+def test_main_builds_the_squared_hinge_with_its_outputs_from_lists(monkeypatch, tmp_path):
+    from distributed_sgd_tpu import main as program
+    from distributed_sgd_tpu.config import Config
+
+    monkeypatch.setenv("DSGD_SYNTHETIC", "400")
+    train, test, model = program.build(Config(labels="lists", model="squared_hinge"))
+    assert train.n_labels == test.n_labels == program.SYNTHETIC_TOPICS
+    assert train.labels.dtype == np.int32 and train.labels.shape[0] == 320
+    assert type(model).__name__ == "SquaredHinge"
+    assert (model.n_outputs, model.regularizer) == (program.SYNTHETIC_TOPICS, "l2")
+    # the same rows and labels as labels='topics' hands out dense
+    dense, _test, _model = program.build(Config(labels="topics"))
+    np.testing.assert_array_equal(to_dense(train.labels, train.n_labels), dense.labels)
+    # a file in the repository's format, every label an output
+    monkeypatch.delenv("DSGD_SYNTHETIC")
+    (tmp_path / "train.txt").write_text(FILE)
+    train, _test, model = program.build(
+        Config(labels="lists", model="squared_hinge", data_path=str(tmp_path)))
+    assert (train.n_features, model.n_outputs, model.weight_shape) == (10, 6, (10, 6))
+    with pytest.raises(ValueError, match="model"):
+        Config(model="squared")
+
+
+# -- (vii) the scope, the counter, the record --------------------------------------------
+
+def _scopes(lowered):
+    return set(re.findall(r"dsgd\.[a-z_]+", lowered.compile().as_text()))
+
+
+@pytest.mark.parametrize("sparse", [False, True])
+def test_the_compiled_programs_carry_the_labels_scope(sparse, monkeypatch):
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0 if sparse else 10**9)
+    w, key = _weights(200), jax.random.PRNGKey(0)
+    for data, said in ((_listed(_dense(200)), True), (_dense(200), False)):
+        bound = _bind(data, 200)
+        d = bound.data
+        epoch = bound._epoch.lower(w, bound._opt_state, d.indices, d.values, d.labels, key)
+        evaluation = bound._eval.lower(w, d.indices, d.values, d.labels)
+        assert ("dsgd.labels" in _scopes(epoch)) == said
+        assert ("dsgd.labels" in _scopes(evaluation)) == said
+        if said:  # nested in the evaluation's own scope
+            assert re.search(r"dsgd\.eval/[^\"]*dsgd\.labels", evaluation.compile().as_text())
+
+
+def test_a_binding_on_lists_is_counted_and_logged(caplog):
+    counter = metrics_mod.global_metrics().counter("bind.labels.lists")
+    gathered = metrics_mod.global_metrics().counter("bind.labels.gathered")
+    before, before_gathered = counter.value, gathered.value
+    _bind(_dense(200), 200)
+    assert (counter.value, gathered.value) == (before, before_gathered + 1)
+    data = _listed(_dense(200))
+    model = make_model("squared_hinge", LAM, D, regularizer="l2", n_outputs=200)
+    trainer = SyncTrainer(model, make_mesh(1), BATCH, LR, virtual_workers=4,
+                          metrics=metrics_mod.Metrics())
+    with caplog.at_level(logging.INFO, logger="dsgd.trainer"):
+        trainer.fit(data.slice(slice(0, 384)), data.slice(slice(384, None)), max_epochs=1)
+    assert (counter.value, gathered.value) == (before + 2, before_gathered + 1)
+    record = next(r.getMessage() for r in caplog.records
+                  if r.getMessage().startswith("train split:"))
+    for said in ("kernel=gather", "outputs=200", "labels=lists", "scatter=words", "eval_rows=384"):
+        assert said in record
+
+
+def test_on_a_tpu_a_wide_binding_writes_rows_and_does_not_merge(monkeypatch, caplog):
+    """The cell's own shape asked of the rules: 203,882 features against
+    28,800 entries a step and 1,024 lanes are the other side of
+    `merges_scatter` twice over."""
+    assert not kernels.merges_scatter(203_882, 1_000, 28_800)
+    assert kernels.merges_scatter(203_882, 1_000, 60_000) is False  # the lanes alone
+    assert kernels.sparse_update("gather", "l2", True, 1e-7, 203_882, 1_000)
+    assert kernels.choose_kernel(203_882, 72, "tpu", "mxu", 1_000) == "gather"
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import mxu
+
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 0)
+    monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
+    data = _listed(_dense(200))
+    want = None
+    for on_tpu in (False, True):
+        monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None, said=on_tpu: said)
+        bound = SyncEngine(make_model("squared_hinge", LAM, D, regularizer="l2", n_outputs=200),
+                           make_mesh(1), BATCH, LR, eval_chunk=64, kernel="gather",
+                           virtual_workers=4).bind(data, steps_per_epoch=3)
+        assert (bound.scatter_rows, bound.scatter_merge) == (on_tpu, False)
+        w, key = _weights(200), jax.random.PRNGKey(3)
+        with pltpu.force_tpu_interpret_mode():
+            got = np.asarray(bound.epoch(w, key))
+        if want is None:
+            want = got
+    np.testing.assert_array_equal(got, want)  # the kernel's write is XLA's

@@ -612,6 +612,54 @@ def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices
     assert "f32[4096,76,128]" not in evaluation
 
 
+def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e):
+    """`amazoncat13k-dismec`'s programs compile for the chip (nothing ran):
+    1,000 outputs in eight lane groups, `W` carried as tiles
+    `[203,888, 8, 128]` (`gather.to_tiles`: a feature's 4 KB contiguous), the
+    update the row DMAs of `scatter_rows` on them, the label lists expanded
+    in the step and in the evaluation, whose chunk's row gather runs in
+    pieces of 512 samples (`kernels.margin_rows`).  And what the tiles are
+    there for: on `[D', 1,024]` the chip's compiler refuses the kernel (the
+    day the second half fails, the compiler has changed and the tiles can
+    go)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.ops import gather
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    d, c, rows, width = 203_882, 1_000, 4096 * 16, 72
+    mesh = Mesh(np.array(v5e.devices[:1]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+    data = ShardedData(shape((rows, width), jnp.int32, sharding=over_rows),
+                       shape((rows, width), jnp.float32, sharding=over_rows),
+                       shape((rows, 8), jnp.int32, sharding=over_rows), rows, width,
+                       label_lists=True)
+    model = make_model("squared_hinge", 8.4e-7, d, regularizer="l2", n_outputs=c)
+    bound = BoundSync(model, mesh, data, 100, 0.1, kernel="gather", virtual_workers=4)
+    assert bound.update_sparse and bound.scatter_rows and not bound.scatter_merge
+    assert bound.rows_tiled and bound.labels_as == "lists" and bound.eval_rows == 512
+    w = shape((d, c), jnp.float32, sharding=everywhere)
+    step = bound._step.lower(w, (), data.indices, data.values, data.labels,
+                             shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
+    kernel = [line for line in step.split("\n") if " custom-call(" in line
+              and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernel) == 1 and "f32[203888,8,128]" in kernel[0]
+    assert "dsgd.scatter/scatter_rows" in kernel[0] and "dsgd.labels" in step
+    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
+    assert "f32[36864,8,128]" in evaluation  # a piece's 512 x 72 gathered tiles
+    assert "f32[294912,8,128]" not in evaluation and "dsgd.labels" in evaluation
+    # the same kernel on rows of eight lane groups that are NOT tiles
+    flat = shape((2048, 1024), jnp.float32, sharding=everywhere)
+    entries = (shape((256,), jnp.int32, sharding=everywhere),
+               shape((256,), jnp.float32, sharding=everywhere),
+               shape((256,), jnp.int32, sharding=everywhere),
+               shape((400, 1024), jnp.float32, sharding=everywhere))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda w2, *e: gather.scatter_rows_into(w2, *e, dma=True)).lower(
+            flat, *entries).compile()
+
+
 # -- (j) a row's label rides in a spare word of the stored row ----------------------
 # (parallel/mesh.py `label_slot`, `BoundSync.draw_rows`; PERF.md section 6, PR 33)
 
