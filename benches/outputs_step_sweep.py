@@ -23,9 +23,18 @@ entries a step, the generator's own 1/r draw of feature ids):
   inside: the DMA a touched row (`_run_sums`, `_add_rows`, `_write_rows`)
   against the merge pass (`_merge_rows`) by its block, piece and product
   width, then both by the rows of `W2` from 47,240 up: the readings beside
-  `gather.MERGE_*` and `kernels.MERGE_MAX_ROWS_PER_ENTRY`.
+  `gather.MERGE_*` and `kernels.MERGE_MAX_ROWS_PER_ENTRY`.  Since PR 37
+  the other ending is the walk of the sorted factors (`_sum_runs_into`):
+  `runs` in the table by rows, beside what it replaced (`a_dma_a_row`);
+- `runs` (PR 37; not in the default run): ONE call of that walk against the
+  path it replaced (the entry rows, `_run_sums`, `_add_rows` with the DMA
+  write) on 28,800 entries (`amazoncat13k-dismec`'s step: ids under the
+  generator's law over 203,882 features, 400 samples) into tiles
+  `[203,888, 8, 128]`, us a call with the sort inside; then the walk by its
+  constants, and by entries and by heads (distinct ids) varied apart: what
+  says whether the scalar core's time goes to the entries or to the DMAs.
 
-    python benches/outputs_step_sweep.py [--rehearse] [--only step,forms,merge]
+    python benches/outputs_step_sweep.py [--rehearse] [--only step,forms,merge,runs]
 
 Prints one JSON document (a line a row on stderr as it goes).  Refuses a
 CPU unless `--rehearse` (tiny shapes, no timing worth reading).
@@ -66,12 +75,14 @@ def main(argv) -> int:
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmark", "configs",
                            "rcv1-topics-hinge.json")) as f:
         config = json.load(f)
-    spec = dict(config["data"], rows_per_chip=409_600, block_rows=40_960)
-    train = rcv1_topics_like.generate(spec, 32, [device], rehearse).train
-    n_features, n_outputs = train.n_features, int(config["n_outputs"])
+    n_outputs = int(config["n_outputs"])
     lam, lr = float(config["lam"]), float(config["learning_rate"])
-    out = {"device": device.device_kind, "rows": len(train), "n_features": n_features,
-           "n_outputs": n_outputs}
+    out = {"device": device.device_kind, "n_outputs": n_outputs}
+    if "step" in only or "forms" in only:  # the configuration's own rows
+        spec = dict(config["data"], rows_per_chip=409_600, block_rows=40_960)
+        train = rcv1_topics_like.generate(spec, 32, [device], rehearse).train
+        n_features = train.n_features
+        out.update(rows=len(train), n_features=n_features)
 
     @contextlib.contextmanager
     def forced(module, name, answer):
@@ -192,19 +203,24 @@ def main(argv) -> int:
                 ids, entry = gather._entry_rows((ids0 + i) % d, val, src, coeff)
                 return after_entry_rows(w2, ids, entry)
 
-            run = jax.jit(lambda w2: jax.lax.fori_loop(0, calls, call, w2))
-            w2 = jnp.zeros((rows, 128), jnp.float32)
-            best = float("inf")
+            return clocked(jax.jit(lambda w2: jax.lax.fori_loop(0, calls, call, w2)),
+                           jnp.zeros((rows, 128), jnp.float32))
+
+        def clocked(run, w2):
             with interpreted():
-                jax.block_until_ready(run(w2))
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(run(w2))
-                    best = min(best, time.perf_counter() - t0)
-            return best / calls * 1e6
+                return best_us(run, w2, calls, reps)
 
         def today(w2, ids, entry):
-            return gather._add_runs(w2, ids, entry, dma)
+            return a_dma_a_row(w2, ids, entry, dma)
+
+        def timed_runs(rows):  # the walk, from the factors: no entry rows
+            d, ids0 = ids_of(rows)
+
+            def call(i, w2):
+                return gather.scatter_rows_into(w2, (ids0 + i) % d, val, src, coeff, dma=dma)
+
+            return clocked(jax.jit(lambda w2: jax.lax.fori_loop(0, calls, call, w2)),
+                           jnp.zeros((rows, 128), jnp.float32))
 
         def merged(block, sub, wide):
             return lambda w2, ids, entry: gather._merge_rows(w2, ids, entry, block, sub, wide)
@@ -233,11 +249,113 @@ def main(argv) -> int:
         print(json.dumps(table), file=sys.stderr, flush=True)
         for factor in (1, 2) if rehearse else (1, 2, 3, 4, 8):
             out["merge"]["by_rows"][rows * factor] = row = {
-                "today": timed(rows * factor, today),
+                "a_dma_a_row": timed(rows * factor, today),
+                "runs": timed_runs(rows * factor),
                 "merge": timed(rows * factor, gather._merge_rows)}
             print(json.dumps({rows * factor: row}), file=sys.stderr, flush=True)
+    if "runs" in only:
+        out["runs"] = runs_table(jax, jnp, gather, device.platform == "tpu", rehearse)
     print(json.dumps(out))
     return 0
+
+
+def best_us(run, w, calls, reps):
+    """us a call of `run`, `calls` calls a run: the best of `reps` runs after
+    one that compiles, each on the weights the last one gave back."""
+    import jax
+
+    best = float("inf")
+    w = jax.block_until_ready(run(w))
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        w = jax.block_until_ready(run(w))
+        best = min(best, time.perf_counter() - t0)
+    return best / calls * 1e6
+
+
+def a_dma_a_row(w2, ids, entry, dma):
+    """What PR 37's walk replaced as `scatter_rows_into`'s DMA ending: the
+    sorted entry rows' runs summed on the MXU, every touched row fetched,
+    added to and written back by `_write_rows`."""
+    import jax.numpy as jnp
+
+    from distributed_sgd_tpu.ops import gather
+
+    head = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+    return gather._add_rows(w2, ids, head, gather._run_sums(ids, entry), dma)
+
+
+def runs_table(jax, jnp, gather, on_tpu, rehearse):
+    """The `runs` section: `gather._sum_runs_into` at `amazoncat13k-dismec`'s
+    shape against the path it replaced, by its constants, by entries and by
+    heads."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    calls, reps = (2, 1) if rehearse else (50, 2)
+    rows, d, lanes, samples, entries = ((512, 500, 256, 8, 1024) if rehearse
+                                        else (203_888, 203_882, 1_024, 400, 28_800))
+    rng = np.random.default_rng(37)
+    coeff = jnp.asarray(rng.normal(size=(samples, lanes)) * 0.01, jnp.float32)
+    interpreted = contextlib.nullcontext if on_tpu else pltpu.force_tpu_interpret_mode
+
+    def factors(n, heads=None):
+        """`n` entries: ids under the generator's law over the features, or
+        uniform over `heads` distinct ones spread over them."""
+        if heads is None:
+            ids = np.minimum(np.exp(rng.uniform(0.0, np.log(d + 1.0), n)).astype(np.int64) - 1,
+                             d - 1)
+        else:
+            ids = (np.arange(heads) * (d // heads))[rng.integers(0, heads, n)]
+        return (jnp.asarray(ids, jnp.int32), jnp.asarray(rng.normal(size=n) * 0.1, jnp.float32),
+                jnp.asarray(rng.integers(0, samples, n), jnp.int32))
+
+    def clocked(ending, ids0, val, src):
+        def call(i, w):  # other ids every call, one law
+            return ending(w, (ids0 + i) % d, val, src)
+
+        run = jax.jit(lambda w: jax.lax.fori_loop(0, calls, call, w), donate_argnums=0)
+        with interpreted():  # 835 MB of weights: each run takes the last one's, donated
+            return best_us(run, jnp.zeros((rows, lanes // 128, 128), jnp.float32), calls, reps)
+
+    def replaced(w, ids, val, src):
+        with jax.named_scope("dsgd.scatter"):
+            return a_dma_a_row(w, *gather._entry_rows(ids, val, src, coeff), on_tpu)
+
+    def walk(block=gather.RUN_BLOCK, unroll=gather.RUN_UNROLL):
+        def ending(w, ids, val, src):
+            return gather._sum_runs_into(
+                w, *gather._sorted_entries(ids, val, src, block), coeff, block, unroll)
+        return ending
+
+    def sort_alone(w, ids, val, src):
+        ids, val, src = gather._sorted_entries(ids, val, src, gather.RUN_BLOCK)
+        return w.at[0, 0, 0].add(jnp.sum(ids) + jnp.sum(val) + jnp.sum(src))
+
+    law = factors(entries)
+    out = {"calls": calls, "entries": entries, "rows": rows, "lanes": lanes,
+           "heads_under_the_law": int(np.unique(np.asarray(law[0])).size)}
+    # one call of each on the same weights
+    w = gather.to_tiles(jnp.asarray(rng.normal(size=(rows, lanes)), jnp.float32))
+    with interpreted():
+        apart = jnp.abs(jax.jit(replaced)(w, *law) - jax.jit(walk())(w, *law))
+    out["max_abs_apart"] = float(jnp.max(apart))
+    del w, apart
+    out["sort_alone"] = clocked(sort_alone, *law)
+    out["replaced"] = clocked(replaced, *law)
+    out["walk"] = clocked(walk(), *law)
+    print(json.dumps(out), file=sys.stderr, flush=True)
+    out["by_constants"] = table = {}
+    for block, unroll in ((128, 4),) if rehearse else (
+            (256, 16), (1024, 16), (512, 8), (512, 32)):
+        table[f"block{block}_unroll{unroll}"] = clocked(walk(block, unroll), *law)
+        print(json.dumps(table), file=sys.stderr, flush=True)
+    out["by_entries_and_heads"] = table = {}
+    for n, heads in ((512, 16), (1024, 16)) if rehearse else (
+            (28_800, 2_880), (28_800, 11_520), (28_800, 23_040),
+            (14_400, 2_880), (14_400, 11_520), (57_600, 11_520)):
+        table[f"entries{n}_heads{heads}"] = clocked(walk(), *factors(n, heads))
+        print(json.dumps(table), file=sys.stderr, flush=True)
+    return out
 
 
 if __name__ == "__main__":

@@ -124,8 +124,7 @@ class SyncTrainer:
                  "merged" if bound_train.margins_merged else "per_worker",
                  bound_train.scatter_shards,
                  "sparse" if bound_train.update_sparse else "dense",
-                 "merge" if bound_train.scatter_merge else
-                 "rows" if bound_train.scatter_rows else "words",
+                 bound_train.scatter_as,
                  self.model.n_outputs,
                  bound_train.labels_as, bound_train.eval_rows, stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"

@@ -46,20 +46,29 @@ summed before they meet its weight, as the dense step's accumulator sums
 them.  Off the TPU the same rows are written by XLA's scatter.
 
 With an output axis (`scatter_rows_into`: an entry's update is a whole row
-of `w2 [D', L]`) the same steps cost a row an ENTRY: at `rcv1-topics-hinge`'s
-shape (30,400 entries a step on ~9,230 of 47,240 rows) 286 us after the sort
-and the entry rows, 135 of them the kernel's 9,230 DMAs (PERF.md section 5,
-PR 32).  Where `w2` is that small beside a step's entries
+of `w2 [D', L]`) those steps cost a row an ENTRY: at `amazoncat13k-dismec`'s
+shape (28,800 entries a step on ~11,500 of 203,882 rows of 4 KB) they moved
+an array of 118 MB nine times, 2,064 us of a 3,034 us step (ledger, PR 36).
+So the entries are handed over as their FACTORS (id, value, sample) beside
+the samples' coefficient rows, and after the sort of three words an entry
+ONE kernel of ours ends the step (PR 37, `_sum_runs_into`): the scalar core
+walks the sorted factors, a run of an id is summed out of the coefficient
+table held in VMEM (at 1,024 lanes a sample's row is one register: a load, a
+splat, a multiply-add an entry, 3.6 ns) and every touched row is DMA'd in,
+added to and DMA'd back once, 17 ns a DMA: 545 us a step with the sort
+where the path it replaced took 2,453 (my chip runs, PR 37: the table beside
+RUN_BLOCK).  Where `w2` is small beside a step's entries
 (`kernels.merges_scatter`: at most 4 rows an entry) the sorted entries are
 MERGED into it instead (PR 35, `_merge_rows`): ONE kernel a step streams
 `w2` through VMEM in 1 MiB blocks and adds the band of sorted entries that
 falls on each block as a 0 / 1 product on the MXU, 128 entries against 128
 to 512 rows a product, the entry rows in three bfloat16 pieces so that
-float32 sums come out (`split3`): 126 + 17 us where the DMA path took 286
-(my chip runs, PR 35, `rcv1-topics-sync-1chip` traced).  What that pass
-pays is a product for every (chunk of entries, piece of rows) pair whether
-it holds one entry or 128, ~0.2 us each, and a pass over `w2`: near 6 rows
-an entry the DMA path is ahead again (the table beside
+float32 sums come out (`split3`): 126 + 17 us at `rcv1-topics-hinge`'s shape
+(30,400 entries on ~9,230 of 47,240 rows of 512 B) where a DMA a touched
+row took 286 and the walk takes 347 (my chip runs, PR 35 and PR 37).  What
+that pass pays is a product for every (chunk of entries, piece of rows) pair
+whether it holds one entry or 128, ~0.2 us each, and a pass over `w2`: near
+6 rows an entry the walk is ahead (the table beside
 `kernels.MERGE_MAX_ROWS_PER_ENTRY`), and `scatter_into`'s words (one lane of
 a row an entry, `w` of 219 MB) never ask.
 
@@ -74,8 +83,9 @@ scatter 8.0 ns with the four workers' replies kept apart, as
 written and timed against these in isolation and lost (gather 10.6 ns an
 entry against 4.2-4.6, scatter 19 ns against 8-12): the walk is bound by
 the scalar core's address arithmetic and, in the scatter, by the load that
-has to wait for the previous entry's store.  `_write_rows` walks nothing in
-VMEM: its scalar loop only starts DMAs (17-20 ns a row eight starts a turn,
+has to wait for the previous entry's store (`_sum_runs_into` walks whole
+registers and carries its sum in one: no load waits).  `_write_rows` walks
+nothing in VMEM: its scalar loop only starts DMAs (17-20 ns a row eight starts a turn,
 29 one; a turn that tests a flag first costs 31 ns whether or not it then
 writes) and waits for a row only when its ring of semaphores comes round.
 Everything is float32: a gather rounds nothing.
@@ -582,41 +592,255 @@ def _merge_rows(w2: jax.Array, ids: jax.Array, entry: jax.Array, block: int = 0,
     return merge(lo, hi, first, last, by_chunk, entry, w2)
 
 
+# The walk (`_sum_runs_into`) takes the sorted entries RUN_BLOCK at a time
+# (the heads of a block are what its DMAs move: two buffers of that many
+# tiles in VMEM each way) and RUN_UNROLL entries, or DMA starts, a turn of its
+# scalar loops.  Timed on a v5e, one call of `scatter_rows_into` (the sort of
+# three words an entry, 42-44 us, inside) on `amazoncat13k-dismec`'s step:
+# 28,800 entries under the generator's law over 203,882 features (11,543
+# heads), 400 samples, tiles `[203,888, 8, 128]`, us a call
+# (`benches/outputs_step_sweep.py --only runs`; my chip runs, PR 37):
+#
+#     what the walk replaced (entry rows, `_run_sums`, a DMA a row)  2,452.6
+#     block   512,  8 entries a turn,  8 starts, a wait a DMA          589.9
+#     block   512, 16,                16,        a wait a DMA          558.4
+#     block   512, 16,                16,        a wait a bit          545.6
+#     block   512,  8 / 16 starts  559.0    16 / 8  555.0    32 / 16   540.5
+#     block   256, 16, 16          556.0    block 1,024, 16, 16        544.3
+#
+# and taken apart (block 512, 16, 16): the walk with no DMA at all 145.9
+# (3.6 ns an entry after the sort), the DMAs with the entries' vector work
+# left out 486.3: the two add up, and what is left after the sort is 17 ns
+# a row DMA (a head costs two), 11 ns where the heads lie 8 rows apart and
+# 24 where they lie 70 apart.  By entries and heads (distinct ids spread
+# evenly; block 512, 8, 8): 28,800 entries on 2,880 / 11,520 / 23,040 heads
+# 317.5 / 543.9 / 711.2; 14,400 entries on 2,880 / 11,520 heads 209.6 /
+# 365.1; 57,600 on 11,520 793.2.
+RUN_BLOCK = 512
+RUN_UNROLL = 16
+# VMEM the walk may ask for: the coefficient table (a tile a sample of ALL
+# the mesh's workers) beside its four buffers; a v5e has 128 MiB.
+RUN_MAX_VMEM_BYTES = 96 * 2 ** 20
+
+
+def _sum_runs_into(w: jax.Array, ids: jax.Array, values: jax.Array, src: jax.Array,
+                   coeff: jax.Array, block: int = 0, unroll: int = RUN_UNROLL) -> jax.Array:
+    """`w` with `values[t] * coeff[src[t]]` added to row `ids[t]`, `ids`
+    SORTED ascending and T' whole blocks of `block` entries (0: RUN_BLOCK),
+    in place: ONE TPU kernel that never builds an entry's row.  `w` is
+    `[D', 128]` or tiles `[D', L / 128, 128]` (`to_tiles`) and stays in HBM;
+    the sorted factors lie in scalar memory, the coefficient table in VMEM
+    as tiles `[S, L / 128, 128]` (at 1,024 lanes a sample's row is ONE
+    register).
+
+    The scalar core walks the entries, `unroll` a turn: an entry is a load
+    of its sample's tile, a splat of its value and a multiply-add into a
+    carried float32 accumulator that starts over at the first entry of a
+    run (a select on `ids[e] != ids[e - 1]`, no branch), stored to the
+    run's slot of a staging buffer every entry, so the last store of a run
+    is its sum.  Block by block of entries: the block's touched rows are
+    DMA'd in while the NEXT block is walked, added to their staged sums and
+    DMA'd back, every touched row read once and written once.  A run that
+    goes on into the next block is not written by this one: the accumulator
+    is carried over and the next block's first slot holds the whole run, so
+    no row is in flight twice (the reads of a block are started before the
+    writes of the one before it have landed)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    block = block or RUN_BLOCK
+    tiled = w.ndim == 3
+    tile = w.shape[1:] if tiled else (1, w.shape[1])  # a row, as VMEM holds it
+    samples = coeff.shape[0]
+    assert block % unroll == 0 and block % SUBLANES == 0, (block, unroll)
+    # VMEM holds a tile in whole registers of 8 sublanes
+    need = 4 * (-(-tile[0] // SUBLANES) * SUBLANES) * tile[1] * (samples + 4 * block)
+    if need > RUN_MAX_VMEM_BYTES:
+        raise ValueError(
+            f"the coefficient rows of {samples} samples x {math.prod(tile)} lanes do not fit "
+            f"the VMEM the scatter's walk keeps them in ({need} > {RUN_MAX_VMEM_BYTES} bytes)")
+
+    table_of_tiles = coeff.astype(jnp.float32).reshape((samples,) + tile)
+
+    def sum_entries(w, ids, values, src):  # one call: what scalar memory holds
+        n = ids.shape[0]
+        n_blocks = n // block
+
+        def kernel(ids_ref, val_ref, src_ref, table_ref, w_ref, out_ref,
+                   table, staged, held, to, sem_table, sem_in, sem_out):
+            def row(ref, r):  # weight row r, as a DMA names it
+                return ref.at[r] if tiled else ref.at[pl.ds(r, 1)]
+
+            def read(buf, k, r):
+                return pltpu.make_async_copy(
+                    row(w_ref, r), held.at[buf * block + k], sem_in.at[buf])
+
+            def write(buf, k, r):
+                return pltpu.make_async_copy(
+                    held.at[buf * block + k], row(out_ref, r), sem_out.at[buf])
+
+            def each(count, one):  # one(k) for k < count, `unroll` a turn
+                whole = count // unroll
+
+                def turn(t, carry):
+                    for k in range(unroll):
+                        one(t * unroll + k)
+                    return carry
+
+                jax.lax.fori_loop(0, whole, turn, None)
+                jax.lax.fori_loop(whole * unroll, count, lambda k, carry: one(k), None)
+
+            def landed(sem, at, count):
+                """`count` row DMAs of the block whose buffer starts at
+                `at`, waited for: a DMA semaphore counts bytes, so one wait
+                a set bit of `count`, for that many rows' worth."""
+                for bit in reversed(range(block.bit_length())):
+                    @pl.when(count & (1 << bit) != 0)
+                    def _():
+                        rows = held.at[pl.ds(at, 1 << bit)]
+                        pltpu.make_async_copy(rows, rows, sem).wait()
+
+            def walk(b, acc, prev):
+                """Block b's runs summed into its slots: (the open run's
+                sum, its id, the slots this block writes)."""
+                base, at = b * block, b % 2 * block
+                # a run that came in with `acc` takes slot 0 as a new one does
+                slot = jnp.where(ids_ref[base] != prev, -1, 0)
+
+                def turn(t, carry):
+                    acc, prev, slot = carry
+                    for k in range(unroll):
+                        e = base + t * unroll + k
+                        i = ids_ref[e]
+                        head = i != prev
+                        slot = slot + head.astype(jnp.int32)
+                        term = val_ref[e] * table[src_ref[e]]
+                        acc = jnp.where(head, term, acc + term)
+                        staged[at + slot] = acc
+                        to[at + slot] = i
+                        prev = i
+                    return acc, prev, slot
+
+                acc, prev, slot = jax.lax.fori_loop(
+                    0, block // unroll, turn, (acc, prev, slot))
+                ahead = ids_ref[jnp.minimum(base + block, n - 1)]
+                goes_on = jnp.logical_and(b + 1 < n_blocks, ahead == prev)
+                return acc, prev, slot + 1 - goes_on.astype(jnp.int32)
+
+            def start_reads(b, count):
+                buf = b % 2
+                each(count, lambda k: read(buf, k, to[buf * block + k]).start())
+
+            def finish(b, count):  # block b's rows, landed: + their sums, and back
+                buf = b % 2
+                at = buf * block
+                landed(sem_in.at[buf], at, count)
+
+                def add(t, carry):
+                    rows = pl.ds(pl.multiple_of(at + t * SUBLANES, SUBLANES), SUBLANES)
+                    held[rows] = held[rows] + staged[rows]
+                    return carry
+
+                jax.lax.fori_loop(0, pl.cdiv(count, SUBLANES), add, None)
+                each(count, lambda k: write(buf, k, to[at + k]).start())
+
+            def wait_writes(buf, count):
+                landed(sem_out.at[buf], buf * block, count)
+
+            fill = pltpu.make_async_copy(table_ref, table, sem_table.at[0])
+            fill.start()
+            fill.wait()
+            acc, prev, first = walk(0, jnp.zeros(tile, jnp.float32), jnp.int32(-1))
+            start_reads(0, first)
+
+            def pass_block(b, carry):  # block b + 1 walked while block b's rows come in
+                acc, prev, count, before = carry
+                acc, prev, ahead = walk(b + 1, acc, prev)
+                wait_writes((b + 1) % 2, before)  # block b - 1's, out of b + 1's buffers
+                start_reads(b + 1, ahead)
+                finish(b, count)
+                return acc, prev, ahead, count
+
+            _, _, count, before = jax.lax.fori_loop(
+                0, n_blocks - 1, pass_block, (acc, prev, first, jnp.int32(0)))
+            wait_writes(n_blocks % 2, before)
+            finish(n_blocks - 1, count)
+            wait_writes((n_blocks - 1) % 2, count)
+
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype, vma=jax.typeof(w).vma),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,  # the sorted factors: scalar memory
+                grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[
+                    pltpu.VMEM((samples,) + tile, jnp.float32),  # the coefficient table
+                    pltpu.VMEM((2 * block,) + tile, jnp.float32),  # two blocks' run sums
+                    pltpu.VMEM((2 * block,) + tile, jnp.float32),  # their weight rows
+                    pltpu.SMEM((2 * block,), jnp.int32),  # the rows' numbers
+                    pltpu.SemaphoreType.DMA((1,)),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SemaphoreType.DMA((2,)),
+                ],
+            ),
+            input_output_aliases={4: 0},
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=need + 16 * 2 ** 20),
+            name="scatter_runs",
+        )(ids, values, src, table_of_tiles, w)
+
+    # a run cut by a call's end is added by both calls, one after the other
+    for lo in range(0, ids.shape[0], DMA_BLOCK):
+        call = slice(lo, lo + DMA_BLOCK)
+        w = sum_entries(w, ids[call], values[call], src[call])
+    return w
+
+
 def scatter_rows_into(w2: jax.Array, ids: jax.Array, values: jax.Array, src: jax.Array,
                       coeff: jax.Array, dma: bool = False, merge: bool = False) -> jax.Array:
     """`w2` with `values[t] * coeff[src[t]]` added to row `ids[t]`:
     `scatter_into` for updates that ARE rows, handed over as their factors
     (an entry's value and the sample it belongs to; the samples'
     coefficient rows `coeff [S, L]`), so that the sort moves three words an
-    entry and no [T, L] array of updates is written before it.  The entries
-    are sorted by id and each takes its sample's coefficient row (a row
-    gather from a table of S rows: `_entry_rows`, 23 + 39 + 17 us for 30,400
-    entries on a v5e).  Then `merge` (a TPU, `w2` small beside the step's
-    entries: `kernels.merges_scatter`): ONE pass over `w2` adds them all
-    (`_merge_rows`, 126 us at `w2 [47,240, 128]`); else the runs of an id
-    are summed on the MXU and every touched row is fetched, added to and
-    written back once, as `scatter_into` does it (`_add_runs`: 286 us at
-    that shape with the DMA write, which has no term in the rows of `w2`;
-    `dma` False: XLA's scatter writes them, off the TPU)."""
+    entry and no [T, L] array of updates is written before it.  Three
+    endings after the sort.  `dma` (a TPU): the sorted factors go to ONE
+    kernel that sums every run of an id out of the coefficient table and
+    reads, adds to and writes every touched row once (`_sum_runs_into`: no
+    array of entry rows at all, no term in the rows of `w2`).  `merge` (a
+    TPU, `w2` small beside the step's entries: `kernels.merges_scatter`):
+    each entry takes its sample's coefficient row (`_entry_rows`, 23 + 39 +
+    17 us for 30,400 entries on a v5e) and ONE pass over `w2` adds them all
+    (`_merge_rows`, 126 us at `w2 [47,240, 128]`).  Neither (off the TPU):
+    the entry rows' runs are summed and XLA's scatter writes the touched
+    rows (`_add_runs`)."""
     with jax.named_scope("dsgd.scatter"):
+        if dma and not merge:
+            return _sum_runs_into(w2, *_sorted_entries(ids, values, src, RUN_BLOCK), coeff)
         ids, entry = _entry_rows(ids, values, src, coeff)
-        return _merge_rows(w2, ids, entry) if merge else _add_runs(w2, ids, entry, dma)
+        return _merge_rows(w2, ids, entry) if merge else _add_runs(w2, ids, entry)
 
 
-def _add_runs(w2: jax.Array, ids: jax.Array, entry: jax.Array, dma: bool) -> jax.Array:
+def _add_runs(w2: jax.Array, ids: jax.Array, entry: jax.Array) -> jax.Array:
     """`w2` with `entry[t]` added to row `ids[t]` (sorted, whole chunks) a
-    row at a time: the runs of an id summed on the MXU, every touched row
-    fetched, added to and written back once."""
+    row at a time: the runs of an id summed (`_run_sums`), every touched row
+    fetched, added to and written back once by XLA's scatter."""
     head = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
-    return _add_rows(w2, ids, head, _run_sums(ids, entry), dma)
+    return _add_rows(w2, ids, head, _run_sums(ids, entry), dma=False)
+
+
+def _sorted_entries(ids: jax.Array, values: jax.Array, src: jax.Array, multiple: int):
+    """(ids int32[T'], values f32[T'], src int32[T']): a step's entries
+    sorted by id, padded to a whole `multiple` with the pad entry (0.0 x
+    sample 0 on feature 0)."""
+    pad = (0, -ids.shape[0] % multiple)
+    ids, src = jnp.pad(ids, pad), jnp.pad(src.astype(jnp.int32), pad)
+    values = jnp.pad(values.astype(jnp.float32), pad)
+    return jax.lax.sort((ids, values, src), num_keys=1, is_stable=False)
 
 
 def _entry_rows(ids: jax.Array, values: jax.Array, src: jax.Array, coeff: jax.Array):
     """(ids int32[T'], entry f32[T', L]): a step's entries sorted by id,
-    padded to whole chunks with the pad entry (0.0 x sample 0 on feature 0),
-    each with its update row `values[t] * coeff[src[t]]`."""
-    pad = (0, -ids.shape[0] % CHUNK)
-    ids, src = jnp.pad(ids, pad), jnp.pad(src.astype(jnp.int32), pad)
-    values = jnp.pad(values.astype(jnp.float32), pad)
-    ids, values, src = jax.lax.sort((ids, values, src), num_keys=1, is_stable=False)
+    whole chunks, each with its update row `values[t] * coeff[src[t]]`."""
+    ids, values, src = _sorted_entries(ids, values, src, CHUNK)
     return ids, values[:, None] * coeff.astype(jnp.float32)[src]

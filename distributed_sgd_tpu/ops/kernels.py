@@ -28,7 +28,7 @@ gradient), `sparse_update` (no gradient at all: the entries scattered
 into the carried weights, the regulariser a scalar on them, so that a
 step's bytes have no term in the feature count), `merges_scatter`
 (with an output axis, whether that scatter is one pass over the weights
-or a DMA a touched row) and `margin_rows` (with an output axis, how many
+or a walk of its entries that moves each touched row once) and `margin_rows` (with an output axis, how many
 samples one row gather of the margins takes).
 """
 
@@ -197,20 +197,27 @@ def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
 # sparse step ends in the merge pass (`merges_scatter`).  Timed on a v5e,
 # one call of `gather.scatter_rows_into` with each ending on 30,400 entries
 # (ids under the generator's law at every D'), `W2 [D', 128]`, us a call
-# (`benches/outputs_step_sweep.py --only merge`; my chip runs, PR 35):
+# (`benches/outputs_step_sweep.py --only merge`; my chip runs, PR 37: the
+# walk of the sorted factors, `gather._sum_runs_into`, the other ending since
+# then; what it replaced, a DMA a row of entry rows summed on the MXU, beside
+# it, as PR 35 timed it too):
 #
-#     D' =  47,240 (1.55 rows an entry)   a DMA a row 353.1   merged 215.3
-#     D' =  94,480 (3.1)                              371.7          269.1
-#     D' = 141,720 (4.7)                              403.2          323.3
-#     D' = 188,960 (6.2)                              422.5          434.1
-#     D' = 377,920 (12.4)                             572.5          729.9
+#     D' =  47,240 (1.55 rows an entry)  the walk 370.7  a DMA a row 352.2  merged 216.6
+#     D' =  94,480 (3.1)                          393.5              371.0         270.7
+#     D' = 141,720 (4.7)                          405.3              404.9         320.7
+#     D' = 188,960 (6.2)                          418.3              418.3         432.7
+#     D' = 377,920 (12.4)                         603.4              572.6         729.6
 #
-# The DMA path has no term in D' but the ids the law spreads over more rows
+# The walk has no term in D' but the ids the law spreads over more rows
 # (and `W2` leaving on-chip memory); the pass moves every row of `W2`
 # through VMEM and multiplies every 128-row piece a chunk's ids reach, ~2 us
-# a thousand rows.  They cross just under 6.2 rows an entry; the constant
-# sits under the crossing, between points where the pass is 28 and 20 % ahead.
+# a thousand rows.  They cross just under 6.2 rows an entry, where PR 35 read
+# the crossing against the DMA a row (on 512 B rows the walk is what that
+# was, to 5 %: a row is one sublane and not a register there, and both are
+# bound by the row DMAs); the constant sits under the crossing, between
+# points where the pass is 21 and 3 % ahead.
 # `rcv1-topics-hinge` asks at 1.55 (47,236 features, 4 x 100 x 76 entries);
+# `amazoncat13k-dismec` at 7.1 and on 1,024 lanes (the walk, twice over);
 # `kdd2012-logistic`'s words would read 12,400 and never ask (one output).
 MERGE_MAX_ROWS_PER_ENTRY = 4
 # Lanes of a weight row up to which the pass's blocks fit the VMEM a kernel
@@ -224,11 +231,11 @@ def merges_scatter(n_features: int, n_outputs: int, n_entries: int) -> bool:
     (`gather._merge_rows`: every block of weight rows through VMEM once, its
     band of the entries placed by a 0 / 1 product on the MXU) instead of
     fetching, adding to and writing back every touched row by itself
-    (`gather._add_rows`, a DMA a row): where `W2` is small beside what a
+    (`gather._sum_runs_into`, two DMAs a row): where `W2` is small beside what a
     step touches, `n_features` rows against `n_entries` entries of ALL the
     mesh's workers.  From shapes alone; a TPU's question (`BoundSync` asks
-    once a binding where the DMA kernel would run and counts the answer
-    under `bind.scatter.merge`)."""
+    once a binding where a kernel of ours would run and counts the answer
+    under `bind.scatter.merge`, or `bind.scatter.runs`)."""
     from distributed_sgd_tpu.ops import gather
 
     return (n_features <= MERGE_MAX_ROWS_PER_ENTRY * n_entries

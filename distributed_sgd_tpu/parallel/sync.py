@@ -255,19 +255,22 @@ class BoundSync:
         if self.update_sparse:
             metrics.counter("bind.update.sparse").increment()
         # which kernel of ours that scatter ends in, on a TPU (the kernel
-        # rule's own platform probe; elsewhere XLA writes the same rows):
-        # with an output axis and weights small beside a step's entries
-        # (kernels.merges_scatter) ONE pass over the weights that merges the
-        # sorted entries in (gather.scatter_rows_into), else a DMA a touched
-        # row (gather.scatter_into)
+        # rule's own platform probe; elsewhere XLA writes the same rows).
+        # With an output axis: where the weights are small beside a step's
+        # entries (kernels.merges_scatter) ONE pass over them that merges
+        # the sorted entries in, else ONE walk of the sorted entries that
+        # sums every run of an id and moves each touched row once
+        # (gather.scatter_rows_into).  Without: a DMA a touched row of
+        # words summed on the MXU (gather.scatter_into)
         on_tpu = self.update_sparse and mxu.blocked_pays_off(mesh.devices.flat[0])
         self.scatter_merge = (on_tpu and model.n_outputs > 1 and kernels.merges_scatter(
             model.n_features, model.n_outputs,
             self.n_workers * self.virtual_workers * self.batch_size * row_width))
         self.scatter_rows = on_tpu and not self.scatter_merge
+        self.scatter_as = ("merge" if self.scatter_merge else "words" if not on_tpu
+                           else "runs" if model.n_outputs > 1 else "rows")
         if on_tpu:
-            metrics.counter(
-                "bind.scatter.merge" if self.scatter_merge else "bind.scatter.rows").increment()
+            metrics.counter(f"bind.scatter.{self.scatter_as}").increment()
         # whether weight rows of more than one lane group are carried as
         # tiles [D', L / 128, 128] (gather.to_tiles: a feature's weights
         # contiguous, what the row DMA can name): everywhere but under the

@@ -239,32 +239,53 @@ def test_the_fits_of_label_ranges_are_the_columns_of_the_fit_of_all():
                                whole.test_losses, rtol=1e-5)
 
 
-# -- (v) the DMA-a-row ending on rows of two and eight lane groups ------------------------
+# -- (v) the walk of the sorted entries on rows of one, two and eight lane groups -----------
 
-@pytest.mark.parametrize("lanes", [256, 1024])
-def test_the_row_write_on_wide_rows_is_the_float64_scatter_add(lanes):
-    """`scatter_rows_into` with the kernel `_write_rows` (Pallas' TPU
-    interpret mode) on weight rows of 1 KB and 4 KB: the sorted entries'
-    runs summed on the MXU, every touched row written once."""
+@pytest.mark.parametrize("lanes", [128, 256, 1024])
+def test_the_row_write_on_wide_rows_is_the_float64_scatter_add(lanes, monkeypatch):
+    """`scatter_rows_into` with the kernel `scatter_runs` (`_sum_runs_into`,
+    Pallas' TPU interpret mode) on weight rows of 512 B (`[D', 128]`), 1 KB
+    and 4 KB (tiles): every run of an id summed out of the coefficient
+    table, every touched row read, added to and written once.  1,300
+    entries are three blocks of the walk in two calls (236 pad entries on
+    feature 0 in front); feature 1's run of 900 is longer than a block,
+    opens inside the first, fills the second and ends in the third, across
+    the calls' cut."""
     from jax.experimental.pallas import tpu as pltpu
 
     rng = np.random.default_rng(36)
-    n_rows, n_entries, samples = 96, 700, 24
+    n_rows, n_entries, samples = 96, 1300, 24
     ids = np.minimum(np.exp(rng.uniform(0, np.log(n_rows + 1), n_entries)).astype(np.int64) - 1,
                      n_rows - 1).astype(np.int32)
-    ids[:200] = 17  # a run longer than a chunk
+    ids[:900] = 1
+    assert 236 + np.sum(ids < 1) < gather.RUN_BLOCK and (
+        2 * gather.RUN_BLOCK < 236 + np.sum(ids <= 1) < n_entries)
+    monkeypatch.setattr(gather, "DMA_BLOCK", 2 * gather.RUN_BLOCK)  # two calls
     values = rng.normal(size=n_entries).astype(np.float32)
     src = rng.integers(0, samples, n_entries).astype(np.int32)
     coeff = (rng.normal(size=(samples, lanes)) * 1e-2).astype(np.float32)
     w2 = (rng.normal(size=(n_rows, lanes)) * 3.0).astype(np.float32)
-    want = w2.astype(np.float64)
-    np.add.at(want, ids, values.astype(np.float64)[:, None] * coeff.astype(np.float64)[src])
-    entries = tuple(jnp.asarray(a) for a in (ids, values, src, coeff))
-    with pltpu.force_tpu_interpret_mode():
-        got = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(
-            w, *entries, dma=True))(jnp.asarray(w2)))
-    xla = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries))(jnp.asarray(w2)))
-    np.testing.assert_array_equal(got, xla)  # one formulation, one write that differs
+    carried = gather.to_tiles if lanes > gather.LANES else (lambda w: w)
+
+    def both(ids):
+        want = w2.astype(np.float64)
+        np.add.at(want, ids, values.astype(np.float64)[:, None] * coeff.astype(np.float64)[src])
+        entries = tuple(jnp.asarray(a) for a in (ids, values, src, coeff))
+        with pltpu.force_tpu_interpret_mode():
+            got = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(
+                carried(w), *entries, dma=True))(jnp.asarray(w2)))
+        xla = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries))(
+            jnp.asarray(w2)))
+        return got.reshape(w2.shape), xla, want
+
+    got, xla, want = both(ids)
+    # another order of addition inside a run than the MXU's, and nothing else
+    np.testing.assert_allclose(got, xla, rtol=1e-6, atol=2e-6)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=2e-6)
+    untouched = np.setdiff1d(np.arange(n_rows), ids)
+    np.testing.assert_array_equal(got[untouched[untouched > 0]], w2[untouched[untouched > 0]])
+    # a step of ONE distinct id: one run through every block, written once, at the end
+    got, _, want = both(np.full(n_entries, 5, np.int32))
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=2e-6)
     assert not kernels.merges_scatter(n_rows, lanes, n_entries) or lanes <= kernels.MERGE_MAX_LANES
 
@@ -406,9 +427,11 @@ def test_on_a_tpu_a_wide_binding_writes_rows_and_does_not_merge(monkeypatch, cap
                            make_mesh(1), BATCH, LR, eval_chunk=64, kernel="gather",
                            virtual_workers=4).bind(data, steps_per_epoch=3)
         assert (bound.scatter_rows, bound.scatter_merge) == (on_tpu, False)
+        assert bound.scatter_as == ("runs" if on_tpu else "words")
         w, key = _weights(200), jax.random.PRNGKey(3)
         with pltpu.force_tpu_interpret_mode():
             got = np.asarray(bound.epoch(w, key))
         if want is None:
             want = got
-    np.testing.assert_array_equal(got, want)  # the kernel's write is XLA's
+    # the kernel's sums are XLA's in another order of addition inside a run
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-7)

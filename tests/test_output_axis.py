@@ -593,6 +593,7 @@ def test_an_epoch_with_the_merge_pass_is_the_epoch_xla_writes(reg, monkeypatch):
     (54_686_452, 103, 4_400, False),        # kdd2012's feature count with outputs
     (47_236, 512, 30_400, True),
     (47_236, 513, 30_400, False),           # five lane groups: rows no block of the pass holds
+    (203_882, 1_000, 28_800, False),        # amazoncat13k-dismec: 7.1 rows an entry, eight groups
 ])
 def test_the_merge_rule_answers_from_shapes_alone(features, outputs, entries, said):
     assert kernels.merges_scatter(features, outputs, entries) is said
@@ -608,24 +609,29 @@ def test_on_a_tpu_a_binding_with_outputs_merges_and_counts_it(merging, monkeypat
     asked = []
     rule = kernels.merges_scatter
     monkeypatch.setattr(kernels, "merges_scatter", lambda *a: asked.append(a) or rule(*a))
-    merge, rows = _count("bind.scatter.merge"), _count("bind.scatter.rows")
+    def counts():
+        return tuple(_count(f"bind.scatter.{ending}") for ending in ("merge", "runs", "rows"))
+
+    merge, runs, rows = counts()
     bound = _bind(_rows())
     assert bound.update_sparse and bound.scatter_merge and not bound.scatter_rows
+    assert bound.scatter_as == "merge"
     assert asked == [(D, C, 4 * BATCH * P)]  # once a binding, the shapes alone
-    assert (_count("bind.scatter.merge"), _count("bind.scatter.rows")) == (merge + 1, rows)
+    assert counts() == (merge + 1, runs, rows)
     bound.step(_weights(), jax.random.PRNGKey(0))
-    assert len(asked) == 1 and _count("bind.scatter.merge") == merge + 1  # no trace, no run
-    # past the crossing the same binding keeps the DMA a row
+    assert len(asked) == 1 and counts() == (merge + 1, runs, rows)  # no trace, no run
+    # past the crossing the same binding walks its sorted entries' runs
     monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 1)
     bound = _bind(_rows())
     assert bound.scatter_rows and not bound.scatter_merge
-    assert (_count("bind.scatter.merge"), _count("bind.scatter.rows")) == (merge + 1, rows + 1)
-    # one output: never asked, whatever its shapes would say
+    assert bound.scatter_as == "runs" and counts() == (merge + 1, runs + 1, rows)
+    # one output: never asked, whatever its shapes would say, and a DMA a row of words
     monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 10**6)
     asked.clear()
     flat = _bind(_rows(n_outputs=1), n_outputs=1, kernel="gather")
     assert flat.update_sparse and flat.scatter_rows and not flat.scatter_merge and not asked
-    assert _count("bind.scatter.merge") == merge + 1
+    assert flat.scatter_as == "rows"
+    assert counts() == (merge + 1, runs + 1, rows + 1)
     # and the train-split record says which
     model = make_model("hinge", LAM, D, regularizer="l2", n_outputs=C)
     data = _rows()

@@ -11,6 +11,8 @@ and every program of a binding gives bit-identical results to the unpadded
 placement; (c) the counters and the span that say it engaged.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -566,16 +568,9 @@ def test_on_a_v5e_no_step_of_the_sparse_epoch_passes_over_the_weights(v5e, devic
 
 # -- (i) weights with an output axis: rows of outputs (PERF.md section 6, PR 32) -----
 
-@pytest.mark.parametrize("devices", [1, 4])
-def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices):
-    """`rcv1-topics-hinge`'s programs compile for the chip (nothing ran):
-    the update is ONE kernel a step, the merge pass over the carry's
-    128-lane rows of OUTPUTS (`kernels.merges_scatter`: 47,236 rows against
-    30,400 entries), the entries cross the mesh as factors in ONE
-    all-gather, and the evaluation's gathered rows split into [P, B, L]
-    where they lie."""
-    import re
-
+def _topics_programs(v5e, devices):
+    """(bound, step text, evaluation lowering) of `rcv1-topics-hinge`'s shape
+    compiled for `devices` v5e chips."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
@@ -590,12 +585,31 @@ def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices
     model = make_model("hinge", 1.7e-7, d, regularizer="l2", n_outputs=c)
     bound = BoundSync(model, mesh, data, 100, 0.25, kernel="gather",
                       virtual_workers=4 // devices)
-    assert bound.update_sparse and bound.scatter_merge and not bound.scatter_rows
     w = shape((d, c), jnp.float32, sharding=everywhere)
     step = bound._step.lower(w, (), data.indices, data.values, data.labels,
                              shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
-    kernel = [line for line in step.split("\n") if " custom-call(" in line
-              and 'custom_call_target="tpu_custom_call"' in line]
+    return bound, step, bound._eval.lower(w, data.indices, data.values, data.labels)
+
+
+def _kernels_of(text):
+    """The lines of a compiled program that call a kernel of ours."""
+    return [line for line in text.split("\n") if " custom-call(" in line
+            and 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices):
+    """`rcv1-topics-hinge`'s programs compile for the chip (nothing ran):
+    the update is ONE kernel a step, the merge pass over the carry's
+    128-lane rows of OUTPUTS (`kernels.merges_scatter`: 47,236 rows against
+    30,400 entries), the entries cross the mesh as factors in ONE
+    all-gather, and the evaluation's gathered rows split into [P, B, L]
+    where they lie."""
+    import re
+
+    bound, step, evaluation = _topics_programs(v5e, devices)
+    assert bound.update_sparse and bound.scatter_merge and not bound.scatter_rows
+    kernel = _kernels_of(step)
     assert len(kernel) == 1 and "f32[47240,128]" in kernel[0]
     assert "dsgd.scatter/scatter_merge" in kernel[0]
     # in place on the carry: the kernel's weights operand is its result
@@ -606,22 +620,39 @@ def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices
     crossing = re.findall(r"= (\S+) all-(?:gather|reduce)(?:-start)?\(", step)
     assert len(crossing) == int(devices > 1), crossing
     assert all(c.startswith("s32[142400]") for c in crossing), crossing
-    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
+    evaluation = evaluation.compile().as_text()
     assert "f32[311296,128]" in evaluation  # a chunk's 4,096 x 76 gathered rows
     # entry-major: no [B, P, L] form of them, which was a copy of all of them
     assert "f32[4096,76,128]" not in evaluation
+
+
+@pytest.mark.parametrize("shape,ending", [("topics", "scatter_merge"), ("kdd2012", "scatter_rows")])
+def test_on_a_v5e_the_merge_pass_and_the_words_keep_their_endings(v5e, shape, ending):
+    """The walk of the sorted factors (`scatter_runs`, PR 37) took the DMA
+    ending of an output axis's rows and no other: `rcv1-topics-hinge`'s step
+    still ends in the merge pass and `kdd2012-logistic`'s words in a DMA a
+    touched row, one kernel a step each."""
+    if shape == "topics":
+        bound, text, _ = _topics_programs(v5e, 1)
+        assert bound.scatter_as == "merge"
+    else:
+        text = _kdd2012_epoch(v5e, 1, True).as_text()
+    kernel = _kernels_of(text)
+    assert len(kernel) == 1 and f"dsgd.scatter/{ending}" in kernel[0]
+    assert "scatter_runs" not in text
 
 
 def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e):
     """`amazoncat13k-dismec`'s programs compile for the chip (nothing ran):
     1,000 outputs in eight lane groups, `W` carried as tiles
     `[203,888, 8, 128]` (`gather.to_tiles`: a feature's 4 KB contiguous), the
-    update the row DMAs of `scatter_rows` on them, the label lists expanded
-    in the step and in the evaluation, whose chunk's row gather runs in
-    pieces of 512 samples (`kernels.margin_rows`).  And what the tiles are
-    there for: on `[D', 1,024]` the chip's compiler refuses the kernel (the
-    day the second half fails, the compiler has changed and the tiles can
-    go)."""
+    update ONE kernel a step (`scatter_runs`: the walk of the sorted factors,
+    PR 37) and nothing of a step's 28,800 entries x 4 KB outside the margins'
+    gather, the label lists expanded in the step and in the evaluation, whose
+    chunk's row gather runs in pieces of 512 samples (`kernels.margin_rows`).
+    And what the tiles are there for: on `[D', 1,024]` the chip's compiler
+    refuses the kernel's row DMAs (the day the second half fails, the
+    compiler has changed and the tiles can go)."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distributed_sgd_tpu.ops import gather
@@ -636,16 +667,29 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
                        shape((rows, 8), jnp.int32, sharding=over_rows), rows, width,
                        label_lists=True)
     model = make_model("squared_hinge", 8.4e-7, d, regularizer="l2", n_outputs=c)
+
+    def endings():
+        return [metrics_mod.counter(f"bind.scatter.{ending}").value
+                for ending in ("runs", "merge", "rows")]
+
+    runs, merge, dma = endings()
     bound = BoundSync(model, mesh, data, 100, 0.1, kernel="gather", virtual_workers=4)
-    assert bound.update_sparse and bound.scatter_rows and not bound.scatter_merge
+    assert endings() == [runs + 1, merge, dma]  # once a binding, and no other ending
+    assert bound.update_sparse and bound.scatter_as == "runs" and not bound.scatter_merge
     assert bound.rows_tiled and bound.labels_as == "lists" and bound.eval_rows == 512
     w = shape((d, c), jnp.float32, sharding=everywhere)
     step = bound._step.lower(w, (), data.indices, data.values, data.labels,
                              shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
-    kernel = [line for line in step.split("\n") if " custom-call(" in line
-              and 'custom_call_target="tpu_custom_call"' in line]
+    kernel = _kernels_of(step)
     assert len(kernel) == 1 and "f32[203888,8,128]" in kernel[0]
-    assert "dsgd.scatter/scatter_rows" in kernel[0] and "dsgd.labels" in step
+    assert "dsgd.scatter/scatter_runs" in kernel[0] and "dsgd.labels" in step
+    # a row an entry exists where the margins gather it and nowhere else; the
+    # benchmark counts a window's steps by its most frequent operation, so
+    # no loop of XLA's may turn inside a step
+    wide = [line for line in step.split("\n") if re.search(
+        r"= f32\[28800,(1024|8,128)\]", line) and "dsgd.margins" not in line]
+    assert not wide, wide[:3]
+    assert " while(" not in step
     evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
     assert "f32[36864,8,128]" in evaluation  # a piece's 512 x 72 gathered tiles
     assert "f32[294912,8,128]" not in evaluation and "dsgd.labels" in evaluation
