@@ -33,8 +33,19 @@ entries a step, the generator's own 1/r draw of feature ids):
   `[203,888, 8, 128]`, us a call with the sort inside; then the walk by its
   constants, and by entries and by heads (distinct ids) varied apart: what
   says whether the scalar core's time goes to the entries or to the DMAs.
+- `margins` (PR 40; not in the default run): ONE call of
+  `gather.matvec_rows` on tiles `[203,888, L / 128, 128]` at 256, 512 and
+  1,024 lanes, 72 entries a row under the generator's law: XLA's gather of
+  a tile an entry and its weighted sum against the margin kernel
+  (`gather._margin_tiles`, its sort inside) by its piece S, on the
+  evaluation's chunk of 4,096 samples and on a step's 400, and at 1,024
+  lanes by its constants; beside each piece size the share of its entries
+  that are distinct tiles (what the kernel fetches).  What
+  `kernels.MARGIN_*` and `gather.MARGIN_*` were set from (`--lanes` picks
+  the widths).
 
-    python benches/outputs_step_sweep.py [--rehearse] [--only step,forms,merge,runs]
+    python benches/outputs_step_sweep.py [--rehearse] [--only step,forms,merge,runs,margins]
+                                         [--lanes 256,512,1024]
 
 Prints one JSON document (a line a row on stderr as it goes).  Refuses a
 CPU unless `--rehearse` (tiny shapes, no timing worth reading).
@@ -255,6 +266,11 @@ def main(argv) -> int:
             print(json.dumps({rows * factor: row}), file=sys.stderr, flush=True)
     if "runs" in only:
         out["runs"] = runs_table(jax, jnp, gather, device.platform == "tpu", rehearse)
+    if "margins" in only:
+        lanes = ([int(x) for x in argv[argv.index("--lanes") + 1].split(",")]
+                 if "--lanes" in argv else (256, 512, 1024))
+        out["margins"] = margins_table(jax, jnp, gather, kernels, device.platform == "tpu",
+                                       rehearse, lanes)
     print(json.dumps(out))
     return 0
 
@@ -357,6 +373,70 @@ def runs_table(jax, jnp, gather, on_tpu, rehearse):
         print(json.dumps(table), file=sys.stderr, flush=True)
     return out
 
+
+def margins_table(jax, jnp, gather, kernels, on_tpu, rehearse, lanes_of=(256, 512, 1024)):
+    """The `margins` section: one call of `gather.matvec_rows` on tiles,
+    XLA's gather against the margin kernel by piece, by lanes and by call
+    (the evaluation's chunk, a step), and at 1,024 lanes by its constants."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops.sparse import SparseBatch
+
+    calls, reps = (2, 1) if rehearse else (20, 2)
+    d, width = (500, 6) if rehearse else (203_882, 72)
+    rows = d + -d % gather.SUBLANES
+    samples_of = {"eval_chunk": 64, "step": 40} if rehearse else {"eval_chunk": 4096, "step": 400}
+    rng = np.random.default_rng(40)
+    interpreted = contextlib.nullcontext if on_tpu else pltpu.force_tpu_interpret_mode
+    out = {"calls": calls, "features": d, "entries_a_row": width}
+    for lanes in (256, 1024) if rehearse else lanes_of:
+        out[lanes] = by_lanes = {}
+        for name, samples in samples_of.items():
+            ids0 = np.minimum(np.exp(rng.uniform(0.0, np.log(d + 1.0), (samples, width))).astype(
+                np.int64) - 1, d - 1)
+            vals = jnp.asarray(rng.normal(size=(samples, width)), jnp.float32)
+            ids = jnp.asarray(ids0, jnp.int32)
+
+            def clocked(margins):
+                def call(i, w):  # other ids every call, one law
+                    m = margins(w, SparseBatch((ids + i) % d, vals))
+                    return w.at[0].add(jnp.sum(m, axis=0).reshape(w.shape[1:]) * 1e-30)
+
+                run = jax.jit(lambda w: jax.lax.fori_loop(0, calls, call, w), donate_argnums=0)
+                with interpreted():  # each run takes the weights the last one gave back
+                    return best_us(run, jnp.zeros((rows, lanes // 128, 128), jnp.float32),
+                                   calls, reps)
+
+            def kernel(piece, **constants):
+                return lambda w, b: gather._margin_tiles(
+                    w, *gather._sorted_pieces(b, piece, rows), piece, width, **constants)
+
+            # the rule's piece at 1,024 lanes (the worst case of a 4 KB tile
+            # an entry in VMEM), and half of it
+            rule = kernels.margin_tiles(samples, width, 1024)
+            row = {"samples": samples, "xla_gather": clocked(
+                lambda w, b: gather.matvec_rows(b, w))}
+            w = gather.to_tiles(jnp.asarray(rng.normal(size=(rows, lanes)), jnp.float32))
+            batch = SparseBatch(ids, vals)
+            with interpreted():  # one call of each on the same weights
+                apart = jnp.abs(jax.jit(lambda w: gather.matvec_rows(batch, w))(w)
+                                - jax.jit(kernel(rule))(w, batch).reshape(samples, lanes))
+            row[f"max_abs_apart_S{rule}"] = float(jnp.max(apart))
+            del w, apart
+            for piece in (rule // 2, rule):
+                flat = ids0.reshape(-1, piece * width)
+                row[f"distinct_share_S{piece}"] = float(np.mean(
+                    [np.unique(p).size for p in flat]) / flat.shape[1])
+                row[f"S{piece}"] = clocked(kernel(piece))
+            if lanes == 1024:  # the kernel's constants, and the sort alone
+                for constants in ({"unroll": 8}, {"unroll": 72}):
+                    (key, value), = constants.items()
+                    row[f"S{rule}_{key}{value}"] = clocked(kernel(rule, **constants))
+                row[f"sort_alone_S{rule}"] = clocked(lambda w, b: w[:1] + sum(
+                    jnp.sum(a[:, :1]) for a in gather._sorted_pieces(b, rule, rows)))
+            by_lanes[name] = row
+            print(json.dumps({lanes: {name: row}}), file=sys.stderr, flush=True)
+    return out
 
 if __name__ == "__main__":
     sys.exit(main(sys.argv[1:]))

@@ -173,12 +173,16 @@ class LinearModel:
                     if kernel == "gather" else w)
         return mxu.from_blocked(w, self.n_features) if kernel in kernels.BLOCKED else w
 
-    def margins(self, w: jax.Array, batch: SparseBatch, kernel: str = "scalar") -> jax.Array:
-        """Per-sample dots x_b . w, `w` in `kernel`'s layout."""
+    def margins(self, w: jax.Array, batch: SparseBatch, kernel: str = "scalar",
+                distinct: bool = False) -> jax.Array:
+        """Per-sample dots x_b . w, `w` in `kernel`'s layout (`distinct`:
+        `gather.matvec_rows`' margin kernel, where tiles of outputs take it)."""
         if batch.is_dense:
             return self.margins_dense(w, batch.values)
+        if kernel == "gather" and self.n_outputs > 1:
+            return gather.matvec_rows(batch, w, distinct)
         if kernel == "gather":
-            return (gather.matvec_rows if self.n_outputs > 1 else gather.matvec)(batch, w)
+            return gather.matvec(batch, w)
         if kernel in kernels.BLOCKED:
             return mxu.matvec_chunked(batch, w)
         with jax.named_scope("dsgd.margins"):
@@ -270,13 +274,14 @@ class LinearModel:
         return batch.indices.reshape(-1), cv.reshape(-1)
 
     def reply_rows(self, v2: jax.Array, batch: SparseBatch, y: jax.Array,
-                   scale: Optional[jax.Array] = None, factor=1.0):
+                   scale: Optional[jax.Array] = None, factor=1.0, distinct: bool = False):
         """`reply_entries` with an output axis (`v2 [D', L]`, `y [B, L]`):
         an entry's update is a whole row, `value x coeff[sample]`, and is
         handed on as its factors: (feature ids [T], values [T], the sample
         of every entry [T], `factor` x the samples' coefficient rows
-        [B, L]), what `gather.scatter_rows_into` takes."""
-        margins = gather.matvec_rows(batch, v2)
+        [B, L]), what `gather.scatter_rows_into` takes.  `distinct`: the
+        margins as `margins` takes them."""
+        margins = gather.matvec_rows(batch, v2, distinct)
         with jax.named_scope("dsgd.update"):
             if scale is not None:
                 margins = scale * margins

@@ -338,7 +338,7 @@ def from_tiles(w3: jax.Array) -> jax.Array:
         return w3.reshape(w3.shape[0], -1)
 
 
-def matvec_rows(batch: SparseBatch, w2: jax.Array) -> jax.Array:
+def matvec_rows(batch: SparseBatch, w2: jax.Array, distinct: bool = False) -> jax.Array:
     """Per-sample, per-output dots `x_b . W[:, c]` -> [B, L]: every stored
     entry gathers its feature's row, a sample's P rows are summed with its
     values as weights (pads contribute 0 * row 0).  The entries are
@@ -346,9 +346,22 @@ def matvec_rows(batch: SparseBatch, w2: jax.Array) -> jax.Array:
     then split into [P, B, L] without moving (B whole sublanes), where
     [B, P, L] with P = 76 is another tiling and cost a copy of all of them
     (0.88 s of the 2.30 s an evaluation of 7.2 M rows took, my chip run,
-    PR 32).  `kernels.margin_rows` says how many samples one gather takes."""
+    PR 32).  `kernels.margin_rows` says how many samples one gather takes.
+
+    `distinct` (a TPU, rows carried as tiles: `BoundSync.margins_distinct`):
+    where `kernels.margin_tiles` gives the shape a piece, ONE kernel of ours
+    takes the margins instead (`_margin_tiles`): each distinct tile of a
+    piece fetched once, a sample's tiles summed in a register, no [P B, L]
+    array at all."""
     tile = w2.shape[1:]  # (L,), or (L / 128, 128) where the rows are tiles
     lanes = math.prod(tile)
+    if distinct and len(tile) == 2:
+        piece = kernels.margin_tiles(*batch.indices.shape, lanes)
+        if piece:
+            with jax.named_scope("dsgd.margins"):
+                m = _margin_tiles(w2, *_sorted_pieces(batch, piece, w2.shape[0]), piece,
+                                  batch.indices.shape[1])
+                return m.reshape(-1, lanes)
 
     def dots(indices, values):
         entry_major = indices.T  # [P, B]
@@ -370,6 +383,185 @@ def matvec_rows(batch: SparseBatch, w2: jax.Array) -> jax.Array:
         m = jax.lax.map(lambda iv: dots(*iv), (batch.indices.reshape(-1, piece, width),
                                                batch.values.reshape(-1, piece, width)))
         return m.reshape(samples, lanes)
+
+
+# Entries the margin kernel (`_margin_tiles`) walks a turn of its scalar
+# loops, and the alignment of a piece's factors in HBM (a DMA into scalar
+# memory starts at a whole tile of a 1-D array).  Timed on a v5e (my chip
+# runs, PR 40; the evaluation's chunk / a step, us a call, the table beside
+# `kernels.margin_tiles`): 8 a turn 4,244 / 489, 24: 3,961 / 459, 36: 3,872,
+# 72: 3,723 / 436, 144: 3,701 / 435.  What 72 buys on the chip it pays on
+# the host: a turn is traced and lowered entry by entry in every process,
+# and at 72 the evaluation program's lowering took 5.5 s on the chip's host
+# (0.2 without the kernel) and the cell's set-up rose by a quarter; at 24
+# in lax primitives it lowers in what 8 took in jnp's operators, which
+# left the set-up where it was.
+MARGIN_UNROLL = 24
+FACTOR_ALIGN = 1024
+
+
+def _sorted_pieces(batch: SparseBatch, piece: int, n_rows: int):
+    """(ids, pos, values), each int32 / f32[n, E'] for the n pieces of
+    `piece` samples (E = piece x P entries, E' = E rounded up to whole
+    FACTOR_ALIGN): a piece's ids sorted ascending with the position in the
+    piece each came from (b P + p), and its values in sample order.  ONE
+    batched sort: of one word an entry, id x E + position, where ids below
+    `n_rows` leave that room in 32 bits (else of the two words)."""
+    per = piece * batch.indices.shape[1]
+    ids = batch.indices.reshape(-1, per)
+    pos = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+    if n_rows * per <= 2 ** 32:
+        key = jax.lax.sort(ids.astype(jnp.uint32) * per + pos.astype(jnp.uint32), dimension=1,
+                           is_stable=False)
+        ids, pos = (key // per).astype(jnp.int32), (key % per).astype(jnp.int32)
+    else:
+        ids, pos = jax.lax.sort((ids, pos), dimension=1, num_keys=1, is_stable=False)
+    pad = ((0, 0), (0, -per % FACTOR_ALIGN))
+    return (jnp.pad(ids, pad), jnp.pad(pos, pad),
+            jnp.pad(batch.values.astype(jnp.float32).reshape(-1, per), pad))
+
+
+def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Array,
+                  piece: int, width: int, unroll: int = MARGIN_UNROLL) -> jax.Array:
+    """m f32[n piece, L / 128, 128]: the margins of the n pieces of `piece`
+    samples of `width` entries whose factors `_sorted_pieces` gives, against
+    tiles `w [D', L / 128, 128]` (`to_tiles`) left in HBM: ONE TPU kernel, a
+    step of its grid a piece, that never gathers a tile an entry.
+
+    A piece's factors come into scalar memory by DMA (the next piece's
+    keys while this one is worked on).  The scalar core walks its sorted
+    ids: the first entry of every run of an id takes the next slot of a
+    tile cache in VMEM, and the slot is stored at the entry's position in
+    the piece (a scalar store: no scatter, no second sort); one DMA a slot
+    then fetches each DISTINCT tile once.  Once they have landed it walks
+    the piece sample by sample in entry order: a load of the entry's slot,
+    a splat of its value and a multiply-add into a float32 accumulator
+    (ONE register at 1,024 lanes), stored once a sample.  The cache
+    holds a tile an entry, the worst case (`kernels.margin_tiles` sizes
+    the piece for it).  Pads add 0 x tile 0; only the order of addition
+    inside a sample is not XLA's.  `unroll`: entries a turn of the walks'
+    loops."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, stride = ids.shape
+    per = piece * width  # entries a piece; `stride` is that in whole FACTOR_ALIGN
+    tile = w.shape[1:]
+
+    def unrolled(count, one, carry, offset):
+        """carry = one(offset + e, carry) for e < count (static), `unroll` a
+        turn; in jax.lax primitives and one add an index, since a turn is
+        traced and lowered entry by entry (MARGIN_UNROLL)."""
+        whole = count // unroll
+
+        def turn(t, carry):
+            at = jax.lax.add(jax.lax.mul(t, unroll), offset)
+            for k in range(unroll):
+                carry = one(jax.lax.add(at, k), carry)
+            return carry
+
+        carry = jax.lax.fori_loop(0, whole, turn, carry)
+        for e in range(whole * unroll, count):
+            carry = one(jax.lax.add(offset, e), carry)
+        return carry
+
+    def kernel(ids_hbm, pos_hbm, val_hbm, w_ref, out_ref,
+               cache, ids_s, pos_s, val_s, slot_of, to, sem_keys, sem_vals, sem_tiles):
+        step = pl.program_id(0)
+
+        def factors(hbm, smem, j, buf, sem):  # piece j's row of factors -> scalar memory
+            return pltpu.make_async_copy(
+                hbm.at[pl.ds(pl.multiple_of(j * stride, FACTOR_ALIGN), stride)],
+                smem.at[pl.ds(pl.multiple_of(buf * stride, FACTOR_ALIGN), stride)], sem)
+
+        def keys(j):  # its sorted ids and their positions, two buffers
+            return [factors(ids_hbm, ids_s, j, j % 2, sem_keys.at[0]),
+                    factors(pos_hbm, pos_s, j, j % 2, sem_keys.at[1])]
+
+        values_in = factors(val_hbm, val_s, step, 0, sem_vals.at[0])
+
+        @pl.when(step == 0)
+        def _():
+            for copy in keys(0):
+                copy.start()
+
+        for copy in keys(step):
+            copy.wait()
+
+        @pl.when(step + 1 < n)
+        def _():
+            for copy in keys(step + 1):
+                copy.start()
+
+        values_in.start()
+        keyed = step % 2 * stride
+
+        def slot(at, carry):
+            prev, last = carry
+            i = ids_s[at]
+            last = jax.lax.add(last, jax.lax.convert_element_type(jax.lax.ne(i, prev), jnp.int32))
+            to[last] = i
+            slot_of[pos_s[at]] = last
+            return i, last
+
+        heads = unrolled(per, slot, (jnp.int32(-1), jnp.int32(-1)), keyed)[1] + 1
+
+        def start(k, carry):
+            pltpu.make_async_copy(w_ref.at[to[k]], cache.at[k], sem_tiles.at[0]).start()
+            return carry
+
+        def turn(t, carry):
+            for k in range(DMA_UNROLL):
+                start(t * DMA_UNROLL + k, carry)
+            return carry
+
+        whole = heads // DMA_UNROLL
+        jax.lax.fori_loop(0, whole, turn, 0)
+        jax.lax.fori_loop(whole * DMA_UNROLL, heads, start, 0)
+        # a DMA semaphore counts bytes: one wait a set bit of the count, for
+        # that many tiles
+        for bit in reversed(range(per.bit_length())):
+            @pl.when(heads & (1 << bit) != 0)
+            def _():
+                tiles = cache.at[pl.ds(0, 1 << bit)]
+                pltpu.make_async_copy(tiles, tiles, sem_tiles.at[0]).wait()
+
+        values_in.wait()
+
+        def term(at, acc):
+            splat = jax.lax.broadcast_in_dim(val_s[at], tile, ())
+            return jax.lax.add(acc, jax.lax.mul(splat, cache[slot_of[at]]))
+
+        def sample(b, carry):
+            out_ref[b] = unrolled(width, term, jnp.zeros(tile, jnp.float32), b * width)
+            return carry
+
+        jax.lax.fori_loop(0, piece, sample, 0)
+
+    tile_bytes = 4 * (-(-tile[0] // SUBLANES) * SUBLANES) * tile[1]  # whole registers
+    need = tile_bytes * (per + 2 * piece)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((n * piece,) + tile, jnp.float32,
+                                       vma=jax.typeof(w).vma),
+        grid=(n,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
+        out_specs=pl.BlockSpec((piece,) + tile, lambda j: (j, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((per,) + tile, jnp.float32),  # the tile cache
+            pltpu.SMEM((2 * stride,), jnp.int32),  # two pieces' sorted ids
+            pltpu.SMEM((2 * stride,), jnp.int32),  # and their positions
+            pltpu.SMEM((stride,), jnp.float32),  # the piece's values
+            pltpu.SMEM((stride,), jnp.int32),  # and its entries' slots
+            pltpu.SMEM((per,), jnp.int32),  # its distinct ids
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=need + 16 * 2 ** 20),
+        name="margin_tiles",
+    )(ids.reshape(-1), pos.reshape(-1), values.reshape(-1), w.astype(jnp.float32))
 
 
 def scatter_add_rows(batch: SparseBatch, coeff: jax.Array, shape: tuple) -> jax.Array:

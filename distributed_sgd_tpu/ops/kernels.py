@@ -21,15 +21,17 @@ and `core/worker.py` all ask it through `resolve`, which reads the
 platform off the device and counts the answer.  An explicit `kernel=`
 still overrides: the rule answers only for `AUTO`.
 
-Five more rules on a binding's shape live here, each asked once a
+Six more rules on a binding's shape live here, each asked once a
 binding by `BoundSync`: `merges_margins` (the K virtual workers' margins
 in one call), `ONE_ACCUMULATOR` (their entries scattered into one
 gradient), `sparse_update` (no gradient at all: the entries scattered
 into the carried weights, the regulariser a scalar on them, so that a
 step's bytes have no term in the feature count), `merges_scatter`
 (with an output axis, whether that scatter is one pass over the weights
-or a walk of its entries that moves each touched row once) and `margin_rows` (with an output axis, how many
-samples one row gather of the margins takes).
+or a walk of its entries that moves each touched row once), `margin_rows` (with an output axis, how many
+samples one row gather of the margins takes) and `margin_tiles` (with rows
+carried as tiles on a TPU, the piece of samples whose distinct tiles the
+margin kernel fetches once each).
 """
 
 from __future__ import annotations
@@ -262,6 +264,58 @@ def margin_rows(samples: int, row_width: int, lanes: int) -> int:
     pieces run one after the other (`lax.map`)."""
     piece = int(samples)
     while piece % 2 == 0 and piece * row_width * lanes * 4 > GATHERED_ROWS_MAX_BYTES:
+        piece //= 2
+    return piece
+
+
+# The margin kernel (`gather._margin_tiles`: each distinct weight tile of a
+# piece of samples fetched once by DMA, a sample's tiles summed in a
+# register) takes weight rows of at least MARGIN_TILES_MIN_LANES lanes, in
+# pieces whose worst case (every entry its own tile: a cache of one tile an
+# entry) fits MARGIN_VMEM_BYTES, and whose factors fit MARGIN_SMEM_BYTES of
+# scalar memory (a v5e has 128 MiB and 1 MiB).  Timed on a v5e, one call of
+# `gather.matvec_rows` with its sort inside, 72 entries a row under the
+# generator's law over 203,882 features, tiles `[203,888, L / 128, 128]`,
+# us a call (`benches/outputs_step_sweep.py --only margins`; my chip runs,
+# PR 40; the kernel at 72 entries a turn of its walks):
+#
+#                   XLA's gather + sum   S = 64 / 128 / 256 (the evaluation's
+#                                        4,096 samples)  S = 100 / 200 (a step's 400)
+#     1,024 lanes        6,998 / 568        4,313 / 3,954 / 3,708      436 / 436
+#       512 lanes        5,104 / 273                3,966 / 3,720      434 / 437
+#       256 lanes        4,308 / 267                3,960 / 3,718      434 / 438
+#
+# (S = 64 on the two-word sort: its call read 4,313 against 3,804 at 256.)
+# The kernel is bound by its scalar core, ~15 ns a distinct tile's DMA and
+# ~5.5 ns an entry walked whatever the lanes, so it wins where XLA's gather
+# pays for 4 KB a row, and at 512 lanes and below a step's 400 samples are
+# faster through XLA; the largest piece that fits is the fastest (a piece's
+# entries that are distinct tiles: 0.549 / 0.492 / 0.436 at S = 64 / 128 /
+# 256).  Two caches, the next piece fetched while one is walked, did not pay
+# in either order of the DMAs and the walk (4,087 and 4,430 against 4,040 at
+# S = 128).
+MARGIN_TILES_MIN_LANES = 1024
+MARGIN_VMEM_BYTES = 96 * 2 ** 20
+MARGIN_SMEM_BYTES = 512 * 2 ** 10
+
+
+def margin_tiles(samples: int, row_width: int, lanes: int) -> int:
+    """Samples a piece of the margin kernel takes, for a call of
+    `gather.matvec_rows` on `samples` rows of `row_width` stored entries
+    against weight tiles of `lanes` lanes: `samples` halved until the
+    piece's worst case fits (0: it never does, or the rows are narrower
+    than MARGIN_TILES_MIN_LANES, and XLA's gather takes the margins).  From
+    shapes alone; a TPU's question (`BoundSync` asks once a binding and
+    counts the kernel under `bind.margins.tiles`)."""
+    if lanes < MARGIN_TILES_MIN_LANES:
+        return 0
+    tile = -(-lanes // 1024) * 4096  # VMEM bytes: whole registers of 8 x 128 words
+    piece = int(samples)
+    # scalar words an entry: two pieces' ids and positions, values, slots, ids fetched
+    while (tile * (piece * row_width + 2 * piece) > MARGIN_VMEM_BYTES
+           or 4 * 7 * piece * row_width > MARGIN_SMEM_BYTES):
+        if piece % 2:
+            return 0
         piece //= 2
     return piece
 

@@ -276,6 +276,18 @@ class BoundSync:
         # contiguous, what the row DMA can name): everywhere but under the
         # merge pass, which streams blocks of [D', L]
         self.rows_tiled = row_lanes > gather.LANES and not self.scatter_merge
+        # whether the margins of the step and of the evaluation fetch each
+        # distinct tile of a piece of samples once (gather._margin_tiles, a
+        # TPU's) and not a tile an entry: tiles of a sparse binding, wide
+        # enough for the rule (kernels.margin_tiles); the evaluation's chunk
+        # is then cut into the kernel's pieces
+        piece = (kernels.margin_tiles(self.eval_chunk, row_width, row_lanes)
+                 if on_tpu and self.rows_tiled else 0)
+        self.margins_distinct = piece > 0
+        self.margin_fetch = "distinct" if self.margins_distinct else "gather"
+        if self.margins_distinct:
+            metrics.counter("bind.margins.tiles").increment()
+            self.eval_rows = piece
         self._opt_state = self._init_opt_state()
         sspec = jax.tree.map(lambda _: P(), self._opt_state)
 
@@ -478,7 +490,8 @@ class BoundSync:
         sample it belongs to) beside every sample's coefficient row, a
         hundredth of the bytes of the rows themselves."""
         at, val, src, coeff = self.model.reply_rows(
-            v2, merged, by.reshape((-1,) + by.shape[2:]), s, factor)
+            v2, merged, by.reshape((-1,) + by.shape[2:]), s, factor,
+            distinct=self.margins_distinct)
         t, (samples, lanes) = at.shape[0], coeff.shape
         with jax.named_scope("dsgd.allreduce"):
             # all of it as bits in one vector: one collective a step
@@ -620,7 +633,8 @@ class BoundSync:
                 cy = self._labels(jax.lax.dynamic_slice_in_dim(y, s, chunk, 0))
                 mask = (cy != 0).astype(jnp.float32)
             # the same gather the step runs (models/linear.py `margins`)
-            margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel)
+            margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel,
+                                         distinct=self.margins_distinct)
             with jax.named_scope("dsgd.eval_reduce"):
                 losses = self.model.losses_from_margins(margins, cy)
                 preds = self.model.predict(margins)
@@ -644,7 +658,8 @@ class BoundSync:
         def body(_, t):
             with jax.named_scope("dsgd.eval_rows"):
                 ci, cv = self.chunk_rows(idx, val, t * chunk)
-            margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel)
+            margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel,
+                                         distinct=self.margins_distinct)
             with jax.named_scope("dsgd.eval_reduce"):
                 return (), self.model.predict(margins)
 

@@ -309,6 +309,129 @@ def test_margins_in_pieces_are_the_margins_in_one(monkeypatch):
                                rtol=1e-6, atol=1e-7)
 
 
+# -- (v b) the margin kernel on tiles: each distinct tile of a piece fetched once (PR 40) ---
+
+def _margin_case(case: str, rng):
+    """(indices, values, piece) of one case of the margin kernel."""
+    samples, width, piece = {"pieces": (64, P, 8), "step": (400, P, 100)}.get(case, (32, P, 16))
+    ids = np.minimum(np.exp(rng.uniform(0, np.log(D + 1), (samples, width))).astype(np.int64) - 1,
+                     D - 1).astype(np.int32)
+    values = rng.normal(size=(samples, width)).astype(np.float32)
+    if case == "all_distinct":  # the worst case the rule sizes the cache for
+        ids = rng.permutation(D)[:samples * width].reshape(samples, width).astype(np.int32)
+    elif case == "one_id":
+        ids[:] = 7
+    elif case == "pads":  # a row's unused entries: 0.0 on feature 0
+        ids[:, 4:], values[:, 4:] = 0, 0.0
+    elif case == "no_row":  # LIST_NO_ROW padding rows: every entry a pad
+        ids[::3], values[::3] = 0, 0.0
+    return ids, values, piece
+
+
+@pytest.mark.parametrize("lanes", [256, 1024])
+@pytest.mark.parametrize("case", ["all_distinct", "one_id", "pads", "no_row", "pieces", "step"])
+def test_the_margin_kernel_is_the_float64_product(case, lanes):
+    """`_margin_tiles` (Pallas' TPU interpret mode) on tiles of 1 KB and
+    4 KB: a piece's distinct tiles fetched once into a cache slot, a
+    sample's tiles summed in a register, against float64 `x . W` and
+    XLA's gather of a tile an entry.  Pads add 0 x tile 0, a padding row's
+    margins are 0."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops.sparse import SparseBatch
+
+    rng = np.random.default_rng(40)
+    ids, values, piece = _margin_case(case, rng)
+    w2 = (rng.normal(size=(D, lanes)) * 0.5).astype(np.float32)
+    batch = SparseBatch(jnp.asarray(ids), jnp.asarray(values))
+    want = np.einsum("bp,bpl->bl", values.astype(np.float64), w2.astype(np.float64)[ids])
+    xla = np.asarray(gather.matvec_rows(batch, gather.to_tiles(jnp.asarray(w2))))
+    # the sort of one word an entry, then of two (ids that leave no room
+    # for the position) with turns that leave a remainder
+    for n_rows, constants in ((D, {}), (2 ** 32, {"unroll": 4})):
+        with pltpu.force_tpu_interpret_mode():
+            got = np.asarray(jax.jit(lambda w: gather._margin_tiles(
+                w, *gather._sorted_pieces(batch, piece, n_rows), piece, ids.shape[1],
+                **constants))(
+                    gather.to_tiles(jnp.asarray(w2)))).reshape(len(ids), lanes)
+        # another order of addition inside a sample, and nothing else
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+        np.testing.assert_allclose(got, xla, rtol=1e-5, atol=2e-6)
+    if case == "no_row":
+        assert not np.any(got[::3])
+
+
+@pytest.mark.parametrize("samples,width,lanes,piece", [
+    (4096, 72, 1024, 256),  # the evaluation's chunk: 18,432 tiles of 4 KB in VMEM
+    (400, 72, 1024, 200),   # a step of 4 x 100 samples
+    (4096, 76, 128, 0),     # rcv1-topics-hinge's 512 B rows: XLA's gather
+    (64, 6, 256, 0),        # two lane groups: no cell, nothing measured
+    (399, 72, 1024, 0),     # odd, and never fits
+    (64, 6, 2048, 64)])
+def test_the_margin_kernel_rule_cuts_pieces_from_shapes_alone(samples, width, lanes, piece):
+    assert kernels.margin_tiles(samples, width, lanes) == piece
+
+
+@pytest.mark.parametrize("outputs,on_tpu,fetch", [
+    (1000, True, "distinct"),  # tiles of eight lane groups on a TPU
+    (1000, False, "gather"),   # off the TPU: XLA's gather, the tests' reference
+    (103, True, "gather"),     # [D', 128] rows, rcv1-topics-hinge's shape
+    (1, True, "gather"),       # flat w, kdd2012-logistic's shape
+])
+def test_only_tiles_on_a_tpu_fetch_distinct_tiles(outputs, on_tpu, fetch, monkeypatch, caplog):
+    """Which bindings take the margin kernel: counted once a binding under
+    `bind.margins.tiles`, said as `margin_fetch=` on the `train split:`
+    record."""
+    from distributed_sgd_tpu.ops import mxu
+
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 0)
+    monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: on_tpu)
+    data = _dense(outputs) if outputs > 1 else rcv1_like(N, n_features=D, nnz=P, seed=3)
+    counter = metrics_mod.global_metrics().counter("bind.margins.tiles")
+    before = counter.value
+    model = make_model("squared_hinge" if outputs > 1 else "logistic", LAM, D,
+                       regularizer="l2", n_outputs=outputs)
+    trainer = SyncTrainer(model, make_mesh(1), BATCH, LR, virtual_workers=4, kernel="gather",
+                          metrics=metrics_mod.Metrics())
+    bound = trainer.engine.bind(data)
+    assert bound.margin_fetch == fetch and bound.margins_distinct == (fetch == "distinct")
+    assert counter.value == before + (fetch == "distinct")
+    if on_tpu:
+        return  # a fit would run the TPU's kernels
+    with caplog.at_level(logging.INFO, logger="dsgd.trainer"):
+        trainer.fit(data.slice(slice(0, 384)), data.slice(slice(384, None)), max_epochs=1)
+    record = next(r.getMessage() for r in caplog.records
+                  if r.getMessage().startswith("train split:"))
+    assert f"margin_fetch={fetch}" in record
+
+
+def test_on_a_tpu_the_margins_of_tiles_are_the_gathers(monkeypatch):
+    """An epoch and an evaluation of a binding on 1,024-lane tiles with the
+    margin kernel (Pallas' interpret mode) against the same binding off
+    the TPU: the step's margins and the evaluation's sums agree to the
+    order of addition inside a sample."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import mxu
+
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 0)
+    data = _listed(_dense(1000))
+    got = []
+    for on_tpu in (False, True):
+        monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None, said=on_tpu: said)
+        bound = SyncEngine(make_model("squared_hinge", LAM, D, regularizer="l2", n_outputs=1000),
+                           make_mesh(1), BATCH, LR, eval_chunk=64, kernel="gather",
+                           virtual_workers=4).bind(data, steps_per_epoch=2)
+        assert bound.margins_distinct == on_tpu
+        w, key = _weights(1000), jax.random.PRNGKey(4)
+        with pltpu.force_tpu_interpret_mode():
+            got.append((np.asarray(bound.epoch(w, key)), np.asarray(bound.evaluate(w))))
+    np.testing.assert_allclose(got[1][0], got[0][0], rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(got[1][1], got[0][1], rtol=1e-5)
+
+
 # -- (vi) the text format ------------------------------------------------------------------
 
 FILE = """5 10 6

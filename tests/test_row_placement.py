@@ -676,23 +676,26 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
     bound = BoundSync(model, mesh, data, 100, 0.1, kernel="gather", virtual_workers=4)
     assert endings() == [runs + 1, merge, dma]  # once a binding, and no other ending
     assert bound.update_sparse and bound.scatter_as == "runs" and not bound.scatter_merge
-    assert bound.rows_tiled and bound.labels_as == "lists" and bound.eval_rows == 512
+    assert bound.rows_tiled and bound.labels_as == "lists" and bound.eval_rows == 256
+    assert bound.margins_distinct and bound.margin_fetch == "distinct"
     w = shape((d, c), jnp.float32, sharding=everywhere)
     step = bound._step.lower(w, (), data.indices, data.values, data.labels,
                              shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
     kernel = _kernels_of(step)
-    assert len(kernel) == 1 and "f32[203888,8,128]" in kernel[0]
-    assert "dsgd.scatter/scatter_runs" in kernel[0] and "dsgd.labels" in step
-    # a row an entry exists where the margins gather it and nowhere else; the
-    # benchmark counts a window's steps by its most frequent operation, so
-    # no loop of XLA's may turn inside a step
-    wide = [line for line in step.split("\n") if re.search(
-        r"= f32\[28800,(1024|8,128)\]", line) and "dsgd.margins" not in line]
+    assert len(kernel) == 2 and all("f32[203888,8,128]" in k for k in kernel)
+    assert "dsgd.margins/margin_tiles" in kernel[0] and "dsgd.labels" in step
+    assert "dsgd.scatter/scatter_runs" in kernel[1]
+    # no array of a row an entry at all (the margins fetch each distinct
+    # tile once, PR 40); the benchmark counts a window's steps by its most
+    # frequent operation, so no loop of XLA's may turn inside a step
+    wide = [line for line in step.split("\n") if re.search(r"= f32\[28800,(1024|8,128)\]", line)]
     assert not wide, wide[:3]
     assert " while(" not in step
     evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
-    assert "f32[36864,8,128]" in evaluation  # a piece's 512 x 72 gathered tiles
-    assert "f32[294912,8,128]" not in evaluation and "dsgd.labels" in evaluation
+    kernel = _kernels_of(evaluation)
+    assert len(kernel) == 1 and "dsgd.margins/margin_tiles" in kernel[0]
+    assert "f32[4096,8,128]" in kernel[0]  # a chunk's margins, 16 pieces of 256 samples
+    assert not re.search(r"f32\[(36864|294912),8,128\]", evaluation) and "dsgd.labels" in evaluation
     # the same kernel on rows of eight lane groups that are NOT tiles
     flat = shape((2048, 1024), jnp.float32, sharding=everywhere)
     entries = (shape((256,), jnp.int32, sharding=everywhere),
@@ -702,6 +705,53 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
     with pytest.raises(Exception, match="aligned to tiling"):
         jax.jit(lambda w2, *e: gather.scatter_rows_into(w2, *e, dma=True)).lower(
             flat, *entries).compile()
+
+
+@pytest.mark.parametrize("d,outputs,model,margin_tiles", [
+    (4096, 1000, "squared_hinge", True),  # tiles of eight lane groups: the margin kernel
+    (47_236, 103, "hinge", False),        # [D', 128] rows under the merge pass
+    (4_000_000, 1, "logistic", False),    # flat w, kdd2012-logistic's form
+])
+def test_on_a_v5e_only_tiles_take_their_margins_from_the_margin_kernel(
+        v5e, d, outputs, model, margin_tiles):
+    """Bindings small enough for tier-1 compiled for a described v5e: on
+    tiles the step's and the evaluation's margins are ONE custom call
+    `margin_tiles` each, under `dsgd.margins` (what `margins_us_per_step`
+    and `eval_margins_ms` read), and the binding is counted once under
+    `bind.margins.tiles`; on 128-lane rows and on flat `w` no such call
+    exists and XLA's gather stays."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_sgd_tpu.ops import kernels
+    from distributed_sgd_tpu.parallel.sync import BoundSync, ShardedData
+
+    rows, width = 4096, 6  # (the sparse step: from 4e6 words of weights on)
+    mesh = Mesh(np.array(v5e.devices[:1]), ("workers",))
+    over_rows, everywhere = NamedSharding(mesh, P("workers")), NamedSharding(mesh, P())
+    shape = jax.ShapeDtypeStruct
+    labels = (rows, 8) if outputs > 1 else (rows,)
+    data = ShardedData(shape((rows, width), jnp.int32, sharding=over_rows),
+                       shape((rows, width), jnp.float32, sharding=over_rows),
+                       shape(labels, jnp.int32, sharding=over_rows), rows, width,
+                       label_lists=outputs > 1)
+    counter = metrics_mod.counter("bind.margins.tiles")
+    before = counter.value
+    bound = BoundSync(make_model(model, 1e-6, d, regularizer="l2", n_outputs=outputs), mesh,
+                      data, 100, 0.1, kernel="gather", virtual_workers=4)
+    assert bound.update_sparse and bound.margins_distinct == margin_tiles
+    assert counter.value == before + margin_tiles
+    assert bound.margin_fetch == ("distinct" if margin_tiles else "gather")
+    w = shape((d, outputs) if outputs > 1 else (d,), jnp.float32, sharding=everywhere)
+    epoch = bound._epoch.lower(w, (), data.indices, data.values, data.labels,
+                               shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
+    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
+    for text, samples in ((epoch, 400), (evaluation, kernels.margin_tiles(4096, width, 1024))):
+        called = [k for k in _kernels_of(text) if "margin_tiles" in k]
+        assert len(called) == margin_tiles and " margin_tiles" not in text.replace(
+            "dsgd.margins/margin_tiles", "")
+        if margin_tiles:
+            assert "dsgd.margins/margin_tiles" in called[0]
+            assert f"f32[{4096 if text is evaluation else samples},8,128]" in called[0]
 
 
 # -- (j) a row's label rides in a spare word of the stored row ----------------------
