@@ -372,6 +372,165 @@ def test_the_margin_kernel_rule_cuts_pieces_from_shapes_alone(samples, width, la
     assert kernels.margin_tiles(samples, width, lanes) == piece
 
 
+# -- (v c) the wide-row kernels' unchecked DMAs, on runs of neighbouring ids --------------
+
+RUN_ROWS, RUN_LANES = 480, 256  # tiles of two lane groups
+
+
+def _run_case_ids(case: str, n: int, rng) -> np.ndarray:
+    """`n` entry ids over RUN_ROWS rows for one case.  Where a case plants
+    a run of neighbouring ids, every other id is a multiple of 3 away from
+    it, so no other run touches it."""
+    spread = 3 * rng.integers(0, RUN_ROWS // 3, n)
+    if case in ("law", "past_dma_block"):
+        return np.minimum(np.exp(rng.uniform(0, np.log(RUN_ROWS + 1), n)).astype(np.int64) - 1,
+                          RUN_ROWS - 1)
+    if case == "spread":  # no two distinct ids neighbours
+        return spread
+    if case == "one_id":
+        return np.full(n, 7)
+    if case in ("across_block", "block_end"):
+        # the scatter pads 330 entries with 54 of feature 0 in front and
+        # sorts them: 60 ids under 150 put the run at sorted entries 114..,
+        # across the first block of 128 or ending at its last entry
+        assert n == 330 and gather.RUN_BLOCK == 128
+        length = 30 if case == "across_block" else 14
+        below = 3 * rng.integers(1, 50, 60)
+        above = 3 * rng.integers(61 + length // 3, RUN_ROWS // 3, n - 60 - length)
+        return np.concatenate([below, 150 + np.arange(length), above])
+    length = int(case[3:])  # "run64", "run65", "run200": 150 .. 150 + length - 1 once each
+    rest = spread[length:]
+    rest[(rest >= 147) & (rest <= 151 + length)] = 3
+    return rng.permutation(np.concatenate([150 + np.arange(length), rest]))
+
+
+def _apart(ids: np.ndarray, w: np.ndarray):
+    """The same call with every id doubled: row 2 i of the wider weights is
+    row i, so no two distinct ids are neighbours, and the order of every
+    sort and of every addition is unchanged (the ids keep their order)."""
+    wide = np.zeros((2 * w.shape[0],) + w.shape[1:], w.dtype)
+    wide[0::2] = w
+    return 2 * ids, wide
+
+
+@pytest.mark.parametrize("kernel,case", [
+    ("scatter", "law"), ("scatter", "spread"), ("scatter", "across_block"),
+    ("scatter", "block_end"), ("scatter", "run64"), ("scatter", "run65"),
+    ("scatter", "run200"), ("scatter", "one_id"), ("scatter", "past_dma_block"),
+    ("margins", "law"), ("margins", "spread"), ("margins", "piece_end"),
+    ("margins", "run64"), ("margins", "run65"), ("margins", "run200"),
+    ("margins", "one_id")])
+def test_the_wide_row_kernels_see_only_the_order_of_the_ids(kernel, case, monkeypatch):
+    """`scatter_runs` (`_sum_runs_into`, blocks of 128 entries here) and
+    `margin_tiles` (Pallas' TPU interpret mode) on runs of neighbouring
+    tiles: bit for bit what the same call gives where no two ids are
+    neighbours (`_apart`), and the float64 sums to the order of addition.
+    The ids: under the generator's law; no neighbours; a run across a block
+    of the walk and one ending at its last entry; runs of 64, 65 and 200
+    tiles; one id in every entry; the scatter's entries in two calls
+    (`DMA_BLOCK`); a run that ends at a margin piece's last slot."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops.sparse import SparseBatch
+
+    rng = np.random.default_rng(41)
+    w = (rng.normal(size=(RUN_ROWS, RUN_LANES)) * 0.5).astype(np.float32)
+    if kernel == "scatter":
+        samples, n = 24, 330
+        monkeypatch.setattr(gather, "RUN_BLOCK", 128)
+        if case == "past_dma_block":
+            monkeypatch.setattr(gather, "DMA_BLOCK", 2 * 128)  # two calls
+        ids = _run_case_ids(case, n, rng).astype(np.int32)
+        values = rng.normal(size=n).astype(np.float32)
+        src = rng.integers(0, samples, n).astype(np.int32)
+        coeff = (rng.normal(size=(samples, RUN_LANES)) * 1e-2).astype(np.float32)
+
+        def call(ids, w):
+            with pltpu.force_tpu_interpret_mode():
+                return np.asarray(jax.jit(lambda w: gather.scatter_rows_into(
+                    w, jnp.asarray(ids), jnp.asarray(values), jnp.asarray(src),
+                    jnp.asarray(coeff), dma=True))(gather.to_tiles(jnp.asarray(w))))
+
+        want = w.astype(np.float64)
+        np.add.at(want, ids, values.astype(np.float64)[:, None] * coeff.astype(np.float64)[src])
+        got, apart = call(ids, w), call(*_apart(ids, w))
+        np.testing.assert_array_equal(got, apart[0::2])
+        assert not np.any(apart[1::2])
+        np.testing.assert_allclose(got.reshape(w.shape), want, rtol=1e-6, atol=2e-6)
+        return
+    samples, width, piece = 64, 8, 32
+    ids = 3 * rng.integers(0, RUN_ROWS // 3, (samples, width))
+    if case == "piece_end":  # the first piece's largest ids: a run to its last slot
+        ids[:piece] = 3 * rng.integers(0, 100, (piece, width))
+        ids[:4] = 400 + np.arange(32).reshape(4, width)
+    elif case.startswith("run"):  # the run lies in the first piece
+        ids[:piece] = _run_case_ids(case, piece * width, rng).reshape(piece, width)
+    elif case != "spread":
+        ids = _run_case_ids(case, samples * width, rng).reshape(samples, width)
+    ids = ids.astype(np.int32)
+    values = rng.normal(size=(samples, width)).astype(np.float32)
+
+    def margins(ids, w):
+        batch = SparseBatch(jnp.asarray(ids), jnp.asarray(values))
+        with pltpu.force_tpu_interpret_mode():
+            return np.asarray(jax.jit(lambda w: gather._margin_tiles(
+                w, *gather._sorted_pieces(batch, piece, w.shape[0]), piece, width))(
+                    gather.to_tiles(jnp.asarray(w))))
+
+    got = margins(ids, w)
+    np.testing.assert_array_equal(got, margins(*_apart(ids, w)))
+    want = np.einsum("bp,bpl->bl", values.astype(np.float64), w.astype(np.float64)[ids])
+    np.testing.assert_allclose(got.reshape(samples, RUN_LANES), want, rtol=1e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("kernel", ["scatter", "margins"])
+def test_an_id_past_the_weights_is_clamped_by_the_margins_and_dropped_by_the_scatter(kernel):
+    """The wide-row kernels start their DMAs unchecked
+    (`disable_bounds_checks`), so an id past the weights is put in range
+    before the call: the margins read the last row for it, as XLA's gather
+    clamps it, and the scatter adds nothing for it, as XLA's scatter drops
+    it: the kernels (Pallas' TPU interpret mode) against XLA's paths."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops.sparse import SparseBatch
+
+    rng = np.random.default_rng(42)
+    w = (rng.normal(size=(RUN_ROWS, RUN_LANES)) * 0.5).astype(np.float32)
+    tiles = gather.to_tiles(jnp.asarray(w))
+    if kernel == "scatter":
+        samples, n = 24, 300
+        ids = _run_case_ids("law", n, rng).astype(np.int32)
+        ids[::7] = RUN_ROWS + rng.integers(0, 1000, len(ids[::7]))
+        values = rng.normal(size=n).astype(np.float32)
+        src = rng.integers(0, samples, n).astype(np.int32)
+        coeff = (rng.normal(size=(samples, RUN_LANES)) * 1e-2).astype(np.float32)
+        entries = tuple(jnp.asarray(a) for a in (ids, values, src, coeff))
+        with pltpu.force_tpu_interpret_mode():
+            got = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries, dma=True))(
+                tiles)).reshape(w.shape)
+        xla = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries))(jnp.asarray(w)))
+        kept = ids < RUN_ROWS
+        want = w.astype(np.float64)
+        np.add.at(want, ids[kept], values[kept].astype(np.float64)[:, None]
+                  * coeff.astype(np.float64)[src[kept]])
+        np.testing.assert_allclose(got, xla, rtol=1e-6, atol=2e-6)
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=2e-6)
+        return
+    samples, width, piece = 32, 8, 16
+    ids = _run_case_ids("law", samples * width, rng).reshape(samples, width).astype(np.int32)
+    ids[::5, 3] = RUN_ROWS + 17
+    values = rng.normal(size=(samples, width)).astype(np.float32)
+    batch = SparseBatch(jnp.asarray(ids), jnp.asarray(values))
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(jax.jit(lambda w: gather._margin_tiles(
+            w, *gather._sorted_pieces(batch, piece, RUN_ROWS), piece, width))(tiles))
+    xla = np.asarray(gather.matvec_rows(batch, tiles))
+    want = np.einsum("bp,bpl->bl", values.astype(np.float64),
+                     w.astype(np.float64)[np.minimum(ids, RUN_ROWS - 1)])
+    np.testing.assert_allclose(got.reshape(samples, RUN_LANES), xla, rtol=1e-5, atol=2e-6)
+    np.testing.assert_allclose(got.reshape(samples, RUN_LANES), want, rtol=1e-5, atol=2e-6)
+
+
 @pytest.mark.parametrize("outputs,on_tpu,fetch", [
     (1000, True, "distinct"),  # tiles of eight lane groups on a TPU
     (1000, False, "gather"),   # off the TPU: XLA's gather, the tests' reference

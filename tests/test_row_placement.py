@@ -640,6 +640,7 @@ def test_on_a_v5e_the_merge_pass_and_the_words_keep_their_endings(v5e, shape, en
     kernel = _kernels_of(text)
     assert len(kernel) == 1 and f"dsgd.scatter/{ending}" in kernel[0]
     assert "scatter_runs" not in text
+    assert "disable_bounds_checks" not in kernel[0]  # only the wide-row kernels' DMAs
 
 
 def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e):
@@ -685,6 +686,8 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
     assert len(kernel) == 2 and all("f32[203888,8,128]" in k for k in kernel)
     assert "dsgd.margins/margin_tiles" in kernel[0] and "dsgd.labels" in step
     assert "dsgd.scatter/scatter_runs" in kernel[1]
+    # their DMAs start unchecked, the ids put in range before the call
+    assert all('"disable_bounds_checks":true' in k for k in kernel)
     # no array of a row an entry at all (the margins fetch each distinct
     # tile once, PR 40); the benchmark counts a window's steps by its most
     # frequent operation, so no loop of XLA's may turn inside a step
@@ -695,6 +698,7 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
     kernel = _kernels_of(evaluation)
     assert len(kernel) == 1 and "dsgd.margins/margin_tiles" in kernel[0]
     assert "f32[4096,8,128]" in kernel[0]  # a chunk's margins, 16 pieces of 256 samples
+    assert '"disable_bounds_checks":true' in kernel[0]
     assert not re.search(r"f32\[(36864|294912),8,128\]", evaluation) and "dsgd.labels" in evaluation
     # the same kernel on rows of eight lane groups that are NOT tiles
     flat = shape((2048, 1024), jnp.float32, sharding=everywhere)
