@@ -121,8 +121,7 @@ def main(argv) -> int:
                 jax.block_until_ready(bound.epoch(w, key))
                 best = min(best, time.perf_counter() - t0)
             out["step"][name] = {"us": best / steps * 1e6, "kernel": bound.kernel,
-                                 "sparse": bound.update_sparse, "dma": bound.scatter_rows,
-                                 "merge": bound.scatter_merge}
+                                 "update": bound.plan.update, "scatter": bound.plan.scatter}
             print(json.dumps({name: out["step"][name]}), file=sys.stderr, flush=True)
 
     if "forms" in only:
@@ -135,7 +134,7 @@ def main(argv) -> int:
                            n_outputs=n_outputs)
         w2 = model.to_layout(jnp.asarray(np.random.default_rng(32).normal(
             size=model.weight_shape) * 0.1, jnp.float32), "gather")
-        dma = device.platform == "tpu"
+        walk = "runs" if device.platform == "tpu" else "words"
         exact, sample = jax.lax.Precision.HIGHEST, jnp.arange(rows)[:, None]
 
         def batch_of(i):  # other ids every call: nothing hoisted, one structure
@@ -144,14 +143,15 @@ def main(argv) -> int:
         def coeff_of(m):
             return model.grad_coeff(m, y) * (-lr / WORKERS)
 
-        def sparse_rows(w2, i, merge=False):
+        def sparse_rows(w2, i, ending=walk):
             b = batch_of(i)
             at, v, src, coeff = model.reply_rows(w2, b, y, None, -lr / WORKERS)
-            return gather.scatter_rows_into(w2, at, v, src, coeff, dma=dma, merge=merge)
+            return gather.scatter_rows_into(w2, at, v, src, coeff, ending)
 
         def dense_rows(w2, i):
             b = batch_of(i)
-            g = gather.scatter_add_rows(b, coeff_of(gather.matvec_rows(b, w2)), w2.shape)
+            g = gather.scatter_add_rows(b, coeff_of(model.margins(w2, b, kernel="gather")),
+                                        w2.shape)
             return w2 + g
 
         def dense_batch(precision):
@@ -164,9 +164,10 @@ def main(argv) -> int:
 
         forms = {
             "b_margins_rows": lambda w2, i: w2.at[0].add(
-                jnp.sum(gather.matvec_rows(batch_of(i), w2), axis=0)),
+                jnp.sum(model.margins(w2, batch_of(i), kernel="gather"), axis=0)),
             "b_step_rows_into_carry": sparse_rows,
-            "b_step_rows_merged_into_carry": lambda w2, i: sparse_rows(w2, i, merge=dma),
+            "b_step_rows_merged_into_carry": lambda w2, i: sparse_rows(
+                w2, i, "merge" if walk == "runs" else walk),
             "b_step_dense_accumulator": dense_rows,
             "c_densify": lambda w2, i: w2.at[0, 0].add(jnp.sum(
                 jnp.zeros((rows, w2.shape[0]), jnp.float32).at[sample, batch_of(i).indices].add(
@@ -228,7 +229,8 @@ def main(argv) -> int:
             d, ids0 = ids_of(rows)
 
             def call(i, w2):
-                return gather.scatter_rows_into(w2, (ids0 + i) % d, val, src, coeff, dma=dma)
+                return gather.scatter_rows_into(w2, (ids0 + i) % d, val, src, coeff,
+                                                "runs" if dma else "words")
 
             return clocked(jax.jit(lambda w2: jax.lax.fori_loop(0, calls, call, w2)),
                            jnp.zeros((rows, 128), jnp.float32))
@@ -298,7 +300,8 @@ def a_dma_a_row(w2, ids, entry, dma):
     from distributed_sgd_tpu.ops import gather
 
     head = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
-    return gather._add_rows(w2, ids, head, gather._run_sums(ids, entry), dma)
+    return gather._add_rows(w2, ids, head, gather._run_sums(ids, entry),
+                            "rows" if dma else "words")
 
 
 def runs_table(jax, jnp, gather, on_tpu, rehearse):
@@ -414,12 +417,13 @@ def margins_table(jax, jnp, gather, kernels, on_tpu, rehearse, lanes_of=(256, 51
             # the rule's piece at 1,024 lanes (the worst case of a 4 KB tile
             # an entry in VMEM), and half of it
             rule = kernels.margin_tiles(samples, width, 1024)
+            xla = ("gather", kernels.margin_rows(samples, width, lanes))
             row = {"samples": samples, "xla_gather": clocked(
-                lambda w, b: gather.matvec_rows(b, w))}
+                lambda w, b: gather.matvec_rows(b, w, *xla))}
             w = gather.to_tiles(jnp.asarray(rng.normal(size=(rows, lanes)), jnp.float32))
             batch = SparseBatch(ids, vals)
             with interpreted():  # one call of each on the same weights
-                apart = jnp.abs(jax.jit(lambda w: gather.matvec_rows(batch, w))(w)
+                apart = jnp.abs(jax.jit(lambda w: gather.matvec_rows(batch, w, *xla))(w)
                                 - jax.jit(kernel(rule))(w, batch).reshape(samples, lanes))
             row[f"max_abs_apart_S{rule}"] = float(jnp.max(apart))
             del w, apart

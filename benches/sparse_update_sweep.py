@@ -188,7 +188,7 @@ def variants(rehearse: bool) -> list:
                 w2.shape)),
             "rows_add": whole(lambda w2, ids, upd: w2.at[ids // lanes].add(entry_rows(ids))),
             "scatter_into_xla": whole(gather.scatter_into),
-            "scatter_into_dma": whole(lambda *a: gather.scatter_into(*a, dma=True)),
+            "scatter_into_dma": whole(lambda *a: gather.scatter_into(*a, "rows")),
             "sort": piece(lambda w2, ids: jnp.sum(jax.lax.sort(
                 (ids, upd), num_keys=1, is_stable=False)[1])),
             "sum_by_row": piece(lambda w2, ids: jnp.sum(gather._sum_by_row(ids, upd)[2])),

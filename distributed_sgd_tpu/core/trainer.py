@@ -116,19 +116,8 @@ class SyncTrainer:
         bound_test = self.engine.bind(test)
         placed = bound_train.placement()
         stored = placed[0][2]  # one layout for every device's rows
-        log.info("train split: %d rows kernel=%s margins=%s scatter_shards=%d update=%s "
-                 "scatter=%s outputs=%d labels=%s eval_rows=%d margin_fetch=%s "
-                 "stored major_to_minor=%s, "
-                 "per device %s",
-                 len(train),
-                 bound_train.kernel,
-                 "merged" if bound_train.margins_merged else "per_worker",
-                 bound_train.scatter_shards,
-                 "sparse" if bound_train.update_sparse else "dense",
-                 bound_train.scatter_as,
-                 self.model.n_outputs,
-                 bound_train.labels_as, bound_train.eval_rows,
-                 bound_train.margin_fetch, stored, " ".join(
+        log.info("train split: %d rows %s stored major_to_minor=%s, per device %s",
+                 len(train), bound_train.plan.record(), stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
         w = (  # [D], or [D, C] for a model with an output axis

@@ -124,14 +124,6 @@ class LinearModel:
             return (self.n_features,)
         return (self.n_features, self.n_outputs)
 
-    def label_lanes(self, kernel: str) -> Optional[int]:
-        """The width an engine stores `y[N, C]` padded to (with 0, the pad
-        mask) so that labels line up with `kernel`'s margins; None: as they
-        come."""
-        if self.n_outputs > 1 and kernel == "gather":
-            return gather.output_lanes(self.n_outputs)
-        return None
-
     # -- abstract ----------------------------------------------------------
     def predict(self, margins: jax.Array) -> jax.Array:
         raise NotImplementedError
@@ -174,13 +166,13 @@ class LinearModel:
         return mxu.from_blocked(w, self.n_features) if kernel in kernels.BLOCKED else w
 
     def margins(self, w: jax.Array, batch: SparseBatch, kernel: str = "scalar",
-                distinct: bool = False) -> jax.Array:
-        """Per-sample dots x_b . w, `w` in `kernel`'s layout (`distinct`:
-        `gather.matvec_rows`' margin kernel, where tiles of outputs take it)."""
+                fetch: Optional[kernels.Fetch] = None) -> jax.Array:
+        """Per-sample dots x_b . w, `w` in `kernel`'s layout (`fetch`: how
+        rows of outputs are read, `_rows_margins`)."""
         if batch.is_dense:
             return self.margins_dense(w, batch.values)
         if kernel == "gather" and self.n_outputs > 1:
-            return gather.matvec_rows(batch, w, distinct)
+            return self._rows_margins(batch, w, fetch)
         if kernel == "gather":
             return gather.matvec(batch, w)
         if kernel in kernels.BLOCKED:
@@ -274,14 +266,15 @@ class LinearModel:
         return batch.indices.reshape(-1), cv.reshape(-1)
 
     def reply_rows(self, v2: jax.Array, batch: SparseBatch, y: jax.Array,
-                   scale: Optional[jax.Array] = None, factor=1.0, distinct: bool = False):
+                   scale: Optional[jax.Array] = None, factor=1.0,
+                   fetch: Optional[kernels.Fetch] = None):
         """`reply_entries` with an output axis (`v2 [D', L]`, `y [B, L]`):
         an entry's update is a whole row, `value x coeff[sample]`, and is
         handed on as its factors: (feature ids [T], values [T], the sample
         of every entry [T], `factor` x the samples' coefficient rows
-        [B, L]), what `gather.scatter_rows_into` takes.  `distinct`: the
+        [B, L]), what `gather.scatter_rows_into` takes.  `fetch`: the
         margins as `margins` takes them."""
-        margins = gather.matvec_rows(batch, v2, distinct)
+        margins = self._rows_margins(batch, v2, fetch)
         with jax.named_scope("dsgd.update"):
             if scale is not None:
                 margins = scale * margins
@@ -291,6 +284,13 @@ class LinearModel:
             src = jax.lax.broadcasted_iota(jnp.int32, batch.indices.shape, 0)
         return (batch.indices.reshape(-1), batch.values.astype(jnp.float32).reshape(-1),
                 src.reshape(-1), coeff.astype(jnp.float32))
+
+    def _rows_margins(self, batch: SparseBatch, w2: jax.Array, fetch=None) -> jax.Array:
+        """`gather.matvec_rows` as a binding's plan says (`fetch`), else XLA's
+        gather in the pieces `kernels.margin_rows` gives this call's shape."""
+        lanes = int(np.prod(w2.shape[1:]))
+        return gather.matvec_rows(batch, w2, *fetch or (
+            "gather", kernels.margin_rows(*batch.indices.shape, lanes)))
 
     def sample_losses(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """Per-sample losses (no regularization term), vectorized."""
@@ -422,7 +422,7 @@ class LinearModel:
         if kernel == "gather" and self.n_outputs > 1:  # rows of outputs
             scatter = functools.partial(gather.scatter_add_rows, batch, shape=w2.shape)
             if margins is None:
-                margins = gather.matvec_rows(batch, w2)
+                margins = self._rows_margins(batch, w2)
         elif kernel == "gather":
             scatter = functools.partial(gather.scatter_add, batch, n_rows=w2.shape[0])
             if margins is None:

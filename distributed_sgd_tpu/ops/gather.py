@@ -121,7 +121,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from distributed_sgd_tpu.ops import kernels
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 
 LANES = 128
@@ -284,20 +283,21 @@ def _write_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, new: jax.Array,
 
 
 def scatter_into(w2: jax.Array, ids: jax.Array, updates: jax.Array,
-                 dma: bool = False) -> jax.Array:
+                 ending: str = "words") -> jax.Array:
     """`w2` with `updates[t]` added at flat feature `ids[t]` (duplicates of
     an id accumulate, a pad adds 0.0 to feature 0): the entries summed by
     weight row first, every touched row then fetched, added to and written
-    back ONCE.  `dma`: the write is the kernel's (`_write_rows`, a TPU's);
-    else XLA's scatter of whole rows, told they are unique.  Inside a scan
-    whose carry `w2` is, both update the carry in place."""
+    back ONCE.  `ending` (`kernels.Plan.scatter`): 'rows', the write is the
+    kernel's (`_write_rows`, a TPU's); 'words', XLA's scatter of whole rows,
+    told they are unique.  Inside a scan whose carry `w2` is, both update
+    the carry in place."""
     with jax.named_scope("dsgd.scatter"):
         rows, head, total = _sum_by_row(ids, updates)
-        return _add_rows(w2, rows, head, total, dma)
+        return _add_rows(w2, rows, head, total, ending)
 
 
 def _add_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, total: jax.Array,
-              dma: bool) -> jax.Array:
+              ending: str = "words") -> jax.Array:
     """`w2` with `total[t]` added to row `rows[t]` wherever `head[t]`."""
     # only the heads' rows are written: off them any row will do, and
     # the gather runs faster over rows that differ than over a run's
@@ -305,7 +305,7 @@ def _add_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, total: jax.Array,
     entry = jnp.arange(rows.shape[0])
     # (rows that are tiles, `to_tiles`: the sums take the tiles' form here)
     new = w2[jnp.where(head, rows, entry % w2.shape[0])] + total.reshape((-1,) + w2.shape[1:])
-    if dma:
+    if ending == "rows":
         return _write_rows(w2, rows, head, new)
     # off the heads: past the last row, each its own index, dropped
     return w2.at[jnp.where(head, rows, w2.shape[0] + entry)].set(
@@ -349,7 +349,7 @@ def to_tiles(w2: jax.Array) -> jax.Array:
     tiling (8)"); with the lane groups a dimension of their own the tiling
     is over (lane group, lane) and a feature's weights are contiguous, 4 KB
     at 1,024 lanes.  What carries a binding's wide rows wherever no merge
-    pass runs over them (`BoundSync.rows_tiled`); every function of this
+    pass runs over them (`kernels.Plan.tiles`); every function of this
     file but `_merge_rows` takes weights in either form."""
     with jax.named_scope("dsgd.layout"):
         return w2.reshape(w2.shape[0], -1, LANES)
@@ -361,7 +361,7 @@ def from_tiles(w3: jax.Array) -> jax.Array:
         return w3.reshape(w3.shape[0], -1)
 
 
-def matvec_rows(batch: SparseBatch, w2: jax.Array, distinct: bool = False) -> jax.Array:
+def matvec_rows(batch: SparseBatch, w2: jax.Array, fetch: str, piece: int) -> jax.Array:
     """Per-sample, per-output dots `x_b . W[:, c]` -> [B, L]: every stored
     entry gathers its feature's row, a sample's P rows are summed with its
     values as weights (pads contribute 0 * row 0).  The entries are
@@ -371,20 +371,18 @@ def matvec_rows(batch: SparseBatch, w2: jax.Array, distinct: bool = False) -> ja
     (0.88 s of the 2.30 s an evaluation of 7.2 M rows took, my chip run,
     PR 32).  `kernels.margin_rows` says how many samples one gather takes.
 
-    `distinct` (a TPU, rows carried as tiles: `BoundSync.margins_distinct`):
-    where `kernels.margin_tiles` gives the shape a piece, ONE kernel of ours
-    takes the margins instead (`_margin_tiles`): each distinct tile of a
-    piece fetched once, a sample's tiles summed in a register, no [P B, L]
-    array at all."""
+    `fetch` and `piece` are the caller's (`kernels.Fetch`): 'gather' takes
+    `piece` samples a gather, piece after piece; 'distinct' (a TPU, rows
+    carried as tiles) runs ONE kernel of ours instead (`_margin_tiles`):
+    each distinct tile of a piece fetched once, a sample's tiles summed in a
+    register, no [P B, L] array at all."""
     tile = w2.shape[1:]  # (L,), or (L / 128, 128) where the rows are tiles
     lanes = math.prod(tile)
-    if distinct and len(tile) == 2:
-        piece = kernels.margin_tiles(*batch.indices.shape, lanes)
-        if piece:
-            with jax.named_scope("dsgd.margins"):
-                m = _margin_tiles(w2, *_sorted_pieces(batch, piece, w2.shape[0]), piece,
-                                  batch.indices.shape[1])
-                return m.reshape(-1, lanes)
+    if fetch == "distinct":
+        with jax.named_scope("dsgd.margins"):
+            m = _margin_tiles(w2, *_sorted_pieces(batch, piece, w2.shape[0]), piece,
+                              batch.indices.shape[1])
+            return m.reshape(-1, lanes)
 
     def dots(indices, values):
         entry_major = indices.T  # [P, B]
@@ -398,7 +396,6 @@ def matvec_rows(batch: SparseBatch, w2: jax.Array, distinct: bool = False) -> ja
 
     with jax.named_scope("dsgd.margins"):
         samples, width = batch.indices.shape
-        piece = kernels.margin_rows(samples, width, lanes)
         if piece == samples:
             return dots(batch.indices, batch.values)
         # wide rows: the gathered rows of a piece stay what the chip has run
@@ -1027,31 +1024,31 @@ def _sum_runs_into(w: jax.Array, ids: jax.Array, values: jax.Array, src: jax.Arr
 
 
 def scatter_rows_into(w2: jax.Array, ids: jax.Array, values: jax.Array, src: jax.Array,
-                      coeff: jax.Array, dma: bool = False, merge: bool = False) -> jax.Array:
+                      coeff: jax.Array, ending: str = "words") -> jax.Array:
     """`w2` with `values[t] * coeff[src[t]]` added to row `ids[t]`:
     `scatter_into` for updates that ARE rows, handed over as their factors
     (an entry's value and the sample it belongs to; the samples'
     coefficient rows `coeff [S, L]`), so that the sort moves three words an
     entry and no [T, L] array of updates is written before it.  Three
-    endings after the sort.  `dma` (a TPU): the sorted factors go to ONE
-    kernel that sums every run of an id out of the coefficient table and
-    reads, adds to and writes every touched row once (`_sum_runs_into`: no
-    array of entry rows at all, no term in the rows of `w2`).  `merge` (a
-    TPU, `w2` small beside the step's entries: `kernels.merges_scatter`):
-    each entry takes its sample's coefficient row (`_entry_rows`, 23 + 39 +
-    17 us for 30,400 entries on a v5e) and ONE pass over `w2` adds them all
-    (`_merge_rows`, 126 us at `w2 [47,240, 128]`).  Neither (off the TPU):
-    the entry rows' runs are summed and XLA's scatter writes the touched
-    rows (`_add_runs`)."""
+    endings after the sort (`kernels.Plan.scatter`).  'runs' (a TPU): the
+    sorted factors go to ONE kernel that sums every run of an id out of the
+    coefficient table and reads, adds to and writes every touched row once
+    (`_sum_runs_into`: no array of entry rows at all, no term in the rows of
+    `w2`).  'merge' (a TPU, `w2` small beside the step's entries:
+    `kernels.merges_scatter`): each entry takes its sample's coefficient
+    row (`_entry_rows`, 23 + 39 + 17 us for 30,400 entries on a v5e) and ONE
+    pass over `w2` adds them all (`_merge_rows`, 126 us at
+    `w2 [47,240, 128]`).  'words' (off the TPU): the entry rows' runs are
+    summed and XLA's scatter writes the touched rows (`_add_runs`)."""
     with jax.named_scope("dsgd.scatter"):
-        if dma and not merge:
+        if ending == "runs":
             # an entry off `w2` adds nothing, as XLA's scatter drops it: the
             # kernel's DMAs are not checked
             kept = (ids >= 0) & (ids < w2.shape[0])
             ids, values = jnp.where(kept, ids, 0), jnp.where(kept, values, 0.0)
             return _sum_runs_into(w2, *_sorted_entries(ids, values, src, RUN_BLOCK), coeff)
         ids, entry = _entry_rows(ids, values, src, coeff)
-        return _merge_rows(w2, ids, entry) if merge else _add_runs(w2, ids, entry)
+        return _merge_rows(w2, ids, entry) if ending == "merge" else _add_runs(w2, ids, entry)
 
 
 def _add_runs(w2: jax.Array, ids: jax.Array, entry: jax.Array) -> jax.Array:
@@ -1059,7 +1056,7 @@ def _add_runs(w2: jax.Array, ids: jax.Array, entry: jax.Array) -> jax.Array:
     row at a time: the runs of an id summed (`_run_sums`), every touched row
     fetched, added to and written back once by XLA's scatter."""
     head = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
-    return _add_rows(w2, ids, head, _run_sums(ids, entry), dma=False)
+    return _add_rows(w2, ids, head, _run_sums(ids, entry))
 
 
 def _sorted_entries(ids: jax.Array, values: jax.Array, src: jax.Array, multiple: int):

@@ -15,29 +15,32 @@ Weights with an output axis (`LinearModel.n_outputs` = C > 1: `W[D, C]`)
 run 'dense', 'gather' (one weight row a FEATURE, the outputs on its lanes)
 or 'scalar'; the one-hot family has no form with outputs.
 
-`choose_kernel` maps (feature count, row width, platform, outputs) to one;
-`SyncEngine.bind` (and through it `LocalSGDEngine`), Hogwild's `_Worker`
-and `core/worker.py` all ask it through `resolve`, which reads the
-platform off the device and counts the answer.  An explicit `kernel=`
-still overrides: the rule answers only for `AUTO`.
+`choose_kernel` maps (feature count, row width, platform, outputs) to one.
+Hogwild's `_Worker` and `core/worker.py` ask it through `resolve`, which
+reads the platform off the device and counts the answer.  An explicit
+`kernel=` still overrides: the rule answers only for `AUTO`.
 
-Six more rules on a binding's shape live here, each asked once a
-binding by `BoundSync`: `merges_margins` (the K virtual workers' margins
-in one call), `ONE_ACCUMULATOR` (their entries scattered into one
-gradient), `sparse_update` (no gradient at all: the entries scattered
-into the carried weights, the regulariser a scalar on them, so that a
-step's bytes have no term in the feature count), `merges_scatter`
-(with an output axis, whether that scatter is one pass over the weights
-or a walk of its entries that moves each touched row once), `margin_rows` (with an output axis, how many
-samples one row gather of the margins takes) and `margin_tiles` (with rows
-carried as tiles on a TPU, the piece of samples whose distinct tiles the
-margin kernel fetches once each).
+A sync binding (`SyncEngine.bind`, and through it `LocalSGDEngine`) asks
+`plan` instead: one call that probes the platform once, asks every rule
+below and counts the `bind.*` counters, and whose frozen `Plan` the engine,
+the model and the kernels read.  The rules: `merges_margins` (the K virtual
+workers' margins in one call), `ONE_ACCUMULATOR` (their entries scattered
+into one gradient), `sparse_update` (no gradient at all: the entries
+scattered into the carried weights, the regulariser a scalar on them, so
+that a step's bytes have no term in the feature count), `merges_scatter`
+(with an output axis, whether that scatter is one pass over the weights or
+a walk of its entries that moves each touched row once), `margin_rows`
+(with an output axis, how many samples one row gather of the margins takes)
+and `margin_tiles` (with rows carried as tiles on a TPU, the piece of
+samples whose distinct tiles the margin kernel fetches once each).
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import dataclasses
+from typing import NamedTuple, Optional
 
+from distributed_sgd_tpu.ops import gather, mxu
 from distributed_sgd_tpu.utils import metrics
 
 AUTO = "auto"
@@ -113,8 +116,8 @@ def merges_margins(kernel: str, row_width: int) -> bool:
     the same `w`, so for sparse rows (`row_width` stored entries; 0: the
     dense layout) through the blocked kernels of ours nothing keeps them
     apart, and K calls batched over the workers cost K calls.  'scalar' and
-    'dense' keep XLA's own batching.  Static per binding: `BoundSync` counts
-    it under `bind.margins.merged`."""
+    'dense' keep XLA's own batching.  Static per binding: `plan` counts it
+    under `bind.margins.merged`."""
     return row_width != 0 and kernel in ("mxu", "gather")
 
 
@@ -188,7 +191,7 @@ def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
     that leaves the sign of `w` (under 1), from SPARSE_UPDATE_MIN_FEATURES
     words of weights on (`n_features` x `n_outputs`).  'dim_sparsity' masks each reply
     by its own support and keeps the dense step, as the one-hot, dense and
-    scalar families do.  Static per binding: `BoundSync` counts it under
+    scalar families do.  Static per binding: `plan` counts it under
     `bind.update.sparse`."""
     return (kernel in ONE_ACCUMULATOR and regularizer in ("l2", "none")
             and plain_sgd and 0.0 <= decay < 1.0
@@ -235,11 +238,9 @@ def merges_scatter(n_features: int, n_outputs: int, n_entries: int) -> bool:
     fetching, adding to and writing back every touched row by itself
     (`gather._sum_runs_into`, two DMAs a row): where `W2` is small beside what a
     step touches, `n_features` rows against `n_entries` entries of ALL the
-    mesh's workers.  From shapes alone; a TPU's question (`BoundSync` asks
-    once a binding where a kernel of ours would run and counts the answer
-    under `bind.scatter.merge`, or `bind.scatter.runs`)."""
-    from distributed_sgd_tpu.ops import gather
-
+    mesh's workers.  From shapes alone; a TPU's question (`plan` asks once
+    a binding where a kernel of ours would run and counts the answer under
+    `bind.scatter.merge`, or `bind.scatter.runs`)."""
     return (n_features <= MERGE_MAX_ROWS_PER_ENTRY * n_entries
             and gather.output_lanes(n_outputs) <= MERGE_MAX_LANES)
 
@@ -305,8 +306,8 @@ def margin_tiles(samples: int, row_width: int, lanes: int) -> int:
     against weight tiles of `lanes` lanes: `samples` halved until the
     piece's worst case fits (0: it never does, or the rows are narrower
     than MARGIN_TILES_MIN_LANES, and XLA's gather takes the margins).  From
-    shapes alone; a TPU's question (`BoundSync` asks once a binding and
-    counts the kernel under `bind.margins.tiles`)."""
+    shapes alone; a TPU's question (`plan` asks once a binding and counts
+    the kernel under `bind.margins.tiles`)."""
     if lanes < MARGIN_TILES_MIN_LANES:
         return 0
     tile = -(-lanes // 1024) * 4096  # VMEM bytes: whole registers of 8 x 128 words
@@ -320,20 +321,112 @@ def margin_tiles(samples: int, row_width: int, lanes: int) -> int:
     return piece
 
 
+class Fetch(NamedTuple):
+    """How a call of the output-axis margins (`gather.matvec_rows`) reads
+    weight rows: 'gather' (XLA's, `piece` samples a gather: `margin_rows`)
+    or 'distinct' (the margin kernel, pieces of `piece`: `margin_tiles`)."""
+    how: str
+    piece: int
+
+
+def _family(kernel: Optional[str], n_features: int, row_width: int, on_tpu: bool,
+            off_tpu: str, n_outputs: int) -> str:
+    """`kernel` as named (dense rows run 'dense'), else the rule's answer."""
+    if kernel in (None, AUTO) or row_width == 0:
+        platform = "tpu" if on_tpu else "cpu"
+        kernel = choose_kernel(n_features, row_width, platform, off_tpu, n_outputs)
+    metrics.counter(f"bind.kernel.{kernel}").increment()
+    return kernel
+
+
 def resolve(kernel: Optional[str], n_features: int, row_width: int,
             device=None, off_tpu: str = "scalar", n_outputs: int = 1) -> str:
-    """What an engine binds: an explicit `kernel` as given (dense rows can
-    only run 'dense'), the rule's answer on the platform of `device` (None:
-    the process default backend) for AUTO / None; `n_outputs` > 1 is never
-    answered with the one-hot family.  `mxu.blocked_pays_off`
-    is the platform probe: one policy for "is this a TPU", which tests
-    steer.  Counted once a binding under `bind.kernel.<name>`."""
-    if kernel in (None, AUTO) or row_width == 0:
-        from distributed_sgd_tpu.ops import mxu
+    """What Hogwild's `_Worker` and the rpc worker bind, on the platform of
+    `device` (None: the default backend) as `mxu.blocked_pays_off` says."""
+    on_tpu = mxu.blocked_pays_off(device)
+    return _family(kernel, n_features, row_width, on_tpu, off_tpu, n_outputs)
 
-        platform = "tpu" if mxu.blocked_pays_off(device) else "cpu"
-        chosen = choose_kernel(n_features, row_width, platform, off_tpu, n_outputs)
-    else:
-        chosen = kernel
-    metrics.counter(f"bind.kernel.{chosen}").increment()
-    return chosen
+
+@dataclasses.dataclass(frozen=True)
+class Plan:
+    """Every kernel decision of one sync binding, made once by `plan`: what
+    `BoundSync` compiles, what `LinearModel` and the kernels are handed and
+    what the `train split:` record says.  `scatter`: the sparse step's
+    ending, a kernel of ours on a TPU ('merge' / 'runs' with outputs, by
+    `merges_scatter`; 'rows' without), else 'words' (XLA's); `tiles`: wide
+    weight rows carried as tiles (`gather.to_tiles`); `lanes`: of a weight
+    row of outputs (0: none), as the margins and stored labels have them;
+    `step_fetch` / `eval_fetch`: a step's K x B samples a device, an
+    evaluation chunk; `decay`: what a step takes off every coordinate."""
+
+    kernel: str
+    margins: str
+    scatter_shards: int
+    update: str
+    scatter: str
+    tiles: bool
+    lanes: int
+    step_fetch: Fetch
+    eval_fetch: Fetch
+    labels: str
+    outputs: int
+    decay: float
+
+    def record(self) -> str:
+        """The binding's fields of the `train split:` record."""
+        return (f"kernel={self.kernel} margins={self.margins} "
+                f"scatter_shards={self.scatter_shards} update={self.update} "
+                f"scatter={self.scatter} outputs={self.outputs} labels={self.labels} "
+                f"eval_rows={self.eval_fetch.piece} margin_fetch={self.eval_fetch.how}")
+
+
+def plan(model, *, learning_rate: float, plain_sgd: bool, row_width: int,
+         virtual_workers: int, batch_size: int, n_workers: int, eval_chunk: int,
+         lists: bool = False, riding: bool = False, kernel: Optional[str] = AUTO,
+         device=None) -> Plan:
+    """The plan of a sync binding of `model` (rows of `row_width` entries, 0:
+    dense; labels as id `lists`, `riding` in a stored row, or gathered);
+    `kernel` names a family or is AUTO.  `device`'s platform is probed once
+    (`mxu.blocked_pays_off`); every decision is counted here under `bind.*`."""
+    if kernel not in (None, AUTO) + KERNELS:
+        raise ValueError(
+            f"kernel must be one of {KERNELS} (or {AUTO!r}: the rule on shape "
+            f"and platform), got {kernel!r}")
+    d, c = model.n_features, model.n_outputs
+    # each of the n workers adds 2 lam w to its reply; the update is lr x their mean
+    decay = 2.0 * learning_rate * model.lam if model.regularizer == "l2" else 0.0
+    on_tpu = mxu.blocked_pays_off(device)
+    kernel = _family(kernel, d, row_width, on_tpu, "mxu", c)
+    sparse = sparse_update(kernel, model.regularizer, plain_sgd, decay, d, c)
+    lanes = gather.output_lanes(c) if kernel == "gather" and c > 1 else 0
+    ours = sparse and on_tpu  # a kernel of ours ends the sparse step
+    merge = ours and c > 1 and merges_scatter(
+        d, c, n_workers * virtual_workers * batch_size * row_width)
+    tiles = lanes > gather.LANES and not merge
+
+    def fetch(samples, distinct):
+        piece = margin_tiles(samples, row_width, lanes) if distinct else 0
+        return Fetch("distinct", piece) if piece else Fetch(
+            "gather", margin_rows(samples, row_width, lanes))
+
+    eval_fetch = fetch(eval_chunk, ours and tiles)
+    merged = virtual_workers > 1 and merges_margins(kernel, row_width)
+    decided = Plan(
+        kernel=kernel, margins="merged" if merged else "per_worker",
+        scatter_shards=(mxu.scatter_shards(batch_size * row_width, mxu.n_blocks(d))
+                        if kernel == "mxu" else 1),
+        update="sparse" if sparse else "dense",
+        scatter="merge" if merge else ("runs" if c > 1 else "rows") if ours else "words",
+        tiles=tiles, lanes=lanes,
+        # the step takes the margin kernel where the evaluation's chunk does
+        step_fetch=fetch(virtual_workers * batch_size, eval_fetch.how == "distinct"),
+        eval_fetch=eval_fetch, labels="lists" if lists else "in_row" if riding else "gathered",
+        outputs=c, decay=decay)
+    for name, counted in (("outputs.multi", c > 1), ("margins.merged", merged),
+                          ("scatter.sharded", decided.scatter_shards > 1),
+                          (f"labels.{decided.labels}", True), ("update.sparse", sparse),
+                          (f"scatter.{decided.scatter}", ours),
+                          ("margins.tiles", eval_fetch.how == "distinct")):
+        if counted:
+            metrics.counter(f"bind.{name}").increment()
+    return decided

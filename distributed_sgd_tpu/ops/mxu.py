@@ -57,7 +57,7 @@ def blocked_pays_off(device=None) -> bool:
     """One shared policy for 'do the blocked kernels pay off on this
     device?': yes on TPU (where they beat scalar scatter ~10x), no on CPU
     (where the scalar gather/scatter wins).  It is the platform probe of
-    the kernel rule (ops/kernels.py `resolve`), which decides WHICH
+    the kernel rules (ops/kernels.py `plan`, `resolve`), which decide WHICH
     blocked family from the shape.  Pass the pinned device when there is
     one; falls back to the process default backend."""
     platform = getattr(device, "platform", None)

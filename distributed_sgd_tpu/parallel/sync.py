@@ -17,8 +17,8 @@ The whole epoch is ONE compiled program: no host round-trips, no
 serialization of the 47k-dim weight vector per batch per worker (the
 reference ships it over gRPC every batch, Master.scala:184-189).
 
-Kernel backends (`kernel=`): `SyncEngine.bind` asks the one rule on shape
-and platform (ops/kernels.py) unless a family is named.  'mxu' keeps
+Kernel backends (`kernel=`): each binding's plan (ops/kernels.py `plan`)
+asks the one rule on shape and platform unless a family is named.  'mxu' keeps
 weights in the lane-blocked [R, 128] view across the epoch scan and runs
 the sparse gather/scatter as one-hot MXU matmuls (ops/mxu.py — ~32 us vs
 ~310 us per 3-worker step at RCV1 shapes on v5e, benches/step_bench.py);
@@ -61,7 +61,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import LIST_NO_ROW, Dataset
 from distributed_sgd_tpu.models.linear import LinearModel, expand_labels, require_single_output
-from distributed_sgd_tpu.ops import gather, kernels, mxu
+from distributed_sgd_tpu.ops import gather, kernels
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
     WORKER_AXIS,
@@ -74,7 +74,7 @@ from distributed_sgd_tpu.parallel.mesh import (
     shard_map,
     unpack_rows,
 )
-from distributed_sgd_tpu.utils import measure, metrics
+from distributed_sgd_tpu.utils import measure
 
 AXIS = WORKER_AXIS
 
@@ -84,7 +84,7 @@ class ShardedData(NamedTuple):
     values: jax.Array  # f32[N_pad, P], sharded over workers
     # [N_pad], or [N_pad, C] for a model with C outputs (bind() stores it
     # zero-padded to the lanes of the kernel's margins,
-    # LinearModel.label_lanes); sharded over workers; 0 = padding mask.
+    # kernels.Plan.lanes); sharded over workers; 0 = padding mask.
     # Or id lists (`label_lists` below).
     # What the evaluation, `predict`'s callers and every step whose
     # `label_slot` is None read
@@ -132,24 +132,10 @@ class BoundSync:
         optimizer=None,
         momentum: float = 0.9,
         donate: bool = False,
+        plan: Optional[kernels.Plan] = None,  # None: made here, for `kernel`
     ):
         if sampling not in ("fresh", "epoch"):
             raise ValueError(f"sampling must be 'fresh' or 'epoch', got {sampling!r}")
-        if kernel not in kernels.KERNELS:
-            raise ValueError(
-                f"kernel must be one of {kernels.KERNELS} (bind() "
-                f"resolves {kernels.AUTO!r} by shape), got {kernel!r}"
-            )
-        dense_data = data.is_dense
-        if (kernel == "dense") != dense_data:
-            raise ValueError(
-                f"kernel='dense' goes with dense-layout data (Dataset.dense) and "
-                f"vice versa; got kernel={kernel!r}, dense data={dense_data}"
-            )
-        model.check_kernel(kernel)
-        self.kernel = kernel
-        if model.n_outputs > 1:  # one row of labels a sample, W[D, C]
-            metrics.counter("bind.outputs.multi").increment()
         # buffer donation (ROADMAP item 2): donate=True marks the weights
         # and optimizer-state arguments of the TRAINING dispatches (step /
         # epoch / fused multi-epoch) as donated, so XLA reuses their HBM
@@ -176,118 +162,50 @@ class BoundSync:
         self.virtual_workers = int(virtual_workers)
         if self.virtual_workers < 1:
             raise ValueError("virtual_workers must be >= 1")
-        # whether the step computes the K workers' margins in one call on
-        # their merged batches (LinearModel.grad_workers; K = 1 never goes
-        # through it): static per binding
-        self.margins_merged = self.virtual_workers > 1 and kernels.merges_margins(
-            kernel, data.indices.shape[1])
-        if self.margins_merged:
-            metrics.counter("bind.margins.merged").increment()
-        # the shards the step's one-hot scatter cuts a worker's contraction
-        # over its batch's entries into (mxu.scatter_shards; 1: one plain
-        # dot, and every other family's answer): static per binding
-        row_width = data.indices.shape[1] if data.width is None else data.width
-        self.scatter_shards = mxu.scatter_shards(
-            self.batch_size * row_width, mxu.n_blocks(model.n_features)
-        ) if kernel == "mxu" else 1
-        if self.scatter_shards > 1:
-            metrics.counter("bind.scatter.sharded").increment()
+        self.shard_n = data.indices.shape[0] // self.n_workers
+        self.eval_chunk = min(eval_chunk, self.shard_n)
+        if self.shard_n % self.eval_chunk != 0:
+            raise ValueError(
+                f"shard size {self.shard_n} not a multiple of eval_chunk {self.eval_chunk}"
+            )
+        # optional optax optimizer (capability superset; the reference is
+        # plain SGD, Master.scala:197).  None = reference update w - lr*g.
+        # State lives in the kernel's weight layout and is threaded through
+        # every compiled loop, replicated over the mesh like the weights.
+        self.opt = resolve_optimizer(optimizer, self.learning_rate, momentum)
+        self.plan = plan or kernels.plan(
+            model, learning_rate=self.learning_rate, plain_sgd=self.opt is None,
+            row_width=0 if data.is_dense else data.width or data.indices.shape[1],
+            virtual_workers=self.virtual_workers, batch_size=self.batch_size,
+            n_workers=self.n_workers, eval_chunk=self.eval_chunk, lists=data.label_lists,
+            riding=data.label_slot is not None, kernel=kernel, device=mesh.devices.flat[0])
+        self.kernel = self.plan.kernel
+        if (self.kernel == "dense") != data.is_dense:
+            raise ValueError(
+                f"kernel='dense' goes with dense-layout data (Dataset.dense) and "
+                f"vice versa; got kernel={self.kernel!r}, dense data={data.is_dense}"
+            )
+        model.check_kernel(self.kernel)
         # rows stored wider than the dataset holds them (mesh.put_rows):
         # every read takes the true width back off (rows / chunk)
         padded = (not data.packed and data.width is not None
                   and data.width < data.values.shape[1])
         self._width = data.width if padded else None
         self._packed = data.width if data.packed else None
-        # whether the step reads a row's label out of the row it has drawn
-        # (mesh.label_slot) and gathers no label: static per binding
-        self.labels_in_row = data.label_slot is not None
-        if self.labels_in_row:
+        if data.label_slot is not None:
             first = 2 * data.width if data.packed else data.width
             stored = (data.indices if data.packed else data.values).shape[1]
             if model.n_outputs > 1 or not first <= data.label_slot < stored:
                 raise ValueError(
                     f"label_slot={data.label_slot} is no spare word of a stored row "
                     f"(words {first}..{stored - 1}, one output)")
-        # whether the resident labels are id lists, which every reader expands
-        # (`_labels`): static per binding
-        self.label_lists = data.label_lists
-        if self.label_lists and self.labels_in_row:
-            raise ValueError("a label list rides in no word of a stored row")
-        self.labels_as = ("lists" if self.label_lists else
-                          "in_row" if self.labels_in_row else "gathered")
-        metrics.counter(f"bind.labels.{self.labels_as}").increment()
-        n_pad = data.indices.shape[0]
-        self.shard_n = n_pad // self.n_workers
-        self.eval_chunk = min(eval_chunk, self.shard_n)
-        if self.shard_n % self.eval_chunk != 0:
-            raise ValueError(
-                f"shard size {self.shard_n} not a multiple of eval_chunk {self.eval_chunk}"
-            )
-        # the lanes of a weight row that holds the outputs (gather.to_rows;
-        # 0: no such rows), and the samples of an evaluation chunk one row
-        # gather of the margins takes (kernels.margin_rows: the whole chunk
-        # but for wide rows)
-        row_lanes = (gather.output_lanes(model.n_outputs)
-                     if kernel == "gather" and model.n_outputs > 1 else 0)
-        self.eval_rows = (kernels.margin_rows(self.eval_chunk, row_width, row_lanes)
-                          if row_lanes else self.eval_chunk)
+            if data.label_lists:
+                raise ValueError("a label list rides in no word of a stored row")
         # reference: maxSamples = max shard size; steps = ceil(max/bs)
         # (Master.scala:138,179) computed over true samples and the TOTAL
         # worker count (mesh devices x virtual workers per device)
         max_shard = math.ceil(data.n_true / (self.n_workers * self.virtual_workers))
         self.steps_per_epoch = steps_per_epoch or max(1, math.ceil(max_shard / self.batch_size))
-
-        # optional optax optimizer (capability superset; the reference is
-        # plain SGD, Master.scala:197).  None = reference update w - lr*g.
-        # State lives in the kernel's weight layout and is threaded through
-        # every compiled loop, replicated over the mesh like the weights.
-        self.opt = resolve_optimizer(optimizer, self.learning_rate, momentum)
-        # whether the step scatters the replies' entries straight into the
-        # carried weights and never builds a gradient (_sparse_step): the
-        # one rule of ops/kernels.py, static per binding.  `_decay` is what
-        # a step's regulariser takes off every coordinate: each of the n
-        # workers adds 2 lam w to its reply and the update is lr x their mean
-        self._decay = (2.0 * self.learning_rate * model.lam
-                       if model.regularizer == "l2" else 0.0)
-        self.update_sparse = kernels.sparse_update(
-            kernel, model.regularizer, self.opt is None, self._decay,
-            model.n_features, model.n_outputs)
-        if self.update_sparse:
-            metrics.counter("bind.update.sparse").increment()
-        # which kernel of ours that scatter ends in, on a TPU (the kernel
-        # rule's own platform probe; elsewhere XLA writes the same rows).
-        # With an output axis: where the weights are small beside a step's
-        # entries (kernels.merges_scatter) ONE pass over them that merges
-        # the sorted entries in, else ONE walk of the sorted entries that
-        # sums every run of an id and moves each touched row once
-        # (gather.scatter_rows_into).  Without: a DMA a touched row of
-        # words summed on the MXU (gather.scatter_into)
-        on_tpu = self.update_sparse and mxu.blocked_pays_off(mesh.devices.flat[0])
-        self.scatter_merge = (on_tpu and model.n_outputs > 1 and kernels.merges_scatter(
-            model.n_features, model.n_outputs,
-            self.n_workers * self.virtual_workers * self.batch_size * row_width))
-        self.scatter_rows = on_tpu and not self.scatter_merge
-        self.scatter_as = ("merge" if self.scatter_merge else "words" if not on_tpu
-                           else "runs" if model.n_outputs > 1 else "rows")
-        if on_tpu:
-            metrics.counter(f"bind.scatter.{self.scatter_as}").increment()
-        # whether weight rows of more than one lane group are carried as
-        # tiles [D', L / 128, 128] (gather.to_tiles: a feature's weights
-        # contiguous, what the row DMA can name): everywhere but under the
-        # merge pass, which streams blocks of [D', L]
-        self.rows_tiled = row_lanes > gather.LANES and not self.scatter_merge
-        # whether the margins of the step and of the evaluation fetch each
-        # distinct tile of a piece of samples once (gather._margin_tiles, a
-        # TPU's) and not a tile an entry: tiles of a sparse binding, wide
-        # enough for the rule (kernels.margin_tiles); the evaluation's chunk
-        # is then cut into the kernel's pieces
-        piece = (kernels.margin_tiles(self.eval_chunk, row_width, row_lanes)
-                 if on_tpu and self.rows_tiled else 0)
-        self.margins_distinct = piece > 0
-        self.margin_fetch = "distinct" if self.margins_distinct else "gather"
-        if self.margins_distinct:
-            metrics.counter("bind.margins.tiles").increment()
-            self.eval_rows = piece
         self._opt_state = self._init_opt_state()
         sspec = jax.tree.map(lambda _: P(), self._opt_state)
 
@@ -327,6 +245,15 @@ class BoundSync:
                 out_specs=P(AXIS),
             )
         )
+
+    @property
+    def update_sparse(self) -> bool:
+        """Whether the step builds no gradient (`_sparse_step`)."""
+        return self.plan.update == "sparse"
+
+    @property
+    def labels_as(self) -> str:
+        return self.plan.labels
 
     # -- per-device bodies (run under shard_map) ---------------------------
 
@@ -430,24 +357,24 @@ class BoundSync:
 
     def _fold_span(self) -> int:
         """Steps the sparse loop runs between two folds of s into v2."""
-        if self._decay == 0.0:
+        if self.plan.decay == 0.0:
             return self.steps_per_epoch
-        return max(1, int(self._FOLD_LOG / -math.log1p(-self._decay)))
+        return max(1, int(self._FOLD_LOG / -math.log1p(-self.plan.decay)))
 
     def _scale(self, since):
         """s after `since` steps since the last fold (None: no decay)."""
-        if self._decay == 0.0:
+        if self.plan.decay == 0.0:
             return None
-        return jnp.exp(since.astype(jnp.float32) * jnp.float32(math.log1p(-self._decay)))
+        return jnp.exp(since.astype(jnp.float32) * jnp.float32(math.log1p(-self.plan.decay)))
 
     def _rescale(self, v2, since: int):
         """The fold: w2 = s v2 after `since` steps, one pass over the
         weights, as v2 + (s - 1) v2 with s - 1 from expm1 in float64 (a
         float32 s would be 1.0 and lose the term)."""
-        if self._decay == 0.0:
+        if self.plan.decay == 0.0:
             return v2
         with jax.named_scope("dsgd.rescale"):
-            return v2 + jnp.float32(math.expm1(since * math.log1p(-self._decay))) * v2
+            return v2 + jnp.float32(math.expm1(since * math.log1p(-self.plan.decay))) * v2
 
     def _sparse_step(self, v2, idx, val, y, key, step, since):
         """One sync DP step on the carried `v2` (blocked weights = s v2,
@@ -482,7 +409,7 @@ class BoundSync:
             both = gather_replicated(jnp.stack([at, bits]), AXIS)  # [devices, 2, T]
             at = both[:, 0].reshape(-1)
             add = jax.lax.bitcast_convert_type(both[:, 1], jnp.float32).reshape(-1)
-        return gather.scatter_into(v2, at, add, dma=self.scatter_rows)
+        return gather.scatter_into(v2, at, add, self.plan.scatter)
 
     def _scatter_reply_rows(self, v2, merged, by, s, factor):
         """The rest of `_sparse_step` with an output axis: an entry's
@@ -490,8 +417,7 @@ class BoundSync:
         sample it belongs to) beside every sample's coefficient row, a
         hundredth of the bytes of the rows themselves."""
         at, val, src, coeff = self.model.reply_rows(
-            v2, merged, by.reshape((-1,) + by.shape[2:]), s, factor,
-            distinct=self.margins_distinct)
+            v2, merged, by.reshape((-1,) + by.shape[2:]), s, factor, self.plan.step_fetch)
         t, (samples, lanes) = at.shape[0], coeff.shape
         with jax.named_scope("dsgd.allreduce"):
             # all of it as bits in one vector: one collective a step
@@ -506,8 +432,7 @@ class BoundSync:
                    + samples * jnp.arange(every.shape[0], dtype=jnp.int32)[:, None]).reshape(-1)
             coeff = jax.lax.bitcast_convert_type(
                 every[:, 3 * t:], jnp.float32).reshape(-1, lanes)
-        return gather.scatter_rows_into(v2, at, val, src, coeff, dma=self.scatter_rows,
-                                        merge=self.scatter_merge)
+        return gather.scatter_rows_into(v2, at, val, src, coeff, self.plan.scatter)
 
     def _sparse_steps(self, v2, idx, val, y, key):
         """`steps_per_epoch` sparse steps on blocked weights, folded."""
@@ -558,11 +483,10 @@ class BoundSync:
         """The labels the losses take, of labels as they are resident: id
         lists expanded to rows of +1 / -1 / 0 (under `dsgd.labels`), every
         other form as it is."""
-        if not self.label_lists:
+        if self.plan.labels != "lists":
             return y
-        model = self.model  # [B, C], or [B, L] beside lane-padded margins
-        return expand_labels(y, model.n_outputs,
-                             model.label_lanes(self.kernel) or model.n_outputs)
+        # [B, C], or [B, L] beside lane-padded margins
+        return expand_labels(y, self.model.n_outputs, self.plan.lanes or self.model.n_outputs)
 
     def chunk_rows(self, idx, val, start):
         """(indices, values) of the evaluation's chunk at `start`, likewise."""
@@ -572,10 +496,10 @@ class BoundSync:
 
     def _to_kernel_layout(self, w):
         w = self.model.to_layout(w, self.kernel)
-        return gather.to_tiles(w) if self.rows_tiled else w
+        return gather.to_tiles(w) if self.plan.tiles else w
 
     def _from_kernel_layout(self, w):
-        return self.model.from_layout(gather.from_tiles(w) if self.rows_tiled else w,
+        return self.model.from_layout(gather.from_tiles(w) if self.plan.tiles else w,
                                       self.kernel)
 
     def _loop_labels(self, y):
@@ -588,7 +512,7 @@ class BoundSync:
         PERF.md section 6, PR 25); a float32 copy made once before the loop
         it keeps there.  Every grad_coeff casts its labels to float32 first,
         so the step computes the same."""
-        if self.labels_in_row or self._width is None or y.ndim == 2:
+        if self.plan.labels == "in_row" or self._width is None or y.ndim == 2:
             return y  # (rows of labels: one gather, no fetch)
         return y.astype(jnp.float32)
 
@@ -634,7 +558,7 @@ class BoundSync:
                 mask = (cy != 0).astype(jnp.float32)
             # the same gather the step runs (models/linear.py `margins`)
             margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel,
-                                         distinct=self.margins_distinct)
+                                         fetch=self.plan.eval_fetch)
             with jax.named_scope("dsgd.eval_reduce"):
                 losses = self.model.losses_from_margins(margins, cy)
                 preds = self.model.predict(margins)
@@ -659,7 +583,7 @@ class BoundSync:
             with jax.named_scope("dsgd.eval_rows"):
                 ci, cv = self.chunk_rows(idx, val, t * chunk)
             margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel,
-                                         distinct=self.margins_distinct)
+                                         fetch=self.plan.eval_fetch)
             with jax.named_scope("dsgd.eval_reduce"):
                 return (), self.model.predict(margins)
 
@@ -916,8 +840,8 @@ class SyncEngine:
         momentum: float = 0.9,
         donate: bool = False,
     ):
-        # kernel: AUTO (the default) lets bind() ask the shape rule
-        # (ops/kernels.py); a family's name pins it
+        # kernel: AUTO (the default) lets each binding's plan ask the shape
+        # rule (ops/kernels.py `plan`); a family's name pins it
         self.model = model
         self.mesh = mesh
         self.batch_size = batch_size
@@ -930,23 +854,11 @@ class SyncEngine:
         self.momentum = momentum
         self.donate = donate
 
-    def _resolve(self, n_features: int, row_width: int) -> str:
-        """The kernel a binding of this shape runs: the one rule on shape
-        and platform unless a family was named; off the TPU the sync
-        engines run the blocked families too (ops/kernels.py `off_tpu`)."""
-        return kernels.resolve(self.kernel, n_features, row_width,
-                               self.mesh.devices.flat[0], off_tpu="mxu",
-                               n_outputs=self.model.n_outputs)
-
     def bind(self, data: Dataset, steps_per_epoch: Optional[int] = None) -> BoundSync:
         n_workers = self.mesh.shape[AXIS]
         n_true = len(data)
         if n_true < n_workers:
             raise ValueError(f"dataset of {n_true} rows < {n_workers} workers")
-        # the one rule on shape and platform, unless a kernel was named;
-        # dense-layout data can only run the dense matmul kernels (there is
-        # no index array to gather with)
-        kernel = self._resolve(data.n_features, data.indices.shape[1])
         total, chunk = padded_layout(n_true, n_workers, self.eval_chunk)
         sharding = NamedSharding(self.mesh, P(AXIS))
         if jax.process_count() > 1 and self.mesh.size == jax.device_count():
@@ -998,39 +910,26 @@ class SyncEngine:
             raise ValueError(
                 f"the rows list their labels among {data.n_labels} outputs, the model "
                 f"has n_outputs={self.model.n_outputs}")
-        # lists stay as narrow as they come: the readers expand them
-        label_lanes = None if lists else self.model.label_lanes(kernel)
+        # the kernel plan: its family's margins say how wide labels are stored
+        plan = kernels.plan(
+            self.model, learning_rate=self.learning_rate,
+            plain_sgd=resolve_optimizer(self.optimizer, self.learning_rate) is None,
+            row_width=data.indices.shape[1], virtual_workers=self.virtual_workers,
+            batch_size=self.batch_size, n_workers=n_workers, eval_chunk=chunk, lists=lists,
+            riding=slot is not None, kernel=self.kernel, device=self.mesh.devices.flat[0])
         sharded = ShardedData(
             indices=indices,
             values=values,
-            labels=(put(local.labels) if label_lanes is None
-                    else put_rows(local.labels, sharding, width=label_lanes)),
+            # lists stay as narrow as they come: the readers expand them
+            labels=(put_rows(local.labels, sharding, width=plan.lanes)
+                    if plan.lanes and not lists else put(local.labels)),
             n_true=n_true,
             width=local.values.shape[1],
             packed=lanes is not None,
             label_slot=slot,
             label_lists=lists,
         )
-        bound = BoundSync(
-            self.model,
-            self.mesh,
-            sharded,
-            self.batch_size,
-            self.learning_rate,
-            sampling=self.sampling,
-            steps_per_epoch=steps_per_epoch,
-            eval_chunk=chunk,
-            kernel=kernel,
-            virtual_workers=self.virtual_workers,
-            optimizer=self.optimizer,
-            momentum=self.momentum,
-            donate=self.donate,
-        )
-        # spin-up fast path (compile_cache.py, DSGD_COMPILE_CACHE): start
-        # the background AOT pass at bind time, so the fit's first epoch
-        # finds its XLA executable in the persistent cache
-        bound._maybe_warmup()
-        return bound
+        return self._bound(sharded, chunk, steps_per_epoch, plan)
 
     def bind_host_local(self, reader, n_samples: int, n_features: int,
                         pad_width: int,
@@ -1056,21 +955,20 @@ class SyncEngine:
         sharded, chunk = host_local_sharded(
             self.mesh, reader, n_samples, n_features, pad_width,
             eval_chunk=self.eval_chunk, labels_dtype=labels_dtype)
+        return self._bound(sharded, chunk, steps_per_epoch)
+
+    def _bound(self, sharded: ShardedData, chunk: int, steps_per_epoch: Optional[int],
+               plan: Optional[kernels.Plan] = None) -> BoundSync:
+        """`sharded` bound with this engine's settings (`plan`: the one
+        `bind` made; None: the binding makes its own).  Spin-up fast path
+        (compile_cache.py, DSGD_COMPILE_CACHE): the background AOT pass
+        starts at bind time, so the fit's first epoch finds its XLA
+        executable in the persistent cache."""
         bound = BoundSync(
-            self.model,
-            self.mesh,
-            sharded,
-            self.batch_size,
-            self.learning_rate,
-            sampling=self.sampling,
-            steps_per_epoch=steps_per_epoch,
-            eval_chunk=chunk,
-            kernel=self._resolve(n_features, pad_width),
-            virtual_workers=self.virtual_workers,
-            optimizer=self.optimizer,
-            momentum=self.momentum,
-            donate=self.donate,
-        )
+            self.model, self.mesh, sharded, self.batch_size, self.learning_rate,
+            sampling=self.sampling, steps_per_epoch=steps_per_epoch, eval_chunk=chunk,
+            kernel=self.kernel, virtual_workers=self.virtual_workers,
+            optimizer=self.optimizer, momentum=self.momentum, donate=self.donate, plan=plan)
         bound._maybe_warmup()
         return bound
 

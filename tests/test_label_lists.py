@@ -132,7 +132,8 @@ def test_one_step_equals_the_reference(n_outputs, reg, sparse, devices, workers,
     monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0 if sparse else 10**9)
     data = _listed(_dense(n_outputs))
     bound = _bind(data, n_outputs, reg=reg, devices=devices, workers=workers)
-    assert (bound.kernel, bound.update_sparse, bound.labels_as) == ("gather", sparse, "lists")
+    assert (bound.kernel, bound.plan.update == "sparse", bound.plan.labels) == (
+        "gather", sparse, "lists")
     w, key = _weights(n_outputs), jax.random.PRNGKey(7)
     want = np.asarray(reference_lists.sync_step(
         "squared_hinge", reg, w, _batches(bound, data, key), LAM, LR))
@@ -188,7 +189,7 @@ def test_the_squared_hinge_at_one_output_is_the_references_column(kernel):
     model = make_model("squared_hinge", LAM, D, regularizer="l2")
     bound = SyncEngine(model, make_mesh(1), BATCH, LR, eval_chunk=64, kernel=kernel,
                        virtual_workers=4).bind(data)
-    assert bound.kernel == kernel and bound.labels_as == "gathered"
+    assert bound.kernel == kernel and bound.plan.labels == "gathered"
     w, key = _weights(1), jax.random.PRNGKey(9)
     as_lists = Dataset(data.indices, data.values,
                        np.where(data.labels > 0, 0, LIST_PAD).astype(np.int32)[:, None], D)
@@ -273,7 +274,7 @@ def test_the_row_write_on_wide_rows_is_the_float64_scatter_add(lanes, monkeypatc
         entries = tuple(jnp.asarray(a) for a in (ids, values, src, coeff))
         with pltpu.force_tpu_interpret_mode():
             got = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(
-                carried(w), *entries, dma=True))(jnp.asarray(w2)))
+                carried(w), *entries, "runs"))(jnp.asarray(w2)))
         xla = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries))(
             jnp.asarray(w2)))
         return got.reshape(w2.shape), xla, want
@@ -302,10 +303,10 @@ def test_margins_in_pieces_are_the_margins_in_one(monkeypatch):
     from distributed_sgd_tpu.ops.sparse import SparseBatch
 
     batch = SparseBatch(jnp.asarray(data.indices[:64]), jnp.asarray(data.values[:64]))
-    whole = np.asarray(gather.matvec_rows(batch, w2))
+    whole = np.asarray(gather.matvec_rows(batch, w2, "gather", 64))
     monkeypatch.setattr(kernels, "GATHERED_ROWS_MAX_BYTES", 16 * P * 256 * 4)
     assert kernels.margin_rows(64, P, 256) == 16
-    np.testing.assert_allclose(np.asarray(gather.matvec_rows(batch, w2)), whole,
+    np.testing.assert_allclose(np.asarray(gather.matvec_rows(batch, w2, "gather", 16)), whole,
                                rtol=1e-6, atol=1e-7)
 
 
@@ -345,7 +346,8 @@ def test_the_margin_kernel_is_the_float64_product(case, lanes):
     w2 = (rng.normal(size=(D, lanes)) * 0.5).astype(np.float32)
     batch = SparseBatch(jnp.asarray(ids), jnp.asarray(values))
     want = np.einsum("bp,bpl->bl", values.astype(np.float64), w2.astype(np.float64)[ids])
-    xla = np.asarray(gather.matvec_rows(batch, gather.to_tiles(jnp.asarray(w2))))
+    xla = np.asarray(gather.matvec_rows(batch, gather.to_tiles(jnp.asarray(w2)), "gather",
+                                        ids.shape[0]))
     # the sort of one word an entry, then of two (ids that leave no room
     # for the position) with turns that leave a remainder
     for n_rows, constants in ((D, {}), (2 ** 32, {"unroll": 4})):
@@ -449,7 +451,7 @@ def test_the_wide_row_kernels_see_only_the_order_of_the_ids(kernel, case, monkey
             with pltpu.force_tpu_interpret_mode():
                 return np.asarray(jax.jit(lambda w: gather.scatter_rows_into(
                     w, jnp.asarray(ids), jnp.asarray(values), jnp.asarray(src),
-                    jnp.asarray(coeff), dma=True))(gather.to_tiles(jnp.asarray(w))))
+                    jnp.asarray(coeff), "runs"))(gather.to_tiles(jnp.asarray(w))))
 
         want = w.astype(np.float64)
         np.add.at(want, ids, values.astype(np.float64)[:, None] * coeff.astype(np.float64)[src])
@@ -506,7 +508,7 @@ def test_an_id_past_the_weights_is_clamped_by_the_margins_and_dropped_by_the_sca
         coeff = (rng.normal(size=(samples, RUN_LANES)) * 1e-2).astype(np.float32)
         entries = tuple(jnp.asarray(a) for a in (ids, values, src, coeff))
         with pltpu.force_tpu_interpret_mode():
-            got = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries, dma=True))(
+            got = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries, "runs"))(
                 tiles)).reshape(w.shape)
         xla = np.asarray(jax.jit(lambda w: gather.scatter_rows_into(w, *entries))(jnp.asarray(w)))
         kept = ids < RUN_ROWS
@@ -524,7 +526,7 @@ def test_an_id_past_the_weights_is_clamped_by_the_margins_and_dropped_by_the_sca
     with pltpu.force_tpu_interpret_mode():
         got = np.asarray(jax.jit(lambda w: gather._margin_tiles(
             w, *gather._sorted_pieces(batch, piece, RUN_ROWS), piece, width))(tiles))
-    xla = np.asarray(gather.matvec_rows(batch, tiles))
+    xla = np.asarray(gather.matvec_rows(batch, tiles, "gather", samples))
     want = np.einsum("bp,bpl->bl", values.astype(np.float64),
                      w.astype(np.float64)[np.minimum(ids, RUN_ROWS - 1)])
     np.testing.assert_allclose(got.reshape(samples, RUN_LANES), xla, rtol=1e-5, atol=2e-6)
@@ -554,7 +556,7 @@ def test_only_tiles_on_a_tpu_fetch_distinct_tiles(outputs, on_tpu, fetch, monkey
     trainer = SyncTrainer(model, make_mesh(1), BATCH, LR, virtual_workers=4, kernel="gather",
                           metrics=metrics_mod.Metrics())
     bound = trainer.engine.bind(data)
-    assert bound.margin_fetch == fetch and bound.margins_distinct == (fetch == "distinct")
+    assert bound.plan.eval_fetch.how == fetch
     assert counter.value == before + (fetch == "distinct")
     if on_tpu:
         return  # a fit would run the TPU's kernels
@@ -583,7 +585,7 @@ def test_on_a_tpu_the_margins_of_tiles_are_the_gathers(monkeypatch):
         bound = SyncEngine(make_model("squared_hinge", LAM, D, regularizer="l2", n_outputs=1000),
                            make_mesh(1), BATCH, LR, eval_chunk=64, kernel="gather",
                            virtual_workers=4).bind(data, steps_per_epoch=2)
-        assert bound.margins_distinct == on_tpu
+        assert (bound.plan.eval_fetch.how == "distinct") == on_tpu
         w, key = _weights(1000), jax.random.PRNGKey(4)
         with pltpu.force_tpu_interpret_mode():
             got.append((np.asarray(bound.epoch(w, key)), np.asarray(bound.evaluate(w))))
@@ -708,8 +710,7 @@ def test_on_a_tpu_a_wide_binding_writes_rows_and_does_not_merge(monkeypatch, cap
         bound = SyncEngine(make_model("squared_hinge", LAM, D, regularizer="l2", n_outputs=200),
                            make_mesh(1), BATCH, LR, eval_chunk=64, kernel="gather",
                            virtual_workers=4).bind(data, steps_per_epoch=3)
-        assert (bound.scatter_rows, bound.scatter_merge) == (on_tpu, False)
-        assert bound.scatter_as == ("runs" if on_tpu else "words")
+        assert bound.plan.scatter == ("runs" if on_tpu else "words")
         w, key = _weights(200), jax.random.PRNGKey(3)
         with pltpu.force_tpu_interpret_mode():
             got = np.asarray(bound.epoch(w, key))

@@ -109,7 +109,7 @@ def test_a_binding_counts_merged_margins_once(kernel, workers, data, merged):
     before = counter.value
     bound = SyncEngine(model, make_mesh(2), 8, 0.1, kernel=kernel, eval_chunk=32,
                        virtual_workers=workers).bind(rows)
-    assert bound.margins_merged == merged
+    assert (bound.plan.margins == "merged") == merged
     assert counter.value - before == int(merged)
     bound.epoch(jnp.zeros((rows.n_features,), jnp.float32), jax.random.PRNGKey(0))
     assert counter.value - before == int(merged)  # a binding, not a trace or a run

@@ -82,7 +82,7 @@ def test_one_step_equals_the_reference(form, reg, monkeypatch):
         monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0 if sparse else 10**9)
     data = _rows(dense)
     bound = _bind(data, reg, kernel=kernels.AUTO if dense else kernel)
-    assert bound.kernel == kernel and bound.update_sparse == bool(sparse)
+    assert bound.kernel == kernel and bound.plan.update == ("sparse" if sparse else "dense")
     w, key = _weights(), jax.random.PRNGKey(7)
     want = _reference_step(bound, data, w, key, reg)
     got = np.asarray(bound.step(w, key))
@@ -161,7 +161,7 @@ def test_four_devices_equal_the_reference(sparse, workers, monkeypatch):
     monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0 if sparse else 10**9)
     data = _rows()
     bound = _bind(data, devices=4, workers=workers)
-    assert (bound.kernel, bound.update_sparse, bound.n_workers) == ("gather", sparse, 4)
+    assert (bound.kernel, bound.plan.update == "sparse", bound.n_workers) == ("gather", sparse, 4)
     w, key = _weights(), jax.random.PRNGKey(13)
     want = _reference_step(bound, data, w, key, "l2")
     got = np.asarray(bound.step(w, key))
@@ -307,7 +307,7 @@ def test_an_optimizer_reads_a_gradient_with_the_output_axis():
     model = make_model("hinge", LAM, D, regularizer="l2", n_outputs=C)
     bound = SyncEngine(model, make_mesh(1), BATCH, LR, eval_chunk=64, virtual_workers=4,
                        optimizer="momentum").bind(data)
-    assert not bound.update_sparse
+    assert bound.plan.update == "dense"
     assert [np.shape(x) for x in bound.opt_state_leaves()] == [(304, 128)]
     w = bound.step(_weights(), jax.random.PRNGKey(3))
     assert w.shape == (D, C) and np.isfinite(np.asarray(w)).all()
@@ -514,7 +514,7 @@ def test_merged_through_scatter_rows_into_is_the_same_call():
     w2, ids, values, src, coeff, cut = _merge_inputs("default_constants", "law")
     args = tuple(jnp.asarray(a) for a in (w2, ids, values, src, coeff))
     with pltpu.force_tpu_interpret_mode():
-        got = np.asarray(jax.jit(lambda *a: gather.scatter_rows_into(*a, merge=True))(*args))
+        got = np.asarray(jax.jit(lambda *a: gather.scatter_rows_into(*a, "merge"))(*args))
         at, entry = gather._entry_rows(*args[1:])
         want = np.asarray(gather._merge_rows(args[0], at, entry, *cut))
     np.testing.assert_array_equal(got, want)
@@ -572,11 +572,11 @@ def test_an_epoch_with_the_merge_pass_is_the_epoch_xla_writes(reg, monkeypatch):
         return bound, np.asarray(bound.epoch(w, key))
 
     bound, want = epoch()
-    assert bound.update_sparse and not bound.scatter_merge and not bound.scatter_rows
+    assert (bound.plan.update, bound.plan.scatter) == ("sparse", "words")
     monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
     with pltpu.force_tpu_interpret_mode():
         bound, got = epoch()
-    assert bound.scatter_merge and not bound.scatter_rows
+    assert bound.plan.scatter == "merge"
     moved = np.abs(want - np.asarray(w)).max()
     assert moved > 1e-3
     np.testing.assert_allclose(got - np.asarray(w), want - np.asarray(w), rtol=2e-5, atol=2e-7)
@@ -614,8 +614,7 @@ def test_on_a_tpu_a_binding_with_outputs_merges_and_counts_it(merging, monkeypat
 
     merge, runs, rows = counts()
     bound = _bind(_rows())
-    assert bound.update_sparse and bound.scatter_merge and not bound.scatter_rows
-    assert bound.scatter_as == "merge"
+    assert (bound.plan.update, bound.plan.scatter) == ("sparse", "merge")
     assert asked == [(D, C, 4 * BATCH * P)]  # once a binding, the shapes alone
     assert counts() == (merge + 1, runs, rows)
     bound.step(_weights(), jax.random.PRNGKey(0))
@@ -623,14 +622,12 @@ def test_on_a_tpu_a_binding_with_outputs_merges_and_counts_it(merging, monkeypat
     # past the crossing the same binding walks its sorted entries' runs
     monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 1)
     bound = _bind(_rows())
-    assert bound.scatter_rows and not bound.scatter_merge
-    assert bound.scatter_as == "runs" and counts() == (merge + 1, runs + 1, rows)
+    assert bound.plan.scatter == "runs" and counts() == (merge + 1, runs + 1, rows)
     # one output: never asked, whatever its shapes would say, and a DMA a row of words
     monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 10**6)
     asked.clear()
     flat = _bind(_rows(n_outputs=1), n_outputs=1, kernel="gather")
-    assert flat.update_sparse and flat.scatter_rows and not flat.scatter_merge and not asked
-    assert flat.scatter_as == "rows"
+    assert (flat.plan.update, flat.plan.scatter) == ("sparse", "rows") and not asked
     assert counts() == (merge + 1, runs + 1, rows + 1)
     # and the train-split record says which
     model = make_model("hinge", LAM, D, regularizer="l2", n_outputs=C)
@@ -648,13 +645,13 @@ def test_off_the_tpu_no_binding_merges(monkeypatch):
     monkeypatch.setattr(kernels, "merges_scatter", lambda *a: pytest.fail("asked off the TPU"))
     merge = _count("bind.scatter.merge")
     bound = _bind(_rows())
-    assert bound.update_sparse and not bound.scatter_merge and not bound.scatter_rows
+    assert (bound.plan.update, bound.plan.scatter) == ("sparse", "words")
     assert _count("bind.scatter.merge") == merge
 
 
 def test_the_merged_epoch_program_carries_the_scatters_scope(merging):
     bound = _bind(_rows())
-    assert bound.scatter_merge
+    assert bound.plan.scatter == "merge"
     d, w, key = bound.data, _weights(), jax.random.PRNGKey(0)
     lowered = bound._epoch.lower(w, bound._opt_state, d.indices, d.values, d.labels, key)
     assert _scopes(lowered) == {

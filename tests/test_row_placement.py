@@ -495,7 +495,7 @@ def _kdd2012_epoch(v5e, devices, packed):
         shape((rows,), jnp.int32, sharding=over_rows), rows, width, packed)
     bound = BoundSync(make_model("logistic", 1.0 / 6_488_064, d, regularizer="l2"), mesh,
                       data, 100, 0.1, kernel="gather", virtual_workers=4 // devices)
-    assert bound.update_sparse and bound.steps_per_epoch == {1: 16_221, 4: 64_881}[devices]
+    assert bound.plan.update == "sparse" and bound.steps_per_epoch == {1: 16_221, 4: 64_881}[devices]
     return bound._epoch.lower(
         shape((d,), jnp.float32, sharding=everywhere), (), data.indices, data.values,
         data.labels, shape((2,), jnp.uint32, sharding=everywhere)).compile()
@@ -608,7 +608,7 @@ def test_on_a_v5e_a_step_with_outputs_gathers_and_writes_whole_rows(v5e, devices
     import re
 
     bound, step, evaluation = _topics_programs(v5e, devices)
-    assert bound.update_sparse and bound.scatter_merge and not bound.scatter_rows
+    assert (bound.plan.update, bound.plan.scatter) == ("sparse", "merge")
     kernel = _kernels_of(step)
     assert len(kernel) == 1 and "f32[47240,128]" in kernel[0]
     assert "dsgd.scatter/scatter_merge" in kernel[0]
@@ -634,7 +634,7 @@ def test_on_a_v5e_the_merge_pass_and_the_words_keep_their_endings(v5e, shape, en
     touched row, one kernel a step each."""
     if shape == "topics":
         bound, text, _ = _topics_programs(v5e, 1)
-        assert bound.scatter_as == "merge"
+        assert bound.plan.scatter == "merge"
     else:
         text = _kdd2012_epoch(v5e, 1, True).as_text()
     kernel = _kernels_of(text)
@@ -675,10 +675,8 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
 
     runs, merge, dma = endings()
     bound = BoundSync(model, mesh, data, 100, 0.1, kernel="gather", virtual_workers=4)
-    assert endings() == [runs + 1, merge, dma]  # once a binding, and no other ending
-    assert bound.update_sparse and bound.scatter_as == "runs" and not bound.scatter_merge
-    assert bound.rows_tiled and bound.labels_as == "lists" and bound.eval_rows == 256
-    assert bound.margins_distinct and bound.margin_fetch == "distinct"
+    # once a binding, and no other ending (the plan itself: tests/test_kernel_plan.py)
+    assert endings() == [runs + 1, merge, dma]
     w = shape((d, c), jnp.float32, sharding=everywhere)
     step = bound._step.lower(w, (), data.indices, data.values, data.labels,
                              shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
@@ -707,7 +705,7 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
                shape((256,), jnp.int32, sharding=everywhere),
                shape((400, 1024), jnp.float32, sharding=everywhere))
     with pytest.raises(Exception, match="aligned to tiling"):
-        jax.jit(lambda w2, *e: gather.scatter_rows_into(w2, *e, dma=True)).lower(
+        jax.jit(lambda w2, *e: gather.scatter_rows_into(w2, *e, "runs")).lower(
             flat, *entries).compile()
 
 
@@ -742,9 +740,10 @@ def test_on_a_v5e_only_tiles_take_their_margins_from_the_margin_kernel(
     before = counter.value
     bound = BoundSync(make_model(model, 1e-6, d, regularizer="l2", n_outputs=outputs), mesh,
                       data, 100, 0.1, kernel="gather", virtual_workers=4)
-    assert bound.update_sparse and bound.margins_distinct == margin_tiles
+    assert bound.plan.update == "sparse"
+    assert (bound.plan.eval_fetch.how == "distinct") == margin_tiles
     assert counter.value == before + margin_tiles
-    assert bound.margin_fetch == ("distinct" if margin_tiles else "gather")
+    assert bound.plan.eval_fetch.how == ("distinct" if margin_tiles else "gather")
     w = shape((d, outputs) if outputs > 1 else (d,), jnp.float32, sharding=everywhere)
     epoch = bound._epoch.lower(w, (), data.indices, data.values, data.labels,
                                shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
@@ -836,8 +835,8 @@ def test_a_label_in_the_row_is_the_gathered_label_bit_for_bit(
     model = make_model("logistic", 1e-3, data.n_features, regularizer="l2")
     gathered, want = _programs(model, *_by_hand(data, 2, placement, False), workers)
     riding, got = _programs(model, *_by_hand(data, 2, placement, True), workers)
-    assert (gathered.labels_in_row, riding.labels_in_row) == (False, True)
-    assert gathered.update_sparse == riding.update_sparse == (update == "sparse")
+    assert (gathered.plan.labels, riding.plan.labels) == ("gathered", "in_row")
+    assert gathered.plan.update == riding.plan.update == update
     # the word holds the label as float32, past everything a reader takes
     stored = np.asarray(riding.data.indices if placement == "packed" else riding.data.values)
     held = stored[:, riding.data.label_slot]
@@ -938,7 +937,7 @@ def test_bind_writes_the_label_where_the_rule_says_and_counts_it(placement, monk
                        np.asarray(bound.evaluate(w)), bound.predict(w))
 
     plain, want = run()
-    assert plain.data.label_slot is None and not plain.labels_in_row
+    assert plain.data.label_slot is None and plain.plan.labels == "gathered"
     counters = ("bind.labels.in_row", "bind.labels.gathered")
     before = [metrics_mod.counter(name).value for name in counters]
     _ride_everywhere(monkeypatch, placement)
@@ -968,7 +967,7 @@ def test_bind_leaves_the_label_an_array_where_no_word_is_spare(
     model = make_model("hinge", 1e-3, 500, regularizer="l2", n_outputs=outputs)
     before = metrics_mod.counter("bind.labels.gathered").value
     bound = SyncEngine(model, make_mesh(2), 8, 0.1, kernel="scalar").bind(data)
-    assert bound.data.label_slot is None and not bound.labels_in_row, why
+    assert bound.data.label_slot is None and bound.plan.labels == "gathered", why
     assert bound.data.values.shape == (64, width)
     assert metrics_mod.counter("bind.labels.gathered").value == before + 1
 

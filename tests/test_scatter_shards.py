@@ -214,7 +214,7 @@ def test_a_binding_counts_a_sharded_scatter_once(kernel, batch, shards):
     before = counter.value
     bound = SyncEngine(model, make_mesh(1), batch, 0.5, kernel=kernel, eval_chunk=32,
                        virtual_workers=4).bind(rows)
-    assert bound.scatter_shards == shards
+    assert bound.plan.scatter_shards == shards
     assert counter.value - before == int(shards > 1)
     bound.step(jnp.zeros((D,), jnp.float32), jax.random.PRNGKey(0))
     assert counter.value - before == int(shards > 1)  # a binding, not a trace or a run

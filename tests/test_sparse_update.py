@@ -123,14 +123,14 @@ def test_a_binding_asks_the_rule_once_and_counts_it(everywhere, kw, sparse):
     counter = metrics_mod.counter("bind.update.sparse")
     before = counter.value
     bound = _bind(_rows(), 1e-4, **kw)
-    assert bound.update_sparse is sparse
+    assert (bound.plan.update == "sparse") is sparse
     assert counter.value - before == int(sparse)
     bound.step(jnp.zeros((D,), jnp.float32), jax.random.PRNGKey(0))
     assert counter.value - before == int(sparse)  # a binding, not a trace or a run
 
 
 def test_under_the_floor_the_family_keeps_the_dense_step():
-    assert not _bind(_rows(), 1e-4).update_sparse
+    assert _bind(_rows(), 1e-4).plan.update == "dense"
 
 
 @pytest.fixture
@@ -168,7 +168,7 @@ def test_on_a_tpu_a_sparse_binding_writes_rows_and_counts_it(everywhere, as_on_a
     counter = metrics_mod.counter("bind.scatter.rows")
     before = counter.value
     bound = _bind(_rows(n=256), 1e-4, **kw)
-    assert bound.scatter_rows is rows and bound.update_sparse is rows
+    assert (bound.plan.update, bound.plan.scatter) == (("sparse", "rows") if rows else ("dense", "words"))
     assert counter.value - before == int(rows)
     bound.step(jnp.zeros((D,), jnp.float32), jax.random.PRNGKey(0))
     assert counter.value - before == int(rows)  # a binding, not a trace or a run
@@ -178,7 +178,7 @@ def test_off_the_tpu_no_binding_counts_the_kernel(everywhere):
     counter = metrics_mod.counter("bind.scatter.rows")
     before = counter.value
     bound = _bind(_rows(n=256), 1e-4)
-    assert bound.update_sparse and not bound.scatter_rows
+    assert (bound.plan.update, bound.plan.scatter) == ("sparse", "words")
     assert counter.value == before
 
 
@@ -286,7 +286,7 @@ def test_a_step_longer_than_scalar_memory_holds_is_written_in_blocks(case, monke
     want = np.asarray(jax.jit(gather.scatter_into)(w2, ids, upd))
     monkeypatch.setattr(gather, "DMA_BLOCK", 16)
     with pltpu.force_tpu_interpret_mode():
-        got = np.asarray(jax.jit(lambda w2: gather.scatter_into(w2, ids, upd, dma=True))(w2))
+        got = np.asarray(jax.jit(lambda w2: gather.scatter_into(w2, ids, upd, "rows"))(w2))
     np.testing.assert_array_equal(got, want)
 
 
@@ -315,7 +315,7 @@ def test_on_a_tpu_the_epoch_is_the_one_xla_writes(everywhere, monkeypatch):
     want = np.asarray(_bind(data, 1e-4, steps=3).epoch(w, key))
     monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
     bound = _bind(data, 1e-4, steps=3)
-    assert bound.scatter_rows
+    assert bound.plan.scatter == "rows"
     with pltpu.force_tpu_interpret_mode():
         got = np.asarray(bound.epoch(w, key))
     np.testing.assert_array_equal(got, want)
@@ -331,7 +331,7 @@ def test_the_hot_id_is_no_farther_from_the_reference_than_the_dense_steps(
     sparse = _bind(data, 1e-4)
     monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 10 * D)
     dense = _bind(data, 1e-4)
-    assert sparse.update_sparse and not dense.update_sparse
+    assert (sparse.plan.update, dense.plan.update) == ("sparse", "dense")
     batches = [(jnp.asarray(data.indices[r]), jnp.asarray(data.values[r]),
                 jnp.asarray(data.labels[r])) for r in _draws(sparse, key, 1)[0]]
     want = _float64_steps(data, _draws(sparse, key, 1), w, 1e-4, LR)
@@ -351,7 +351,7 @@ def test_the_hot_id_is_no_farther_from_the_reference_than_the_dense_steps(
 def test_one_step_is_the_references(everywhere, devices, workers, reg, lam):
     data, w = _rows(), _weights()
     bound = _bind(data, lam, devices, workers, reg=reg)
-    assert bound.update_sparse
+    assert bound.plan.update == "sparse"
     key = jax.random.PRNGKey(7)
     got = np.asarray(bound.step(jnp.asarray(w), key))
     batches = [(jnp.asarray(data.indices[rows]), jnp.asarray(data.values[rows]),
@@ -391,7 +391,7 @@ def test_over_2000_steps_the_sparse_step_is_nearer_float64_than_the_dense_step(
     sparse = _bind(data, lam, steps=steps)
     monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 10 * D)
     dense = _bind(data, lam, steps=steps)
-    assert sparse.update_sparse and not dense.update_sparse
+    assert (sparse.plan.update, dense.plan.update) == ("sparse", "dense")
     got_sparse = np.asarray(sparse.epoch(jnp.asarray(w), key))
     got_dense = np.asarray(dense.epoch(jnp.asarray(w), key))
     draws = _draws(sparse, key, steps)
@@ -429,7 +429,7 @@ def test_over_2000_steps_the_sparse_step_is_nearer_float64_than_the_dense_step(
 def test_many_steps_with_folds_are_the_float64_equations(everywhere, reg, lam, steps, folds):
     data, w, key = _rows(seed=6), _weights(seed=7), jax.random.PRNGKey(13)
     bound = _bind(data, lam, steps=steps, reg=reg)
-    assert bound.update_sparse
+    assert bound.plan.update == "sparse"
     assert (steps - 1) // bound._fold_span() >= folds
     got = np.asarray(bound.epoch(jnp.asarray(w), key))
     want = _float64_steps(data, _draws(bound, key, steps), w, lam, LR, reg)
@@ -442,16 +442,16 @@ def test_many_steps_with_folds_are_the_float64_equations(everywhere, reg, lam, s
 
 def test_the_scale_is_never_a_float32_product():
     bound = _bind(_rows(), 1.5e-7)
-    c = bound._decay
+    c = bound.plan.decay
     assert c == pytest.approx(3e-8)
     # float32's 1 - c is 1 - 2^-24: it would take TWICE the term a step
     assert (1.0 - float(np.float32(1.0) - np.float32(c))) / c > 1.9
     s = np.asarray(jax.jit(bound._scale)(jnp.int32(16_220)))
-    np.testing.assert_allclose(s, (1.0 - bound._decay) ** 16_220, rtol=1.2e-7)
+    np.testing.assert_allclose(s, (1.0 - bound.plan.decay) ** 16_220, rtol=1.2e-7)
     assert s < 1.0
     w2 = jnp.full((8, 128), 3.0, jnp.float32)
     np.testing.assert_allclose(np.asarray(bound._rescale(w2, 16_220)),
-                               3.0 * (1.0 - bound._decay) ** 16_220, rtol=1.2e-7)
+                               3.0 * (1.0 - bound.plan.decay) ** 16_220, rtol=1.2e-7)
 
 
 def test_epochs_in_one_program_fold_as_single_epochs_do(everywhere, monkeypatch):
@@ -460,7 +460,7 @@ def test_epochs_in_one_program_fold_as_single_epochs_do(everywhere, monkeypatch)
     step is far above float32's rounding, so the dense step keeps it)."""
     data, w, key = _rows(seed=8), jnp.asarray(_weights(seed=9)), jax.random.PRNGKey(17)
     sparse = _bind(data, 0.05, steps=150)
-    assert sparse.update_sparse and sparse._fold_span() < 150
+    assert sparse.plan.update == "sparse" and sparse._fold_span() < 150
     monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 10 * D)
     dense = _bind(data, 0.05, steps=150)
     got, want = (np.asarray(b.multi_epoch(w, key, 2)) for b in (sparse, dense))
