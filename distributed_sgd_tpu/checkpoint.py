@@ -28,6 +28,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_sgd_tpu.ops import ftrl
+
 log = logging.getLogger("dsgd.checkpoint")
 
 
@@ -141,6 +143,7 @@ def sync_fit_extra(
     test_losses_newest_first, opt_kind: str, opt_leaves
 ) -> Dict[str, Any]:
     """Build the `extra` dict saved alongside the weights."""
+    ftrl.refuse(opt_kind, "checkpoint.FitState")
     extra: Dict[str, Any] = {}
     if test_losses_newest_first:
         extra["test_losses_nf"] = np.asarray(test_losses_newest_first, np.float32)
@@ -281,6 +284,7 @@ def save_fit_state(
     """Atomic full-fit-state snapshot (see the section comment above)."""
     from distributed_sgd_tpu.utils.measure import span
 
+    ftrl.refuse(opt_kind, "checkpoint.FitState")
     with span("ckpt.save", step=int(epoch), batch=int(batch)):
         state: Dict[str, Any] = {
             "weights": np.asarray(weights, np.float32),

@@ -191,8 +191,12 @@ class Config:
     labels: str = "ccat"  # ccat | topics | lists
     virtual_workers: int = 1  # reference workers emulated per mesh device
     exact_topology: bool = False  # insist on exactly node_count workers
-    optimizer: str = "sgd"  # sgd (reference) | momentum | adam (sync engine)
+    # sgd (reference) | momentum | adam (sync engine) | ftrl: per-coordinate
+    # FTRL-Proximal (ops/ftrl.py; the mesh sync engine only), learning_rate
+    # its alpha and lam its L2 strength
+    optimizer: str = "sgd"
     momentum: float = 0.9  # used by optimizer='momentum'
+    l1: float = 0.0  # optimizer='ftrl': the L1 strength (exact zeros)
     steps_per_dispatch: int = 1  # async: k local steps per gossip dispatch
     # gradient compression on the wire paths (compress/, docs/COMPRESSION.md):
     # sync Gradient replies + async delta gossip.  'none' keeps the wire
@@ -413,7 +417,7 @@ class Config:
         "kernel": ("auto", "mxu", "scalar", "gather"),
         "regularizer": (None, "dim_sparsity", "l2", "none"),
         "labels": ("ccat", "topics", "lists"),
-        "optimizer": ("sgd", "momentum", "adam"),
+        "optimizer": ("sgd", "momentum", "adam", "ftrl"),
         "compress": ("none", "topk", "qint8"),
     }
 
@@ -552,6 +556,12 @@ class Config:
                 "the feature-sharded engine runs the reference's plain SGD "
                 "update; optimizer must be 'sgd' when feature_shards > 1"
             )
+        if self.optimizer == "ftrl" and (self.use_async or self.engine != "mesh"):
+            raise ValueError(
+                "optimizer='ftrl' carries its state (z, n) in the mesh sync engine "
+                "only: engine='mesh' without use_async")
+        if self.l1 < 0:
+            raise ValueError(f"l1 must be >= 0, got {self.l1}")
         if self.exact_topology and self.virtual_workers != 1:
             raise ValueError(
                 "exact_topology and an explicit virtual_workers are mutually "
@@ -769,6 +779,7 @@ class Config:
             exact_topology=_env("DSGD_EXACT_TOPOLOGY", cls.exact_topology, bool),
             optimizer=_env("DSGD_OPTIMIZER", cls.optimizer, str),
             momentum=_env("DSGD_MOMENTUM", cls.momentum, float),
+            l1=_env("DSGD_FTRL_L1", cls.l1, float),
             steps_per_dispatch=_env("DSGD_STEPS_PER_DISPATCH", cls.steps_per_dispatch, int),
             compress=_env("DSGD_COMPRESS", cls.compress, str),
             compress_k=_env("DSGD_COMPRESS_K", cls.compress_k, float),

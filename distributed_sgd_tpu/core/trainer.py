@@ -29,7 +29,7 @@ from distributed_sgd_tpu.core.early_stopping import Criterion
 from distributed_sgd_tpu.core.grad_state import GradState
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel
-from distributed_sgd_tpu.ops import kernels
+from distributed_sgd_tpu.ops import ftrl, kernels
 from distributed_sgd_tpu.parallel.sync import BoundSync, SyncEngine
 from distributed_sgd_tpu.utils import measure
 from distributed_sgd_tpu.utils import metrics as metrics_mod
@@ -46,6 +46,11 @@ class FitResult:
     test_accuracies: List[float] = field(default_factory=list)
     epochs_run: int = 0
     epoch_seconds: List[float] = field(default_factory=list)
+    # under FTRL, after each epoch: the weights that are not exactly 0, and
+    # l1 ||w||_1 + (l2/2) ||w||^2, the part of each objective that is not
+    # the mean loss
+    nonzero: List[int] = field(default_factory=list)
+    penalty: List[float] = field(default_factory=list)
 
     @property
     def weights(self):
@@ -96,6 +101,8 @@ class SyncTrainer:
         )
         from distributed_sgd_tpu.checkpoint import opt_kind_tag
 
+        if checkpointer is not None:
+            ftrl.refuse(optimizer, "checkpoint.FitState")
         self._opt_kind = opt_kind_tag(optimizer)
         self.model = model
         self.metrics = metrics or metrics_mod.global_metrics()
@@ -120,6 +127,8 @@ class SyncTrainer:
                  len(train), bound_train.plan.record(), stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
+        if bound_train.plan.optimizer == "ftrl" and initial_weights is not None:
+            raise ValueError("an FTRL fit starts from its state (z, n) = 0, not from weights")
         w = (  # [D], or [D, C] for a model with an output axis
             jnp.zeros(self.model.weight_shape, dtype=jnp.float32)
             if initial_weights is None
@@ -197,9 +206,14 @@ class SyncTrainer:
                 self.metrics.histogram("master.sync.loss").record(loss)
                 self.metrics.histogram("master.sync.acc").record(100 * acc)
                 self.metrics.histogram("master.sync.epoch.seconds").record(epoch_s)
+                nonzero = ""
+                if bound_train.plan.optimizer == "ftrl":  # L1's exact zeros beside the objective
+                    result.nonzero.append(int(jnp.count_nonzero(w)))
+                    result.penalty.append(ftrl.penalty(w, bound_train.ftrl))
+                    nonzero = f" nonzero={result.nonzero[-1]}"
                 log.info(
-                    "epoch %d: loss=%.6f acc=%.4f test_loss=%.6f test_acc=%.4f (%.2fs)",
-                    epoch, loss, acc, test_loss, test_acc, epoch_s,
+                    "epoch %d: loss=%.6f acc=%.4f test_loss=%.6f test_acc=%.4f%s (%.2fs)",
+                    epoch, loss, acc, test_loss, test_acc, nonzero, epoch_s,
                 )
 
             if self.checkpointer is not None and (epoch + 1) % self.checkpoint_every == 0:

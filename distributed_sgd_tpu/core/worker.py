@@ -27,6 +27,7 @@ import numpy as np
 
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
+from distributed_sgd_tpu.ops import ftrl
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.rpc import codec, dsgd_pb2 as pb
 from distributed_sgd_tpu.rpc.service import (
@@ -1011,6 +1012,7 @@ class WorkerNode:
     def start_async(self, w0: np.ndarray, assignment: np.ndarray, batch_size: int,
                     learning_rate: float, optimizer: str = "",
                     momentum: float = 0.9) -> None:
+        ftrl.refuse(optimizer, "the rpc worker")
         # a re-issued StartAsync (master watchdog reassignment after a peer
         # death, master.py _async_watchdog) REPLACES any running loop: stop
         # and join it first so two loops never race on the shared state

@@ -42,6 +42,7 @@ from distributed_sgd_tpu.data.multilabel import read_multilabel, to_lists
 from distributed_sgd_tpu.data.rcv1 import Dataset, dim_sparsity, load_rcv1, train_test_split
 from distributed_sgd_tpu.data.synthetic import rcv1_like
 from distributed_sgd_tpu.models.linear import make_model
+from distributed_sgd_tpu.ops.ftrl import Ftrl
 from distributed_sgd_tpu.utils import measure
 from distributed_sgd_tpu.utils import metrics as metrics_mod
 from distributed_sgd_tpu.utils.log import setup as setup_logging
@@ -89,10 +90,21 @@ def build(cfg: Config):
                            regularizer=cfg.regularizer or "l2",
                            n_outputs=data.n_labels or data.labels.shape[1])
         return train, test, model
+    if cfg.optimizer == "ftrl":  # FTRL takes lam as its L2 strength
+        return train, test, make_model(cfg.model, cfg.lam, train.n_features,
+                                       regularizer=cfg.regularizer or "l2")
     ds = measure.duration_log("dim sparsity", lambda: dim_sparsity(train), log)
     model = make_model(cfg.model, cfg.lam, train.n_features, dim_sparsity=ds,
                        regularizer=cfg.regularizer)
     return train, test, model
+
+
+def optimizer_of(cfg: Config):
+    """What the mesh engines take as `optimizer=`: the name, or for 'ftrl'
+    FTRL-Proximal's own hyperparameters (ops/ftrl.py)."""
+    if cfg.optimizer == "ftrl":
+        return Ftrl(l1=cfg.l1)
+    return cfg.optimizer
 
 
 def _make_checkpointer(cfg: Config):
@@ -262,7 +274,7 @@ def scenario_mesh(cfg: Config, train: Dataset, test: Dataset, model) -> None:
             learning_rate=cfg.learning_rate, check_every=cfg.check_every,
             leaky_loss=cfg.leaky_loss, seed=cfg.seed, checkpointer=ckpt,
             steps_per_dispatch=cfg.steps_per_dispatch,
-            optimizer=cfg.optimizer, momentum=cfg.momentum,
+            optimizer=optimizer_of(cfg), momentum=cfg.momentum,
             compress=cfg.compress, compress_k=cfg.compress_k,
             compress_ef=cfg.compress_ef,
             gossip_topology=cfg.gossip_topology,
@@ -277,7 +289,7 @@ def scenario_mesh(cfg: Config, train: Dataset, test: Dataset, model) -> None:
             learning_rate=cfg.learning_rate, sync_period=cfg.sync_period,
             check_every=cfg.check_every, leaky_loss=cfg.leaky_loss, seed=cfg.seed,
             kernel=cfg.kernel, checkpointer=ckpt,
-            optimizer=cfg.optimizer, momentum=cfg.momentum,
+            optimizer=optimizer_of(cfg), momentum=cfg.momentum,
         )
         res = eng.fit(train, test, cfg.max_epochs, criterion,
                       initial_weights=_restore_weights(ckpt))
@@ -289,7 +301,7 @@ def scenario_mesh(cfg: Config, train: Dataset, test: Dataset, model) -> None:
             learning_rate=cfg.learning_rate, seed=cfg.seed,
             kernel=cfg.kernel, virtual_workers=virtual,
             checkpointer=ckpt, checkpoint_every=cfg.checkpoint_every,
-            optimizer=cfg.optimizer, momentum=cfg.momentum,
+            optimizer=optimizer_of(cfg), momentum=cfg.momentum,
             profile_dir=cfg.profile_dir,
         )
         res = trainer.fit(train, test, cfg.max_epochs, criterion)

@@ -245,7 +245,7 @@ class LinearModel:
             return jnp.sum(gk, axis=0)  # summed here, mean-normalized by the caller
 
     def reply_entries(self, v2: jax.Array, batch: SparseBatch, y: jax.Array,
-                      scale: Optional[jax.Array] = None, factor=1.0):
+                      scale: Optional[jax.Array] = None, factor=1.0, matvec=gather.matvec):
         """The sync replies of the workers whose batches `batch` merges,
         without their regulariser term, as ENTRIES: (flat feature ids [T],
         `factor` x coefficient x value [T]).  Scattered into a zeroed
@@ -254,8 +254,11 @@ class LinearModel:
         scatters them into the carried weights (`BoundSync._sparse_step`),
         after exchanging them as entries where it has more than one device.
         The blocked weights are `scale * v2` (None: `v2` itself): a gathered
-        margin is linear in them, so the scalar goes on the margins."""
-        margins = gather.matvec(batch, v2)
+        margin is linear in them, so the scalar goes on the margins.
+        `matvec(batch, v2)`: the margins, where the weights are a function
+        of the state `v2` holds (FTRL's closed form of (z, n),
+        ops/ftrl.py `matvec`) and not `v2` itself."""
+        margins = matvec(batch, v2)
         with jax.named_scope("dsgd.update"):
             if scale is not None:
                 margins = scale * margins

@@ -174,20 +174,22 @@ DMA_UNROLL = 8
 DMA_BLOCK = 32_768
 
 
-def _sum_by_row(ids: jax.Array, updates: jax.Array):
+def _sum_by_row(ids: jax.Array, updates: jax.Array, per_row: int = LANES):
     """A step's entries grouped by weight row: (rows int32[T'], head
     bool[T'], total f32[T', 128]) with the entries sorted by id, T' = T
     padded to whole chunks with the pad entry (0.0 at feature 0), `rows`
     their weight rows in ascending order, `head` the first entry of every
     row's run and `total[t]`, at a head, the dense sum of ALL the run's
     entries (duplicates of an id and other lanes of the row alike; off the
-    heads it is not a run's whole sum).  Float32 throughout: the 0 / 1
-    equality operand is exact in every pass of a HIGHEST-precision product,
-    so the products only ever add float32 updates."""
+    heads it is not a run's whole sum).  A row holds `per_row` coordinates,
+    id i in lane i % per_row (64: FTRL's state, ops/ftrl.py).  Float32
+    throughout: the 0 / 1 equality operand is exact in every pass of a
+    HIGHEST-precision product, so the products only ever add float32
+    updates."""
     pad = -ids.shape[0] % CHUNK
     ids, updates = jnp.pad(ids, (0, pad)), jnp.pad(updates.astype(jnp.float32), (0, pad))
     ids, updates = jax.lax.sort((ids, updates), num_keys=1, is_stable=False)
-    rows, lane = ids // LANES, ids % LANES
+    rows, lane = ids // per_row, ids % per_row
     head = jnp.concatenate([jnp.ones((1,), bool), rows[1:] != rows[:-1]])
     lanes = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], LANES), 1)
     entry = jnp.where(lanes == lane[:, None], updates[:, None], 0.0)  # [T', 128]
@@ -283,28 +285,33 @@ def _write_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, new: jax.Array,
 
 
 def scatter_into(w2: jax.Array, ids: jax.Array, updates: jax.Array,
-                 ending: str = "words") -> jax.Array:
+                 ending: str = "words", row=None, per_row: int = LANES) -> jax.Array:
     """`w2` with `updates[t]` added at flat feature `ids[t]` (duplicates of
     an id accumulate, a pad adds 0.0 to feature 0): the entries summed by
     weight row first, every touched row then fetched, added to and written
     back ONCE.  `ending` (`kernels.Plan.scatter`): 'rows', the write is the
     kernel's (`_write_rows`, a TPU's); 'words', XLA's scatter of whole rows,
     told they are unique.  Inside a scan whose carry `w2` is, both update
-    the carry in place."""
+    the carry in place.  `row(old, total)`: what a touched row becomes in
+    place of `old + total` (FTRL's per-coordinate update, ops/ftrl.py
+    `rows`, on rows of `per_row` coordinates)."""
     with jax.named_scope("dsgd.scatter"):
-        rows, head, total = _sum_by_row(ids, updates)
-        return _add_rows(w2, rows, head, total, ending)
+        rows, head, total = _sum_by_row(ids, updates, per_row)
+        return _add_rows(w2, rows, head, total, ending, row)
 
 
 def _add_rows(w2: jax.Array, rows: jax.Array, head: jax.Array, total: jax.Array,
-              ending: str = "words") -> jax.Array:
-    """`w2` with `total[t]` added to row `rows[t]` wherever `head[t]`."""
+              ending: str = "words", row=None) -> jax.Array:
+    """`w2` with `total[t]` added to row `rows[t]` wherever `head[t]`, or
+    the row made `row(old, total[t])`."""
     # only the heads' rows are written: off them any row will do, and
     # the gather runs faster over rows that differ than over a run's
     # repeats (34 -> 18 us for 4,480 rows, 1,200 of them one row)
     entry = jnp.arange(rows.shape[0])
+    old = w2[jnp.where(head, rows, entry % w2.shape[0])]
     # (rows that are tiles, `to_tiles`: the sums take the tiles' form here)
-    new = w2[jnp.where(head, rows, entry % w2.shape[0])] + total.reshape((-1,) + w2.shape[1:])
+    total = total.reshape((-1,) + w2.shape[1:])
+    new = old + total if row is None else row(old, total)
     if ending == "rows":
         return _write_rows(w2, rows, head, new)
     # off the heads: past the last row, each its own index, dropped

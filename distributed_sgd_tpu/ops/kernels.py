@@ -176,7 +176,7 @@ def merges_margins(kernel: str, row_width: int) -> bool:
 SPARSE_UPDATE_MIN_FEATURES = 4_000_000
 
 
-def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
+def sparse_update(kernel: str, regularizer: str, optimizer: str,
                   decay: float, n_features: int, n_outputs: int = 1) -> bool:
     """Whether a sync binding's step never materialises a gradient: the
     workers' replies stay (id, coefficient x value) entries up to the
@@ -187,14 +187,17 @@ def sparse_update(kernel: str, regularizer: str, plain_sgd: bool,
     else, so it holds for a family whose scatter walks entries
     (`ONE_ACCUMULATOR`), under a regulariser that is linear in `w` ('l2':
     `decay` = 2 lr lam a step; 'none': 0), with the reference update
-    (`plain_sgd`: an optax optimizer reads a whole gradient) and a decay
-    that leaves the sign of `w` (under 1), from SPARSE_UPDATE_MIN_FEATURES
-    words of weights on (`n_features` x `n_outputs`).  'dim_sparsity' masks each reply
-    by its own support and keeps the dense step, as the one-hot, dense and
-    scalar families do.  Static per binding: `plan` counts it under
-    `bind.update.sparse`."""
+    (`optimizer` 'sgd'; an optax optimizer, 'optax', reads a whole
+    gradient) and a decay that leaves the sign of `w` (under 1), from
+    SPARSE_UPDATE_MIN_FEATURES words of weights on (`n_features` x
+    `n_outputs`).  'ftrl' (ops/ftrl.py) takes it too: its update is
+    per-coordinate, the touched rows of its (z, n) state are what a step's
+    entries name, and its L2 term lives in the closed form (`decay` 0).
+    'dim_sparsity' masks each reply by its own support and keeps the dense
+    step, as the one-hot, dense and scalar families do.  Static per binding:
+    `plan` counts it under `bind.update.sparse`."""
     return (kernel in ONE_ACCUMULATOR and regularizer in ("l2", "none")
-            and plain_sgd and 0.0 <= decay < 1.0
+            and optimizer in ("sgd", "ftrl") and 0.0 <= decay < 1.0
             and n_features * n_outputs >= SPARSE_UPDATE_MIN_FEATURES)
 
 
@@ -357,7 +360,9 @@ class Plan:
     weight rows carried as tiles (`gather.to_tiles`); `lanes`: of a weight
     row of outputs (0: none), as the margins and stored labels have them;
     `step_fetch` / `eval_fetch`: a step's K x B samples a device, an
-    evaluation chunk; `decay`: what a step takes off every coordinate."""
+    evaluation chunk; `decay`: what a step takes off every coordinate;
+    `optimizer`: the update, 'sgd' (the reference's), 'ftrl' (the state
+    (z, n) carried where the weights would be, ops/ftrl.py) or 'optax'."""
 
     kernel: str
     margins: str
@@ -371,33 +376,43 @@ class Plan:
     labels: str
     outputs: int
     decay: float
+    optimizer: str
 
     def record(self) -> str:
         """The binding's fields of the `train split:` record."""
         return (f"kernel={self.kernel} margins={self.margins} "
                 f"scatter_shards={self.scatter_shards} update={self.update} "
                 f"scatter={self.scatter} outputs={self.outputs} labels={self.labels} "
-                f"eval_rows={self.eval_fetch.piece} margin_fetch={self.eval_fetch.how}")
+                f"eval_rows={self.eval_fetch.piece} margin_fetch={self.eval_fetch.how} "
+                f"optimizer={self.optimizer}")
 
 
-def plan(model, *, learning_rate: float, plain_sgd: bool, row_width: int,
+OPTIMIZERS = ("sgd", "ftrl", "optax")
+
+
+def plan(model, *, learning_rate: float, optimizer: str, row_width: int,
          virtual_workers: int, batch_size: int, n_workers: int, eval_chunk: int,
          lists: bool = False, riding: bool = False, kernel: Optional[str] = AUTO,
          device=None) -> Plan:
     """The plan of a sync binding of `model` (rows of `row_width` entries, 0:
-    dense; labels as id `lists`, `riding` in a stored row, or gathered);
-    `kernel` names a family or is AUTO.  `device`'s platform is probed once
-    (`mxu.blocked_pays_off`); every decision is counted here under `bind.*`."""
+    dense; labels as id `lists`, `riding` in a stored row, or gathered;
+    `optimizer` one of OPTIMIZERS); `kernel` names a family or is AUTO.
+    `device`'s platform is probed once (`mxu.blocked_pays_off`); every
+    decision is counted here under `bind.*`."""
     if kernel not in (None, AUTO) + KERNELS:
         raise ValueError(
             f"kernel must be one of {KERNELS} (or {AUTO!r}: the rule on shape "
             f"and platform), got {kernel!r}")
+    if optimizer not in OPTIMIZERS:
+        raise ValueError(f"optimizer must be one of {OPTIMIZERS}, got {optimizer!r}")
     d, c = model.n_features, model.n_outputs
-    # each of the n workers adds 2 lam w to its reply; the update is lr x their mean
-    decay = 2.0 * learning_rate * model.lam if model.regularizer == "l2" else 0.0
+    # each of the n workers adds 2 lam w to its reply; the update is lr x their
+    # mean (FTRL's L2 term lives in its closed form: no decay)
+    decay = (2.0 * learning_rate * model.lam
+             if model.regularizer == "l2" and optimizer != "ftrl" else 0.0)
     on_tpu = mxu.blocked_pays_off(device)
     kernel = _family(kernel, d, row_width, on_tpu, "mxu", c)
-    sparse = sparse_update(kernel, model.regularizer, plain_sgd, decay, d, c)
+    sparse = sparse_update(kernel, model.regularizer, optimizer, decay, d, c)
     lanes = gather.output_lanes(c) if kernel == "gather" and c > 1 else 0
     ours = sparse and on_tpu  # a kernel of ours ends the sparse step
     merge = ours and c > 1 and merges_scatter(
@@ -421,8 +436,9 @@ def plan(model, *, learning_rate: float, plain_sgd: bool, row_width: int,
         # the step takes the margin kernel where the evaluation's chunk does
         step_fetch=fetch(virtual_workers * batch_size, eval_fetch.how == "distinct"),
         eval_fetch=eval_fetch, labels="lists" if lists else "in_row" if riding else "gathered",
-        outputs=c, decay=decay)
+        outputs=c, decay=decay, optimizer=optimizer)
     for name, counted in (("outputs.multi", c > 1), ("margins.merged", merged),
+                          ("optimizer.ftrl", optimizer == "ftrl"),
                           ("scatter.sharded", decided.scatter_shards > 1),
                           (f"labels.{decided.labels}", True), ("update.sparse", sparse),
                           (f"scatter.{decided.scatter}", ours),

@@ -54,6 +54,7 @@ from distributed_sgd_tpu.core.split import vanilla_split
 from distributed_sgd_tpu.core.trainer import FitResult
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
+from distributed_sgd_tpu.ops import ftrl
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import make_mesh
 from distributed_sgd_tpu.parallel.sync import SyncEngine
@@ -336,6 +337,7 @@ class HogwildEngine:
         gossip_topology: str = "all",
     ):
         require_single_output(model, 'HogwildEngine')
+        ftrl.refuse(optimizer, 'HogwildEngine')
         """steps_per_dispatch=k amortizes host dispatch: each worker runs k
         local SGD steps in one compiled program and gossips the summed
         delta every k steps.  k=1 is the reference's per-step gossip

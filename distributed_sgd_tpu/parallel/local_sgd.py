@@ -33,6 +33,7 @@ from distributed_sgd_tpu.core.loss_check import LossChecker, async_fit_result
 from distributed_sgd_tpu.core.trainer import FitResult
 from distributed_sgd_tpu.data.rcv1 import Dataset
 from distributed_sgd_tpu.models.linear import LinearModel, require_single_output
+from distributed_sgd_tpu.ops import ftrl
 from distributed_sgd_tpu.ops import kernels
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import WORKER_AXIS as AXIS, pcast_varying, shard_map
@@ -60,6 +61,7 @@ class LocalSGDEngine:
         momentum: float = 0.9,
     ):
         require_single_output(model, 'LocalSGDEngine')
+        ftrl.refuse(optimizer, 'LocalSGDEngine')
         if not (0.0 <= leaky_loss <= 1.0):
             raise ValueError("leaking coefficient must be between 0 and 1")
         if kernel not in (kernels.AUTO, "mxu", "scalar", "gather"):

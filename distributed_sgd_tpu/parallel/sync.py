@@ -51,6 +51,7 @@ reference's #2 hot loop (SURVEY.md §3.5).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional, Tuple
 
@@ -61,7 +62,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_sgd_tpu.data.rcv1 import LIST_NO_ROW, Dataset
 from distributed_sgd_tpu.models.linear import LinearModel, expand_labels, require_single_output
-from distributed_sgd_tpu.ops import gather, kernels
+from distributed_sgd_tpu.ops import ftrl, gather, kernels
 from distributed_sgd_tpu.ops.sparse import SparseBatch
 from distributed_sgd_tpu.parallel.mesh import (
     WORKER_AXIS,
@@ -172,13 +173,24 @@ class BoundSync:
         # plain SGD, Master.scala:197).  None = reference update w - lr*g.
         # State lives in the kernel's weight layout and is threaded through
         # every compiled loop, replicated over the mesh like the weights.
-        self.opt = resolve_optimizer(optimizer, self.learning_rate, momentum)
+        # FTRL-Proximal (ops/ftrl.py; `optimizer='ftrl'` or `ftrl.Ftrl`) is
+        # no optax update: its state (z, n) rides in the optimizer state's
+        # place, and every w the binding hands out is the closed form of it.
+        # The plan says which update runs; `self.ftrl` holds FTRL's numbers.
+        kind = optimizer_kind(optimizer)
         self.plan = plan or kernels.plan(
-            model, learning_rate=self.learning_rate, plain_sgd=self.opt is None,
+            model, learning_rate=self.learning_rate, optimizer=kind,
             row_width=0 if data.is_dense else data.width or data.indices.shape[1],
             virtual_workers=self.virtual_workers, batch_size=self.batch_size,
             n_workers=self.n_workers, eval_chunk=self.eval_chunk, lists=data.label_lists,
             riding=data.label_slot is not None, kernel=kernel, device=mesh.devices.flat[0])
+        if self.plan.optimizer != kind:
+            raise ValueError(f"the plan is for optimizer={self.plan.optimizer!r}, "
+                             f"the binding's optimizer is {kind!r}")
+        self.ftrl = (ftrl.params(optimizer, self.learning_rate, model)
+                     if kind == "ftrl" else None)
+        self.opt = (resolve_optimizer(optimizer, self.learning_rate, momentum)
+                    if kind == "optax" else None)
         self.kernel = self.plan.kernel
         if (self.kernel == "dense") != data.is_dense:
             raise ValueError(
@@ -206,8 +218,13 @@ class BoundSync:
         # worker count (mesh devices x virtual workers per device)
         max_shard = math.ceil(data.n_true / (self.n_workers * self.virtual_workers))
         self.steps_per_epoch = steps_per_epoch or max(1, math.ceil(max_shard / self.batch_size))
-        self._opt_state = self._init_opt_state()
-        sspec = jax.tree.map(lambda _: P(), self._opt_state)
+        # FTRL's 2 x 4 D bytes are made where a binding first trains (`_state`):
+        # the test split's binding and the checks' never do
+        self._opt_state = None if kind == "ftrl" else self._init_opt_state()
+        sspec = P() if kind == "ftrl" else jax.tree.map(lambda _: P(), self._opt_state)
+        # the workers' replies under FTRL: the loss's gradient alone
+        self._loss_model = model if kind != "ftrl" else type(model)(
+            0.0, model.n_features, regularizer="none")
 
         dspec = (P(AXIS), P(AXIS), P(AXIS))
         self._epoch = jax.jit(
@@ -301,6 +318,20 @@ class BoundSync:
         """One sync DP step on weights in the kernel's native layout:
         dense [D] for 'scalar'/'dense', lane-blocked [R, 128] for
         'mxu'/'gather'.  Returns (w', opt_state')."""
+        g = self._gradient(w, idx, val, y, key, step, self.model)
+        with jax.named_scope("dsgd.update"):
+            # master mean over ALL workers (Master.scala:194)
+            g = g / (self.n_workers * self.virtual_workers)
+            if self.opt is None:  # reference update (Master.scala:197)
+                return w - self.learning_rate * g, opt_state
+            import optax
+
+            updates, opt_state = self.opt.update(g, opt_state, w)
+            return optax.apply_updates(w, updates), opt_state
+
+    def _gradient(self, w, idx, val, y, key, step, model):
+        """The SUM over all workers of their replies (`model`'s gradients
+        of the step's draw at `w`), in the kernel's layout."""
         # The jax.named_scope names (dsgd.draw, dsgd.allreduce, dsgd.update
         # here; dsgd.onehot / margins / coeff / scatter / regularize where
         # the kernels are defined) are HLO metadata only: the benchmark's
@@ -314,20 +345,38 @@ class BoundSync:
             bi, bv, by = self.draw_rows(idx, val, y, ids)  # the resident-row gathers
         by = self._labels(by)
         if one:  # one worker's Gradient reply (Slave.scala:142-157)
-            g = self.model.grad(w, SparseBatch(bi, bv), by, kernel=self.kernel)
-        else:  # the K virtual workers' replies, summed (mean-normalized below)
-            g = self.model.grad_workers(w, bi, bv, by, kernel=self.kernel)
+            g = model.grad(w, SparseBatch(bi, bv), by, kernel=self.kernel)
+        else:  # the K virtual workers' replies, summed (mean-normalized by the caller)
+            g = model.grad_workers(w, bi, bv, by, kernel=self.kernel)
         with jax.named_scope("dsgd.allreduce"):
-            g = jax.lax.psum(g, AXIS)
-        with jax.named_scope("dsgd.update"):
-            # master mean over ALL workers (Master.scala:194)
-            g = g / (self.n_workers * self.virtual_workers)
-            if self.opt is None:  # reference update (Master.scala:197)
-                return w - self.learning_rate * g, opt_state
-            import optax
+            return jax.lax.psum(g, AXIS)
 
-            updates, opt_state = self.opt.update(g, opt_state, w)
-            return optax.apply_updates(w, updates), opt_state
+    def _ftrl_step(self, state, idx, val, y, key, step):
+        """The dense step under FTRL (a binding `kernels.sparse_update` does
+        not name): w from (z, n) over all of D, the workers' loss gradients
+        as `_one_step` takes them (no regulariser term: the L2 strength is in
+        the closed form), their mean over ALL workers, and the same
+        per-coordinate update over all of D (ops/ftrl.py)."""
+        w = self._to_kernel_layout(self._ftrl_weights(state))
+        g = self._gradient(w, idx, val, y, key, step, self._loss_model)
+        with jax.named_scope(ftrl.SCOPE):
+            g = self._from_kernel_layout(g) / (self.n_workers * self.virtual_workers)
+            return ftrl.apply(state, g, self.ftrl)
+
+    def _ftrl_steps(self, state, idx, val, y, key):
+        """`steps_per_epoch` steps of FTRL on its state."""
+        if self.update_sparse:
+            return self._sparse_steps(state, idx, val, y, key)
+
+        def body(state, step):
+            return self._ftrl_step(state, idx, val, y, key, step), ()
+
+        state, _ = jax.lax.scan(body, state, jnp.arange(self.steps_per_epoch))
+        return state
+
+    def _ftrl_weights(self, state):
+        """w[D] of FTRL's state: what every program of the binding hands out."""
+        return ftrl.materialise(state, self.model.n_features, self.ftrl)
 
     # -- the sparse step (kernels.sparse_update) ------------------------------
     #
@@ -390,6 +439,15 @@ class BoundSync:
         # the K virtual workers share the weights: one call on their merged
         # batches (kernels.merges_margins), one scatter of all their entries
         merged = SparseBatch(bi.reshape(-1, width), bv.reshape(-1, width))
+        if self.plan.optimizer == "ftrl":
+            # `v2` is FTRL's state: the margins read w off it, the entries are
+            # g (the mean over ALL workers of their sums), and a touched row's
+            # 64 coordinates take the update from their summed g
+            at, add = self.model.reply_entries(
+                v2, merged, by.reshape(-1), factor=1.0 / n,
+                matvec=functools.partial(ftrl.matvec, p=self.ftrl))
+            return self._scatter_entries(v2, at, add, row=functools.partial(
+                ftrl.rows, p=self.ftrl), per_row=ftrl.HALF)
         with jax.named_scope("dsgd.update"):
             s, s_next = self._scale(since), self._scale(since + 1)
             # master mean over ALL workers (Master.scala:194) and the
@@ -400,6 +458,10 @@ class BoundSync:
         if self.model.n_outputs > 1:
             return self._scatter_reply_rows(v2, merged, by, s, factor)
         at, add = self.model.reply_entries(v2, merged, by.reshape(-1), s, factor)
+        return self._scatter_entries(v2, at, add)
+
+    def _scatter_entries(self, v2, at, add, **ending):
+        """Every device's entries (ids `at`, updates `add`) into `v2`."""
         # every device scatters every device's entries: the sum a psum of
         # the dense replies would give, in another order (one device: the
         # all-gather is the identity, and what types the entries replicated)
@@ -409,7 +471,7 @@ class BoundSync:
             both = gather_replicated(jnp.stack([at, bits]), AXIS)  # [devices, 2, T]
             at = both[:, 0].reshape(-1)
             add = jax.lax.bitcast_convert_type(both[:, 1], jnp.float32).reshape(-1)
-        return gather.scatter_into(v2, at, add, self.plan.scatter)
+        return gather.scatter_into(v2, at, add, self.plan.scatter, **ending)
 
     def _scatter_reply_rows(self, v2, merged, by, s, factor):
         """The rest of `_sparse_step` with an output axis: an entry's
@@ -518,6 +580,11 @@ class BoundSync:
 
     def _epoch_shard(self, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
+        if self.plan.optimizer == "ftrl":
+            # `opt_state` is (z, n); `w` is taken as every binding's programs
+            # take it and not read: the weights are the closed form of (z, n)
+            state = self._ftrl_steps(opt_state, idx, val, self._loop_labels(y), key)
+            return self._ftrl_weights(state), state
         w = self._to_kernel_layout(w)
         y = self._loop_labels(y)
         if self.update_sparse:
@@ -533,6 +600,12 @@ class BoundSync:
 
     def _step_shard(self, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
+        if self.plan.optimizer == "ftrl":  # `w` not read, as in `_epoch_shard`
+            zero = jnp.int32(0)
+            state = (self._sparse_step(opt_state, idx, val, y, key, zero, zero)
+                     if self.update_sparse else
+                     self._ftrl_step(opt_state, idx, val, y, key, zero))
+            return self._ftrl_weights(state), state
         w = self._to_kernel_layout(w)
         if self.update_sparse:
             zero = jnp.int32(0)
@@ -593,6 +666,12 @@ class BoundSync:
 
     def _multi_epoch_shard(self, n_epochs, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
+        if self.plan.optimizer == "ftrl":  # `w` not read, as in `_epoch_shard`
+            state, _ = jax.lax.scan(
+                lambda s, e: (self._ftrl_steps(s, idx, val, self._loop_labels(y),
+                                               jax.random.fold_in(key, e)), ()),
+                opt_state, jnp.arange(n_epochs))
+            return self._ftrl_weights(state), state
         w = self._to_kernel_layout(w)
         y = self._loop_labels(y)
 
@@ -650,7 +729,7 @@ class BoundSync:
         d = self.data
 
         def epoch():
-            self._epoch.lower(w0, self._opt_state, d.indices, d.values,
+            self._epoch.lower(w0, self._state(), d.indices, d.values,
                               d.labels, key).compile()
 
         def evaluate():
@@ -686,7 +765,7 @@ class BoundSync:
     def epoch(self, w: jax.Array, key: jax.Array) -> jax.Array:
         self._check_trainable()
         w, self._opt_state = self._epoch(
-            w, self._opt_state, self.data.indices, self.data.values,
+            w, self._state(), self.data.indices, self.data.values,
             self.data.labels, key,
         )
         return w
@@ -713,7 +792,7 @@ class BoundSync:
                 donate_argnums=self._donate,
             )
         w, self._opt_state = self._multi_cache[n_epochs](
-            w, self._opt_state, self.data.indices, self.data.values,
+            w, self._state(), self.data.indices, self.data.values,
             self.data.labels, key,
         )
         return w
@@ -721,12 +800,20 @@ class BoundSync:
     def step(self, w: jax.Array, key: jax.Array) -> jax.Array:
         self._check_trainable()
         w, self._opt_state = self._step(
-            w, self._opt_state, self.data.indices, self.data.values,
+            w, self._state(), self.data.indices, self.data.values,
             self.data.labels, key,
         )
         return w
 
+    def _state(self):
+        """The optimizer state, FTRL's made on first use."""
+        if self._opt_state is None:
+            self._opt_state = self._init_opt_state()
+        return self._opt_state
+
     def _init_opt_state(self):
+        if self.plan.optimizer == "ftrl":
+            return ftrl.zeros(self.model.n_features)
         if self.opt is None:
             return ()
         return self.opt.init(
@@ -739,11 +826,11 @@ class BoundSync:
 
     def opt_state_leaves(self):
         """Optimizer state as a flat list of arrays (checkpoint form)."""
-        return jax.tree.leaves(self._opt_state)
+        return jax.tree.leaves(self._state())
 
     def load_opt_state_leaves(self, leaves) -> None:
         """Restore optimizer state from `opt_state_leaves()` output."""
-        treedef = jax.tree.structure(self._opt_state)
+        treedef = jax.tree.structure(self._state())
         self._opt_state = jax.tree.unflatten(
             treedef, [jnp.asarray(x) for x in leaves]
         )
@@ -759,7 +846,8 @@ class BoundSync:
     def evaluate(self, w: jax.Array) -> Tuple[float, float]:
         """(objective, accuracy) over the bound split.
 
-        objective = lam*||w||^2 + mean sample loss (SparseSVM.scala:20-23);
+        objective = lam*||w||^2 + mean sample loss (SparseSVM.scala:20-23),
+        under FTRL l1 ||w||_1 + (l2 / 2) ||w||^2 + mean sample loss;
         accuracy = fraction(forward == y) (Master.scala:98-101).  With an
         output axis a sample's loss is the sum over its outputs and the
         accuracy is over (sample, output) pairs.
@@ -781,7 +869,8 @@ class BoundSync:
         with measure.span("trainer.evaluate.pull", histogram=False, root=False):
             hit_sum = float(sums[1])
         with measure.span("trainer.evaluate.reg", histogram=False, root=False):
-            reg = self.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
+            reg = (self.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
+                   if self.plan.optimizer != "ftrl" else ftrl.penalty(w, self.ftrl))
         n = self.data.n_true
         return reg + loss_sum / n, hit_sum / (n * self.model.n_outputs)
 
@@ -806,7 +895,9 @@ def local_update(opt, learning_rate: float, g, w, opt_state):
 def resolve_optimizer(optimizer, learning_rate: float, momentum: float = 0.9):
     """None/'sgd' -> None (the reference's plain update, Master.scala:197);
     'momentum'/'adam' -> the optax transformation at `learning_rate`; an
-    optax GradientTransformation passes through untouched."""
+    optax GradientTransformation passes through untouched.  FTRL is none of
+    these (BoundSync takes it before it asks here)."""
+    ftrl.refuse(optimizer, "an engine whose update reads a whole gradient")
     if optimizer is None or optimizer == "sgd":
         return None
     if isinstance(optimizer, str):
@@ -821,6 +912,15 @@ def resolve_optimizer(optimizer, learning_rate: float, momentum: float = 0.9):
             f"GradientTransformation, got {optimizer!r}"
         )
     return optimizer
+
+
+def optimizer_kind(optimizer) -> str:
+    """The update a sync binding runs, as `kernels.plan` takes it: 'ftrl'
+    (ops/ftrl.py), 'sgd' (None or 'sgd': the reference's) or 'optax' (every
+    other, which `resolve_optimizer` makes or refuses)."""
+    if ftrl.of(optimizer) is not None:
+        return "ftrl"
+    return "sgd" if optimizer is None or optimizer == "sgd" else "optax"
 
 
 class SyncEngine:
@@ -913,7 +1013,7 @@ class SyncEngine:
         # the kernel plan: its family's margins say how wide labels are stored
         plan = kernels.plan(
             self.model, learning_rate=self.learning_rate,
-            plain_sgd=resolve_optimizer(self.optimizer, self.learning_rate) is None,
+            optimizer=optimizer_kind(self.optimizer),
             row_width=data.indices.shape[1], virtual_workers=self.virtual_workers,
             batch_size=self.batch_size, n_workers=n_workers, eval_chunk=chunk, lists=lists,
             riding=slot is not None, kernel=self.kernel, device=self.mesh.devices.flat[0])

@@ -34,52 +34,52 @@ SHAPES = {
 PLANS = {
     ("rcv1-hinge", "tpu"): (
         "kernel=mxu margins=merged scatter_shards=1 update=dense scatter=words outputs=1 "
-        "labels=in_row eval_rows=4096 margin_fetch=gather", ("gather", 400), False),
+        "labels=in_row eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400), False),
     ("rcv1-hinge", "cpu"): (
         "kernel=mxu margins=merged scatter_shards=1 update=dense scatter=words outputs=1 "
-        "labels=gathered eval_rows=4096 margin_fetch=gather", ("gather", 400), False),
+        "labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400), False),
     ("rcv1-hinge-4chip", "tpu"): (
         "kernel=mxu margins=per_worker scatter_shards=1 update=dense scatter=words outputs=1 "
-        "labels=in_row eval_rows=4096 margin_fetch=gather", ("gather", 100), False),
+        "labels=in_row eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 100), False),
     ("rcv1-hinge-4chip", "cpu"): (
         "kernel=mxu margins=per_worker scatter_shards=1 update=dense scatter=words outputs=1 "
-        "labels=gathered eval_rows=4096 margin_fetch=gather", ("gather", 100), False),
+        "labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 100), False),
     ("epsilon-logistic", "tpu"): (
         "kernel=dense margins=per_worker scatter_shards=1 update=dense scatter=words "
-        "outputs=1 labels=in_row eval_rows=4096 margin_fetch=gather", ("gather", 400), False),
+        "outputs=1 labels=in_row eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400), False),
     ("epsilon-logistic", "cpu"): (
         "kernel=dense margins=per_worker scatter_shards=1 update=dense scatter=words "
-        "outputs=1 labels=gathered eval_rows=4096 margin_fetch=gather", ("gather", 400),
+        "outputs=1 labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400),
         False),
     ("criteo-logistic", "tpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=dense scatter=words outputs=1 "
-        "labels=in_row eval_rows=4096 margin_fetch=gather", ("gather", 400), False),
+        "labels=in_row eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400), False),
     ("criteo-logistic", "cpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=dense scatter=words outputs=1 "
-        "labels=gathered eval_rows=4096 margin_fetch=gather", ("gather", 400), False),
+        "labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400), False),
     ("kdd2012-logistic", "tpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=rows outputs=1 "
-        "labels=in_row eval_rows=4096 margin_fetch=gather", ("gather", 400), False),
+        "labels=in_row eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400), False),
     ("kdd2012-logistic", "cpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=words outputs=1 "
-        "labels=gathered eval_rows=4096 margin_fetch=gather", ("gather", 400), False),
+        "labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400), False),
     ("rcv1-topics-hinge", "tpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=merge "
-        "outputs=103 labels=gathered eval_rows=4096 margin_fetch=gather", ("gather", 400),
+        "outputs=103 labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400),
         False),
     ("rcv1-topics-hinge", "cpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=words "
-        "outputs=103 labels=gathered eval_rows=4096 margin_fetch=gather", ("gather", 400),
+        "outputs=103 labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400),
         False),
     # a chunk's margins in pieces of 256 samples through the margin kernel on
     # the TPU, 512 a row gather elsewhere; a step's 400 in pieces of 200
     ("amazoncat13k-dismec", "tpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=runs "
-        "outputs=1000 labels=lists eval_rows=256 margin_fetch=distinct", ("distinct", 200),
+        "outputs=1000 labels=lists eval_rows=256 margin_fetch=distinct optimizer=sgd", ("distinct", 200),
         True),
     ("amazoncat13k-dismec", "cpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=words "
-        "outputs=1000 labels=lists eval_rows=512 margin_fetch=gather", ("gather", 400), True),
+        "outputs=1000 labels=lists eval_rows=512 margin_fetch=gather optimizer=sgd", ("gather", 400), True),
 }
 
 
@@ -91,7 +91,7 @@ def test_each_configuration_plans_what_its_cell_records(config, platform, monkey
     model = make_model(loss, lam, features, regularizer=regularizer, n_outputs=outputs,
                        dim_sparsity=np.ones(features) if regularizer == "dim_sparsity" else None)
     plan = kernels.plan(
-        model, learning_rate=lr, plain_sgd=True, row_width=width, virtual_workers=workers,
+        model, learning_rate=lr, optimizer="sgd", row_width=width, virtual_workers=workers,
         batch_size=100, n_workers=devices, eval_chunk=4096, lists=labels == "lists",
         riding=labels == "riding" and platform == "tpu")
     record, step_fetch, tiles = PLANS[config, platform]

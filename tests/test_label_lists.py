@@ -694,7 +694,7 @@ def test_on_a_tpu_a_wide_binding_writes_rows_and_does_not_merge(monkeypatch, cap
     `merges_scatter` twice over."""
     assert not kernels.merges_scatter(203_882, 1_000, 28_800)
     assert kernels.merges_scatter(203_882, 1_000, 60_000) is False  # the lanes alone
-    assert kernels.sparse_update("gather", "l2", True, 1e-7, 203_882, 1_000)
+    assert kernels.sparse_update("gather", "l2", "sgd", 1e-7, 203_882, 1_000)
     assert kernels.choose_kernel(203_882, 72, "tpu", "mxu", 1_000) == "gather"
     from jax.experimental.pallas import tpu as pltpu
 

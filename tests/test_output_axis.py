@@ -293,11 +293,11 @@ def test_the_kernel_rule_takes_the_output_count(platform, off_tpu, n_outputs, wa
 
 
 def test_the_update_rule_counts_the_words_of_the_weights():
-    rule = lambda d, c: kernels.sparse_update("gather", "l2", True, 1e-7, d, c)  # noqa: E731
+    rule = lambda d, c: kernels.sparse_update("gather", "l2", "sgd", 1e-7, d, c)  # noqa: E731
     assert rule(47_236, 103) and not rule(47_236, 1) and not rule(47_236, 84)
     assert rule(4_000_000, 1) and not rule(3_999_999, 1)
-    assert not kernels.sparse_update("scalar", "l2", True, 1e-7, 47_236, 103)
-    assert not kernels.sparse_update("gather", "l2", False, 1e-7, 47_236, 103)
+    assert not kernels.sparse_update("scalar", "l2", "sgd", 1e-7, 47_236, 103)
+    assert not kernels.sparse_update("gather", "l2", "optax", 1e-7, 47_236, 103)
 
 
 def test_an_optimizer_reads_a_gradient_with_the_output_axis():

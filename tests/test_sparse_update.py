@@ -93,20 +93,20 @@ def _float64_steps(data, draws, w, lam, lr, reg="l2"):
 
 # -- the rule ---------------------------------------------------------------------
 
-@pytest.mark.parametrize("kernel,reg,plain,decay,features,said", [
-    ("gather", "l2", True, 3e-8, 54_686_452, True),     # kdd2012-logistic
-    ("gather", "none", True, 0.0, 54_686_452, True),
-    ("gather", "l2", True, 1.8e-8, 1_000_000, False),   # criteo-logistic: under the floor
-    ("gather", "dim_sparsity", True, 0.0, 54_686_452, False),
-    ("gather", "l2", False, 3e-8, 54_686_452, False),   # an optax optimizer
-    ("gather", "l2", True, 1.0, 54_686_452, False),     # a step that would flip w's sign
-    ("mxu", "l2", True, 3e-8, 54_686_452, False),
-    ("scalar", "l2", True, 3e-8, 54_686_452, False),
-    ("dense", "l2", True, 3e-8, 54_686_452, False),
+@pytest.mark.parametrize("kernel,reg,optimizer,decay,features,said", [
+    ("gather", "l2", "sgd", 3e-8, 54_686_452, True),     # kdd2012-logistic
+    ("gather", "none", "sgd", 0.0, 54_686_452, True),
+    ("gather", "l2", "sgd", 1.8e-8, 1_000_000, False),   # criteo-logistic: under the floor
+    ("gather", "dim_sparsity", "sgd", 0.0, 54_686_452, False),
+    ("gather", "l2", "optax", 3e-8, 54_686_452, False),   # an optax optimizer
+    ("gather", "l2", "sgd", 1.0, 54_686_452, False),     # a step that would flip w's sign
+    ("mxu", "l2", "sgd", 3e-8, 54_686_452, False),
+    ("scalar", "l2", "sgd", 3e-8, 54_686_452, False),
+    ("dense", "l2", "sgd", 3e-8, 54_686_452, False),
 ])
 def test_one_rule_says_which_bindings_scatter_into_the_weights(
-        kernel, reg, plain, decay, features, said):
-    assert kernels.sparse_update(kernel, reg, plain, decay, features) is said
+        kernel, reg, optimizer, decay, features, said):
+    assert kernels.sparse_update(kernel, reg, optimizer, decay, features) is said
 
 
 def test_the_floor_lies_between_the_two_cells_feature_counts():
