@@ -42,7 +42,9 @@ entries a step, the generator's own 1/r draw of feature ids):
   lanes by its constants; beside each piece size the share of its entries
   that are distinct tiles (what the kernel fetches).  What
   `kernels.MARGIN_*` and `gather.MARGIN_*` were set from (`--lanes` picks
-  the widths).
+  the widths); also the kernel on a plan of the pieces made
+  once (`gather.plan_pieces`, what the evaluation's chunks read) and what
+  making that plan costs a call.
 
     python benches/outputs_step_sweep.py [--rehearse] [--only step,forms,merge,runs,margins]
                                          [--lanes 256,512,1024]
@@ -438,6 +440,20 @@ def margins_table(jax, jnp, gather, kernels, on_tpu, rehearse, lanes_of=(256, 51
                     row[f"S{rule}_{key}{value}"] = clocked(kernel(rule, **constants))
                 row[f"sort_alone_S{rule}"] = clocked(lambda w, b: w[:1] + sum(
                     jnp.sum(a[:, :1]) for a in gather._sorted_pieces(b, rule, rows)))
+                # the kernel on a plan made once (`gather.plan_pieces`: rows
+                # fixed, as the evaluation's are), and what making it costs
+                plan = jax.jit(lambda i: gather.plan_pieces(i, rule, rows))(ids)
+                row[f"S{rule}_planned"] = clocked(lambda w, b: gather.matvec_rows(
+                    SparseBatch(ids, b.values), w, "planned", rule, plan=plan))
+                row[f"plan_alone_S{rule}"] = clocked(lambda w, b: w[:1] + sum(
+                    jnp.sum(a[..., :1]) for a in gather.plan_pieces(b.indices, rule, rows)))
+                w = gather.to_tiles(jnp.asarray(rng.normal(size=(rows, lanes)), jnp.float32))
+                with interpreted():  # the planned kernel is the walking one, bit for bit
+                    walked = jax.jit(kernel(rule))(w, batch).reshape(samples, lanes)
+                    planned = jax.jit(lambda w: gather.matvec_rows(
+                        batch, w, "planned", rule, plan=plan))(w)
+                row[f"planned_equal_S{rule}"] = bool(jnp.array_equal(walked, planned))
+                del w, walked, planned
             by_lanes[name] = row
             print(json.dumps({lanes: {name: row}}), file=sys.stderr, flush=True)
     return out

@@ -124,7 +124,7 @@ class SyncTrainer:
         placed = bound_train.placement()
         stored = placed[0][2]  # one layout for every device's rows
         log.info("train split: %d rows %s stored major_to_minor=%s, per device %s",
-                 len(train), bound_train.plan.record(), stored, " ".join(
+                 len(train), bound_train.record(), stored, " ".join(
                      f"[id={d} rows={r} bytes_in_use={b}]"
                      for d, r, _stored, b in placed))
         if bound_train.plan.optimizer == "ftrl" and initial_weights is not None:
@@ -260,5 +260,9 @@ class SyncTrainer:
         return bound.predict(weights)
 
     def evaluate(self, weights: jax.Array, data: Dataset):
-        """(objective, accuracy) — Master.distributedLoss/Accuracy."""
+        """(objective, accuracy) — Master.distributedLoss/Accuracy.  Binds
+        `data` afresh: where the evaluation's margins are planned
+        (`kernels.Fetch` 'planned') every call makes the binding's margin
+        plan again; a caller that evaluates one split often binds it once
+        (`engine.bind`) and calls the binding's `evaluate`."""
         return self.engine.bind(data).evaluate(weights)

@@ -166,13 +166,14 @@ class LinearModel:
         return mxu.from_blocked(w, self.n_features) if kernel in kernels.BLOCKED else w
 
     def margins(self, w: jax.Array, batch: SparseBatch, kernel: str = "scalar",
-                fetch: Optional[kernels.Fetch] = None) -> jax.Array:
+                fetch: Optional[kernels.Fetch] = None, plan=None) -> jax.Array:
         """Per-sample dots x_b . w, `w` in `kernel`'s layout (`fetch`: how
-        rows of outputs are read, `_rows_margins`)."""
+        rows of outputs are read, `_rows_margins`; `plan`: the batch's
+        `gather.PiecePlan` where `fetch` is 'planned')."""
         if batch.is_dense:
             return self.margins_dense(w, batch.values)
         if kernel == "gather" and self.n_outputs > 1:
-            return self._rows_margins(batch, w, fetch)
+            return self._rows_margins(batch, w, fetch, plan)
         if kernel == "gather":
             return gather.matvec(batch, w)
         if kernel in kernels.BLOCKED:
@@ -288,12 +289,13 @@ class LinearModel:
         return (batch.indices.reshape(-1), batch.values.astype(jnp.float32).reshape(-1),
                 src.reshape(-1), coeff.astype(jnp.float32))
 
-    def _rows_margins(self, batch: SparseBatch, w2: jax.Array, fetch=None) -> jax.Array:
+    def _rows_margins(self, batch: SparseBatch, w2: jax.Array, fetch=None,
+                      plan=None) -> jax.Array:
         """`gather.matvec_rows` as a binding's plan says (`fetch`), else XLA's
         gather in the pieces `kernels.margin_rows` gives this call's shape."""
         lanes = int(np.prod(w2.shape[1:]))
         return gather.matvec_rows(batch, w2, *fetch or (
-            "gather", kernels.margin_rows(*batch.indices.shape, lanes)))
+            "gather", kernels.margin_rows(*batch.indices.shape, lanes)), plan=plan)
 
     def sample_losses(self, w: jax.Array, batch: SparseBatch, y: jax.Array) -> jax.Array:
         """Per-sample losses (no regularization term), vectorized."""

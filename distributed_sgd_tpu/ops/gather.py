@@ -117,6 +117,7 @@ RUN_BLOCK).
 from __future__ import annotations
 
 import math
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -368,7 +369,8 @@ def from_tiles(w3: jax.Array) -> jax.Array:
         return w3.reshape(w3.shape[0], -1)
 
 
-def matvec_rows(batch: SparseBatch, w2: jax.Array, fetch: str, piece: int) -> jax.Array:
+def matvec_rows(batch: SparseBatch, w2: jax.Array, fetch: str, piece: int,
+                plan: Optional["PiecePlan"] = None) -> jax.Array:
     """Per-sample, per-output dots `x_b . W[:, c]` -> [B, L]: every stored
     entry gathers its feature's row, a sample's P rows are summed with its
     values as weights (pads contribute 0 * row 0).  The entries are
@@ -382,13 +384,20 @@ def matvec_rows(batch: SparseBatch, w2: jax.Array, fetch: str, piece: int) -> ja
     `piece` samples a gather, piece after piece; 'distinct' (a TPU, rows
     carried as tiles) runs ONE kernel of ours instead (`_margin_tiles`):
     each distinct tile of a piece fetched once, a sample's tiles summed in a
-    register, no [P B, L] array at all."""
+    register, no [P B, L] array at all; 'planned' runs the same kernel on
+    the batch's `plan` (`plan_pieces`, made once for rows that do not
+    change), which it reads in place of sorting and walking the ids."""
     tile = w2.shape[1:]  # (L,), or (L / 128, 128) where the rows are tiles
     lanes = math.prod(tile)
+    width = batch.indices.shape[1]
     if fetch == "distinct":
         with jax.named_scope("dsgd.margins"):
-            m = _margin_tiles(w2, *_sorted_pieces(batch, piece, w2.shape[0]), piece,
-                              batch.indices.shape[1])
+            m = _margin_tiles(w2, *_sorted_pieces(batch, piece, w2.shape[0]), piece, width)
+            return m.reshape(-1, lanes)
+    if fetch == "planned":
+        with jax.named_scope("dsgd.margins"):
+            m = _margin_tiles(w2, plan.to, plan.slots, _piece_values(batch.values, piece * width),
+                              piece, width, heads=plan.heads)
             return m.reshape(-1, lanes)
 
     def dots(indices, values):
@@ -432,30 +441,80 @@ MARGIN_UNROLL = 24
 FACTOR_ALIGN = 1024
 
 
-def _sorted_pieces(batch: SparseBatch, piece: int, n_rows: int):
-    """(ids, pos, values), each int32 / f32[n, E'] for the n pieces of
-    `piece` samples (E = piece x P entries, E' = E rounded up to whole
-    FACTOR_ALIGN): a piece's ids sorted ascending with the position in the
-    piece each came from (b P + p), and its values in sample order.  ONE
-    batched sort: of one word an entry, id x E + position, where ids below
-    `n_rows` leave that room in 32 bits (else of the two words)."""
-    per = piece * batch.indices.shape[1]
+def _sorted_ids(indices: jax.Array, piece: int, n_rows: int):
+    """(ids, pos), each int32[n, E] for the n pieces of `piece` samples (E
+    = piece x P entries): a piece's ids sorted ascending with the position
+    in the piece each came from (b P + p).  ONE batched sort: of one word an
+    entry, id x E + position, where ids below `n_rows` leave that room in 32
+    bits (else of the two words)."""
+    per = piece * indices.shape[1]
     # in range, as XLA's gather clamps: the kernel's DMAs are not checked
-    ids = jnp.clip(batch.indices, 0, min(n_rows, 2 ** 31) - 1).reshape(-1, per)
+    ids = jnp.clip(indices, 0, min(n_rows, 2 ** 31) - 1).reshape(-1, per)
     pos = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
     if n_rows * per <= 2 ** 32:
         key = jax.lax.sort(ids.astype(jnp.uint32) * per + pos.astype(jnp.uint32), dimension=1,
                            is_stable=False)
-        ids, pos = (key // per).astype(jnp.int32), (key % per).astype(jnp.int32)
+        return (key // per).astype(jnp.int32), (key % per).astype(jnp.int32)
+    return jax.lax.sort((ids, pos), dimension=1, num_keys=1, is_stable=False)
+
+
+def _aligned(factors: jax.Array) -> jax.Array:
+    """[n, E] -> [n, E'], E' = E rounded up to whole FACTOR_ALIGN."""
+    return jnp.pad(factors, ((0, 0), (0, -factors.shape[1] % FACTOR_ALIGN)))
+
+
+def _piece_values(values: jax.Array, per: int) -> jax.Array:
+    """f32[n, E']: the values of the pieces of `per` entries, in sample order."""
+    return _aligned(values.astype(jnp.float32).reshape(-1, per))
+
+
+def _sorted_pieces(batch: SparseBatch, piece: int, n_rows: int):
+    """(ids, pos, values), each int32 / f32[n, E'] (`_sorted_ids` in whole
+    FACTOR_ALIGN), and the pieces' values in sample order: what the margin
+    kernel walks."""
+    ids, pos = _sorted_ids(batch.indices, piece, n_rows)
+    return (_aligned(ids), _aligned(pos),
+            _piece_values(batch.values, piece * batch.indices.shape[1]))
+
+
+class PiecePlan(NamedTuple):
+    """The margin kernel's plan of n pieces (`plan_pieces`): `to` int32[n,
+    E'], a piece's distinct tile ids ascending, its first `heads[j]` words
+    meant; `slots` int32[n, E'], every entry's slot in that list, in the
+    piece's sample order (b P + p); `heads` int32[n].  A leading axis more
+    (the evaluation's chunks) where a binding holds them."""
+    to: jax.Array
+    slots: jax.Array
+    heads: jax.Array
+
+
+def plan_pieces(indices: jax.Array, piece: int, n_rows: int) -> PiecePlan:
+    """The plan the margin kernel makes of a batch's ids by its slot walk
+    (`_margin_tiles`), made by XLA instead: `_sorted_ids`' sort, a flag at
+    the first entry of every run of an id and their running count (the
+    slots, in sorted order), then two more batched sorts of one word an
+    entry, one that puts every slot back at its entry's position and one
+    that moves the runs' ids ahead of the rest.  For rows that do not change
+    (a binding's evaluation chunks), so that the kernel sorts and walks
+    nothing when it runs."""
+    ids, pos = _sorted_ids(indices, piece, n_rows)
+    n, per = ids.shape
+    head = jnp.concatenate([jnp.ones((n, 1), bool), ids[:, 1:] != ids[:, :-1]], axis=1)
+    slot = jnp.cumsum(head, axis=1, dtype=jnp.int32) - 1
+    if per * per <= 2 ** 32:
+        key = jax.lax.sort(pos.astype(jnp.uint32) * per + slot.astype(jnp.uint32), dimension=1,
+                           is_stable=False)
+        slots = (key % per).astype(jnp.int32)
     else:
-        ids, pos = jax.lax.sort((ids, pos), dimension=1, num_keys=1, is_stable=False)
-    pad = ((0, 0), (0, -per % FACTOR_ALIGN))
-    return (jnp.pad(ids, pad), jnp.pad(pos, pad),
-            jnp.pad(batch.values.astype(jnp.float32).reshape(-1, per), pad))
+        slots = jax.lax.sort((pos, slot), dimension=1, num_keys=1, is_stable=False)[1]
+    to = jax.lax.sort(jnp.where(head, ids, jnp.iinfo(jnp.int32).max), dimension=1,
+                      is_stable=False)
+    return PiecePlan(_aligned(to), _aligned(slots), slot[:, -1] + 1)
 
 
 def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Array,
-                  piece: int, width: int, unroll: int = MARGIN_UNROLL) -> jax.Array:
+                  piece: int, width: int, unroll: int = MARGIN_UNROLL,
+                  heads: Optional[jax.Array] = None) -> jax.Array:
     """m f32[n piece, L / 128, 128]: the margins of the n pieces of `piece`
     samples of `width` entries whose factors `_sorted_pieces` gives, against
     tiles `w [D', L / 128, 128]` (`to_tiles`) left in HBM: ONE TPU kernel, a
@@ -473,13 +532,20 @@ def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Arra
     holds a tile an entry, the worst case (`kernels.margin_tiles` sizes
     the piece for it).  Pads add 0 x tile 0; only the order of addition
     inside a sample is not XLA's.  `unroll`: entries a turn of the walks'
-    loops."""
+    loops.
+
+    With `heads` (int32[n]) the pieces come planned (`plan_pieces`): `ids`
+    is each piece's distinct tiles (`PiecePlan.to`) and `pos` every entry's
+    slot (`.slots`), and they come in with the values, the next piece's
+    while this one is worked on; the kernel walks no ids, starts the
+    `heads[j]` DMAs at once and sums as above, in the same order."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     n, stride = ids.shape
     per = piece * width  # entries a piece; `stride` is that in whole FACTOR_ALIGN
     tile = w.shape[1:]
+    planned = heads is not None
 
     def unrolled(count, one, carry, offset):
         """carry = one(offset + e, carry) for e < count (static), `unroll` a
@@ -498,8 +564,12 @@ def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Arra
             carry = one(jax.lax.add(offset, e), carry)
         return carry
 
-    def kernel(ids_hbm, pos_hbm, val_hbm, w_ref, out_ref,
-               cache, ids_s, pos_s, val_s, slot_of, to, sem_keys, sem_vals, sem_tiles):
+    def kernel(ids_hbm, pos_hbm, val_hbm, w_ref, *refs):
+        if planned:  # the plan: distinct ids, slots and values, two pieces each
+            heads_hbm, out_ref, cache, to, slot_of, val_s, heads_s, sem_keys, sem_tiles = refs
+        else:
+            (out_ref, cache, ids_s, pos_s, val_s, slot_of, to, sem_keys, sem_vals,
+             sem_tiles) = refs
         step = pl.program_id(0)
 
         def factors(hbm, smem, j, buf, sem):  # piece j's row of factors -> scalar memory
@@ -507,16 +577,24 @@ def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Arra
                 hbm.at[pl.ds(pl.multiple_of(j * stride, FACTOR_ALIGN), stride)],
                 smem.at[pl.ds(pl.multiple_of(buf * stride, FACTOR_ALIGN), stride)], sem)
 
-        def keys(j):  # its sorted ids and their positions, two buffers
+        def keys(j):  # its sorted ids and their positions (or its plan), two buffers
+            if planned:
+                return [factors(hbm, smem, j, j % 2, sem_keys.at[k]) for k, (hbm, smem) in
+                        enumerate(((ids_hbm, to), (pos_hbm, slot_of), (val_hbm, val_s)))]
             return [factors(ids_hbm, ids_s, j, j % 2, sem_keys.at[0]),
                     factors(pos_hbm, pos_s, j, j % 2, sem_keys.at[1])]
 
-        values_in = factors(val_hbm, val_s, step, 0, sem_vals.at[0])
+        if not planned:
+            values_in = factors(val_hbm, val_s, step, 0, sem_vals.at[0])
 
         @pl.when(step == 0)
         def _():
             for copy in keys(0):
                 copy.start()
+            if planned:  # every piece's count of distinct tiles, once a call
+                counts = pltpu.make_async_copy(heads_hbm, heads_s, sem_keys.at[3])
+                counts.start()
+                counts.wait()
 
         for copy in keys(step):
             copy.wait()
@@ -526,21 +604,26 @@ def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Arra
             for copy in keys(step + 1):
                 copy.start()
 
-        values_in.start()
+        if not planned:
+            values_in.start()
         keyed = step % 2 * stride
+        if planned:
+            heads = heads_s[step]
+        else:
+            def slot(at, carry):
+                prev, last = carry
+                i = ids_s[at]
+                last = jax.lax.add(last, jax.lax.convert_element_type(jax.lax.ne(i, prev),
+                                                                      jnp.int32))
+                to[last] = i
+                slot_of[pos_s[at]] = last
+                return i, last
 
-        def slot(at, carry):
-            prev, last = carry
-            i = ids_s[at]
-            last = jax.lax.add(last, jax.lax.convert_element_type(jax.lax.ne(i, prev), jnp.int32))
-            to[last] = i
-            slot_of[pos_s[at]] = last
-            return i, last
-
-        heads = unrolled(per, slot, (jnp.int32(-1), jnp.int32(-1)), keyed)[1] + 1
+            heads = unrolled(per, slot, (jnp.int32(-1), jnp.int32(-1)), keyed)[1] + 1
 
         def start(k, carry):
-            pltpu.make_async_copy(w_ref.at[to[k]], cache.at[k], sem_tiles.at[0]).start()
+            i = to[keyed + k] if planned else to[k]  # (the plan's: this piece's buffer)
+            pltpu.make_async_copy(w_ref.at[i], cache.at[k], sem_tiles.at[0]).start()
             return carry
 
         def turn(t, carry):
@@ -559,29 +642,36 @@ def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Arra
                 tiles = cache.at[pl.ds(0, 1 << bit)]
                 pltpu.make_async_copy(tiles, tiles, sem_tiles.at[0]).wait()
 
-        values_in.wait()
+        if not planned:
+            values_in.wait()
 
         def term(at, acc):
             splat = jax.lax.broadcast_in_dim(val_s[at], tile, ())
             return jax.lax.add(acc, jax.lax.mul(splat, cache[slot_of[at]]))
 
         def sample(b, carry):
-            out_ref[b] = unrolled(width, term, jnp.zeros(tile, jnp.float32), b * width)
+            out_ref[b] = unrolled(width, term, jnp.zeros(tile, jnp.float32),
+                                  b * width + keyed if planned else b * width)
             return carry
 
         jax.lax.fori_loop(0, piece, sample, 0)
 
     tile_bytes = 4 * (-(-tile[0] // SUBLANES) * SUBLANES) * tile[1]  # whole registers
     need = tile_bytes * (per + 2 * piece)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n * piece,) + tile, jnp.float32,
-                                       vma=jax.typeof(w).vma),
-        grid=(n,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 4,
-        out_specs=pl.BlockSpec((piece,) + tile, lambda j: (j, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((per,) + tile, jnp.float32),  # the tile cache
+    cache = pltpu.VMEM((per,) + tile, jnp.float32)  # the tile cache
+    if planned:
+        scratch = [
+            cache,
+            pltpu.SMEM((2 * stride,), jnp.int32),  # two pieces' distinct ids
+            pltpu.SMEM((2 * stride,), jnp.int32),  # their entries' slots
+            pltpu.SMEM((2 * stride,), jnp.float32),  # and values
+            pltpu.SMEM((n,), jnp.int32),  # every piece's count of distinct ids
+            pltpu.SemaphoreType.DMA((4,)),
+            pltpu.SemaphoreType.DMA((1,)),
+        ]
+    else:
+        scratch = [
+            cache,
             pltpu.SMEM((2 * stride,), jnp.int32),  # two pieces' sorted ids
             pltpu.SMEM((2 * stride,), jnp.int32),  # and their positions
             pltpu.SMEM((stride,), jnp.float32),  # the piece's values
@@ -590,12 +680,21 @@ def _margin_tiles(w: jax.Array, ids: jax.Array, pos: jax.Array, values: jax.Arra
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((1,)),
             pltpu.SemaphoreType.DMA((1,)),
-        ],
+        ]
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((n * piece,) + tile, jnp.float32,
+                                       vma=jax.typeof(w).vma),
+        grid=(n,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (5 if planned else 4),
+        out_specs=pl.BlockSpec((piece,) + tile, lambda j: (j, 0, 0)),
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=need + 16 * 2 ** 20,
             disable_bounds_checks=True),  # the module docstring's last paragraph
         name="margin_tiles",
-    )(ids.reshape(-1), pos.reshape(-1), values.reshape(-1), w.astype(jnp.float32))
+    )(ids.reshape(-1), pos.reshape(-1), values.reshape(-1), w.astype(jnp.float32),
+      *([heads] if planned else []))
 
 
 def scatter_add_rows(batch: SparseBatch, coeff: jax.Array, shape: tuple) -> jax.Array:

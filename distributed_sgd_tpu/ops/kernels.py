@@ -32,7 +32,9 @@ that a step's bytes have no term in the feature count), `merges_scatter`
 a walk of its entries that moves each touched row once), `margin_rows`
 (with an output axis, how many samples one row gather of the margins takes)
 and `margin_tiles` (with rows carried as tiles on a TPU, the piece of
-samples whose distinct tiles the margin kernel fetches once each).
+samples whose distinct tiles the margin kernel fetches once each; where the
+rows are the evaluation's, fixed for the binding, the kernel reads a plan of
+them made at bind, `Fetch` 'planned').
 """
 
 from __future__ import annotations
@@ -326,8 +328,11 @@ def margin_tiles(samples: int, row_width: int, lanes: int) -> int:
 
 class Fetch(NamedTuple):
     """How a call of the output-axis margins (`gather.matvec_rows`) reads
-    weight rows: 'gather' (XLA's, `piece` samples a gather: `margin_rows`)
-    or 'distinct' (the margin kernel, pieces of `piece`: `margin_tiles`)."""
+    weight rows: 'gather' (XLA's, `piece` samples a gather: `margin_rows`),
+    'distinct' (the margin kernel, pieces of `piece`: `margin_tiles`) or
+    'planned' (the margin kernel on rows fixed for the binding, the
+    evaluation's chunks: it reads the plan of their pieces the binding made
+    once, `gather.plan_pieces`, and sorts and walks no ids)."""
     how: str
     piece: int
 
@@ -359,8 +364,9 @@ class Plan:
     `merges_scatter`; 'rows' without), else 'words' (XLA's); `tiles`: wide
     weight rows carried as tiles (`gather.to_tiles`); `lanes`: of a weight
     row of outputs (0: none), as the margins and stored labels have them;
-    `step_fetch` / `eval_fetch`: a step's K x B samples a device, an
-    evaluation chunk; `decay`: what a step takes off every coordinate;
+    `step_fetch` / `eval_fetch`: a step's K x B samples a device (drawn
+    anew every step), an evaluation chunk (rows fixed for the binding:
+    'planned' where the kernel reads them); `decay`: what a step takes off every coordinate;
     `optimizer`: the update, 'sgd' (the reference's), 'ftrl' (the state
     (z, n) carried where the weights would be, ops/ftrl.py) or 'optax'."""
 
@@ -419,12 +425,13 @@ def plan(model, *, learning_rate: float, optimizer: str, row_width: int,
         d, c, n_workers * virtual_workers * batch_size * row_width)
     tiles = lanes > gather.LANES and not merge
 
-    def fetch(samples, distinct):
+    def fetch(samples, distinct, fixed=False):
         piece = margin_tiles(samples, row_width, lanes) if distinct else 0
-        return Fetch("distinct", piece) if piece else Fetch(
+        return Fetch("planned" if fixed else "distinct", piece) if piece else Fetch(
             "gather", margin_rows(samples, row_width, lanes))
 
-    eval_fetch = fetch(eval_chunk, ours and tiles)
+    # the evaluation's rows never change: their pieces are planned once
+    eval_fetch = fetch(eval_chunk, ours and tiles, fixed=True)
     merged = virtual_workers > 1 and merges_margins(kernel, row_width)
     decided = Plan(
         kernel=kernel, margins="merged" if merged else "per_worker",
@@ -434,7 +441,7 @@ def plan(model, *, learning_rate: float, optimizer: str, row_width: int,
         scatter="merge" if merge else ("runs" if c > 1 else "rows") if ours else "words",
         tiles=tiles, lanes=lanes,
         # the step takes the margin kernel where the evaluation's chunk does
-        step_fetch=fetch(virtual_workers * batch_size, eval_fetch.how == "distinct"),
+        step_fetch=fetch(virtual_workers * batch_size, eval_fetch.how == "planned"),
         eval_fetch=eval_fetch, labels="lists" if lists else "in_row" if riding else "gathered",
         outputs=c, decay=decay, optimizer=optimizer)
     for name, counted in (("outputs.multi", c > 1), ("margins.merged", merged),
@@ -442,7 +449,8 @@ def plan(model, *, learning_rate: float, optimizer: str, row_width: int,
                           ("scatter.sharded", decided.scatter_shards > 1),
                           (f"labels.{decided.labels}", True), ("update.sparse", sparse),
                           (f"scatter.{decided.scatter}", ours),
-                          ("margins.tiles", eval_fetch.how == "distinct")):
+                          ("margins.tiles", eval_fetch.how == "planned"),
+                          ("margins.planned", eval_fetch.how == "planned")):
         if counted:
             metrics.counter(f"bind.{name}").increment()
     return decided

@@ -226,6 +226,13 @@ class BoundSync:
         self._loss_model = model if kind != "ftrl" else type(model)(
             0.0, model.n_features, regularizer="none")
 
+        # the evaluation's margin plan (`kernels.Fetch` 'planned'): every
+        # chunk's pieces as the margin kernel reads them, made once, from rows
+        # that never change; an argument of the evaluation programs beside them
+        planned = self.plan.eval_fetch.how == "planned"
+        self.margin_plan = self._plan_margins() if planned else None
+        pspec = (P(AXIS),) if planned else ()
+
         dspec = (P(AXIS), P(AXIS), P(AXIS))
         self._epoch = jax.jit(
             shard_map(
@@ -250,7 +257,7 @@ class BoundSync:
             shard_map(
                 self._eval_shard,
                 mesh=mesh,
-                in_specs=(P(),) + dspec,
+                in_specs=(P(),) + dspec + pspec,
                 out_specs=P(),
             )
         )
@@ -258,10 +265,16 @@ class BoundSync:
             shard_map(
                 self._predict_shard,
                 mesh=mesh,
-                in_specs=(P(),) + dspec[:2],
+                in_specs=(P(),) + dspec[:2] + pspec,
                 out_specs=P(AXIS),
             )
         )
+
+    @property
+    def _planned(self) -> tuple:
+        """The evaluation programs' arguments past the rows: the margin plan,
+        where the binding has one."""
+        return () if self.margin_plan is None else (self.margin_plan,)
 
     @property
     def update_sparse(self) -> bool:
@@ -614,7 +627,14 @@ class BoundSync:
         w, opt_state = self._one_step(w, opt_state, idx, val, y, key, jnp.int32(0))
         return self._from_kernel_layout(w), opt_state
 
-    def _eval_shard(self, w, idx, val, y) -> Tuple[jax.Array, jax.Array]:
+    def _chunk_plan(self, plan, t):
+        """Chunk `t`'s pieces of the margin plan (None: the binding has none)."""
+        if plan is None:
+            return None
+        with jax.named_scope("dsgd.margins"):
+            return jax.tree.map(lambda a: a[t], plan)
+
+    def _eval_shard(self, w, idx, val, y, plan=None) -> Tuple[jax.Array, jax.Array]:
         # chunked scan so the working set stays small; pads (label 0) masked;
         # bind() padded each shard to a multiple of eval_chunk
         chunk = self.eval_chunk
@@ -631,7 +651,8 @@ class BoundSync:
                 mask = (cy != 0).astype(jnp.float32)
             # the same gather the step runs (models/linear.py `margins`)
             margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel,
-                                         fetch=self.plan.eval_fetch)
+                                         fetch=self.plan.eval_fetch,
+                                         plan=self._chunk_plan(plan, t))
             with jax.named_scope("dsgd.eval_reduce"):
                 losses = self.model.losses_from_margins(margins, cy)
                 preds = self.model.predict(margins)
@@ -647,7 +668,7 @@ class BoundSync:
         with jax.named_scope("dsgd.allreduce"):
             return jax.lax.psum(sums, AXIS)
 
-    def _predict_shard(self, w, idx, val) -> jax.Array:
+    def _predict_shard(self, w, idx, val, plan=None) -> jax.Array:
         chunk = self.eval_chunk
         n_chunks = self.shard_n // chunk
         w_layout = self._to_kernel_layout(w)
@@ -656,13 +677,52 @@ class BoundSync:
             with jax.named_scope("dsgd.eval_rows"):
                 ci, cv = self.chunk_rows(idx, val, t * chunk)
             margins = self.model.margins(w_layout, SparseBatch(ci, cv), kernel=self.kernel,
-                                         fetch=self.plan.eval_fetch)
+                                         fetch=self.plan.eval_fetch,
+                                         plan=self._chunk_plan(plan, t))
             with jax.named_scope("dsgd.eval_reduce"):
                 return (), self.model.predict(margins)
 
         with jax.named_scope("dsgd.eval"):
             _, preds = jax.lax.scan(body, (), jnp.arange(n_chunks))
             return preds.reshape((-1,) + preds.shape[2:])
+
+    def _margin_plan_shard(self, idx, val) -> gather.PiecePlan:
+        """This device's margin plan: `gather.plan_pieces` of every chunk of
+        its shard, a leading axis the chunks, one chunk at a time."""
+        chunk = self.eval_chunk
+        d = self.model.n_features
+        rows = d + -d % gather.SUBLANES  # of the weight tiles (`gather.to_rows`)
+
+        def one(t):
+            return gather.plan_pieces(self.chunk_rows(idx, val, t * chunk)[0],
+                                      self.plan.eval_fetch.piece, rows)
+
+        return jax.lax.map(one, jnp.arange(self.shard_n // chunk))
+
+    def _plan_margins(self) -> gather.PiecePlan:
+        """The binding's margin plan, made on its devices (under the span
+        `bind.margin_plan`); of shapes alone where the rows are (a program
+        lowered for a device this process does not hold)."""
+        make = jax.jit(shard_map(self._margin_plan_shard, mesh=self.mesh,
+                                 in_specs=(P(AXIS), P(AXIS)), out_specs=P(AXIS)))
+        rows = (self.data.indices, self.data.values)
+        if not all(isinstance(a, jax.Array) for a in rows):
+            return jax.eval_shape(make, *rows)
+        with measure.span("bind.margin_plan"):
+            return jax.block_until_ready(make(*rows))
+
+    def record(self) -> str:
+        """The binding's fields of the `train split:` record: the plan's
+        (`kernels.Plan.record`), and where the evaluation's margins are
+        planned, the margin plan's bytes and the share of its entries that
+        are distinct tiles (what the kernel fetches)."""
+        if self.margin_plan is None:
+            return self.plan.record()
+        plan = self.margin_plan
+        share = float(jnp.sum(plan.heads)) / (plan.heads.size * self.plan.eval_fetch.piece
+                                              * (self.data.width or self.data.indices.shape[1]))
+        return (f"{self.plan.record()} margin_plan_bytes="
+                f"{sum(a.nbytes for a in plan)} margin_plan_distinct={share:.4f}")
 
     def _multi_epoch_shard(self, n_epochs, w, opt_state, idx, val, y, key):
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
@@ -733,7 +793,7 @@ class BoundSync:
                               d.labels, key).compile()
 
         def evaluate():
-            self._eval.lower(w0, d.indices, d.values, d.labels).compile()
+            self._eval.lower(w0, d.indices, d.values, d.labels, *self._planned).compile()
 
         return [("epoch", epoch), ("eval", evaluate)]
 
@@ -838,7 +898,7 @@ class BoundSync:
     def predict(self, w: jax.Array) -> np.ndarray:
         """Model predictions for every (true) sample in the bound split,
         the Master.predict fan-out equivalent (Master.scala:61-75)."""
-        preds = self._predict(w, self.data.indices, self.data.values)
+        preds = self._predict(w, self.data.indices, self.data.values, *self._planned)
         preds = np.asarray(preds)[: self.data.n_true]
         # with an output axis [n, C]: the margins' pad lanes taken off
         return preds if preds.ndim == 1 else preds[:, : self.model.n_outputs]
@@ -863,7 +923,8 @@ class BoundSync:
         # first one a wake-up and a launch late: 0.8 ms an evaluation on the
         # v5e, 4 % of an `epsilon` period; PERF.md section 6, PR 34.)
         with measure.span("trainer.evaluate.dispatch", histogram=False, root=False):
-            sums = self._eval(w, self.data.indices, self.data.values, self.data.labels)
+            sums = self._eval(w, self.data.indices, self.data.values, self.data.labels,
+                              *self._planned)
         with measure.span("trainer.evaluate.wait", histogram=False, root=False):
             loss_sum = float(sums[0])
         with measure.span("trainer.evaluate.pull", histogram=False, root=False):

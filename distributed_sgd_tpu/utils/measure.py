@@ -62,6 +62,7 @@ SPAN_NAME_ALLOWLIST = frozenset({
     "slave.async.push",
     "master.async.check",
     "sync.bind.place",
+    "bind.margin_plan",
 })
 MAX_DISTINCT_SPAN_NAMES = 64
 SPAN_OVERFLOW_NAME = "other"

@@ -72,10 +72,11 @@ PLANS = {
         "outputs=103 labels=gathered eval_rows=4096 margin_fetch=gather optimizer=sgd", ("gather", 400),
         False),
     # a chunk's margins in pieces of 256 samples through the margin kernel on
-    # the TPU, 512 a row gather elsewhere; a step's 400 in pieces of 200
+    # the TPU, planned at bind (its rows never change), 512 a row gather
+    # elsewhere; a step's 400 in pieces of 200, sorted and walked every step
     ("amazoncat13k-dismec", "tpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=runs "
-        "outputs=1000 labels=lists eval_rows=256 margin_fetch=distinct optimizer=sgd", ("distinct", 200),
+        "outputs=1000 labels=lists eval_rows=256 margin_fetch=planned optimizer=sgd", ("distinct", 200),
         True),
     ("amazoncat13k-dismec", "cpu"): (
         "kernel=gather margins=merged scatter_shards=1 update=sparse scatter=words "

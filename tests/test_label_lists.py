@@ -330,17 +330,24 @@ def _margin_case(case: str, rng):
 
 
 @pytest.mark.parametrize("lanes", [256, 1024])
-@pytest.mark.parametrize("case", ["all_distinct", "one_id", "pads", "no_row", "pieces", "step"])
-def test_the_margin_kernel_is_the_float64_product(case, lanes):
+@pytest.mark.parametrize("case", ["all_distinct", "one_id", "pads", "no_row", "pieces", "step",
+                                  "binding"])
+def test_the_margin_kernel_is_the_float64_product(case, lanes, monkeypatch):
     """`_margin_tiles` (Pallas' TPU interpret mode) on tiles of 1 KB and
     4 KB: a piece's distinct tiles fetched once into a cache slot, a
     sample's tiles summed in a register, against float64 `x . W` and
     XLA's gather of a tile an entry.  Pads add 0 x tile 0, a padding row's
-    margins are 0."""
+    margins are 0.  The kernel on the pieces' plan (`plan_pieces`: it
+    walks no ids) is the walking kernel bit for bit; and a binding whose
+    evaluation's margins are planned evaluates and predicts what the same
+    binding walking them does, bit for bit (`binding`)."""
     from jax.experimental.pallas import tpu as pltpu
 
     from distributed_sgd_tpu.ops.sparse import SparseBatch
 
+    if case == "binding":
+        _a_planned_binding_is_the_walking_one(lanes, monkeypatch)
+        return
     rng = np.random.default_rng(40)
     ids, values, piece = _margin_case(case, rng)
     w2 = (rng.normal(size=(D, lanes)) * 0.5).astype(np.float32)
@@ -350,6 +357,7 @@ def test_the_margin_kernel_is_the_float64_product(case, lanes):
                                         ids.shape[0]))
     # the sort of one word an entry, then of two (ids that leave no room
     # for the position) with turns that leave a remainder
+    walked = []
     for n_rows, constants in ((D, {}), (2 ** 32, {"unroll": 4})):
         with pltpu.force_tpu_interpret_mode():
             got = np.asarray(jax.jit(lambda w: gather._margin_tiles(
@@ -359,8 +367,102 @@ def test_the_margin_kernel_is_the_float64_product(case, lanes):
         # another order of addition inside a sample, and nothing else
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
         np.testing.assert_allclose(got, xla, rtol=1e-5, atol=2e-6)
+        walked.append(got)
+    with pltpu.force_tpu_interpret_mode():  # the walking kernel's constants
+        planned = np.asarray(jax.jit(lambda w, plan: gather.matvec_rows(
+            batch, w, "planned", piece, plan=plan))(
+                gather.to_tiles(jnp.asarray(w2)), gather.plan_pieces(batch.indices, piece, D)))
+    np.testing.assert_array_equal(planned, walked[0])
     if case == "no_row":
         assert not np.any(got[::3])
+
+
+def _a_planned_binding_is_the_walking_one(lanes: int, monkeypatch):
+    """A binding on tiles of `lanes` lanes (on a steered TPU, the kernels in
+    Pallas' interpret mode) whose evaluation's chunks of 64 samples take
+    the margin kernel in pieces of 16 on the margin plan it made at bind,
+    against the same binding with the evaluation's fetch 'distinct' (the
+    walking kernel): `evaluate` and `predict` equal bit for bit, and the
+    evaluation the float64 reference's."""
+    import dataclasses
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    from distributed_sgd_tpu.ops import mxu
+    from distributed_sgd_tpu.parallel.sync import BoundSync
+
+    outputs = {256: 200, 1024: 1000}[lanes]
+    monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
+    monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 0)
+    monkeypatch.setattr(kernels, "MARGIN_TILES_MIN_LANES", lanes)
+    # a piece's worst case fits at 16 samples of P entries and no more
+    tile = -(-lanes // 1024) * 4096
+    monkeypatch.setattr(kernels, "MARGIN_VMEM_BYTES", tile * (16 * P + 2 * 16))
+    monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: True)
+    data = _listed(_dense(outputs)).slice(slice(0, 128))
+    model = make_model("squared_hinge", LAM, D, regularizer="l2", n_outputs=outputs)
+    planned = SyncEngine(model, make_mesh(1), BATCH, LR, eval_chunk=64, kernel="gather",
+                         virtual_workers=4).bind(data)
+    assert planned.plan.eval_fetch == kernels.Fetch("planned", 16)
+    assert planned.margin_plan.heads.shape == (len(data) // 64, 4)
+    walked = BoundSync(model, planned.mesh, planned.data, BATCH, LR, eval_chunk=64,
+                       virtual_workers=4, plan=dataclasses.replace(
+                           planned.plan, eval_fetch=kernels.Fetch("distinct", 16)))
+    assert walked.margin_plan is None
+    w = _weights(outputs)
+    with pltpu.force_tpu_interpret_mode():
+        got = [(bound.evaluate(w), bound.predict(w)) for bound in (planned, walked)]
+    assert got[0][0] == got[1][0]
+    np.testing.assert_array_equal(got[0][1], got[1][1])
+    loss, acc = reference_lists.evaluate("squared_hinge", w, data.indices, data.values,
+                                         data.labels, LAM)
+    np.testing.assert_allclose(got[0][0], (loss, acc), rtol=1e-5)
+
+
+def _plan_case(case: str, rng):
+    """(indices int32[samples, P], piece) of one case of the margin plan."""
+    samples, piece = (4096, 256) if case == "chunk" else (64, 16)
+    ids = np.minimum(np.exp(rng.uniform(0, np.log(D + 1), (samples, P))).astype(np.int64) - 1,
+                     D - 1).astype(np.int32)
+    if case == "all_distinct":
+        ids = rng.permutation(D)[:samples * P].reshape(samples, P).astype(np.int32)
+    elif case == "one_id":
+        ids[:] = 7
+    elif case == "past_the_rows":  # clamped to the last row, as XLA's gather clamps
+        ids[::5, 3] = D + 17
+        ids[1, :] = 2 ** 31 - 1
+    elif case == "no_row":  # padding rows: every entry a pad of feature 0
+        ids[::3] = 0
+    return ids, piece
+
+
+@pytest.mark.parametrize("case", ["law", "all_distinct", "one_id", "past_the_rows", "no_row",
+                                  "chunk"])
+def test_the_margin_plan_is_each_pieces_distinct_tiles_and_every_entrys_slot(case):
+    """`gather.plan_pieces` (XLA: three batched sorts, no walk) against
+    NumPy: per piece the distinct ids, clamped into the weights' rows,
+    ascending in `to`, their count in `heads`, and every entry's slot, in
+    sample order, naming its own id.  Pieces of 16 samples of a batch of
+    64, and the evaluation's chunk of 4,096 in pieces of 256."""
+    rng = np.random.default_rng(45)
+    ids, piece = _plan_case(case, rng)
+    rows = D + 8  # the weights' rows: D rounded up to whole sublanes, and more
+    plan = jax.jit(lambda i: gather.plan_pieces(i, piece, rows))(jnp.asarray(ids))
+    to, slots, heads = (np.asarray(a) for a in plan)
+    per = piece * P
+    assert to.shape == slots.shape == (len(ids) // piece, per + -per % gather.FACTOR_ALIGN)
+    clamped = np.minimum(ids, rows - 1).reshape(-1, per)
+    for j, entries in enumerate(clamped):
+        distinct = np.unique(entries)
+        assert heads[j] == distinct.size
+        np.testing.assert_array_equal(to[j, :heads[j]], distinct)
+        np.testing.assert_array_equal(to[j, :heads[j]][slots[j, :per]], entries)
+    if case == "all_distinct":
+        assert np.all(heads == per)
+    if case == "one_id":
+        assert np.all(heads == 1) and not np.any(slots[:, :per])
+    if case == "no_row":  # a piece of 16 rows holds 5 or 6 padding rows: feature 0 is a tile
+        assert np.all(to[:, 0] == 0)
 
 
 @pytest.mark.parametrize("samples,width,lanes,piece", [
@@ -540,24 +642,30 @@ def test_an_id_past_the_weights_is_clamped_by_the_margins_and_dropped_by_the_sca
     (1, True, "gather"),       # flat w, kdd2012-logistic's shape
 ])
 def test_only_tiles_on_a_tpu_fetch_distinct_tiles(outputs, on_tpu, fetch, monkeypatch, caplog):
-    """Which bindings take the margin kernel: counted once a binding under
-    `bind.margins.tiles`, said as `margin_fetch=` on the `train split:`
-    record."""
+    """Which bindings take the margin kernel (`fetch`: a step's): counted
+    once a binding under `bind.margins.tiles`, said as `margin_fetch=` on
+    the `train split:` record.  Its evaluation reads the margin plan the
+    binding made once (`planned`, counted under `bind.margins.planned`),
+    and no other binding makes one."""
     from distributed_sgd_tpu.ops import mxu
 
     monkeypatch.setattr(kernels, "SPARSE_UPDATE_MIN_FEATURES", 0)
     monkeypatch.setattr(kernels, "MERGE_MAX_ROWS_PER_ENTRY", 0)
     monkeypatch.setattr(mxu, "blocked_pays_off", lambda device=None: on_tpu)
     data = _dense(outputs) if outputs > 1 else rcv1_like(N, n_features=D, nnz=P, seed=3)
-    counter = metrics_mod.global_metrics().counter("bind.margins.tiles")
-    before = counter.value
+    counters = [metrics_mod.global_metrics().counter(f"bind.margins.{name}")
+                for name in ("tiles", "planned")]
+    before = [counter.value for counter in counters]
     model = make_model("squared_hinge" if outputs > 1 else "logistic", LAM, D,
                        regularizer="l2", n_outputs=outputs)
     trainer = SyncTrainer(model, make_mesh(1), BATCH, LR, virtual_workers=4, kernel="gather",
                           metrics=metrics_mod.Metrics())
     bound = trainer.engine.bind(data)
-    assert bound.plan.eval_fetch.how == fetch
-    assert counter.value == before + (fetch == "distinct")
+    distinct = fetch == "distinct"
+    assert bound.plan.step_fetch.how == fetch
+    assert bound.plan.eval_fetch.how == ("planned" if distinct else fetch)
+    assert [counter.value for counter in counters] == [b + distinct for b in before]
+    assert (bound.margin_plan is not None) == distinct
     if on_tpu:
         return  # a fit would run the TPU's kernels
     with caplog.at_level(logging.INFO, logger="dsgd.trainer"):
@@ -585,7 +693,7 @@ def test_on_a_tpu_the_margins_of_tiles_are_the_gathers(monkeypatch):
         bound = SyncEngine(make_model("squared_hinge", LAM, D, regularizer="l2", n_outputs=1000),
                            make_mesh(1), BATCH, LR, eval_chunk=64, kernel="gather",
                            virtual_workers=4).bind(data, steps_per_epoch=2)
-        assert (bound.plan.eval_fetch.how == "distinct") == on_tpu
+        assert (bound.plan.eval_fetch.how == "planned") == on_tpu
         w, key = _weights(1000), jax.random.PRNGKey(4)
         with pltpu.force_tpu_interpret_mode():
             got.append((np.asarray(bound.epoch(w, key)), np.asarray(bound.evaluate(w))))

@@ -692,7 +692,8 @@ def test_on_a_v5e_wide_rows_are_tiles_a_dma_can_name_and_lists_are_expanded(v5e)
     wide = [line for line in step.split("\n") if re.search(r"= f32\[28800,(1024|8,128)\]", line)]
     assert not wide, wide[:3]
     assert " while(" not in step
-    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
+    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels,
+                                   bound.margin_plan).compile().as_text()
     kernel = _kernels_of(evaluation)
     assert len(kernel) == 1 and "dsgd.margins/margin_tiles" in kernel[0]
     assert "f32[4096,8,128]" in kernel[0]  # a chunk's margins, 16 pieces of 256 samples
@@ -720,8 +721,11 @@ def test_on_a_v5e_only_tiles_take_their_margins_from_the_margin_kernel(
     tiles the step's and the evaluation's margins are ONE custom call
     `margin_tiles` each, under `dsgd.margins` (what `margins_us_per_step`
     and `eval_margins_ms` read), and the binding is counted once under
-    `bind.margins.tiles`; on 128-lane rows and on flat `w` no such call
-    exists and XLA's gather stays."""
+    `bind.margins.tiles` and once under `bind.margins.planned`: the
+    evaluation reads the margin plan made at bind and sorts nothing, the
+    epoch program sorts every step's fresh draw for its kernel as before.
+    On 128-lane rows and on flat `w` no such call exists, no plan is made
+    and XLA's gather stays."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from distributed_sgd_tpu.ops import kernels
@@ -736,25 +740,36 @@ def test_on_a_v5e_only_tiles_take_their_margins_from_the_margin_kernel(
                        shape((rows, width), jnp.float32, sharding=over_rows),
                        shape(labels, jnp.int32, sharding=over_rows), rows, width,
                        label_lists=outputs > 1)
-    counter = metrics_mod.counter("bind.margins.tiles")
-    before = counter.value
+    counters = [metrics_mod.counter(f"bind.margins.{name}") for name in ("tiles", "planned")]
+    before = [counter.value for counter in counters]
     bound = BoundSync(make_model(model, 1e-6, d, regularizer="l2", n_outputs=outputs), mesh,
                       data, 100, 0.1, kernel="gather", virtual_workers=4)
     assert bound.plan.update == "sparse"
-    assert (bound.plan.eval_fetch.how == "distinct") == margin_tiles
-    assert counter.value == before + margin_tiles
-    assert bound.plan.eval_fetch.how == ("distinct" if margin_tiles else "gather")
+    assert [counter.value for counter in counters] == [b + margin_tiles for b in before]
+    assert bound.plan.eval_fetch.how == ("planned" if margin_tiles else "gather")
+    assert bound.plan.step_fetch.how == ("distinct" if margin_tiles else "gather")
+    assert (bound.margin_plan is None) != margin_tiles
     w = shape((d, outputs) if outputs > 1 else (d,), jnp.float32, sharding=everywhere)
     epoch = bound._epoch.lower(w, (), data.indices, data.values, data.labels,
                                shape((2,), jnp.uint32, sharding=everywhere)).compile().as_text()
-    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels).compile().as_text()
-    for text, samples in ((epoch, 400), (evaluation, kernels.margin_tiles(4096, width, 1024))):
+    evaluation = bound._eval.lower(w, data.indices, data.values, data.labels,
+                                   *([bound.margin_plan] if margin_tiles else [])
+                                   ).compile().as_text()
+    piece = kernels.margin_tiles(4096, width, 1024)
+    for text, samples in ((epoch, 400), (evaluation, piece)):
         called = [k for k in _kernels_of(text) if "margin_tiles" in k]
         assert len(called) == margin_tiles and " margin_tiles" not in text.replace(
             "dsgd.margins/margin_tiles", "")
         if margin_tiles:
             assert "dsgd.margins/margin_tiles" in called[0]
             assert f"f32[{4096 if text is evaluation else samples},8,128]" in called[0]
+    if margin_tiles:
+        # a step's draw is sorted for its kernel; the evaluation's chunk is
+        # not: its pieces' plan came with the call
+        step = bound.plan.step_fetch.piece
+        sorts = [line for line in epoch.split("\n") if " sort(" in line]
+        assert any(f"u32[{400 // step},{step * width}]" in line for line in sorts), sorts
+        assert " sort(" not in evaluation
 
 
 # -- (j) a row's label rides in a spare word of the stored row ----------------------
