@@ -181,24 +181,41 @@ class SyncTrainer:
                 jax.profiler.start_trace(self.profile_dir)
                 profiling = profiled = True
             t0 = time.perf_counter()
-            # keyed by absolute epoch index: a resumed run continues the same
-            # batch-sampling stream instead of replaying epochs 0..N-1's keys
-            ek = jax.random.fold_in(base_key, epoch)
             # every span here has three sinks (utils/measure.py): the
             # histogram exporters, a DSGD_TRACE span, and the profiler's
             # clock, where the benchmark lays them against the device's gaps
             with span("trainer.epoch", metrics=self.metrics,
                       node="trainer", epoch=epoch):
-                w = bound_train.epoch(w, ek)
-                jax.block_until_ready(w)
-            epoch_s = time.perf_counter() - t0
-
+                # keyed by absolute epoch index: a resumed run continues the
+                # same batch-sampling stream instead of replaying epochs
+                # 0..N-1's keys; folded inside the epoch program
+                w = bound_train.epoch(w, base_key, at=epoch)
+            # The boundary is one queue and one wait: both evaluation
+            # programs are enqueued behind the epoch program before the host
+            # waits for anything, and their outputs come back in one pull,
+            # so the device has work queued while the host waits.  Both
+            # splits' phases stay (BoundSync.evaluate's): the train split's
+            # dispatch and wait hold both splits', each split's pull and reg
+            # take its own numbers apart.
             with span("trainer.evaluate", metrics=self.metrics,
                       node="trainer", epoch=epoch, split="train"):
-                loss, acc = bound_train.evaluate(w)
+                with span("trainer.evaluate.dispatch", histogram=False, root=False):
+                    outs = (bound_train.dispatch_evaluation(w),
+                            bound_test.dispatch_evaluation(w))
+                with span("trainer.evaluate.wait", histogram=False, root=False):
+                    jax.block_until_ready(w)
+                    epoch_s = time.perf_counter() - t0  # the epoch program's own time
+                    pulled = jax.device_get(outs)
+                train_eval = bound_train.read(pulled[0])
             with span("trainer.evaluate", metrics=self.metrics,
                       node="trainer", epoch=epoch, split="test"):
-                test_loss, test_acc = bound_test.evaluate(w)
+                with span("trainer.evaluate.dispatch", histogram=False, root=False):
+                    pass  # enqueued with the train split's
+                with span("trainer.evaluate.wait", histogram=False, root=False):
+                    pass  # pulled with the train split's
+                test_eval = bound_test.read(pulled[1])
+            loss, acc = train_eval.objective, train_eval.accuracy
+            test_loss, test_acc = test_eval.objective, test_eval.accuracy
             with span("trainer.bookkeeping", metrics=self.metrics,
                       node="trainer", epoch=epoch):
                 record_epoch(result, test_losses_newest_first, epoch,
@@ -207,10 +224,10 @@ class SyncTrainer:
                 self.metrics.histogram("master.sync.acc").record(100 * acc)
                 self.metrics.histogram("master.sync.epoch.seconds").record(epoch_s)
                 nonzero = ""
-                if bound_train.plan.optimizer == "ftrl":  # L1's exact zeros beside the objective
-                    result.nonzero.append(int(jnp.count_nonzero(w)))
-                    result.penalty.append(ftrl.penalty(w, bound_train.ftrl))
-                    nonzero = f" nonzero={result.nonzero[-1]}"
+                if train_eval.nonzero is not None:  # FTRL: L1's exact zeros beside the objective
+                    result.nonzero.append(train_eval.nonzero)
+                    result.penalty.append(train_eval.penalty)
+                    nonzero = f" nonzero={train_eval.nonzero}"
                 log.info(
                     "epoch %d: loss=%.6f acc=%.4f test_loss=%.6f test_acc=%.4f%s (%.2fs)",
                     epoch, loss, acc, test_loss, test_acc, nonzero, epoch_s,

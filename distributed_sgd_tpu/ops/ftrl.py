@@ -177,8 +177,24 @@ def apply(state, g, p: Params):
     return jnp.concatenate([z, n], axis=1)
 
 
+def weight_sums(w) -> jax.Array:
+    """float32 [||w||_1, ||w||^2], what `penalty` is made of.  The
+    evaluation program sums them inside it (`BoundSync._weight_sums`), fenced
+    off so that they compile as `penalty`'s own program does: a fit's
+    objective less `penalty(w)` is then its mean loss to the bit."""
+    w = jnp.asarray(w, jnp.float32)
+    return jnp.stack([jnp.sum(jnp.abs(w)), jnp.sum(w * w)])
+
+
+_weight_sums = jax.jit(weight_sums)
+
+
 def penalty(w, p: Params) -> float:
     """l1 ||w||_1 + (l2 / 2) ||w||^2, the regulariser of the objective an
     FTRL fit reports beside its mean loss."""
-    w = jnp.asarray(w, jnp.float32)
-    return p.l1 * float(jnp.sum(jnp.abs(w))) + 0.5 * p.l2 * float(jnp.sum(w * w))
+    return penalty_of(*jax.device_get(_weight_sums(w)).tolist(), p)
+
+
+def penalty_of(abs_sum: float, squares: float, p: Params) -> float:
+    """`penalty` of the two `weight_sums`."""
+    return p.l1 * abs_sum + 0.5 * p.l2 * squares

@@ -78,6 +78,20 @@ from distributed_sgd_tpu.parallel.mesh import (
 from distributed_sgd_tpu.utils import measure
 
 AXIS = WORKER_AXIS
+# the evaluation program returns an FTRL fit's count of nonzero weights as
+# its quotient and remainder by this: float32 holds each exactly below 2**24
+NONZERO_SPLIT = 1 << 16
+
+
+class Evaluation(NamedTuple):
+    """One split's evaluation as the host reads it (`BoundSync.read`)."""
+
+    objective: float  # penalty + mean sample loss
+    accuracy: float
+    # the objective's regulariser: lam ||w||^2, under FTRL
+    # l1 ||w||_1 + (l2 / 2) ||w||^2
+    penalty: float
+    nonzero: Optional[int]  # under FTRL the weights not exactly 0, else None
 
 
 class ShardedData(NamedTuple):
@@ -592,6 +606,8 @@ class BoundSync:
         return y.astype(jnp.float32)
 
     def _epoch_shard(self, w, opt_state, idx, val, y, key):
+        if isinstance(key, tuple):  # (base key, epoch): the fit's key, folded here (`epoch`)
+            key = jax.random.fold_in(*key)
         key = jax.random.fold_in(key, jax.lax.axis_index(AXIS))
         if self.plan.optimizer == "ftrl":
             # `opt_state` is (z, n); `w` is taken as every binding's programs
@@ -634,7 +650,7 @@ class BoundSync:
         with jax.named_scope("dsgd.margins"):
             return jax.tree.map(lambda a: a[t], plan)
 
-    def _eval_shard(self, w, idx, val, y, plan=None) -> Tuple[jax.Array, jax.Array]:
+    def _eval_shard(self, w, idx, val, y, plan=None) -> jax.Array:
         # chunked scan so the working set stays small; pads (label 0) masked;
         # bind() padded each shard to a multiple of eval_chunk
         chunk = self.eval_chunk
@@ -666,7 +682,25 @@ class BoundSync:
             (loss_sum, hit_sum), _ = jax.lax.scan(body, init, jnp.arange(n_chunks))
             sums = jnp.stack([loss_sum, hit_sum])
         with jax.named_scope("dsgd.allreduce"):
-            return jax.lax.psum(sums, AXIS)
+            sums = jax.lax.psum(sums, AXIS)
+        # the objective's regulariser, of the replicated `w`: the same on
+        # every device, so past the psum
+        with jax.named_scope("dsgd.eval"), jax.named_scope("dsgd.eval_reduce"):
+            return jnp.concatenate([sums, self._weight_sums(w)])
+
+    def _weight_sums(self, w) -> jax.Array:
+        """[||w||^2], under FTRL [||w||_1, ||w||^2, nonzero // NONZERO_SPLIT,
+        nonzero % NONZERO_SPLIT]: `ftrl.weight_sums` fenced off from the rest
+        of the program (so it gives `ftrl.penalty`'s bits), and the count in
+        two parts that float32 holds exactly (`read`)."""
+        w = jnp.asarray(w, jnp.float32)
+        if self.plan.optimizer != "ftrl":
+            return jnp.sum(w * w)[None]
+        fence = jax.lax.optimization_barrier
+        nonzero = jnp.count_nonzero(w)
+        return jnp.concatenate([fence(ftrl.weight_sums(fence(w))), jnp.stack([
+            (nonzero // NONZERO_SPLIT).astype(jnp.float32),
+            (nonzero % NONZERO_SPLIT).astype(jnp.float32)])])
 
     def _predict_shard(self, w, idx, val, plan=None) -> jax.Array:
         chunk = self.eval_chunk
@@ -776,26 +810,34 @@ class BoundSync:
 
     def warmup_thunks(self):
         """Flagship compile thunks for the AOT warmup pass
-        (compile_cache.py, DSGD_COMPILE_CACHE): pre-lower + XLA-compile
-        the per-epoch training program and the eval program at this
-        binding's exact shapes WITHOUT executing them — ``lower(...)``
-        takes the real bound arrays (lowering reads shapes/shardings
-        only; donation consumes nothing until execution) and
-        ``.compile()`` populates the persistent cache, so the fit's first
-        dispatch re-traces cheaply and reads the XLA executable from
-        disk instead of re-running the backend compile."""
+        (compile_cache.py, DSGD_COMPILE_CACHE): pre-lower + XLA-compile,
+        at this binding's exact shapes and WITHOUT executing them, the
+        programs `SyncTrainer.fit` dispatches, with the arguments it passes
+        (`fit_signatures`).  ``lower(...)`` takes the real bound arrays
+        (lowering reads shapes/shardings only; donation consumes nothing
+        until execution) and ``.compile()`` populates the persistent cache,
+        so the fit's first dispatch re-traces cheaply and reads the XLA
+        executable from disk instead of re-running the backend compile."""
+        return [(name, lambda p=program, a=args: p.lower(*a).compile())
+                for name, program, args in self.fit_signatures()]
+
+    def fit_signatures(self):
+        """[(name, program, arguments)] of what a fit dispatches on this
+        binding: the epoch program on the fit's first weights (as made, on
+        no device in particular) with the key folded inside it, again on
+        the weights and state it returns (replicated over the mesh: the
+        epoch program compiles once for each), and the evaluation program
+        on those weights.  Shapes stand where no array does yet."""
         w0 = jnp.zeros(self.model.weight_shape, jnp.float32)
-        key = jax.random.PRNGKey(0)
-        d = self.data
-
-        def epoch():
-            self._epoch.lower(w0, self._state(), d.indices, d.values,
-                              d.labels, key).compile()
-
-        def evaluate():
-            self._eval.lower(w0, d.indices, d.values, d.labels, *self._planned).compile()
-
-        return [("epoch", epoch), ("eval", evaluate)]
+        state = self._state()
+        rows = (self.data.indices, self.data.values, self.data.labels)
+        key = (jax.random.PRNGKey(0), 0)  # `epoch(w, key, at)`'s
+        replicated = NamedSharding(self.mesh, P())
+        out = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=replicated)  # noqa: E731
+        w = out(w0)
+        return [("epoch", self._epoch, (w0, state) + rows + (key,)),
+                ("epoch.again", self._epoch, (w, jax.tree.map(out, state)) + rows + (key,)),
+                ("eval", self._eval, (w,) + rows + self._planned)]
 
     def _maybe_warmup(self) -> None:
         """Kick the background warmup at bind time when the entry point
@@ -822,11 +864,13 @@ class BoundSync:
             for s in self.data.indices.addressable_shards
         ]
 
-    def epoch(self, w: jax.Array, key: jax.Array) -> jax.Array:
+    def epoch(self, w: jax.Array, key: jax.Array, at: Optional[int] = None) -> jax.Array:
+        """One epoch on `key`; given `at`, on `fold_in(key, at)`, folded inside
+        the program (the fit's key of epoch `at`, with no program of its own)."""
         self._check_trainable()
         w, self._opt_state = self._epoch(
             w, self._state(), self.data.indices, self.data.values,
-            self.data.labels, key,
+            self.data.labels, key if at is None else (key, at),
         )
         return w
 
@@ -903,6 +947,31 @@ class BoundSync:
         # with an output axis [n, C]: the margins' pad lanes taken off
         return preds if preds.ndim == 1 else preds[:, : self.model.n_outputs]
 
+    def dispatch_evaluation(self, w: jax.Array) -> jax.Array:
+        """The evaluation program enqueued on `w`, not waited for: its output
+        is the split's loss and hit sums and the sums over `w` its objective
+        adds (`_eval_shard`), a few float32 that `read` takes apart once
+        pulled."""
+        return self._eval(w, self.data.indices, self.data.values, self.data.labels,
+                          *self._planned)
+
+    def read(self, pulled: np.ndarray) -> Evaluation:
+        """The split's evaluation from what `dispatch_evaluation` returned,
+        pulled to the host: no device work.  Phases of the caller's
+        `trainer.evaluate` (see `evaluate`)."""
+        with measure.span("trainer.evaluate.pull", histogram=False, root=False):
+            loss_sum, hit_sum, *weight_sums = pulled.tolist()
+        with measure.span("trainer.evaluate.reg", histogram=False, root=False):
+            if self.plan.optimizer == "ftrl":
+                abs_sum, squares, high, low = weight_sums
+                penalty = ftrl.penalty_of(abs_sum, squares, self.ftrl)
+                nonzero = int(high) * NONZERO_SPLIT + int(low)
+            else:
+                penalty, nonzero = self.model.lam * weight_sums[0], None
+        n = self.data.n_true
+        return Evaluation(penalty + loss_sum / n, hit_sum / (n * self.model.n_outputs),
+                          penalty, nonzero)
+
     def evaluate(self, w: jax.Array) -> Tuple[float, float]:
         """(objective, accuracy) over the bound split.
 
@@ -914,26 +983,15 @@ class BoundSync:
         """
         # phases of the caller's span (trainer.evaluate, master.async.check):
         # no histogram, and no span of their own outside one.  They tile the
-        # call, so the device's idle time inside it is one phase's.  The
-        # wait IS the first pull: its slice and its transfer are enqueued
-        # behind the running program and end with it, so the device idles
-        # there only for that transfer and the host's wake-up; the second
-        # pull and the regulariser are round trips the host starts once it
-        # is awake.  (A `block_until_ready(sums)` before the pulls starts the
-        # first one a wake-up and a launch late: 0.8 ms an evaluation on the
-        # v5e, 4 % of an `epsilon` period; PERF.md section 6, PR 34.)
+        # call, so the device's idle time inside it is one phase's: the
+        # program's dispatch, the one pull of its output (which waits for
+        # it), and the host taking the numbers apart (`read`).  The fit
+        # (`SyncTrainer.fit`) opens the same phases around the same calls.
         with measure.span("trainer.evaluate.dispatch", histogram=False, root=False):
-            sums = self._eval(w, self.data.indices, self.data.values, self.data.labels,
-                              *self._planned)
+            out = self.dispatch_evaluation(w)
         with measure.span("trainer.evaluate.wait", histogram=False, root=False):
-            loss_sum = float(sums[0])
-        with measure.span("trainer.evaluate.pull", histogram=False, root=False):
-            hit_sum = float(sums[1])
-        with measure.span("trainer.evaluate.reg", histogram=False, root=False):
-            reg = (self.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
-                   if self.plan.optimizer != "ftrl" else ftrl.penalty(w, self.ftrl))
-        n = self.data.n_true
-        return reg + loss_sum / n, hit_sum / (n * self.model.n_outputs)
+            pulled = jax.device_get(out)
+        return self.read(pulled)[:2]
 
 
 def local_update(opt, learning_rate: float, g, w, opt_state):

@@ -343,11 +343,16 @@ def test_sync_fit_yields_every_span_with_its_epoch(tmp_path):
         == [(2, "train"), (2, "test")]
     for name in ("trainer.bookkeeping", "trainer.criterion"):
         assert [int(e[3]["epoch"]) for e in by_name[name]] == [2]
-    # each evaluation holds its four phases
+    # each evaluation holds its four phases, in order (the test split's
+    # dispatch and wait too, though its program is enqueued and pulled with
+    # the train split's)
     for phase in EVALUATE_PHASES:
         assert len(by_name[phase]) == 2
         for child, parent in zip(by_name[phase], by_name["trainer.evaluate"]):
             assert _inside(child, parent)
+    for parent in by_name["trainer.evaluate"]:
+        inside = sorted(e for e in line if e[2] in EVALUATE_PHASES and _inside(e, parent))
+        assert [e[2] for e in inside] == list(EVALUATE_PHASES)
     # the phases feed no histogram; the epoch is filed once, under its own name
     hists = trainer.metrics._hists
     assert hists["span.trainer.epoch"].count == 5
@@ -365,12 +370,69 @@ def test_a_short_sync_fit_profiles_its_last_epoch(tmp_path):
         ("trainer.epoch", 1), ("trainer.evaluate", 1), ("trainer.evaluate", 1)]
 
 
-def test_the_four_phases_tile_an_evaluation_in_order(tmp_path):
+def _phase_work(monkeypatch):
+    """[(split, phase, call)] of what the fit does inside each phase span of
+    `trainer.evaluate`: the evaluation programs' dispatch (by the split of
+    the binding), the wait on the weights, the pull, the host's `read`."""
+    from distributed_sgd_tpu.parallel import sync
+
+    open_spans, work = [], []
+    enter, exit_ = measure.span.__enter__, measure.span.__exit__
+
+    def spy_enter(self):
+        open_spans.append((self.name, self._args.get("split")))
+        return enter(self)
+
+    def spy_exit(self, *a):
+        open_spans.pop()
+        return exit_(self, *a)
+
+    def at(call):
+        if open_spans and open_spans[-1][0] in EVALUATE_PHASES:
+            split = next(s for n, s in reversed(open_spans) if n == "trainer.evaluate")
+            work.append((split, open_spans[-1][0], call))
+
+    def spy(name, fn, label=lambda self, *a: None):
+        def called(*a, **k):
+            at((name, label(*a)))
+            return fn(*a, **k)
+        return called
+
+    splits = {}
+    bind = sync.SyncEngine.bind
+
+    def spy_bind(self, data, *a, **k):
+        b = bind(self, data, *a, **k)
+        splits[b] = "train" if not splits else "test"
+        return b
+
+    monkeypatch.setattr(measure.span, "__enter__", spy_enter)
+    monkeypatch.setattr(measure.span, "__exit__", spy_exit)
+    monkeypatch.setattr(sync.SyncEngine, "bind", spy_bind)
+    monkeypatch.setattr(sync.BoundSync, "dispatch_evaluation", spy(
+        "dispatch_evaluation", sync.BoundSync.dispatch_evaluation, lambda b, w: splits[b]))
+    monkeypatch.setattr(jax, "block_until_ready", spy("block_until_ready", jax.block_until_ready))
+    monkeypatch.setattr(jax, "device_get", spy("device_get", jax.device_get))
+    return work
+
+
+def test_the_four_phases_tile_an_evaluation_in_order(tmp_path, monkeypatch):
     """dispatch, wait, pull, reg: one after the other inside `trainer.evaluate`,
     none overlapping, nothing of the call outside them but the spans' own
     entries and exits (so the device's idle time inside an evaluation is the
-    four phases' and the benchmark's split of it sums to the whole)."""
+    four phases' and the benchmark's split of it sums to the whole).  The
+    epoch boundary is one queue: the train split's dispatch enqueues both
+    splits' evaluation programs, its wait waits for the epoch program and
+    pulls both outputs once; the test split's dispatch and wait hold nothing
+    (its program went with the train split's), and each split's pull and reg
+    take its own numbers apart on the host."""
+    work = _phase_work(monkeypatch)
     _sync_fit(tmp_path, 5, criterion=lambda losses: False)
+    per_epoch = [("train", "trainer.evaluate.dispatch", ("dispatch_evaluation", "train")),
+                 ("train", "trainer.evaluate.dispatch", ("dispatch_evaluation", "test")),
+                 ("train", "trainer.evaluate.wait", ("block_until_ready", None)),
+                 ("train", "trainer.evaluate.wait", ("device_get", None))]
+    assert work == per_epoch * 5
     (line,) = _host_spans(tmp_path, {"trainer.evaluate", *EVALUATE_PHASES}).values()
     evaluations = [e for e in line if e[2] == "trainer.evaluate"]
     assert len(evaluations) == 2
@@ -387,9 +449,10 @@ def test_the_four_phases_tile_an_evaluation_in_order(tmp_path):
 @pytest.mark.parametrize("kernel,n_outputs", [
     ("mxu", 1), ("gather", 1), ("dense", 1), ("gather", 3)])
 def test_evaluate_returns_the_formula_of_before_the_phases_bit_for_bit(kernel, n_outputs):
-    """The parent's `evaluate`: the program's two sums pulled with `float()`,
-    the eager `lam*||w||^2`, in that order; the phases add no call of their
-    own (the wait is the first pull)."""
+    """The formula of before the phases: the program's two sums as
+    `float()` pulled them, bit for bit, beside `lam*||w||^2`, which the
+    program now sums itself (to 1e-6 of the eager one); one dispatch and
+    one pull (the wait)."""
     b, _w = _bound(kernel, 4, 1, n_outputs=n_outputs, labels=True)
     shape = (b.model.n_features,) + ((n_outputs,) if n_outputs > 1 else ())
     w = 0.1 * jax.random.normal(jax.random.PRNGKey(7), shape, jnp.float32)
@@ -397,7 +460,9 @@ def test_evaluate_returns_the_formula_of_before_the_phases_bit_for_bit(kernel, n
     loss_sum, hit_sum = float(sums[0]), float(sums[1])
     n = b.data.n_true
     reg = b.model.lam * float(jnp.sum(jnp.asarray(w, jnp.float32) ** 2))
-    assert b.evaluate(w) == (reg + loss_sum / n, hit_sum / (n * b.model.n_outputs))
+    penalty = b.read(np.asarray(sums)).penalty
+    assert penalty == pytest.approx(reg, rel=1e-6)
+    assert b.evaluate(w) == (penalty + loss_sum / n, hit_sum / (n * b.model.n_outputs))
     assert 0.0 < hit_sum < n * b.model.n_outputs and loss_sum > 0.0  # a problem, not zeros
 
 
